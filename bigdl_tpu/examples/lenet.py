@@ -50,10 +50,13 @@ def main(argv=None):
 
 
 def cli():
-    """Console entry: discard main()'s return value so the generated
-    script exits 0 (sys.exit(<object>) would exit 1)."""
+    """Console entry, the owner of its process: turns the compile cache
+    on, and discards main()'s return value so the generated script
+    exits 0 (sys.exit(<object>) would exit 1)."""
+    from bigdl_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()
 
 
 if __name__ == "__main__":
-    main()
+    cli()

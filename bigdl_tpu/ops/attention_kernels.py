@@ -38,6 +38,7 @@ Shapes follow [batch, heads, length, head_dim] ("BHTD").
 from __future__ import annotations
 
 import functools
+import math
 import os
 from typing import NamedTuple, Optional
 
@@ -45,8 +46,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from bigdl_tpu.ops.pallas_compat import pltpu
-from bigdl_tpu.ops.pallas_compat import compiler_params as _compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["dot_product_attention", "flash_attention",
            "flash_attention_partial", "xla_attention"]
@@ -149,21 +149,14 @@ class _FlashCfg(NamedTuple):
 def _dimsem(*sems):
     """TPU compiler hint: which grid dims are parallel (megacore-
     splittable) vs sequential ("arbitrary" — carries a VMEM/output
-    accumulator).  No-op where pltpu is unavailable."""
-    if pltpu is None:  # pragma: no cover
-        return {}
-    return {"compiler_params": _compiler_params()(
+    accumulator)."""
+    return {"compiler_params": pltpu.CompilerParams(
         dimension_semantics=sems)}
 
 
 def _scratch(shape):
     """VMEM scratch allocation (fp32 accumulator carried across the
     sequential k grid dimension)."""
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError(
-            "flash_attention needs jax.experimental.pallas.tpu (VMEM "
-            "scratch accumulators); use force='xla' / "
-            "BIGDL_TPU_ATTENTION=xla on this backend")
     return pltpu.VMEM(shape, jnp.float32)
 
 
@@ -642,11 +635,6 @@ def flash_attention_partial(q, k, v, acc, m, l, *, q_offset, k_offset,
         scale = 1.0 / (d ** 0.5)
     block_q, block_k = _resolve_blocks(block_q, block_k, tq, tk, d)
     assert tq % block_q == 0 and tk % block_k == 0, (tq, tk)
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError(
-            "flash_attention_partial needs jax.experimental.pallas.tpu "
-            "(scalar prefetch); use kernel='xla' / BIGDL_TPU_ATTENTION="
-            "xla on this backend")
     cfg = _FlashCfg(causal=bool(causal), scale=float(scale),
                     block_q=int(block_q), block_k=int(block_k),
                     interpret=bool(interpret))
@@ -762,11 +750,6 @@ def flash_attention_dq_partial(q, k, v, do, lse, delta, *, q_offset,
     tk = k.shape[2]
     block_q, block_k = _resolve_blocks(block_q, block_k, tq, tk, d)
     assert tq % block_q == 0 and tk % block_k == 0, (tq, tk)
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError(
-            "the flash partial backward needs jax.experimental.pallas"
-            ".tpu (scalar prefetch); use kernel='xla' / "
-            "BIGDL_TPU_ATTENTION=xla on this backend")
     cfg = _FlashCfg(bool(causal), float(scale), int(block_q),
                     int(block_k), bool(interpret))
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -807,11 +790,6 @@ def flash_attention_dkv_partial(q, k, v, do, lse, delta, *, q_offset,
     tk = k.shape[2]
     block_q, block_k = _resolve_blocks(block_q, block_k, tq, tk, d)
     assert tq % block_q == 0 and tk % block_k == 0, (tq, tk)
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError(
-            "the flash partial backward needs jax.experimental.pallas"
-            ".tpu (scalar prefetch); use kernel='xla' / "
-            "BIGDL_TPU_ATTENTION=xla on this backend")
     cfg = _FlashCfg(bool(causal), float(scale), int(block_q),
                     int(block_k), bool(interpret))
     kblk = pl.BlockSpec((None, block_k, d), lambda bh, j, i, *r: (bh, j, 0))
@@ -926,10 +904,9 @@ def flash_attention(q, k, v, bias=None, *, causal: bool = False,
 # ---------------------------------------------------------------------------
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    """Backend errors propagate: a chip that fails to come up must not
+    read as "not a TPU" and silently select the XLA path."""
+    return jax.default_backend() == "tpu"
 
 
 def dot_product_attention(q, k, v, bias=None, *, causal: bool = False,
@@ -939,12 +916,46 @@ def dot_product_attention(q, k, v, bias=None, *, causal: bool = False,
     models).  Chooses the Pallas flash kernel on TPU when the sequence
     tiles cleanly, else the XLA path.  ``force`` ∈ {"flash", "xla", None};
     env var BIGDL_TPU_ATTENTION overrides the default choice.
+
+    Traced under a mesh of several devices (the Optimizer's step), the
+    kernel runs per shard: batch over the mesh's batch axes, heads over
+    the tensor-parallel axis.  The compiler partitions XLA ops on its
+    own; a Mosaic kernel it refuses to.
     """
     choice = force or os.environ.get("BIGDL_TPU_ATTENTION")
     tq, tk, d = q.shape[-2], k.shape[-2], q.shape[-1]
     tiles = (tq % 128 == 0 and tk % 128 == 0 and d % 8 == 0
              and (not causal or tq == tk))
     if choice == "flash" or (choice is None and _on_tpu() and tiles):
-        return flash_attention(q, k, v, bias, causal=causal, scale=scale,
-                               interpret=not _on_tpu())
+        kernel = functools.partial(flash_attention, causal=causal,
+                                   scale=scale, interpret=not _on_tpu())
+        from bigdl_tpu.parallel.mesh import ambient_mesh
+        mesh = ambient_mesh()
+        if mesh is None or mesh.size == 1:
+            return kernel(q, k, v, bias)
+        return _per_shard(kernel, mesh, q, k, v, bias)
     return xla_attention(q, k, v, bias, causal=causal, scale=scale)
+
+
+def _per_shard(kernel, mesh, q, k, v, bias):
+    """``kernel(q, k, v, bias)`` under ``shard_map``: the batch dim split
+    over the batch axes of ``mesh`` and the head dim over its ``model``
+    axis, each only where it divides; sequence and depth stay whole."""
+    from jax.sharding import PartitionSpec as P
+    from bigdl_tpu.parallel.mesh import BATCH_AXES, shard_map_compat
+
+    b, h = q.shape[0], q.shape[1]
+    batch = tuple(a for a in BATCH_AXES if a in mesh.axis_names)
+    if not batch or b % math.prod(mesh.shape[a] for a in batch):
+        batch = None
+    head = ("model" if "model" in mesh.axis_names
+            and h % mesh.shape["model"] == 0 else None)
+    spec = P(batch, head, None, None)
+    args, specs = [q, k, v], [spec, spec, spec]
+    if bias is not None:
+        bias = bias.reshape((1,) * (4 - bias.ndim) + bias.shape)
+        args.append(bias)
+        specs.append(P(batch if bias.shape[0] > 1 else None,
+                       head if bias.shape[1] > 1 else None, None, None))
+    return shard_map_compat(kernel, mesh, in_specs=tuple(specs),
+                            out_specs=spec)(*args)

@@ -56,8 +56,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from bigdl_tpu.ops.pallas_compat import pltpu
-from bigdl_tpu.ops.pallas_compat import compiler_params as _compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["fused_matmul_bn", "fused_matmul_bn_reference",
            "fused_block_supported", "fused_conv3x3_bn",
@@ -288,9 +287,7 @@ def _fused_fwd(x, w, mean_in, scale_in, beta_in, kshift, cfg: _Cfg):
 
 
 def _params():
-    if pltpu is None:
-        return None
-    return _compiler_params()(dimension_semantics=("arbitrary",))
+    return pltpu.CompilerParams(dimension_semantics=("arbitrary",))
 
 
 def _fused_bwd(cfg: _Cfg, res, ct):
@@ -642,9 +639,7 @@ def _conv3_specs(b, h, w_, c, co, bh):
 
 
 def _conv3_params():
-    if pltpu is None:
-        return None
-    return _compiler_params()(
+    return pltpu.CompilerParams(
         dimension_semantics=("arbitrary", "arbitrary"))
 
 

@@ -34,7 +34,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["make_mesh", "data_parallel_mesh", "MeshConfig", "P",
            "NamedSharding", "Mesh", "local_device_count",
-           "batch_sharding", "shard_map_compat", "axis_coord_maps",
+           "batch_sharding", "shard_map_compat", "ambient_mesh",
+           "axis_coord_maps",
            "mesh_axes", "pin_replicated"]
 
 
@@ -54,20 +55,27 @@ def pin_replicated(tree, mesh):
         lambda l: jax.lax.with_sharding_constraint(l, rep), tree)
 
 
+def ambient_mesh() -> Optional[Mesh]:
+    """The mesh of the enclosing ``with mesh:`` — how the Optimizer
+    traces and runs its step — or None outside one, and None inside a
+    ``shard_map`` body, where the code is already per-device.  Code
+    that must behave differently when its caller's program spans
+    several devices (a Pallas kernel cannot be partitioned by the
+    compiler) asks here instead of taking a mesh argument through every
+    layer above it.  JAX keeps this context for its own use and offers
+    no public reader of it inside ``jit``."""
+    from jax._src import mesh as _mesh_lib
+    if jax.sharding.get_abstract_mesh().manual_axes:
+        return None
+    mesh = _mesh_lib.thread_resources.env.physical_mesh
+    return None if mesh.empty else mesh
+
+
 def shard_map_compat(f, mesh, in_specs, out_specs):
-    """Version-compat shard_map — THE one spelling every module maps
-    over a mesh with: ``jax.shard_map`` (with ``check_vma=False``)
-    where the public name exists, else the ``jax.experimental``
-    form (with the equivalent ``check_rep=False``).  Older jax
-    releases only ship the experimental name, newer ones deprecate
-    it; call sites that hardcode either spelling break on the other
-    side of that line."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """THE one spelling every module maps over a mesh with:
+    ``jax.shard_map`` with ``check_vma=False``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 logger = logging.getLogger("bigdl_tpu.parallel")
 

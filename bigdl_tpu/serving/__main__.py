@@ -284,4 +284,6 @@ def _fabric_main(args, model, stdin, stdout, stderr) -> int:
 
 
 if __name__ == "__main__":
+    from bigdl_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

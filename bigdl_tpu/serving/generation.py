@@ -201,10 +201,15 @@ class SlotPool:
     def _build_programs(self):
         import jax
         import jax.numpy as jnp
-        model = self.model
         counts = self.trace_counts
 
-        def _decode(caches, tok, index, active):
+        # The model is an ARGUMENT of every program that runs it, never
+        # a closure: closed over, its weights are baked into each
+        # executable as constants — hundreds of MB per program at a
+        # 32k vocabulary, slow to compile and too large for the
+        # persistent compilation cache to keep.
+
+        def _decode(model, caches, tok, index, active):
             counts["decode"] += 1
 
             def one(cache, tok1, idx1):
@@ -236,9 +241,9 @@ class SlotPool:
             return new_caches, new_tok, new_index, \
                 jnp.where(active, nxt, 0)
 
-        self._decode_jit = jax.jit(_decode, donate_argnums=(0, 1, 2))
+        self._decode_jit = jax.jit(_decode, donate_argnums=(1, 2, 3))
 
-        def _prefill(ptoks):
+        def _prefill(model, ptoks):
             t = int(ptoks.shape[1])
             counts["prefill"][t + 1] = counts["prefill"].get(t + 1, 0) + 1
             return model.prefill_kv(ptoks)
@@ -265,7 +270,7 @@ class SlotPool:
 
         self._scatter_jit = jax.jit(_scatter, donate_argnums=(0,))
 
-        def _chunk_prefill(caches, slot_id, toks, index):
+        def _chunk_prefill(model, caches, slot_id, toks, index):
             w = int(toks.shape[0])
             counts["chunk_prefill"][w] = \
                 counts["chunk_prefill"].get(w, 0) + 1
@@ -277,7 +282,7 @@ class SlotPool:
             return model.prefill_chunk(toks[None], index, caches,
                                        slot=slot_id)
 
-        self._chunk_jit = jax.jit(_chunk_prefill, donate_argnums=(0,))
+        self._chunk_jit = jax.jit(_chunk_prefill, donate_argnums=(1,))
 
         def _kv_copy(caches, slot_id, layers_kv, pad, index):
             g = int(pad.shape[0])
@@ -350,7 +355,7 @@ class SlotPool:
         import jax.numpy as jnp
         s = (self.slots,)
         return self._decode_jit.lower(
-            self._cache_avals(),
+            self.model, self._cache_avals(),
             jax.ShapeDtypeStruct(s, jnp.int32),
             jax.ShapeDtypeStruct(s, jnp.int32),
             jax.ShapeDtypeStruct(s, jnp.bool_)).compile()
@@ -368,7 +373,7 @@ class SlotPool:
         import jax.numpy as jnp
         scalar = jax.ShapeDtypeStruct((), jnp.int32)
         return self._chunk_jit.lower(
-            self._cache_avals(), scalar,
+            self.model, self._cache_avals(), scalar,
             jax.ShapeDtypeStruct((width,), jnp.int32), scalar).compile()
 
     def kv_copy_compiled(self, granularity: int):
@@ -453,7 +458,8 @@ class SlotPool:
                 padded[n:] = padded[0]
             ids = np.full((self.prefill_batch,), self.slots, np.int32)
             ids[:n] = np.asarray(slot_ids, np.int32)
-            layers_kv, pads = self._prefill_jit(jnp.asarray(padded[:, :-1]))
+            layers_kv, pads = self._prefill_jit(
+                self.model, jnp.asarray(padded[:, :-1]))
             self.caches = self._scatter_jit(
                 self.caches, jnp.asarray(ids), layers_kv, pads)
         for p, s in zip(prompts, slot_ids):
@@ -469,7 +475,7 @@ class SlotPool:
         to everything already written below ``index``."""
         import jax.numpy as jnp
         self.caches = self._chunk_jit(
-            self.caches, np.int32(slot),
+            self.model, self.caches, np.int32(slot),
             jnp.asarray(np.ascontiguousarray(toks, np.int32)),
             np.int32(index))
 
@@ -510,7 +516,7 @@ class SlotPool:
             self._dirty = False
         tok_d, idx_d, act_d = self._dev
         self.caches, new_tok, new_idx, emit = self._decode_jit(
-            self.caches, tok_d, idx_d, act_d)
+            self.model, self.caches, tok_d, idx_d, act_d)
         self._dev = (new_tok, new_idx, act_d)
         self._emit_active = self.active.copy()
         self._touched[:] = False

@@ -87,14 +87,6 @@ def step_unattributed_fraction() -> Gauge:
         "docs/performance.md 'Attributing an MFU gap')")
 
 
-def bench_rounds_carried_forward_total() -> Counter:
-    return get_registry().counter(
-        "bench_rounds_carried_forward_total",
-        "Bench rounds that re-published prior confirmed on-device "
-        "evidence (carried_forward) because the backend was "
-        "unreachable at bench time")
-
-
 # ---- mesh observability: collectives + fleet -------------------------------
 
 def collective_bytes_total() -> Counter:
@@ -580,7 +572,7 @@ _PREREGISTER = (
     optimizer_data_wait_seconds, optimizer_step_seconds,
     optimizer_validation_seconds, optimizer_retries_total,
     step_phase_seconds, step_mfu_vs_measured,
-    step_unattributed_fraction, bench_rounds_carried_forward_total,
+    step_unattributed_fraction,
     collective_bytes_total, collective_calls_total, fleet_step_skew,
     hbm_bytes_peak,
     training_nonfinite_total, training_anomalies_total, grad_norm,

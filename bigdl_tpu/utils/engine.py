@@ -92,19 +92,12 @@ class Engine:
         per-node accelerator parallelism."""
         with cls._lock:
             s = cls._state
-            if node_number is not None:
-                s.node_number = node_number
-            else:
-                try:
-                    import jax
-                    s.node_number = jax.process_count()
-                except Exception:
-                    s.node_number = 1
-            try:
-                import jax
-                s.local_device_count = jax.local_device_count()
-            except Exception:
-                s.local_device_count = 1
+            import jax
+            # backend errors propagate: a chip that fails to come up
+            # must not read as "1 node, 1 device"
+            s.node_number = (node_number if node_number is not None
+                             else jax.process_count())
+            s.local_device_count = jax.local_device_count()
             if core_number is not None:
                 s.core_number = core_number
             else:
@@ -169,11 +162,8 @@ class Engine:
                     num_processes=num_processes,
                     process_id=process_id, **kw)
             except RuntimeError as e:
-                # already initialized elsewhere (e.g. by the launcher):
-                # jax phrases this "should only be called once" (0.9's
-                # exact text) / "already initialized" in other versions
-                msg = str(e).lower()
-                if "already" not in msg and "once" not in msg:
+                # already initialized elsewhere (e.g. by the launcher)
+                if "should only be called once" not in str(e):
                     raise
             cls._state.dist_inited = True
         cls.init()  # re-discover topology with the global view

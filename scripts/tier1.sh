@@ -57,16 +57,6 @@ if bash "$(dirname "$0")/data_smoke.sh" >"$data_log" 2>&1; then
 else
   echo "data_smoke: FAILED (non-fatal ride-along; see $data_log)"
 fi
-# perf-attribution smoke (attribution invariant on a CPU optimize
-# loop + bench.py carried-forward under a forced probe failure):
-# warn-only ride-along; run scripts/perf_smoke.sh standalone for the
-# fatal form
-perf_log=$(mktemp /tmp/perf_smoke.XXXXXX.log)
-if bash "$(dirname "$0")/perf_smoke.sh" >"$perf_log" 2>&1; then
-  tail -n 1 "$perf_log"
-else
-  echo "perf_smoke: FAILED (non-fatal ride-along; see $perf_log)"
-fi
 # mesh-observability smoke (collective bytes vs HLO cross-check, fleet
 # /statusz + straggler, forced-OOM forensics): warn-only ride-along;
 # run scripts/fleet_smoke.sh standalone for the fatal form

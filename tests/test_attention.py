@@ -53,6 +53,59 @@ def test_flash_kernel_matches_xla(causal):
                                rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("axes,bias_shape", [
+    ({"data": 4, "model": 2}, None),
+    ({"data": 4, "model": 2}, (1, 1, 128, 128)),
+    ({"fsdp": 2, "model": 2, "pipe": 2}, (4, 1, 128, 128)),
+    ({"data": 8}, (4, 6, 128, 128)),    # batch 4 does not divide 8
+])
+def test_flash_runs_per_shard_under_a_mesh(axes, bias_shape):
+    """Traced under a multi-device mesh (the Optimizer's ``with mesh:``)
+    the flash kernel must run inside a shard_map — the TPU compiler
+    refuses to partition a Mosaic kernel — over the batch axes and the
+    tensor-parallel axis, wherever they divide, and give the same
+    values and gradients as the XLA path."""
+    from bigdl_tpu.ops.attention_kernels import dot_product_attention
+    from bigdl_tpu.parallel.mesh import make_mesh
+    mesh = make_mesh(axes)
+    q, k, v = (jnp.asarray(rnd(4, 6, 128, 32, seed=s)) for s in (1, 2, 3))
+    bias = (None if bias_shape is None
+            else jnp.asarray(rnd(*bias_shape, seed=4)))
+    causal = bias is None
+
+    def loss(attend):
+        def f(q, k, v):
+            return jnp.sum(attend(q, k, v, bias, causal=causal) ** 2)
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2)))
+
+    flash = loss(lambda *a, **kw: dot_product_attention(
+        *a, force="flash", **kw))
+    with mesh:
+        assert "shard_map" in str(jax.make_jaxpr(flash)(q, k, v))
+        got = flash(q, k, v)
+    assert "shard_map" not in str(jax.make_jaxpr(flash)(q, k, v))
+    want = loss(xla_attention)(q, k, v)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4)
+
+
+def test_on_tpu_lets_a_backend_error_through(monkeypatch):
+    """A backend that fails to come up must not read as "not a TPU" and
+    silently select the XLA path."""
+    from bigdl_tpu.ops import attention_kernels
+
+    def broken():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+    monkeypatch.setattr(attention_kernels.jax, "default_backend", broken)
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+        attention_kernels._on_tpu()
+    q = jnp.zeros((1, 1, 128, 8))
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+        attention_kernels.dot_product_attention(q, q, q)
+
+
 @pytest.mark.parametrize("causal,with_bias", [
     (False, False), (True, False), (False, True), (True, True)])
 def test_flash_grads_match_xla(causal, with_bias):

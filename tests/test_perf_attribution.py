@@ -1,14 +1,12 @@
 """Perf-attribution layer (telemetry.perf): step-time decomposition
 (phases + residual summing to wall), MFU/roofline accounting, the
-RoundArtifact durable-evidence schema (confirmed vs carried-forward,
-chip-session promotion), the xla_cost cost_breakdown satellite, and the
+RoundArtifact envelope, the xla_cost cost_breakdown satellite, and the
 optimizer's window-record capture end-to-end — including the
 stalled-pipeline chaos run attributing the gap to data-wait.
 """
 
 import json
 import os
-import time
 
 import numpy as np
 import pytest
@@ -154,6 +152,27 @@ class TestRoofline:
             pytest.approx(819e9)
         assert perf.device_hbm_bytes_per_s("weird-chip") is None
 
+    @pytest.mark.parametrize("fn,v5e", [
+        (perf.device_peak_flops, 197e12),
+        (perf.device_hbm_bytes_per_s, 819e9),
+        (perf.device_ici_bytes_per_s, 200e9),
+        (perf.device_dcn_bytes_per_s, 12.5e9),
+    ])
+    def test_unknown_tpu_kind_is_an_error(self, fn, v5e, monkeypatch):
+        # the v5e reports itself as "TPU v5 lite"; the CPU the tests run
+        # on has no peak; a TPU the table does not know must raise so a
+        # utilization never silently disappears on a new chip
+        monkeypatch.delenv("BIGDL_TPU_DCN_BYTES_PER_S", raising=False)
+        assert fn("TPU v5 lite") == pytest.approx(v5e)
+        assert fn("cpu") is None
+        with pytest.raises(ValueError, match="unknown TPU device_kind"):
+            fn("TPU v9 hyper")
+
+    def test_attribution_report_unknown_tpu_raises(self):
+        with pytest.raises(ValueError, match="unknown TPU device_kind"):
+            perf.attribution_report([_rec(), _rec()], flops_per_step=1e12,
+                                    device_kind="TPU v9 hyper")
+
 
 class TestAttributionReport:
     def test_mfu_overall_vs_device(self):
@@ -298,126 +317,20 @@ class TestRoundArtifact:
         assert perf.artifact_payload(loaded)["value"] == 123.4
         assert perf.artifact_timestamp(loaded) == 1234.5
 
-    def test_is_confirmed_rules(self):
-        # new schema: confirmed flag, not carried forward, nonzero value
-        good = perf.make_round_artifact(
-            {"value": 1.0}, kind="bench", timestamp=1.0,
-            confirmed_on_device=True)
-        assert perf.is_confirmed(good)
-        cf = perf.make_round_artifact(
-            {"value": 1.0}, kind="bench", timestamp=1.0,
-            confirmed_on_device=True, carried_forward=True)
-        assert not perf.is_confirmed(cf)  # stale evidence can't launder
-        zero = perf.make_round_artifact(
-            {"value": 0.0}, kind="bench", timestamp=1.0,
-            confirmed_on_device=True)
-        assert not perf.is_confirmed(zero)
-        unconfirmed = perf.make_round_artifact(
-            {"value": 5.0}, kind="bench", timestamp=1.0)
-        assert not perf.is_confirmed(unconfirmed)
-        # legacy flat files: complete real-chip run only
-        assert perf.is_confirmed({"platform": "tpu", "value": 2221.4})
-        assert not perf.is_confirmed({"platform": "tpu", "value": 2221.4,
-                                      "partial": "watchdog"})
-        assert not perf.is_confirmed({"platform": "cpu", "value": 99.0})
-        assert not perf.is_confirmed({"platform": "tpu", "value": 0.0})
-        assert not perf.is_confirmed({"platform": "tpu", "value": 10.0,
-                                      "carried_forward": True})
-        assert not perf.is_confirmed(None)
+    def test_git_revision_spawns_nothing_outside_a_checkout(
+            self, tmp_path, monkeypatch):
+        # the chip tool's copy of the tree is not a git repository
+        def boom(*a, **k):
+            raise AssertionError("git_revision started a process")
+        monkeypatch.setattr(perf.subprocess, "run", boom)
+        assert perf.git_revision(str(tmp_path)) is None
 
-    def test_latest_confirmed_ordering_and_skips(self, tmp_path):
-        d = str(tmp_path)
-        # legacy confirmed file (timestampless: ordered by mtime)
-        legacy = {"metric": "m", "value": 100.0, "platform": "tpu"}
-        with open(os.path.join(d, "BENCH_measured_2026-01-01.json"),
-                  "w") as f:
-            json.dump(legacy, f)
-        old = time.time() - 3600
-        os.utime(os.path.join(d, "BENCH_measured_2026-01-01.json"),
-                 (old, old))
-        # newer envelope artifact wins by its own timestamp
-        art = perf.make_round_artifact(
-            {"metric": "m", "value": 200.0, "platform": "tpu"},
-            kind="bench", timestamp=time.time(), confirmed_on_device=True)
-        perf.write_round_artifact(
-            os.path.join(d, "BENCH_measured_2026-02-02.json"), art)
-        # distractors: a corrupt file, a driver round wrapper, a
-        # carried-forward copy — all skipped
-        with open(os.path.join(d, "BENCH_corrupt.json"), "w") as f:
-            f.write("{not json")
-        with open(os.path.join(d, "BENCH_r05.json"), "w") as f:
-            json.dump({"n": 5, "cmd": "python bench.py", "rc": 0,
-                       "tail": "..."}, f)
-        cf = perf.make_round_artifact(
-            {"value": 999.0, "platform": "tpu"}, kind="bench",
-            timestamp=time.time() + 999, confirmed_on_device=True,
-            carried_forward=True)
-        perf.write_round_artifact(
-            os.path.join(d, "BENCH_measured_2026-03-03.json"), cf)
-
-        path, doc = perf.latest_confirmed(d)
-        assert os.path.basename(path) == "BENCH_measured_2026-02-02.json"
-        assert perf.artifact_payload(doc)["value"] == 200.0
-        # with the envelope gone, the legacy file is still usable
-        os.remove(path)
-        path2, doc2 = perf.latest_confirmed(d)
-        assert os.path.basename(path2) == "BENCH_measured_2026-01-01.json"
-        assert perf.artifact_payload(doc2)["value"] == 100.0
-
-    def test_latest_confirmed_empty_dir(self, tmp_path):
-        assert perf.latest_confirmed(str(tmp_path)) is None
-
-    def test_carried_forward_result(self, tmp_path):
-        art = perf.make_round_artifact(
-            {"metric": "resnet", "value": 2221.4, "platform": "tpu",
-             "mfu_vs_measured": 0.34},
-            kind="bench", timestamp=777.0, confirmed_on_device=True)
-        path = str(tmp_path / "BENCH_measured_prior.json")
-        perf.write_round_artifact(path, art)
-        out = perf.carried_forward_result(art, path, note="wedged")
-        assert out["carried_forward"] is True
-        assert out["carried_forward_from"] == "BENCH_measured_prior.json"
-        assert out["original_timestamp"] == 777.0  # the MEASUREMENT time
-        assert out["value"] == 2221.4  # never a 0.0 round
-        assert out["carried_forward_note"] == "wedged"
-        assert out["schema_version"] == perf.ROUND_ARTIFACT_VERSION
-        # and the copy itself can never become a confirmed source
-        assert not perf.is_confirmed(out)
-
-    def test_promote_chip_session(self, tmp_path):
-        session = {
-            "date": "2026-08-03",
-            "bench": {"metric": "resnet", "value": 2300.0,
-                      "platform": "tpu", "device_kind": "TPU v5 lite"},
-            "real_jpeg_train": {"records_per_sec": 1890.0,
-                                "mode": "real-jpeg-train"},
-            "int8_infer": {"error": "timeout 420s"},  # errors stay out
-        }
-        path = perf.promote_chip_session(
-            session, timestamp=555.0, out_dir=str(tmp_path),
-            git_rev="deadbee")
-        assert os.path.basename(path) == "BENCH_measured_2026-08-03.json"
-        doc = perf.load_round_artifact(path)
-        assert perf.is_confirmed(doc)
-        assert doc["timestamp"] == 555.0 and doc["git_rev"] == "deadbee"
-        payload = perf.artifact_payload(doc)
-        # real-JPEG device training landed IN the round record
-        assert payload["real_jpeg_train"]["records_per_sec"] == 1890.0
-        assert "int8_infer" not in payload
-        # and bench.py's degradation path would find it
-        found = perf.latest_confirmed(str(tmp_path))
-        assert found is not None and found[0] == path
-
-    def test_promote_refuses_unconfirmed_sessions(self, tmp_path):
-        # CPU smoke run / partial / absent bench: nothing to promote
-        for bench in (None, {"error": "timeout"},
-                      {"value": 50.0, "platform": "cpu"},
-                      {"value": 100.0, "platform": "tpu",
-                       "partial": "watchdog"}):
-            session = {"date": "d", "bench": bench}
-            assert perf.promote_chip_session(
-                session, timestamp=1.0, out_dir=str(tmp_path)) is None
-        assert perf.latest_confirmed(str(tmp_path)) is None
+    def test_git_revision_in_a_checkout(self):
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        if not os.path.exists(os.path.join(root, ".git")):
+            pytest.skip("tests are not running from a git checkout")
+        rev = perf.git_revision(root)
+        assert rev and len(rev) >= 7
 
 
 # --------------------------------------------------------------------------
