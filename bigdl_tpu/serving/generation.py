@@ -588,6 +588,28 @@ class _ActiveSlot:
         self.t_decode: Optional[float] = None   # decode-join stamp
 
 
+# The engine thread's wall time, split: every instant of ``_run`` lies in
+# exactly one of these (``other`` is a pass's own time: the reliability
+# sweep, the queue poll, loop bookkeeping; ``idle`` is blocked on an empty
+# queue or in the follower poll), so the seven sum to the thread's life.
+_ENGINE_PHASES = ("admit", "prefill_dispatch", "decode_dispatch",
+                  "readback_wait", "emit", "other", "idle")
+_ENGINE_COUNTERS = _ENGINE_PHASES + (
+    "iterations", "decode_dispatches", "pipeline_drains",
+    "gaps_plain", "gaps_prefill", "gap_seconds_plain",
+    "gap_seconds_prefill", "prefill_positions", "prefill_prompt_tokens",
+    "admitted", "queue_wait_seconds")
+
+
+def _fold_counts(acc: Dict[str, float], eng: Dict[str, float]) -> None:
+    """Move what the engine thread gathered since the last fold into the
+    published sums.  The caller holds the scheduler's lock."""
+    for k, v in acc.items():
+        if v:
+            eng[k] += v
+            acc[k] = 0
+
+
 class _Reservoir:
     """Bounded uniform sample for host-side latency quantiles — the
     serving.metrics reservoir scheme, sized for the engine (TTFT and
@@ -712,7 +734,8 @@ class GenerationScheduler:
         # dedup followers parked on another request's in-flight prefill
         # (engine-thread-only, like _slot_state/_prefill_work)
         self._follow_work: List[_ActiveSlot] = []
-        self._pending: Optional[Tuple] = None   # (emit, n_active, t0)
+        # (step handle, n_active, prefill programs dispatched before it)
+        self._pending: Optional[Tuple] = None
         self._lock = threading.Lock()
         self._outstanding = 0
         self._dedup_leaders = 0
@@ -721,8 +744,19 @@ class GenerationScheduler:
         self._tokens_emitted = 0
         self._decode_steps = 0
         self._prefill_calls = 0
-        self._decode_s = 0.0
-        self._prefill_s = 0.0
+        # always-on measurement of the engine thread.  ``_acc`` belongs
+        # to that thread alone: phase seconds (``_mark``) and counters
+        # gather there between folds.  ``_eng`` is the published sum
+        # that stats() reads; ``_fold_counts`` moves one into the other
+        # under the lock, once a pass in ``_emit_step``.
+        self._acc: Dict[str, float] = dict.fromkeys(_ENGINE_COUNTERS, 0)
+        self._eng: Dict[str, float] = dict.fromkeys(_ENGINE_COUNTERS, 0)
+        self._phase_key = "other"
+        self._phase_t = time.perf_counter()
+        # read-back return of the previous decode step; None across a
+        # pause in which the pool was empty (no step gap spans it)
+        self._t_readback: Optional[float] = None
+        self._prefill_since_dispatch = 0
         self._occupancy_sum = 0
         self._ttft_sum = 0.0
         self._ttft_n = 0
@@ -920,13 +954,21 @@ class GenerationScheduler:
             steps = self._decode_steps
             ttft_q = self._ttft_res.quantiles()
             itl_q = self._itl_res.quantiles()
+            eng = dict(self._eng)
+            # seconds between the read-back returns of consecutive decode
+            # steps: each second of decoding is counted once, however
+            # many steps are in flight
+            decode_s = eng["gap_seconds_plain"] + eng["gap_seconds_prefill"]
             out = {
                 "requests_done": self._requests_done,
                 "tokens_emitted": self._tokens_emitted,
                 "decode_steps": steps,
                 "prefill_calls": self._prefill_calls,
-                "decode_seconds": self._decode_s,
-                "prefill_seconds": self._prefill_s,
+                "decode_seconds": decode_s,
+                # host seconds spent DISPATCHING prefill programs (a jit
+                # call returns when the program is enqueued), not the
+                # device's time in them
+                "prefill_seconds": float(eng["prefill_dispatch"]),
                 "slot_occupancy_mean": (self._occupancy_sum / steps
                                         if steps else 0.0),
                 "queue_to_first_token_s_mean": (
@@ -945,29 +987,73 @@ class GenerationScheduler:
                 "role": self.role,
                 "shed": self._shed,
                 "slots": self.pool.slots,
-                "tokens_per_second": (self._tokens_emitted / self._decode_s
-                                      if self._decode_s else 0.0),
+                "tokens_per_second": (self._tokens_emitted / decode_s
+                                      if decode_s else 0.0),
+                "iterations": eng["iterations"],
+                "engine_phase_seconds": {k: float(eng[k])
+                                         for k in _ENGINE_PHASES},
+                "decode_dispatches": eng["decode_dispatches"],
+                "pipeline_drains": eng["pipeline_drains"],
+                "step_gaps": {"plain": eng["gaps_plain"],
+                              "prefill": eng["gaps_prefill"]},
+                "step_gap_seconds": {
+                    "plain": float(eng["gap_seconds_plain"]),
+                    "prefill": float(eng["gap_seconds_prefill"])},
+                "prefill_positions": eng["prefill_positions"],
+                "prefill_prompt_tokens": eng["prefill_prompt_tokens"],
+                "admitted": eng["admitted"],
+                "queue_wait_seconds": float(eng["queue_wait_seconds"]),
             }
         cache = self._prefix_cache
         out["prefix_cache"] = None if cache is None else cache.stats()
         return out
 
+    # -- measurement (engine thread) ----------------------------------------
+
+    def _mark(self, key: str) -> float:
+        """The engine thread passes from one phase to the next: the time
+        since the last mark goes to the phase that ends here.  One
+        ``perf_counter()`` read; returns it so that callers stamp with
+        the same instant."""
+        now = time.perf_counter()
+        self._acc[self._phase_key] += now - self._phase_t
+        self._phase_key = key
+        self._phase_t = now
+        return now
+
+    def _fold(self) -> None:
+        """Publish what the engine thread gathered, where no decode step
+        will do it soon: before it blocks, and when it ends."""
+        acc = self._acc
+        with self._lock:
+            _fold_counts(acc, self._eng)
+
     # -- the engine loop ----------------------------------------------------
 
     def _run(self) -> None:
-        pool = self.pool
+        self._phase_key = "other"
+        self._phase_t = time.perf_counter()
+        try:
+            self._loop()
+        finally:
+            self._mark("other")
+            self._fold()
+
+    def _loop(self) -> None:
         while True:
             with self._lock:
                 exc = self._die_exc
             if exc is not None:
                 self._fail_in_flight(exc)
                 return              # hard-killed: nothing drains
-            self._sweep_reliability()
-            occupied = sum(1 for st in self._slot_state if st is not None)
             arrivals: List[GenerationRequest] = []
-            if occupied == 0 and self._pending is None \
-                    and not self._prefill_work:
-                first = self._queue.get(timeout=None)
+            if self._pending is None and not self._prefill_work \
+                    and not any(st is not None for st in self._slot_state):
+                with tracing.span("serving/idle"):
+                    self._mark("idle")
+                    self._fold()
+                    first = self._queue.get(timeout=None)
+                    self._mark("other")
                 if first is None:
                     with self._lock:
                         exc = self._die_exc
@@ -975,38 +1061,63 @@ class GenerationScheduler:
                         self._fail_in_flight(exc)
                     return          # closed + drained, nothing in flight
                 arrivals.append(first)
-            free = pool.slots - occupied - len(arrivals)
-            if free > 0:
-                arrivals.extend(self._queue.get_nowait_up_to(free))
-            try:
-                if arrivals or self._prefill_work or self._follow_work:
-                    # admits, prefix copies and prefill chunks only
-                    # extend the donated cache chain — they are safe
-                    # with a decode step in flight (the pipeline is
-                    # drained lazily by _dispatch_decode when the
-                    # mirrors must be pushed), so prefill work does not
-                    # forfeit the async-readback overlap
-                    if arrivals:
+            with tracing.span("serving/iteration",
+                              n_active=self.pool.n_active(),
+                              queued=len(self._queue)):
+                self._iteration(arrivals)
+
+    def _iteration(self, arrivals: List[GenerationRequest]) -> None:
+        """One pass that has work: sweep, poll the queue, admit, at most
+        a budget of prefill programs, one pooled decode step."""
+        pool = self.pool
+        self._acc["iterations"] += 1
+        self._sweep_reliability()
+        occupied = sum(1 for st in self._slot_state if st is not None)
+        free = pool.slots - occupied - len(arrivals)
+        if free > 0:
+            arrivals.extend(self._queue.get_nowait_up_to(free))
+        try:
+            if arrivals or self._prefill_work or self._follow_work:
+                # admits, prefix copies and prefill chunks only
+                # extend the donated cache chain — they are safe
+                # with a decode step in flight (the pipeline is
+                # drained lazily by _dispatch_decode when the
+                # mirrors must be pushed), so prefill work does not
+                # forfeit the async-readback overlap
+                if arrivals:
+                    with tracing.span("serving/admit",
+                                      arrivals=len(arrivals)):
+                        self._mark("admit")
                         self._admit(arrivals)
-                    self._run_prefill()
-                if pool.n_active():
-                    self._dispatch_decode()
-                else:
-                    self._drain_pending()
-                    if self._follow_work and not self._prefill_work:
-                        # every parked follower waits on ANOTHER
-                        # engine's in-flight prefill (a shared cache —
-                        # a local leader would still be in
-                        # _prefill_work): poll, don't spin
+                        self._mark("other")
+                self._run_prefill()
+            if pool.n_active():
+                self._dispatch_decode()
+            else:
+                if self._pending is not None:
+                    # the pool emptied with a step in flight: its
+                    # read-back overlaps nothing
+                    self._acc["pipeline_drains"] += 1
+                self._drain_pending()
+                self._t_readback = None     # no step gap spans the pause
+                if self._follow_work and not self._prefill_work:
+                    # every parked follower waits on ANOTHER
+                    # engine's in-flight prefill (a shared cache —
+                    # a local leader would still be in
+                    # _prefill_work): poll, don't spin
+                    with tracing.span("serving/idle"):
+                        self._mark("idle")
                         time.sleep(0.0005)
-            except Exception as e:  # noqa: BLE001 - engine must survive
-                # the BatchScheduler invariant, kept: a failing dispatch
-                # fails the affected futures and the loop continues —
-                # it never kills the one engine thread and strands
-                # RUNNING futures forever (per-site handlers below fail
-                # narrowly; this belt catches bookkeeping bugs)
-                logger.exception("generation engine iteration failed")
-                self._fail_in_flight(e)
+                        self._mark("other")
+        except Exception as e:  # noqa: BLE001 - engine must survive
+            # the BatchScheduler invariant, kept: a failing dispatch
+            # fails the affected futures and the loop continues —
+            # it never kills the one engine thread and strands
+            # RUNNING futures forever (per-site handlers below fail
+            # narrowly; this belt catches bookkeeping bugs)
+            logger.exception("generation engine iteration failed")
+            self._mark("other")
+            self._fail_in_flight(e)
 
     def _fail_in_flight(self, exc: Exception) -> None:
         """Fail every slot-resident request (decoding or mid-prefill)
@@ -1014,6 +1125,7 @@ class GenerationScheduler:
         arrivals (positions are freshly written before read, so a
         poisoned cache cannot leak into a new occupant)."""
         self._pending = None
+        self._t_readback = None
         self._prefill_work.clear()
         self._follow_work.clear()   # followers are slot-resident: the
         # loop below fails them with everyone else
@@ -1148,13 +1260,16 @@ class GenerationScheduler:
                    else self.default_eos_id)
             st = _ActiveSlot(req, eos, slot)
             self._slot_state[slot] = st
+            # queue phase ends at slot assignment, not at dequeue:
+            # "how long before a slot worked on it", which is what an
+            # SLO debugger wants (and what queue_wait_seconds sums)
+            t_slot = time.perf_counter()
+            self._acc["admitted"] += 1
+            self._acc["queue_wait_seconds"] += t_slot - req.t_enqueue
             if req.trace is not None:
-                # queue phase ends at slot assignment, not at dequeue:
-                # the trace's queue span is "how long before a slot
-                # worked on it", which is what an SLO debugger wants
                 request_trace.record_span(
-                    "request/queue", req.t_enqueue,
-                    time.perf_counter(), ctx=req.trace, slot=slot)
+                    "request/queue", req.t_enqueue, t_slot,
+                    ctx=req.trace, slot=slot)
             try:
                 st.next_pos = self._copy_cached_prefix(st, tel)
             except Exception as e:  # noqa: BLE001 - fail the request,
@@ -1345,16 +1460,19 @@ class GenerationScheduler:
         """The original batched bucket prefill (whole prompt, one
         program call, up to ``prefill_batch`` requests amortized)."""
         pool = self.pool
-        t0 = time.perf_counter()
         try:
-            # tracing.span is its own no-op when telemetry is off;
-            # prefill is not the per-token hot path
+            # the span and the seconds cover the DISPATCH of the prefill
+            # and scatter programs: a jit call returns once its program
+            # is enqueued, and the device runs it later
             with tracing.span("serving/prefill", bucket=bucket,
                               n_real=len(sts)):
+                t0 = self._mark("prefill_dispatch")
                 pool.prefill_into([st.req.prompt for st in sts],
                                   [st.slot for st in sts], bucket)
+                t1 = self._mark("other")
         except Exception as e:  # noqa: BLE001 - fail the chunk, not the
             # engine: the slots were never activated
+            self._mark("other")
             logger.exception("prefill of bucket %d failed", bucket)
             for st in sts:
                 self._release_claims(st)
@@ -1363,9 +1481,15 @@ class GenerationScheduler:
                 self._slot_state[st.slot] = None
             self._sweep_followers(tel)  # a parked follower re-claims
             return
-        t1 = time.perf_counter()
         dt = t1 - t0
+        self._prefill_since_dispatch += 1
+        if bucket > 1:
+            # every lane of the fixed-width batch is computed, dead
+            # lanes and bucket padding included
+            self._acc["prefill_positions"] += \
+                pool.prefill_batch * (bucket - 1)
         for st in sts:
+            self._acc["prefill_prompt_tokens"] += st.end_pos - st.next_pos
             st.next_pos = st.end_pos
             self._store_prefix(st)
             self._release_claims(st)
@@ -1381,7 +1505,6 @@ class GenerationScheduler:
         self._sweep_followers(tel)
         with self._lock:
             self._prefill_calls += 1
-            self._prefill_s += dt
         if tel:
             from bigdl_tpu.telemetry import families
             families.generation_phase_seconds().labels(
@@ -1410,11 +1533,14 @@ class GenerationScheduler:
                 # before they are ever attended
                 toks = np.concatenate(
                     [toks, np.zeros(w - len(toks), np.int32)])
-        t0 = time.perf_counter()
         try:
-            with tracing.span("serving/prefill", chunk=w, index=s):
+            with tracing.span("serving/prefill", chunk=w, index=s,
+                              slot=st.slot):
+                t0 = self._mark("prefill_dispatch")
                 pool.chunk_prefill_into(toks, st.slot, s)
+                t1 = self._mark("other")
         except Exception as e:  # noqa: BLE001 - fail this request only
+            self._mark("other")
             logger.exception("chunked prefill failed for slot %d",
                              st.slot)
             self._release_claims(st)
@@ -1423,7 +1549,6 @@ class GenerationScheduler:
             self._slot_state[st.slot] = None
             self._sweep_followers(tel)  # a parked follower re-claims
             return
-        t1 = time.perf_counter()
         dt = t1 - t0
         if st.req.trace is not None:
             # one child span PER CHUNK: a slow prefill shows up in the
@@ -1431,10 +1556,13 @@ class GenerationScheduler:
             request_trace.record_span(
                 "request/prefill", t0, t1, ctx=st.req.trace,
                 chunk=w, index=s)
-        st.next_pos = end if s + w >= end else s + w
+        new_pos = end if s + w >= end else s + w
+        self._prefill_since_dispatch += 1
+        self._acc["prefill_positions"] += w
+        self._acc["prefill_prompt_tokens"] += new_pos - st.next_pos
+        st.next_pos = new_pos
         with self._lock:
             self._prefill_calls += 1
-            self._prefill_s += dt
         if tel:
             from bigdl_tpu.telemetry import families
             families.generation_phase_seconds().labels(
@@ -1491,25 +1619,38 @@ class GenerationScheduler:
     def _dispatch_decode(self) -> None:
         pool = self.pool
         prev = self._pending
+        drained = False
         if prev is not None and pool.dirty:
             # membership changed since that step was dispatched (an EOS
             # leave) — fold its emit into the mirrors BEFORE the
             # refreshed mirrors are pushed to the device
             self._pending = None
+            self._acc["pipeline_drains"] += 1
             self._emit_step(prev)
             prev = None
+            drained = True
             if pool.n_active() == 0:
                 return
         n_active = pool.n_active()
-        t0 = time.perf_counter()
+        # prefill programs dispatched since the previous decode dispatch
+        # run on the device between that step and this one: the flag
+        # rides this step's handle to the gap its read-back closes
+        after_prefill = self._prefill_since_dispatch
         try:
-            emit = pool.decode_dispatch()
+            with tracing.span("serving/decode_dispatch", n_active=n_active,
+                              after_prefill=after_prefill, drained=drained):
+                self._mark("decode_dispatch")
+                emit = pool.decode_dispatch()
+                self._mark("other")
         except Exception as e:  # noqa: BLE001 - fail the residents,
             # keep the engine thread alive for later arrivals
+            self._mark("other")
             logger.exception("pooled decode step failed")
             self._fail_in_flight(e)
             return
-        self._pending = (emit, n_active, t0)
+        self._prefill_since_dispatch = 0
+        self._acc["decode_dispatches"] += 1
+        self._pending = (emit, n_active, after_prefill)
         if prev is not None:
             # THE async-readback overlap: step N's host-side emit work
             # (int conversion, callbacks, EOS checks) runs while step
@@ -1518,16 +1659,26 @@ class GenerationScheduler:
 
     def _emit_step(self, pending: Tuple) -> None:
         pool = self.pool
-        emit, n_active, t0 = pending
-        out, credit = pool.read_emit_masked(emit)
-        now = time.perf_counter()
-        dt = now - t0
-        emitted = 0
-        # (gap_s, trace-or-None) pairs: the trace rides along so the
-        # inter-token histogram can attach an exemplar and the tail
-        # sampler can watermark the causing request, not just the value
-        gaps: List[tuple] = []
-        finished: List[int] = []
+        emit, n_active, after_prefill = pending
+        with tracing.span("serving/readback"):
+            self._mark("readback_wait")
+            out, credit = pool.read_emit_masked(emit)
+            now = self._mark("emit")
+        # the step gap: from the previous step's read-back return to this
+        # one's.  Consecutive gaps tile the time the pool spent decoding,
+        # whatever is in flight; dispatch-to-read-back intervals overlap
+        # their neighbours under the one-deep pipeline.
+        dt = None if self._t_readback is None else now - self._t_readback
+        self._t_readback = now
+        if dt is not None:
+            kind = "prefill" if after_prefill else "plain"
+            self._acc["gaps_" + kind] += 1
+            self._acc["gap_seconds_" + kind] += dt
+        # who emits, and who ends with this token: decided before the
+        # span opens, because a profiler annotation takes its arguments
+        # when it starts
+        plan: List[tuple] = []
+        n_finished = 0
         for slot in range(pool.slots):
             st = self._slot_state[slot]
             if st is None or st.phase != "decode" or not credit[slot]:
@@ -1535,8 +1686,26 @@ class GenerationScheduler:
             tok = int(out[slot])
             if tok == 0:
                 continue    # slot was not active at this dispatch
+            done = (st.eos_id is not None and tok == st.eos_id) \
+                or len(st.emitted) + 1 >= st.req.max_new_tokens
+            n_finished += done
+            plan.append((slot, st, tok, done))
+        with tracing.span("serving/emit", emitted=len(plan),
+                          finished=n_finished):
+            self._emit_tokens(plan, n_active, dt, now)
+        self._mark("other")
+
+    def _emit_tokens(self, plan: List[tuple], n_active: int,
+                     dt: Optional[float], now: float) -> None:
+        """Host work of one read-back step: callbacks, bookkeeping, the
+        requests that end with this token."""
+        pool = self.pool
+        # (gap_s, trace-or-None) pairs: the trace rides along so the
+        # inter-token histogram can attach an exemplar and the tail
+        # sampler can watermark the causing request, not just the value
+        gaps: List[tuple] = []
+        for _slot, st, tok, _done in plan:
             st.emitted.append(tok)
-            emitted += 1
             if st.t_first is None:
                 st.t_first = now
             else:
@@ -1547,28 +1716,25 @@ class GenerationScheduler:
                     st.req.on_token(tok)
                 except Exception:   # noqa: BLE001 - user callback
                     logger.exception("on_token callback failed")
-            done = (st.eos_id is not None and tok == st.eos_id) \
-                or len(st.emitted) >= st.req.max_new_tokens
-            if done:
-                finished.append(slot)
         tel = telemetry.enabled()
+        acc = self._acc
         # counters BEFORE any future resolves: a waiter whose result()
         # just returned may immediately read stats(), which must
         # already include the iteration that finished it
         with self._lock:
             self._decode_steps += 1
-            self._tokens_emitted += emitted
-            self._decode_s += dt
+            self._tokens_emitted += len(plan)
             self._occupancy_sum += n_active
             for g, _ in gaps:
                 self._itl_res.add(g)
-        for slot in finished:
-            st = self._slot_state[slot]
-            self._finish(st, now, tel)
-            self._slot_state[slot] = None
-            pool.release(slot)
+            _fold_counts(acc, self._eng)
+        for slot, st, _tok, done in plan:
+            if done:
+                self._finish(st, now, tel)
+                self._slot_state[slot] = None
+                pool.release(slot)
         if tel:
-            self._publish_telemetry(dt, n_active, emitted, gaps, now)
+            self._publish_telemetry(dt, n_active, len(plan), gaps, now)
 
     def _finish(self, st: _ActiveSlot, now: float, tel: bool) -> None:
         req = st.req
@@ -1577,12 +1743,16 @@ class GenerationScheduler:
         row[len(req.prompt):len(req.prompt) + len(st.emitted)] = st.emitted
         ttft = ((st.t_first if st.t_first is not None else now)
                 - req.t_enqueue)
+        acc = self._acc
         with self._lock:
-            # before set_result, same reason as the step counters
+            # before set_result, same reason as the step counters (a
+            # prefill-role engine never emits: this is its only fold
+            # between idle periods)
             self._requests_done += 1
             self._ttft_sum += ttft
             self._ttft_n += 1
             self._ttft_res.add(ttft)
+            _fold_counts(acc, self._eng)
         if req.trace is not None:
             # BEFORE set_result: the router's terminal callback files
             # the trace the moment the future resolves, and these
@@ -1609,10 +1779,13 @@ class GenerationScheduler:
                                 prompt_len=len(req.prompt),
                                 new_tokens=len(st.emitted))
 
-    def _publish_telemetry(self, dt: float, n_active: int, emitted: int,
-                           gaps: List[tuple], now: float) -> None:
+    def _publish_telemetry(self, dt: Optional[float], n_active: int,
+                           emitted: int, gaps: List[tuple],
+                           now: float) -> None:
         from bigdl_tpu.telemetry import families
-        families.generation_phase_seconds().labels("decode").observe(dt)
+        if dt is not None:
+            families.generation_phase_seconds().labels(
+                "decode").observe(dt)
         families.generation_slot_occupancy().set(n_active / self.pool.slots)
         itl = families.generation_inter_token_seconds()
         for g, ctx in gaps:

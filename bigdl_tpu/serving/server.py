@@ -33,6 +33,7 @@ from bigdl_tpu.serving.admission import (
 from bigdl_tpu.serving.batching import bucket_sizes
 from bigdl_tpu.serving.metrics import MetricsRegistry
 from bigdl_tpu.serving.scheduler import BatchScheduler
+from bigdl_tpu.telemetry import tracing
 
 __all__ = ["ModelServer", "install_shutdown_signals"]
 
@@ -289,9 +290,11 @@ class ModelServer:
         :class:`~bigdl_tpu.telemetry.request_trace.TraceContext`)
         carries the request's distributed-trace identity so the engine
         files its queue/prefill/decode spans under it."""
-        return self._gen().submit_async(
-            prompt, max_new_tokens, eos_id=eos_id, on_token=on_token,
-            timeout=timeout, deadline=deadline, trace=trace)
+        # the caller's thread: validation, admission, enqueue
+        with tracing.span("serving/submit"):
+            return self._gen().submit_async(
+                prompt, max_new_tokens, eos_id=eos_id, on_token=on_token,
+                timeout=timeout, deadline=deadline, trace=trace)
 
     def cancel_generate(self, fut: Future) -> bool:
         """Best-effort cancel of a generation future — queued requests

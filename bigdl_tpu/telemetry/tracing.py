@@ -21,9 +21,14 @@ Cross-thread propagation: a worker thread adopts a parent with::
         with tracing.span("serving/execute"):
             ...
 
-When telemetry is disabled (the default) ``span`` yields a shared
-no-op — the hot path pays one bool read and one dict-free function
-call, nothing else.
+Two sinks, one call.  ``span`` always enters a
+``jax.profiler.TraceAnnotation`` (a TraceMe: one atomic flag read while
+no profile is being taken), so every span site lands in any
+``jax.profiler`` session an operator or the benchmark runs, on the
+clock of the device's ``XLA Ops`` line.  The ring buffer above records
+only while ``telemetry.enabled()``.  :func:`record_span` is
+retroactive and therefore ring-only: the profiler cannot be handed an
+interval that has already passed.
 """
 
 from __future__ import annotations
@@ -67,6 +72,38 @@ _DEFAULT_CAPACITY = 16384
 
 _ids = itertools.count(1)
 _tls = threading.local()
+
+
+class _NoAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation`` where jax cannot
+    be imported: ``telemetry`` has to work without it."""
+
+    __slots__ = ()
+
+    def __init__(self, name, **args):
+        pass
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+# resolved on the first span(): importing jax here would make
+# ``import bigdl_tpu.telemetry`` pay for (and depend on) it
+_annotation = None
+
+
+def _trace_annotation():
+    global _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except Exception:   # noqa: BLE001 - no jax: the ring still works
+            TraceAnnotation = _NoAnnotation
+        _annotation = TraceAnnotation
+    return _annotation
 
 _buf_lock = threading.Lock()
 _buffer: deque = deque(maxlen=_DEFAULT_CAPACITY)
@@ -134,24 +171,28 @@ def propagate(parent_id: Optional[int]) -> Iterator[None]:
 
 @contextmanager
 def span(name: str, **args) -> Iterator[Optional[int]]:
-    """Record one timed interval.  Yields the span id (None when
-    telemetry is disabled).  ``args`` become Chrome-trace args."""
+    """Record one timed interval: always as a profiler annotation (seen
+    only by a running ``jax.profiler`` session), and into the ring when
+    telemetry is enabled.  Yields the ring's span id (None when
+    telemetry is disabled).  ``args`` become the annotation's metadata
+    and the Chrome-trace args."""
     from bigdl_tpu import telemetry
-    if not telemetry.enabled():
-        yield None
-        return
-    st = _stack()
-    parent = st[-1] if st else None
-    sid = next(_ids)
-    st.append(sid)
-    t0 = time.perf_counter()
-    try:
-        yield sid
-    finally:
-        t1 = time.perf_counter()
-        st.pop()
-        _record(SpanRecord(name, t0, t1, sid, parent,
-                           threading.get_ident(), args or None))
+    with (_annotation or _trace_annotation())(name, **args):
+        if not telemetry.enabled():
+            yield None
+            return
+        st = _stack()
+        parent = st[-1] if st else None
+        sid = next(_ids)
+        st.append(sid)
+        t0 = time.perf_counter()
+        try:
+            yield sid
+        finally:
+            t1 = time.perf_counter()
+            st.pop()
+            _record(SpanRecord(name, t0, t1, sid, parent,
+                               threading.get_ident(), args or None))
 
 
 def record_span(name: str, t_start: float, t_end: float,
@@ -160,7 +201,8 @@ def record_span(name: str, t_start: float, t_end: float,
     ``time.perf_counter`` clock).  Used where the interval's endpoints
     are only known after the fact — e.g. the optimizer's async loss
     drain learns a window's completion time in a worker thread, and a
-    serving request's queue wait starts at its ``t_enqueue``."""
+    serving request's queue wait starts at its ``t_enqueue``.  Ring
+    only: a profiler session takes no interval after the fact."""
     from bigdl_tpu import telemetry
     if not telemetry.enabled():
         return None

@@ -382,6 +382,255 @@ def test_generation_telemetry_off_by_default(lm):
 
 
 # ---------------------------------------------------------------------------
+# the engine's own measurement: phase seconds, step gaps, counters, spans
+# ---------------------------------------------------------------------------
+
+def _timed_engine(lm, **kw):
+    """An engine whose thread's wall time is taken from outside it."""
+    eng = GenerationScheduler(lm, start=False, **kw)
+    wall = {}
+    run = eng._run
+
+    def timed_run():
+        wall["t0"] = time.perf_counter()
+        try:
+            run()
+        finally:
+            wall["t1"] = time.perf_counter()
+
+    eng._run = timed_run
+    eng.start()
+    return eng, wall
+
+
+def test_engine_phase_seconds_sum_to_the_threads_wall_time(lm):
+    rng = np.random.default_rng(21)
+    prompts, max_news = _requests(rng, 6, pmax=30)
+    eng, wall = _timed_engine(lm, slots=2, prefill_chunk=8)
+    try:
+        futs = [eng.submit_async(p, m) for p, m in zip(prompts, max_news)]
+        [f.result(timeout=120) for f in futs]
+        time.sleep(0.05)            # blocked on the empty queue: idle
+        eng.submit(prompts[0], 3, timeout=120)
+    finally:
+        eng.shutdown()
+    stats = eng.stats()
+    phases = stats["engine_phase_seconds"]
+    assert set(phases) == {"admit", "prefill_dispatch", "decode_dispatch",
+                           "readback_wait", "emit", "other", "idle"}
+    assert all(v >= 0.0 for v in phases.values())
+    assert phases["idle"] >= 0.05
+    for key in ("admit", "prefill_dispatch", "decode_dispatch", "emit",
+                "other"):
+        assert phases[key] > 0.0, key
+    life = wall["t1"] - wall["t0"]
+    assert sum(phases.values()) == pytest.approx(life, rel=0.05)
+    # a pass per decode step at least, and the lame-duck drains
+    assert stats["iterations"] >= stats["decode_steps"]
+    assert stats["decode_dispatches"] == stats["decode_steps"]
+
+
+def test_decode_seconds_is_the_sum_of_step_gaps_within_wall_time(lm):
+    """Under the one-deep pipeline a step's dispatch-to-read-back interval
+    covers up to two device steps and overlaps its neighbour's: summed,
+    they exceeded wall time.  Step gaps (read-back to read-back) tile it."""
+    rng = np.random.default_rng(22)
+    eng = GenerationScheduler(lm, slots=2)
+    read = eng.pool.read_emit_masked
+
+    def slow_read(handle):
+        time.sleep(0.02)            # the device, busy with the step
+        return read(handle)
+
+    eng.pool.read_emit_masked = slow_read
+    try:
+        t0 = time.perf_counter()
+        eng.submit(rng.integers(1, 51, 5).astype(np.int32), 12,
+                   timeout=120)
+        wall = time.perf_counter() - t0
+        stats = eng.stats()
+    finally:
+        eng.shutdown()
+    gaps = stats["step_gap_seconds"]
+    assert stats["decode_seconds"] == pytest.approx(
+        gaps["plain"] + gaps["prefill"], rel=1e-12)
+    n = stats["step_gaps"]["plain"] + stats["step_gaps"]["prefill"]
+    assert n >= 11                  # 12 tokens: 12 steps, 11 gaps
+    assert stats["decode_seconds"] >= n * 0.02
+    assert stats["decode_seconds"] <= wall
+    assert stats["tokens_per_second"] == pytest.approx(
+        stats["tokens_emitted"] / stats["decode_seconds"])
+    # the sleeping read-back is where the thread waited
+    assert stats["engine_phase_seconds"]["readback_wait"] >= n * 0.02
+
+
+def test_step_gap_is_flagged_prefill_when_a_chunk_preceded_its_step(lm):
+    """Scripted arrivals: B's long prompt arrives at A's fifth token and
+    is prefilled in chunks between A's decode steps.  The expectation
+    comes from the pool's own call log, not from a chunking rule."""
+    rng = np.random.default_rng(23)
+    eng = GenerationScheduler(lm, slots=2, prefill_chunk=8, start=False)
+    pool = eng.pool
+    log = []
+
+    def logged(name, fn):
+        def call(*a, **k):
+            log.append(name)
+            return fn(*a, **k)
+        return call
+
+    pool.chunk_prefill_into = logged("prefill", pool.chunk_prefill_into)
+    pool.prefill_into = logged("prefill", pool.prefill_into)
+    pool.decode_dispatch = logged("dispatch", pool.decode_dispatch)
+    pool.read_emit_masked = logged("read", pool.read_emit_masked)
+    eng.start()
+    b_prompt = rng.integers(1, 51, 20).astype(np.int32)
+    seen, futs = [], []
+
+    def on_a(_tok):
+        seen.append(_tok)
+        if len(seen) == 5:          # on the engine thread: only enqueues
+            futs.append(eng.submit_async(b_prompt, 6))
+
+    try:
+        a = eng.submit_async(rng.integers(1, 51, 4).astype(np.int32), 30,
+                             on_token=on_a)
+        a.result(timeout=120)
+        futs[0].result(timeout=120)
+        eng.shutdown()
+        stats = eng.stats()
+    finally:
+        eng.shutdown()
+    # A outlives B, so the pool never empties between the first dispatch
+    # and the last read-back: one chain of steps, a gap for every step
+    # but the first, flagged by what was dispatched before ITS dispatch
+    flags, since = [], False
+    for name in log:
+        if name == "prefill":
+            since = True
+        elif name == "dispatch":
+            flags.append(since)
+            since = False
+    assert log.count("read") == len(flags) == stats["decode_steps"]
+    assert stats["step_gaps"] == {"prefill": sum(flags[1:]),
+                                  "plain": len(flags) - 1 - sum(flags[1:])}
+    # B's 19 prefill positions at 8 a chunk: three chunks, three gaps
+    assert stats["step_gaps"]["prefill"] == 3
+    assert stats["step_gaps"]["plain"] >= 25
+    assert stats["pipeline_drains"] == 1        # the pool emptied at the end
+    assert all(v > 0.0 for v in stats["step_gap_seconds"].values())
+
+
+def test_prefill_counters_cover_every_prompt_once(lm):
+    """Prefix cache off: the prefill programs cover positions [0, Tp-1)
+    of every prompt exactly once (the last prompt token is fed to the
+    first decode step); what they compute is that plus padding, dead
+    batch lanes and suffix-aligned overlap."""
+    rng = np.random.default_rng(24)
+    lens = [1, 3, 7, 8, 9, 17, 20, 31]      # bucketed and chunked paths
+    prompts = [rng.integers(1, 51, n).astype(np.int32) for n in lens]
+    eng = GenerationScheduler(lm, slots=3, prefill_chunk=8, prefill_batch=2)
+    try:
+        futs = [eng.submit_async(p, 3) for p in prompts]
+        [f.result(timeout=120) for f in futs]
+        stats = eng.stats()
+    finally:
+        eng.shutdown()
+    assert stats["requests_done"] == len(lens)
+    assert stats["prefill_prompt_tokens"] == sum(n - 1 for n in lens)
+    assert stats["prefill_positions"] >= stats["prefill_prompt_tokens"]
+    assert stats["prefill_calls"] >= 4
+    assert stats["admitted"] == len(lens)
+    # 8 requests into 3 slots: the later ones waited for a slot
+    assert stats["queue_wait_seconds"] > 0.0
+    assert stats["queue_wait_seconds"] <= \
+        stats["queue_to_first_token_s_mean"] * len(lens)
+
+
+def test_engine_pass_spans_nest_under_the_iteration(lm):
+    from bigdl_tpu import telemetry
+    telemetry.enable()
+    telemetry.reset()
+    try:
+        rng = np.random.default_rng(25)
+        server = ModelServer(
+            generator=GenerationScheduler(lm, slots=2, prefill_chunk=8))
+        caller = threading.get_ident()
+        try:
+            futs = [server.submit_generate_async(
+                rng.integers(1, 51, n).astype(np.int32), 4)
+                for n in (5, 20)]
+            [f.result(timeout=120) for f in futs]
+        finally:
+            server.shutdown()
+        spans = telemetry.finished_spans()
+    finally:
+        telemetry.reset()
+        telemetry.disable()
+    by_id = {s.span_id: s for s in spans}
+    names = {s.name for s in spans}
+    assert {"serving/iteration", "serving/idle", "serving/admit",
+            "serving/prefill", "serving/decode_dispatch",
+            "serving/readback", "serving/emit", "serving/submit"} <= names
+    engine_threads = {s.thread for s in spans
+                      if s.name == "serving/iteration"}
+    assert len(engine_threads) == 1 and caller not in engine_threads
+    for s in spans:
+        if s.name in ("serving/admit", "serving/prefill",
+                      "serving/decode_dispatch", "serving/readback",
+                      "serving/emit"):
+            parent = by_id[s.parent_id]
+            assert parent.name == "serving/iteration", s.name
+            assert parent.t_start <= s.t_start and s.t_end <= parent.t_end
+        elif s.name in ("serving/idle", "serving/iteration"):
+            assert s.parent_id is None
+            assert s.thread in engine_threads
+        elif s.name == "serving/submit":
+            assert s.thread == caller
+    disp = [s for s in spans if s.name == "serving/decode_dispatch"]
+    assert all(set(s.args) == {"n_active", "after_prefill", "drained"}
+               for s in disp)
+    # the 20-token prompt was chunked while the other request decoded
+    assert any(s.args["after_prefill"] for s in disp)
+    emits = [s for s in spans if s.name == "serving/emit"]
+    assert sum(s.args["emitted"] for s in emits) == 8
+    assert sum(s.args["finished"] for s in emits) == 2
+
+
+def test_engine_spans_reach_the_profiler_with_telemetry_off(lm, monkeypatch):
+    """The ring stays empty and nothing is allocated into it, while every
+    pass still opens its profiler annotations."""
+    from bigdl_tpu import telemetry
+    from bigdl_tpu.telemetry import tracing
+    seen = []
+
+    class Counting:
+        def __init__(self, name, **args):
+            seen.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    telemetry.disable()
+    telemetry.reset()
+    monkeypatch.setattr(tracing, "_annotation", Counting)
+    eng = GenerationScheduler(lm, slots=2)
+    try:
+        eng.submit(np.arange(1, 6, dtype=np.int32), 4, timeout=120)
+    finally:
+        eng.shutdown()      # the lame-duck step is read before it ends
+    stats = eng.stats()
+    assert telemetry.finished_spans() == []
+    assert seen.count("serving/iteration") == stats["iterations"]
+    assert seen.count("serving/decode_dispatch") == stats["decode_steps"]
+    assert seen.count("serving/readback") == stats["decode_steps"]
+    assert seen.count("serving/emit") == stats["decode_steps"]
+
+
+# ---------------------------------------------------------------------------
 # workload harness (shared with bench.py + serving_gen_smoke.sh)
 # ---------------------------------------------------------------------------
 

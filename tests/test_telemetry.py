@@ -149,6 +149,56 @@ class TestTracing:
         assert tracing.finished_spans() == []
         telemetry.enable()
 
+    def test_disabled_span_still_nests_a_profiler_annotation(
+            self, monkeypatch):
+        """Two sinks: the ring is behind ``telemetry.enabled()``, the
+        ``jax.profiler`` annotation is not — a running profiler session
+        is what makes a span appear in a device trace."""
+        log = []
+
+        class Counting:
+            def __init__(self, name, **args):
+                self.name, self.args = name, args
+
+            def __enter__(self):
+                log.append(("enter", self.name, self.args))
+
+            def __exit__(self, *exc):
+                log.append(("exit", self.name, self.args))
+                return False
+
+        monkeypatch.setattr(tracing, "_annotation", Counting)
+        telemetry.disable()
+        with tracing.span("outer", a=1) as outer:
+            with tracing.span("inner") as inner:
+                assert outer is None and inner is None
+        assert tracing.finished_spans() == []
+        assert log == [("enter", "outer", {"a": 1}), ("enter", "inner", {}),
+                       ("exit", "inner", {}), ("exit", "outer", {"a": 1})]
+        # retroactive spans are ring-only: no annotation, on or off
+        assert tracing.record_span("late", 1.0, 2.0) is None
+        telemetry.enable()
+        assert tracing.record_span("late", 1.0, 2.0) is not None
+        assert len(log) == 4
+        # enabled: both sinks, the annotation outermost
+        with tracing.span("both", b=2):
+            assert log[-1] == ("enter", "both", {"b": 2})
+        assert [s.name for s in tracing.finished_spans()] == ["late", "both"]
+        assert log[-1] == ("exit", "both", {"b": 2})
+
+    def test_span_annotation_is_the_profilers_and_resolved_lazily(self):
+        import jax
+        tracing._annotation = None
+        with tracing.span("resolves"):
+            pass
+        assert tracing._annotation is jax.profiler.TraceAnnotation
+        # an exception passes through both sinks and still records
+        with pytest.raises(KeyError):
+            with tracing.span("raises"):
+                raise KeyError("x")
+        assert [s.name for s in tracing.finished_spans()] == [
+            "resolves", "raises"]
+
     def test_record_span_retroactive(self):
         t0 = time.perf_counter()
         sid = tracing.record_span("retro", t0 - 1.0, t0, note="x")
@@ -581,6 +631,32 @@ def test_serving_spans_and_http_metrics_endpoint():
     names = {s.name for s in tracing.finished_spans()}
     assert {"serving/enqueue", "serving/batch", "serving/execute",
             "serving/reply"} <= names
+
+
+ENGINE_SPANS = ["serving/iteration", "serving/idle", "serving/admit",
+                "serving/prefill", "serving/decode_dispatch",
+                "serving/readback", "serving/emit", "serving/submit"]
+
+
+@pytest.mark.parametrize("name", ENGINE_SPANS)
+def test_engine_span_is_a_literal_site_in_the_catalog(name):
+    """Every span of an engine pass is written as a literal at its site
+    (so the metrics-catalog pass sees it) and listed in the span
+    inventory of docs/observability.md."""
+    import os
+    from bigdl_tpu.analysis.astutil import load_tree
+    from bigdl_tpu.analysis.passes import metrics_catalog
+    tree = load_tree()
+    _, spans = metrics_catalog.collect(tree)
+    sites = [s for s in spans if s.name == name]
+    assert sites and all(s.kind == "span" for s in sites), sites
+    where = {s.file for s in sites}
+    assert where == ({"bigdl_tpu/serving/server.py"}
+                     if name == "serving/submit"
+                     else {"bigdl_tpu/serving/generation.py"})
+    inventory = metrics_catalog.span_inventory(
+        os.path.join(tree.repo, "docs", "observability.md"))
+    assert name in inventory
 
 
 def test_metrics_lint_passes_on_this_tree():
