@@ -243,7 +243,18 @@ class TransformerLM(Module):
         (logits [B, vocab+1], new caches).  Equivalent to column
         ``index`` of the full forward incl. its padding mask (tested),
         at O(T) cost instead of O(T^2).  ``with_logits=False`` skips
-        the vocab projection (prefill)."""
+        the vocab projection (prefill).
+
+        ``index`` is a scalar (every row at the same position:
+        ``generate()``, beam search) or has shape ``[B]``: row ``b`` is
+        written and masked at ``index[b]`` — the serving slot pool,
+        whose rows are requests at positions of their own.  Same
+        mathematics either way; the rank of ``index`` only decides how
+        the new key and value reach the cache (see
+        :meth:`_decode_step_rows`)."""
+        if jnp.ndim(index) == 1:
+            return self._decode_step_rows(tokens, index, caches,
+                                          with_logits)
         pad = jax.lax.dynamic_update_slice(
             caches["pad"], tokens == 0, (0, index))
         x = self.embedding.forward(jnp.maximum(tokens, 1))
@@ -258,6 +269,73 @@ class TransformerLM(Module):
             x, nc = blk.forward(x, self_bias=bias, cache=cache,
                                 cache_index=index)
             new_layers.append(nc)
+        new_caches = {"layers": new_layers, "pad": pad}
+        if not with_logits:
+            return None, new_caches
+        x = self.final_norm(x)
+        logits = jnp.einsum("bth,vh->btv", x, self.embedding.weight)
+        return logits[:, 0], new_caches
+
+    def _decode_step_rows(self, tokens, index, caches, with_logits):
+        """:meth:`decode_step` with a position per row (``index [B]``).
+
+        Each row's new key, value and padding flag go into the cache
+        with a ``dynamic_update_slice`` of their own at
+        ``(b, 0, index[b], 0)`` — a static loop over ``B`` — and
+        attention then reads the cache where it lies.  A
+        ``dynamic_update_slice`` touches only its window, so a donated
+        cache is updated in place in whatever layout it has.
+
+        Why not ``jax.vmap`` of the scalar path: a batched
+        ``dynamic_update_slice`` whose start differs per row becomes a
+        ``scatter`` over the whole cache leaf.  On a TPU a leaf
+        ``f32[B, h, T, d]`` with ``d`` = 64 lives as ``{2,3,1,0}``
+        (positions minor: 64 would fill half a lane tile), the
+        compiler expands the scatter into a loop that wants
+        ``{3,2,1,0}``, and transposes the leaf in and back out: four
+        copies of the whole cache per layer and step, three quarters of
+        a served token's time (PERF.md, PR 27).  The layout is the
+        compiler's to choose, so nothing here names it;
+        tests/test_tpu_compile.py holds the compiled programs to "no
+        cache-sized copy".
+
+        Attention is inlined as in :meth:`prefill_chunk` (the K/V
+        written are the K/V attended), expecting eval mode."""
+        from bigdl_tpu.nn.attention import _residual_dropout
+        from bigdl_tpu.ops import dot_product_attention
+        rows = range(tokens.shape[0])
+
+        def write(leaf, new):
+            # new holds one position per row: [B, h, 1, d] for a K/V
+            # leaf [B, h, T, d], [B, 1] for the padding flags [B, T]
+            new = new.astype(leaf.dtype)
+            for b in rows:
+                at = ((b, index[b]) if leaf.ndim == 2
+                      else (b, 0, index[b], 0))
+                leaf = jax.lax.dynamic_update_slice(leaf, new[b:b + 1], at)
+            return leaf
+
+        pad = write(caches["pad"], tokens == 0)
+        x = self.embedding.forward(jnp.maximum(tokens, 1))
+        x = x * (self.hidden_size ** 0.5)
+        pos = jnp.take(position_encoding(self.max_len, self.hidden_size,
+                                         dtype=x.dtype), index, axis=0)
+        x = x + pos[:, None]
+        bias = incremental_bias(self.max_len, index, pad, x.dtype)
+        new_layers = []
+        for blk, cache in zip(self.blocks, caches["layers"]):
+            attn = blk.self_attn
+            xn = blk.self_norm(x)
+            old = cache["self"]
+            k = write(old["k"], attn._split_heads(attn.k_layer(xn)))
+            v = write(old["v"], attn._split_heads(attn.v_layer(xn)))
+            new_layers.append({"self": {"k": k, "v": v}})
+            q = attn._split_heads(attn.q_layer(xn))
+            ctxt = dot_product_attention(q, k, v, bias)
+            y = attn.output_layer(attn._combine_heads(ctxt))
+            x = x + _residual_dropout(y, blk.ffn_dropout, blk.training)
+            y = blk.ffn(blk.ffn_norm(x))
+            x = x + _residual_dropout(y, blk.ffn_dropout, blk.training)
         new_caches = {"layers": new_layers, "pad": pad}
         if not with_logits:
             return None, new_caches

@@ -79,16 +79,20 @@ def incremental_bias(max_len: int, index, pad=None, dtype=jnp.float32):
     """Additive attention bias over a fixed-size KV cache for one decode
     step at position ``index``: slots beyond ``index`` (not yet written)
     are masked, and so are per-batch padding slots when ``pad``
-    ([B, max_len] bool) is given.  Returns [1,1,1,max_len] (no pad) or
-    [B,1,1,max_len].  Shared by every incremental decoder so the
-    cache-masking logic has one home."""
-    invalid = jnp.arange(max_len) > index
+    ([B, max_len] bool) is given.  ``index`` is a scalar (every row at
+    the same position) or ``[B]`` (row ``b`` at ``index[b]``).  Returns
+    [1,1,1,max_len] (scalar index, no pad) or [B,1,1,max_len].  Shared
+    by every incremental decoder so the cache-masking logic has one
+    home."""
+    if jnp.ndim(index) == 1:
+        index = index[:, None]
+    invalid = jnp.arange(max_len) > index      # [max_len] or [B, max_len]
     if pad is not None:
-        invalid = invalid[None, :] | pad
-        return jnp.where(invalid, _NEG_INF, 0.0).astype(dtype)[
-            :, None, None, :]
-    return jnp.where(invalid, _NEG_INF, 0.0).astype(dtype)[
-        None, None, None, :]
+        invalid = invalid | pad                # [B, max_len]
+    bias = jnp.where(invalid, _NEG_INF, 0.0).astype(dtype)
+    if bias.ndim == 2:
+        return bias[:, None, None, :]
+    return bias[None, None, None, :]
 
 
 def chunk_incremental_bias(max_len: int, index, width: int, pad,

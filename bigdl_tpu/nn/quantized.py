@@ -73,9 +73,12 @@ class QuantizedLinear(Module):
         self.output_size = linear.output_size
 
     def forward(self, x):
-        squeeze = x.ndim == 1
-        if squeeze:
-            x = x[None]
+        # any leading dims, like Linear: every feature vector is a row
+        # with its own window (a [B, T, in] activation of a transformer
+        # block quantizes per position, so padding never moves a real
+        # position's scale)
+        lead = x.shape[:-1]
+        x = x.reshape(-1, self.input_size)
         qx, sx = _quantize_rows(x)                      # [b,in], [b,1]
         acc = jax.lax.dot_general(
             qx, self.qweight,
@@ -84,8 +87,7 @@ class QuantizedLinear(Module):
         out = acc.astype(jnp.float32) * sx * self.wscale[None, :]
         if self.bias is not None:
             out = out + self.bias
-        out = out.astype(x.dtype)
-        return out[0] if squeeze else out
+        return out.astype(x.dtype).reshape(lead + (self.output_size,))
 
 
 class QuantizedSpatialConvolution(Module):

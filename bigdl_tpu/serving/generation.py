@@ -142,7 +142,24 @@ class SlotPool:
     authoritative copy lives on device so decode steps chain without a
     host round-trip, and the mirrors are pushed only when membership
     changes (``_dirty``).  The pooled caches live on device and are
-    donated through every update."""
+    donated through every update.
+
+    **How the pool lies on the chip.**  A K or V leaf is
+    ``[S, heads, max_len, head_dim]``.  With a head size under the 128
+    lanes of a TPU tile (OPT's 64) the compiler stores it as
+    ``{2,3,1,0}``: positions minor, so no lane is padding.  Every
+    program that writes the pool (``_decode``, ``_chunk_prefill``,
+    ``_scatter``, ``_kv_copy``) therefore writes windows with
+    ``dynamic_update_slice`` / ``.at[].set`` at traced starts, which the
+    donated leaf absorbs in place in that layout.  What must never reach
+    the pool is a per-lane position under ``jax.vmap``: the batched
+    ``dynamic_update_slice`` becomes a ``scatter`` whose operand is the
+    whole leaf, the compiler's expansion of it wants ``{3,2,1,0}``, and
+    each K and V of each layer is transposed in and back out on every
+    step (41.9 of a 54.4 ms step at 6 slots of 2048; PERF.md, PR 27).
+    ``tests/test_tpu_compile.py`` compiles the four programs for a
+    described v5e and fails on a pool-sized ``copy``;
+    ``docs/performance.md`` says how to read such a program's HLO."""
 
     def __init__(self, model, slots: int, dtype=None,
                  prefill_batch: int = 4):
@@ -212,15 +229,11 @@ class SlotPool:
         def _decode(model, caches, tok, index, active):
             counts["decode"] += 1
 
-            def one(cache, tok1, idx1):
-                cache1 = jax.tree_util.tree_map(lambda a: a[None], cache)
-                logits, nc = model.decode_step(tok1[None, None], idx1,
-                                               cache1)
-                nxt = (jnp.argmax(model._mask_untrained_logit(logits),
-                                  axis=-1).astype(jnp.int32) + 1)[0]
-                return jax.tree_util.tree_map(lambda a: a[0], nc), nxt
-
-            # every lane writes its position's K/V (S is shape-stable),
+            # ONE batched step over the S slots, each written and masked
+            # at its own position (decode_step's per-row path) — never a
+            # vmap of a batch-1 step: see the class docstring.
+            #
+            # Every lane writes its position's K/V (S is shape-stable),
             # so an INACTIVE lane must write somewhere provably unread:
             # max_len-1 is beyond every prefill query's mask and is
             # always freshly rewritten by an occupant's own decode
@@ -230,7 +243,10 @@ class SlotPool:
             # inactive_rows)
             safe_index = jnp.where(active, index,
                                    jnp.int32(model.max_len - 1))
-            new_caches, nxt = jax.vmap(one)(caches, tok, safe_index)
+            logits, new_caches = model.decode_step(tok[:, None],
+                                                   safe_index, caches)
+            nxt = jnp.argmax(model._mask_untrained_logit(logits),
+                             axis=-1).astype(jnp.int32) + 1
             # the feed advances IN-GRAPH so step N+1 can be dispatched
             # before step N's emit is read on the host; inactive slots
             # still burn a lane (S is shape-stable) — mask their

@@ -163,6 +163,81 @@ def test_slot_pool_cache_donation_hlo_alias(lm):
 
 
 # ---------------------------------------------------------------------------
+# the batched step under the pool: a position per row
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("cache_dtype", ["float32", "bfloat16"])
+def test_per_row_decode_step_equals_batch1_scalar_calls(lm, cache_dtype):
+    """``decode_step`` with ``index [S]`` is S batch-1 steps with a
+    scalar index: the same logits, the same key, value and padding flag
+    written at ``index[b]`` of row ``b``, and nothing else of the cache
+    touched.  Row 1 feeds a padding token; row 3 is an inactive lane
+    parked at ``max_len - 1``, as ``SlotPool._decode`` parks it."""
+    import jax
+    import jax.numpy as jnp
+    dtype = jnp.dtype(cache_dtype)
+    tol = 1e-5 if cache_dtype == "float32" else 2e-2
+    rng = np.random.default_rng(11)
+    index = np.array([3, 17, 40, lm.max_len - 1], np.int32)
+    tokens = np.array([[7], [0], [12], [5]], np.int32)
+    # a cache with history: random K/V and some padding flags below
+    # each row's position, so the step attends over something
+    caches = jax.tree_util.tree_map(
+        lambda a: jnp.asarray(
+            rng.normal(size=a.shape) if a.dtype != bool
+            else rng.random(a.shape) < 0.2, a.dtype),
+        lm.init_cache(len(index), dtype))
+    logits, new = jax.jit(lm.decode_step)(
+        jnp.asarray(tokens), jnp.asarray(index), caches)
+    assert logits.shape == (len(index), 51)
+
+    def leaves(tree):
+        return [np.asarray(a, np.float32)
+                for a in jax.tree_util.tree_leaves(tree)]
+
+    for b, at in enumerate(index):
+        row = jax.tree_util.tree_map(lambda a: a[b:b + 1], caches)
+        want_logits, want = lm.decode_step(
+            jnp.asarray(tokens[b:b + 1]), int(at), row)
+        np.testing.assert_allclose(logits[b], want_logits[0],
+                                   rtol=tol, atol=tol)
+        got = jax.tree_util.tree_map(lambda a: a[b:b + 1], new)
+        for g, w, before in zip(leaves(got), leaves(want), leaves(row)):
+            np.testing.assert_allclose(g, w, rtol=tol, atol=tol)
+            # in place: only position index[b] of the row moved (the
+            # position axis is the last of the flags [1, T] and the
+            # second to last of a K/V leaf [1, h, T, d])
+            axis = 1 if g.ndim == 2 else 2
+            np.testing.assert_array_equal(np.delete(g, at, axis),
+                                          np.delete(before, at, axis))
+            if g.ndim == 4:
+                assert (np.take(g, at, axis)
+                        != np.take(before, at, axis)).all()
+    pad = np.asarray(new["pad"])
+    assert pad[1, 17] and not pad[0, 3] and not pad[2, 40]
+
+
+def test_quantized_model_through_slot_pool_matches_its_generate(lm):
+    """A model whose ``Linear``s are int8 rides the batched step: the
+    per-row path calls the projections as modules, so the pool's greedy
+    rows equal the quantized model's own ``generate()``."""
+    from bigdl_tpu.nn.quantized import QuantizedLinear, quantize
+    qlm = quantize(lm)
+    assert isinstance(qlm.blocks[0].self_attn.k_layer, QuantizedLinear)
+    rng = np.random.default_rng(12)
+    prompts, max_news = _requests(rng, 5)
+    eng = GenerationScheduler(qlm, slots=3, prefill_batch=2)
+    try:
+        rows = [f.result(timeout=120) for f in
+                [eng.submit_async(p, m)
+                 for p, m in zip(prompts, max_news)]]
+    finally:
+        eng.shutdown()
+    for p, m, row in zip(prompts, max_news, rows):
+        np.testing.assert_array_equal(row, solo(qlm, p, m))
+
+
+# ---------------------------------------------------------------------------
 # streaming, stats, validation, admission
 # ---------------------------------------------------------------------------
 

@@ -9,12 +9,18 @@ results or times, and is never reported as a chip run.
 Shapes are the ones the main path uses at real width: the transformer LM
 of ``chip_smoke.py`` and the four ResNet-50 stages at batch 128.
 
+The serving slot pool's donated programs are compiled the same way and
+held to "no copy of a whole pool leaf": a relayout the compiler puts
+inside a program is invisible to every CPU test and to the input/output
+alias, and cost three quarters of a served token's time before PR 27.
+
 Loading libtpu takes its multi-process lock for the life of the process,
 so two processes that compile for a described chip cannot overlap; the
 CPU-platform workers of test_distributed_multiprocess.py never load it.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -180,3 +186,104 @@ def test_ring_attention_flash_blocks_compile_on_four_chips(
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "collective-permute" in text
+
+
+# ---------------------------------------------------------------------------
+# The slot pool's donated programs: no relayout of a pool leaf
+# ---------------------------------------------------------------------------
+
+POOL_SLOTS, POOL_HEADS, POOL_HEAD_DIM, POOL_MAX_LEN = 6, 2, 64, 2048
+POOL_PROGRAMS = ["decode", "chunk_prefill", "scatter", "kv_copy"]
+
+
+def _pool_leaf_ops(text, ops):
+    """Instructions of the optimized HLO ``text`` named in ``ops`` whose
+    result has a pool leaf's shape, with or without the unit axis a
+    vmapped program inserts behind the slots."""
+    leaf = r"f32\[%d,(?:1,)?%d,%d,%d\]" % (
+        POOL_SLOTS, POOL_HEADS, POOL_MAX_LEN, POOL_HEAD_DIM)
+    return re.findall(r"= %s\S* (?:%s)\(" % (leaf, "|".join(ops)), text)
+
+
+@pytest.fixture(scope="module")
+def pool():
+    """A slot pool at head size 64 and 2,048 positions: the smallest
+    whose leaves the TPU compiler stores positions-minor (``{2,3,1,0}``:
+    64 would fill half a lane tile), which is what made the vmapped
+    decode step transpose them.  Two heads, one layer: 12 MB."""
+    from bigdl_tpu.models import transformer_lm
+    from bigdl_tpu.serving.generation import SlotPool
+    lm = transformer_lm(vocab_size=30, num_layers=1,
+                        hidden_size=POOL_HEADS * POOL_HEAD_DIM,
+                        num_heads=POOL_HEADS, filter_size=256,
+                        max_len=POOL_MAX_LEN)
+    return SlotPool(lm, slots=POOL_SLOTS)
+
+
+def _lower_pool_program(pool, program, sharding=None):
+    """``program`` of ``pool`` lowered on abstract arguments, placed on
+    ``sharding`` when given (a described chip) and on the process's own
+    backend otherwise."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def aval(tree):
+        return jax.tree_util.tree_map(
+            lambda a: sds(a.shape, a.dtype), tree)
+
+    model, caches = aval(pool.model), aval(pool.caches)
+    scalar = sds((), jnp.int32)
+    h, d, s = POOL_HEADS, POOL_HEAD_DIM, pool.slots
+
+    def rows(lead, t):
+        return [{"k": sds(lead + (h, t, d), jnp.float32),
+                 "v": sds(lead + (h, t, d), jnp.float32)}
+                for _ in pool.caches["layers"]]
+
+    if program == "decode":
+        return pool._decode_jit.lower(
+            model, caches, sds((s,), jnp.int32), sds((s,), jnp.int32),
+            sds((s,), jnp.bool_))
+    if program == "chunk_prefill":
+        return pool._chunk_jit.lower(
+            model, caches, scalar, sds((64,), jnp.int32), scalar)
+    if program == "scatter":
+        b = pool.prefill_batch
+        return pool._scatter_jit.lower(
+            caches, sds((b,), jnp.int32), rows((b,), 255),
+            sds((b, 255), jnp.bool_))
+    assert program == "kv_copy"
+    return pool._kv_copy_jit.lower(
+        caches, scalar, rows((), 64), sds((64,), jnp.bool_), scalar)
+
+
+@pytest.mark.parametrize("program", POOL_PROGRAMS)
+def test_pool_program_holds_no_pool_sized_copy_on_v5e(v5e, pool, program):
+    """Compiled for the described v5e, no donated pool program copies a
+    whole K or V leaf, and the decode step holds no ``while`` (what the
+    compiler makes of a scatter over the pool).  The vmapped decode step
+    this replaced held four such copies per layer and one loop per
+    scatter; the chunk program never did, which is how it was known
+    that they could go."""
+    text = _lower_pool_program(
+        pool, program, SingleDeviceSharding(v5e.devices[0])
+    ).compile().as_text()
+    assert "dynamic-update-slice" in text or "scatter" in text
+    assert not _pool_leaf_ops(text, ["copy", "copy-start"])
+    if program == "decode":
+        assert " while(" not in text
+
+
+def test_pool_decode_step_lowers_to_no_scatter_over_the_pool(pool):
+    """Runs anywhere: the decode step as JAX hands it to the compiler
+    (StableHLO) writes the pool with ``dynamic_update_slice`` and never
+    with a ``scatter`` whose operand is a pool leaf — the form a
+    per-lane position under ``vmap`` takes, and the cause of the
+    relayout the test above looks for."""
+    text = _lower_pool_program(pool, "decode").as_text()
+    # the first operand's type closes each scatter's update region
+    operands = re.findall(
+        r'"stablehlo\.scatter"\(.*?\}\) : \((tensor<[^>]*>)', text, re.S)
+    assert not [t for t in operands if t.endswith(
+        "x%dx%dxf32>" % (POOL_MAX_LEN, POOL_HEAD_DIM))], operands
+    assert "stablehlo.dynamic_update_slice" in text
