@@ -30,6 +30,23 @@ def param_spec(cfg: Dict[str, Any], prefix: str = "") \
     return spec
 
 
+def param_blocks(cfg: Dict[str, Any]) -> List[Tuple[str, List[int]]]:
+    """The served model in the blocks the check walks, as ``(name,
+    indices into param_spec)``: the embedding, each layer, and the final
+    norm with the head (tied: the embedding's leaf once more)."""
+    paths = [p for p, _ in param_spec(cfg)]
+    blocks = [("embedding", [paths.index(".embedding.weight")])]
+    for i in range(cfg["num_hidden_layers"]):
+        blocks.append((f"blocks[{i}]", [n for n, p in enumerate(paths)
+                                        if p.startswith(f".blocks[{i}].")]))
+    return blocks + [("head", [n for n, p in enumerate(paths) if p.startswith(
+        (".embedding.", ".final_norm."))])]
+
+
+# bytes one pooled decode step of this kind must read, for decode_roofline
+decode_step_bytes = flops.decode_step_bytes
+
+
 def _lm(cfg: Dict[str, Any], max_len: int, **kw):
     from bigdl_tpu.models import transformer_lm
     return transformer_lm(

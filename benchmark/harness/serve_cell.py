@@ -21,6 +21,7 @@ from harness import manifest, result, serve_metrics as sm, traffic
 from harness import trace as tr
 
 TRACE_SECONDS = 4.0
+STALL_S = 0.05     # an engine pass takes 0.01-0.02 s: one this long stood still or idled
 
 
 class Load:
@@ -85,6 +86,30 @@ class Load:
         return [r for r in self.records if r["submit"] is not None]
 
 
+class FullCollections:
+    """Counts the collector's full (oldest generation) passes and their
+    seconds from now on: the whole process stands still for each."""
+
+    def __init__(self):
+        self.passes: List[List[float]] = []     # [start, stop]
+        gc.callbacks.append(self._note)
+
+    def _note(self, phase: str, info: Dict[str, Any]) -> None:
+        if info["generation"] != 2:
+            return
+        if phase == "start":
+            self.passes.append([time.perf_counter(), float("nan")])
+        elif self.passes:
+            self.passes[-1][1] = time.perf_counter()
+
+    def within(self, t0: float, t1: float) -> Dict[str, Any]:
+        inside = [b - a for a, b in self.passes if t0 <= a < t1]
+        return {"full_collections": len(inside), "full_collection_s": sum(inside)}
+
+    def close(self) -> None:
+        gc.callbacks.remove(self._note)
+
+
 def warm_up(server, kind, cfg, reqs: List[Dict[str, Any]], seed: int) -> int:
     """One short request for every prefill program the run's prompts
     need (and with them the decode, scatter and seed programs)."""
@@ -130,6 +155,7 @@ def run(man, cell, cfg, spec, args, t_proc0: float, devices,
     compiles0 = compiles(engine)
 
     load = Load(server, reqs, kind, serving)
+    full_gc = FullCollections()
     traffic_t0 = load.start()
     t_open = traffic_t0 + preroll_s
     t_close = t_open + args.seconds
@@ -142,7 +168,7 @@ def run(man, cell, cfg, spec, args, t_proc0: float, devices,
         import shutil
         trace_dir = os.path.join(manifest.ROOT, ".bench_trace", cell["name"])
         shutil.rmtree(trace_dir, ignore_errors=True)
-        jax.profiler.start_trace(trace_dir)
+        tr.start(trace_dir)
         with jax.profiler.TraceAnnotation("bench.wait_window"):
             time.sleep(min(TRACE_SECONDS, args.seconds))
         jax.profiler.stop_trace()
@@ -167,22 +193,32 @@ def run(man, cell, cfg, spec, args, t_proc0: float, devices,
             continue
         row = np.asarray(fut.result())
         done.append((r["prompt"], row[len(r["prompt"]):]))
+    # a prompt that was being prefilled at the close has no stamp yet, and
+    # without one its part of the window cannot be placed: the engine runs
+    # on for the mix's ``close_grace_s`` so that its first token comes
+    time.sleep(float(spec.get("close_grace_s", 0.0)))
     # stop the engine without draining, then free it for the reference
     server.kill()
     server.shutdown(drain=False, timeout=60.0)
+    full_gc.close()
     del server, engine, load.futures
     gc.collect()
 
     recs = load.submitted()
     gaps = sm.gaps_in_window(recs, t_open, t_close)
     toks = sm.tokens_in_window(recs, t_open, t_close)
+    its = sm.iterations(recs)
+    stalls = [b - a for a, b in zip(its, its[1:])
+              if t_open <= b < t_close and STALL_S <= b - a < 1.0]
     result.say("setup", imports_s=t_imports, weights_s=weights_s,
                warmup_s=warmup_s, warmup_shapes=n_shapes, preroll_s=preroll_s,
                setup_s=setup_s)
     result.say("window", gaps=len(gaps), beyond_p95=int(len(gaps) * 0.05),
                submitted=len(recs), finished=len(done),
                generated=toks["generated"], prompt_tokens=toks["prompt"],
-               backlog_end=backlog_end, queue_end=queue_end)
+               backlog_end=backlog_end, queue_end=queue_end,
+               passes_over_50ms=len(stalls), passes_over_50ms_s=sum(stalls),
+               **full_gc.within(t_open, t_close))
     if len(gaps) < 200:
         raise RuntimeError(f"{len(gaps)} gaps in the window: a 95th "
                            f"percentile needs ten beyond it")
@@ -227,11 +263,40 @@ def run(man, cell, cfg, spec, args, t_proc0: float, devices,
     result.final_line(ok, len(recs), failed, metrics, device, breakdown)
 
 
+def logit_gaps(ref_logits, columns, low_logits=None) -> Dict[str, float]:
+    """For one request's served tokens (``columns`` of the logits, one per
+    row), the widest gap by which a served token's reference logit lies
+    below the reference's best at its position.  With ``low_logits`` (the
+    control's, a lower precision's) also the gap of the token that
+    precision puts first at each position."""
+    import jax.numpy as jnp
+    best = jnp.max(ref_logits, axis=-1)
+
+    def below_best(cols):
+        return best - jnp.take_along_axis(ref_logits, cols[:, None], axis=1)[:, 0]
+    gap = below_best(jnp.asarray(columns))
+    out = {"gap_max": float(jnp.max(gap)), "positions": len(columns)}
+    if low_logits is not None:
+        cgap = below_best(jnp.argmax(low_logits, axis=-1))
+        out.update(control_gap_max=float(jnp.max(cgap)),
+                   control_gap_median=float(jnp.median(cgap)),
+                   gap_median=float(jnp.median(gap)))
+    return out
+
+
 def check(kind, cfg, spec, seed: int, done, control: str = "",
           sample_seed=None) -> bool:
     """Compare a seeded sample of the finished requests, the longest
     among them, with the reference: the widest gap by which a served
-    token's reference logit lies below the reference's best."""
+    token's reference logit lies below the reference's best.
+
+    The reference runs once over each prompt followed by its served
+    tokens (padded to ``max_len``; under a causal mask padding changes
+    nothing before it), a block of the model at a time: the blocks are
+    the outer loop and the sampled requests the inner one, so the device
+    holds one block in float32 and every request's hidden states, never
+    the model.  With ``control`` (a lower precision) a second stream goes
+    through the same blocks in that precision."""
     import jax
     import jax.numpy as jnp
     from harness import weights
@@ -248,27 +313,73 @@ def check(kind, cfg, spec, seed: int, done, control: str = "",
     rest = [i for i in order[1:]]
     rng.shuffle(rest)
     pick += rest[:max(int(spec["check_requests"]) - 1, 0)]
-    param_spec = kind.param_spec(cfg)
-    served_dtype = jnp.dtype(cfg["serving"]["weights_dtype"])
-    leaves = weights.make(param_spec, seed, served_dtype)
-    params = {p: l.astype(jnp.float32) for (p, _), l in zip(param_spec, leaves)}
-    del leaves
-    worst, cworst, n_tok = 0.0, None, 0
+
+    # each request's tokens, and the rows that predicted a served token:
+    # ``rows`` consecutive positions from ``start``, the served ones from
+    # ``off`` among them
+    max_len = cfg["serving"]["max_len"]
     served_pad = -(-int(spec["new_tokens"]["max"]) // 128) * 128
+    seqs, spans = [], []
+    for i in pick:
+        prompt, served = done[i]
+        n_p, n_s = len(prompt), len(served)
+        seq = np.full((1, max_len), ref.TOKEN_BASE, np.int32)
+        seq[0, :n_p] = prompt
+        seq[0, n_p:n_p + n_s - 1] = served[:-1]
+        rows = min(max(served_pad, n_s), max_len)
+        start = min(n_p - 1, max_len - rows)
+        seqs.append(seq)
+        spans.append((start, rows, (n_p - 1) - start, n_s))
+
+    param_spec = kind.param_spec(cfg)
+    blocks = kind.param_blocks(cfg)
+    precisions = ["float32"] + ([control] if control else [])
+    states: Dict[str, List[Any]] = {}     # one [1, max_len, H] per request
+    logits: Dict[str, List[Any]] = {}
+    block_bytes = 0
     with jax.default_matmul_precision("highest"):
-        for i in pick:
-            prompt, served = done[i]
-            out = ref.served_gaps(params, cfg, prompt, served,
-                                  cfg["serving"]["max_len"], served_pad, control)
-            worst = max(worst, out["gap_max"])
-            n_tok += out["positions"]
-            if control:
-                cworst = (out["control_gap_max"] if cworst is None
-                          else min(cworst, out["control_gap_max"]))
-                result.say("control", request=i, **out)
-    del params
+        for b, (_name, params) in enumerate(weights.blocks_float32(
+                param_spec, blocks, seed,
+                jnp.dtype(cfg["serving"]["weights_dtype"]))):
+            block_bytes = max(block_bytes,
+                              sum(l.nbytes for l in params.values()))
+            for prec in precisions:
+                if b == 0:
+                    states[prec] = [ref.embed(params, cfg, jnp.asarray(seq))
+                                    for seq in seqs]
+                elif b < len(blocks) - 1:
+                    states[prec] = [ref.block(params, cfg, b - 1, x, prec)
+                                    for x in states[prec]]
+                else:
+                    logits[prec] = [
+                        ref.head(params, cfg, jax.lax.dynamic_slice_in_dim(
+                            x[0], start, rows), prec)[off:off + n_s]
+                        for x, (start, rows, off, n_s)
+                        in zip(states.pop(prec), spans)]
+            # the host runs ahead of the device: without this the next
+            # block's leaves are allocated while this one's are still held
+            jax.block_until_ready((states, logits))
+            del params
+
+    worst, cworst, n_tok = 0.0, None, 0
+    for r, i in enumerate(pick):
+        out = logit_gaps(
+            logits["float32"][r], np.asarray(done[i][1], np.int32) - ref.TOKEN_BASE,
+            logits[control][r] if control else None)
+        worst = max(worst, out["gap_max"])
+        n_tok += out["positions"]
+        if control:
+            cworst = (out["control_gap_max"] if cworst is None
+                      else min(cworst, out["control_gap_max"]))
+            result.say("control", request=i, **out)
+    del logits
     gc.collect()
+    stats = jax.local_devices()[0].memory_stats() or {}
     result.say("sample", requests=len(pick), served_tokens=n_tok,
-               control_gap_min_of_max=cworst)
+               control_gap_min_of_max=cworst, blocks=len(blocks),
+               largest_block_float32_bytes=block_bytes,
+               hidden_bytes=len(pick) * len(precisions) * max_len
+               * cfg["hidden_size"] * 4,
+               process_peak_bytes=stats.get("peak_bytes_in_use"))
     return result.compare("served_logit_gap_max", worst,
                           cfg["correct"]["serve"]["logit_gap_max"])

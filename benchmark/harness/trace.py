@@ -24,6 +24,20 @@ CONTAINERS = ("while", "conditional", "call")
 _LAYOUT = re.compile(r"\{[^{}]*\}")
 
 
+def start(logdir: str) -> None:
+    """Start the profiler with its Python tracer off.  That tracer (on by
+    default) records every Python call of every thread; under it the
+    serving engine's dispatches grew from 7 to 38 ms of host as the trace
+    filled and the device waited for them (PERF.md, Findings of PRs 27 and
+    28): a traced window then measured the tracer.  The device planes and
+    the ``TraceAnnotation`` spans the readers use are the host tracer's
+    and stay."""
+    import jax
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(logdir, profiler_options=options)
+
+
 def find_xplane(logdir: str) -> str:
     files = sorted(glob.glob(os.path.join(
         logdir, "plugins", "profile", "*", "*.xplane.pb")))

@@ -166,7 +166,7 @@ class Window:
             self.start = recs[WARM_WINDOWS - 1]["t_device_ready"]
         if self.start is not None and self.trace_dir:
             if not self.tracing and self.trace_t0 is None:
-                jax.profiler.start_trace(self.trace_dir)
+                tr.start(self.trace_dir)
                 self.tracing, self.trace_t0 = True, time.perf_counter()
             elif self.tracing and now - self.trace_t0 >= min(
                     TRACE_SECONDS, self.seconds / 4):

@@ -1,8 +1,8 @@
-"""Time the bytes of one pooled decode step need at the HBM peak (every
-weight once, and the keys and values of the positions the active slots
-have live, from shapes and the stamps), over the decode program's mean
-device time in the trace (``jit__decode`` on the XLA Modules line). HBM-
-bound.
+"""Time the bytes of one pooled decode step need at the HBM peak (as the
+configuration's kind counts them, ``decode_step_bytes``: every weight
+once, and the keys and values of the positions the active slots have
+live, from shapes and the stamps), over the decode program's mean device
+time in the trace (``jit__decode`` on the XLA Modules line). HBM-bound.
 """
 LAYER = "kernels"
 SOURCE = "device_trace"
@@ -14,7 +14,7 @@ def read(obs):
     t, traced = obs.get("trace"), obs.get("traced")
     if t is None or not traced or not t.devices():
         return None
-    from harness import flops
+    from harness import manifest
     secs, n = t.module_seconds("jit__decode")
     if n == 0:
         return None
@@ -28,7 +28,7 @@ def read(obs):
         return None
     size = {"bfloat16": 2, "float16": 2, "float32": 4}
     serving = obs["cfg"]["serving"]
-    nbytes = flops.decode_step_bytes(
+    nbytes = manifest.load_kind(obs["kind"]).decode_step_bytes(
         obs["cfg"], live / len(steps), size[serving["weights_dtype"]],
         size[serving["cache_dtype"]])
     return 100.0 * (nbytes / obs["peaks"]["hbm_bytes_per_s"]) / (secs / n)

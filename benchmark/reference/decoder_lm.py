@@ -11,7 +11,7 @@ Weights come as a dict ``path -> array`` from ``harness.weights``.
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, List, Sequence
+from typing import Any, Dict, List
 
 from reference.precision import quantizer
 
@@ -36,48 +36,83 @@ def layer_norm(x, w, b):
     return (x - mean) * jax.lax.rsqrt(var + LN_EPS) * w + b
 
 
-def block(x, params: Dict[str, Any], p: str, heads: int, q):
-    """One decoder block on ``x [B, T, H]`` with a causal mask."""
+# scores of one request's attention, [heads, T, T] float32, above which
+# the queries go in blocks of Q_BLOCK: max_len squared would not fit
+SCORES_BYTES = 1 << 30
+Q_BLOCK = 512
+
+
+def attention(qh, kh, vh, q, in_blocks: bool = False):
+    """Causal attention of ``[B, heads, T, d]`` queries over keys and
+    values of the same length.  ``in_blocks``, where the scores of the
+    whole sequence pass SCORES_BYTES the queries go Q_BLOCK at a time,
+    each block against every key: the same rows of the same softmax."""
+    import jax
+    import jax.numpy as jnp
+    hi = jax.lax.Precision.HIGHEST
+    B, heads, T, d = qh.shape
+    cols = jnp.arange(T)
+
+    def rows(q_rows, first):
+        s = jnp.einsum("bhqd,bhkd->bhqk", q(q_rows), q(kh), precision=hi) \
+            / math.sqrt(d)
+        mask = (first + jnp.arange(q_rows.shape[2]))[:, None] >= cols[None, :]
+        w = jax.nn.softmax(jnp.where(mask, s, NEG), axis=-1)
+        return jnp.einsum("bhqk,bhkd->bhqd", q(w), q(vh), precision=hi)
+
+    if not in_blocks or B * heads * T * T * 4 <= SCORES_BYTES or T % Q_BLOCK:
+        return rows(qh, 0)
+    blocks = qh.reshape(B, heads, T // Q_BLOCK, Q_BLOCK, d).transpose(2, 0, 1, 3, 4)
+    out = jax.lax.map(lambda a: rows(a[0], a[1]),
+                      (blocks, jnp.arange(T // Q_BLOCK) * Q_BLOCK))
+    return out.transpose(1, 2, 0, 3, 4).reshape(B, heads, T, d)
+
+
+def _block(x, w: Dict[str, Any], heads: int, q, in_blocks: bool = False):
+    """One decoder block on ``x [B, T, H]`` with a causal mask; ``w`` holds
+    the block's leaves by their names inside it (``.self_norm.weight``)."""
     import jax
     import jax.numpy as jnp
     hi = jax.lax.Precision.HIGHEST
     B, T, H = x.shape
     d = H // heads
 
-    def lin(a, w):
-        return jnp.einsum("bti,oi->bto", q(a), q(params[w]), precision=hi)
+    def lin(a, name):
+        return jnp.einsum("bti,oi->bto", q(a), q(w[name]), precision=hi)
 
     def split(a):
         return a.reshape(B, T, heads, d).transpose(0, 2, 1, 3)
 
-    xn = layer_norm(x, params[p + ".self_norm.weight"], params[p + ".self_norm.bias"])
-    qh = split(lin(xn, p + ".self_attn.q_layer.weight"))
-    kh = split(lin(xn, p + ".self_attn.k_layer.weight"))
-    vh = split(lin(xn, p + ".self_attn.v_layer.weight"))
-    s = jnp.einsum("bhqd,bhkd->bhqk", q(qh), q(kh), precision=hi) / math.sqrt(d)
-    mask = jnp.tril(jnp.ones((T, T), bool))
-    s = jnp.where(mask, s, NEG)
-    w = jax.nn.softmax(s, axis=-1)
-    ctx = jnp.einsum("bhqk,bhkd->bhqd", q(w), q(vh), precision=hi)
+    xn = layer_norm(x, w[".self_norm.weight"], w[".self_norm.bias"])
+    ctx = attention(split(lin(xn, ".self_attn.q_layer.weight")),
+                    split(lin(xn, ".self_attn.k_layer.weight")),
+                    split(lin(xn, ".self_attn.v_layer.weight")), q, in_blocks)
     ctx = ctx.transpose(0, 2, 1, 3).reshape(B, T, H)
-    x = x + lin(ctx, p + ".self_attn.output_layer.weight")
-    y = layer_norm(x, params[p + ".ffn_norm.weight"], params[p + ".ffn_norm.bias"])
-    y = jax.nn.relu(lin(y, p + ".ffn.filter_layer.weight")
-                    + params[p + ".ffn.filter_layer.bias"])
-    y = lin(y, p + ".ffn.output_layer.weight") + params[p + ".ffn.output_layer.bias"]
+    x = x + lin(ctx, ".self_attn.output_layer.weight")
+    y = layer_norm(x, w[".ffn_norm.weight"], w[".ffn_norm.bias"])
+    y = jax.nn.relu(lin(y, ".ffn.filter_layer.weight")
+                    + w[".ffn.filter_layer.bias"])
+    y = lin(y, ".ffn.output_layer.weight") + w[".ffn.output_layer.bias"]
     return x + y
+
+
+def _inside(params: Dict[str, Any], p: str) -> Dict[str, Any]:
+    """The leaves under the path ``p``, by their names inside it."""
+    return {k[len(p):]: v for k, v in params.items() if k.startswith(p + ".")}
+
+
+def _embed(emb, tokens, hidden: int):
+    return emb[tokens - 1] * math.sqrt(hidden) + positions(tokens.shape[1], hidden)
 
 
 def hidden_states(params: Dict[str, Any], tokens, cfg: Dict[str, Any], q,
                   prefix: str = "", remat: bool = False):
     """Final-norm hidden states ``[B, T, H]`` for 1-based ``tokens``."""
     import jax
-    H = cfg["hidden_size"]
-    emb = params[prefix + ".embedding.weight"]
-    x = emb[tokens - 1] * math.sqrt(H) + positions(tokens.shape[1], H)
+    x = _embed(params[prefix + ".embedding.weight"], tokens, cfg["hidden_size"])
     for i in range(cfg["num_hidden_layers"]):
-        f = (lambda x_, p=f"{prefix}.blocks[{i}]":
-             block(x_, params, p, cfg["num_attention_heads"], q))
+        f = (lambda x_, w=_inside(params, f"{prefix}.blocks[{i}]"):
+             _block(x_, w, cfg["num_attention_heads"], q))
         x = jax.checkpoint(f)(x) if remat else f(x)
     return layer_norm(x, params[prefix + ".final_norm.weight"],
                       params[prefix + ".final_norm.bias"])
@@ -137,61 +172,46 @@ def train_losses(params: Dict[str, Any], x, y, cfg: Dict[str, Any],
     return out
 
 
-_FWD_CACHE: Dict[Any, Any] = {}
+# ---- the model a block at a time, as the serving check walks it -------------
+# (``harness.kinds.decoder_lm.param_blocks`` names the blocks: the embedding,
+# each layer, the final norm with the tied head).  Each step takes only its
+# own block's leaves, by their full paths, and the name of the precision its
+# matrix products run in; layers share one compiled program.
+
+TOKEN_BASE = 1      # column j of the head's logits scores token id j + 1
+
+_STEPS: Dict[Any, Any] = {}
 
 
-def _served_forward(frozen, served_pad: int, precision: str):
-    """Jitted: logits of ``served_pad`` consecutive positions from
-    ``start`` on, in ``precision``; built once per shape."""
+def _step(name: str, cfg: Dict[str, Any], precision: str, build, **jit_kw):
     import jax
-    key = (frozen, served_pad, precision)
-    if key not in _FWD_CACHE:
-        q = quantizer(precision)
-
-        def fwd(params, seq, start):
-            h = hidden_states(params, seq, dict(frozen), q)
-            # only the positions that predicted a served token need logits
-            h = jax.lax.dynamic_slice_in_dim(h[0], start, served_pad, axis=0)
-            lg = logits_of(params, h, q)
-            return lg.at[:, -1].set(NEG)      # the never-trained extra row
-
-        _FWD_CACHE[key] = jax.jit(fwd)
-    return _FWD_CACHE[key]
+    key = (name, cfg["hidden_size"], cfg["num_attention_heads"], precision)
+    if key not in _STEPS:
+        _STEPS[key] = jax.jit(build(quantizer(precision)), **jit_kw)
+    return _STEPS[key]
 
 
-def served_gaps(params: Dict[str, Any], cfg: Dict[str, Any],
-                prompt: Sequence[int], served: Sequence[int],
-                pad_to: int, served_pad: int, control: str = "") -> Dict[str, float]:
-    """For one finished request, the widest gap by which a served token's
-    logit lies below the reference's best at its position.  The
-    reference runs once over the prompt followed by the served tokens
-    (padded to ``pad_to``; under a causal mask padding changes nothing
-    before it).  With ``control`` (a lower precision) it also reads the
-    gap of the token that precision puts first at each position."""
-    import jax.numpy as jnp
-    import numpy as np
-    n_p, n_s = len(prompt), len(served)
-    seq = np.ones((1, pad_to), np.int32)
-    seq[0, :n_p] = prompt
-    seq[0, n_p:n_p + n_s - 1] = served[:-1]
-    frozen = tuple(sorted((k, v) for k, v in cfg.items()
-                          if isinstance(v, (int, float, str, bool))))
-    served_pad = min(max(served_pad, n_s), pad_to)
-    start = min(n_p - 1, pad_to - served_pad)
-    off = (n_p - 1) - start
-    seq_d = jnp.asarray(seq)
-    ref = _served_forward(frozen, served_pad, "float32")(
-        params, seq_d, start)[off:off + n_s]
-    best = jnp.max(ref, axis=-1)
-    tok = jnp.asarray(np.asarray(served, np.int32) - 1)
-    gap = best - jnp.take_along_axis(ref, tok[:, None], axis=1)[:, 0]
-    out = {"gap_max": float(jnp.max(gap)), "positions": n_s}
-    if control:
-        low = _served_forward(frozen, served_pad, control)(
-            params, seq_d, start)[off:off + n_s]
-        pick = jnp.argmax(low, axis=-1)
-        cgap = best - jnp.take_along_axis(ref, pick[:, None], axis=1)[:, 0]
-        out["control_gap_max"] = float(jnp.max(cgap))
-        out["control_gap_median"] = float(jnp.median(cgap))
-        out["gap_median"] = float(jnp.median(gap))
-    return out
+def embed(params: Dict[str, Any], cfg: Dict[str, Any], tokens):
+    """``[B, T]`` 1-based token ids to the residual stream ``[B, T, H]``."""
+    return _step("embed", cfg, "float32", lambda q: lambda emb, t: _embed(
+        emb, t, cfg["hidden_size"]))(params[".embedding.weight"], tokens)
+
+
+def block(params: Dict[str, Any], cfg: Dict[str, Any], i: int, x,
+          precision: str = "float32"):
+    """Layer ``i`` on ``x [B, T, H]``; ``x`` is given up to the result."""
+    return _step("block", cfg, precision, lambda q: lambda w, x_: _block(
+        x_, w, cfg["num_attention_heads"], q, in_blocks=True), donate_argnums=1)(
+            _inside(params, f".blocks[{i}]"), x)
+
+
+def head(params: Dict[str, Any], cfg: Dict[str, Any], rows,
+         precision: str = "float32"):
+    """Logits ``[..., vocab + 1]`` of the residual stream's ``rows``: the
+    final norm and the tied head, the never-trained extra row at NEG."""
+    def build(q):
+        def f(w, r):
+            h = layer_norm(r, w[".final_norm.weight"], w[".final_norm.bias"])
+            return logits_of(w, h, q).at[..., -1].set(NEG)
+        return f
+    return _step("head", cfg, precision, build)(params, rows)

@@ -9,7 +9,10 @@ configurations and traffic under ``benchmark/rehearsal/``, with and
 without the trace, names the device as the CPU it is and prints no
 device metric: ``train_mfu``, the serving times, roofline and idle
 shares read "not measured".  It finds wrong paths, arguments and control
-flow before a chip call does.
+flow before a chip call does.  The serving cells' check walks the model
+block by block as on the chip; the plans under ``rehearsal/specs/``
+(written-out specs with leaves no cell has yet, such as experts stacked
+``[E, out, in]``) go through the same walk (``block_peak.py``).
 """
 import os
 import sys
@@ -41,6 +44,13 @@ def main(argv=None) -> int:
                       "--seconds", str(args.seconds), "--trace", str(trace)],
                      rehearsal_dir=os.path.join(BENCH_DIR, "rehearsal"),
                      t_start=time.perf_counter())
+    if args.workload is None:
+        import glob
+        import block_peak
+        for plan in sorted(glob.glob(os.path.join(
+                BENCH_DIR, "rehearsal", "specs", "*.json"))):
+            print(f"== rehearsal: block by block through {plan}", flush=True)
+            block_peak.walk(manifest.load_json(plan), args.seed)
     return 0
 
 

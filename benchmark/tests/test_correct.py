@@ -109,33 +109,34 @@ def test_token_altered_where_it_is_produced_is_not_correct(capsys, monkeypatch):
     assert line["correct"] is False
 
 
-def test_lower_precision_in_the_programs_place_fails_a_serving_limit():
+def test_lower_precision_in_the_programs_place_fails_a_serving_limit(capsys):
     """The control at a size a test can hold (4 layers of width 256,
     where logits are smaller than at the cell's size, so the limit here
     is this size's own; the cell's is set from chip readings, PERF.md
-    section 2).  The reference computed in int8, the precision below
-    bfloat16 that this repo has a path for, picks tokens whose reference
-    logit lies further below the best than the limit allows; bfloat16's
-    own picks stay inside it, with a factor of three to spare."""
-    import jax
-    import jax.numpy as jnp
-    from harness import weights
+    section 2), through the check a run makes, block by block.  The
+    reference computed in int8, the precision below bfloat16 that this
+    repo has a path for, picks tokens whose reference logit lies further
+    below the best than the limit allows; bfloat16's own picks stay
+    inside it, with a factor of three to spare."""
+    from harness import serve_cell
     from harness.kinds import decoder_lm as kind
-    from reference import decoder_lm as ref
-    cfg = {"vocab_size": 4000, "hidden_size": 256, "num_hidden_layers": 4,
-           "num_attention_heads": 4, "ffn_dim": 1024}
     limit = 0.01
-    spec = kind.param_spec(cfg)
-    sound, low = [], []
+    cfg = {"vocab_size": 4000, "hidden_size": 256, "num_hidden_layers": 4,
+           "num_attention_heads": 4, "ffn_dim": 1024,
+           "serving": {"weights_dtype": "bfloat16", "max_len": 256},
+           "correct": {"serve": {"logit_gap_max": limit}}}
+    mix = {"check_requests": 1, "new_tokens": {"max": 128}}
+    got = {"bfloat16": [], "int8": []}
     for seed in (11, 12, 13):
-        leaves = weights.make(spec, seed, jnp.bfloat16)
-        params = {p: l.astype(jnp.float32) for (p, _), l in zip(spec, leaves)}
         rng = np.random.default_rng(seed)
         prompt = rng.integers(1, 4001, 96).astype(np.int32)
         served = rng.integers(1, 4001, 128).astype(np.int32)
-        with jax.default_matmul_precision("highest"):
-            out = ref.served_gaps(params, cfg, prompt, served, 256, 128, "bfloat16")
-            sound.append(out["control_gap_max"])
-            out = ref.served_gaps(params, cfg, prompt, served, 256, 128, "int8")
-            low.append(out["control_gap_max"])
-    assert 3 * max(sound) <= limit < min(low), (sound, low, limit)
+        for precision, into in got.items():
+            capsys.readouterr()
+            # the served tokens are random here, so the run's own number
+            # fails; what is read is the control's
+            assert not serve_cell.check(kind, cfg, mix, seed,
+                                        [(prompt, served)], precision)
+            lines = capsys.readouterr().out.strip().splitlines()
+            into.append(tagged(lines, "control")[0]["control_gap_max"])
+    assert 3 * max(got["bfloat16"]) <= limit < min(got["int8"]), (got, limit)

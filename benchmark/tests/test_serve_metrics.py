@@ -71,3 +71,18 @@ def test_iterations_merge_the_stamps_of_one_step():
         [req(0.0, [1.12], prompt_len=10, calls=1), a, b], its)
     # a and b got their first token in iteration 0, the third request in 2
     assert flags == [True, False, True]
+
+
+@pytest.mark.parametrize("first_token_after_close, counted", [
+    (None, 0.0),      # the engine was stopped at the close: nothing to place
+    (20.2, 100.0)])   # it ran on until the first token came (close_grace_s)
+def test_a_prompt_in_prefill_at_the_close_counts_once_it_has_a_stamp(
+        first_token_after_close, counted):
+    # window [10, 20); another request's tokens give the passes their stamps,
+    # one every 0.1 s; the prompt's 4 calls run from 19.8 to 20.2, half inside
+    other = req(5.0, [10.0 + 0.1 * i for i in range(-20, 110)], calls=1)
+    stamps = [] if first_token_after_close is None else [first_token_after_close]
+    late = req(12.0, stamps, prompt_len=200, calls=4)
+    got = sm.tokens_in_window([other, late], 10.0, 20.0)["prompt"] \
+        - sm.tokens_in_window([other], 10.0, 20.0)["prompt"]
+    assert got == pytest.approx(counted, abs=1e-6)
