@@ -1,0 +1,364 @@
+"""Decoder-only language model built from a per-layer list of kinds:
+window and full grouped-query attention layers, a leading dense gated
+feed-forward layer, and a held share of sigmoid-routed gated experts.
+
+The block of ``mimo_v2`` (MiMo-V2.5), pre-norm and sequential:
+``h = x + Attn_i(RMSNorm(x))``, ``y = h + FFN_i(RMSNorm(h))``.
+``hybrid_layer_pattern[i]`` names layer ``i``'s attention: 0 a **full**
+layer (causal over everything), 1 a **window** layer (the last
+``sliding_window`` positions, a learned sink logit per head, key/value
+heads and rotary base of its own).  Keys are ``head_dim`` wide, values
+``v_head_dim``; the first ``partial_rotary_factor * head_dim`` dims of
+every query and key head are rotated by position.  ``moe_layer_freq[i]``
+names the feed-forward: 0 a dense gated layer
+``W_d(silu(W_g n) * W_u n)``, 1 :class:`bigdl_tpu.nn.HeldExperts`.  Final
+RMSNorm, an untied head, the embedding not scaled.
+
+It keeps the repo's conventions (``TransformerLM``): token ids are
+1-based with 0 as padding, and generation emits ``argmax + 1`` (the
+untied head has exactly ``vocab_size`` rows: none is untrained).  It has
+the incremental API the serving slot pool drives — ``init_cache``,
+``decode_step`` with a position per row, ``prefill_kv``,
+``prefill_chunk``, ``max_len``, ``_mask_untrained_logit`` — and declares
+each layer's cache (:meth:`cache_layers`): a ``full`` row of ``max_len``
+positions, or a ``ring`` of the window.  A model with expert layers also
+returns what they did (``routing``, int32 ``[4]``) from every pass the
+pool runs.
+
+The residual stream, the norms, the scores and the router are float32;
+the matrix products take their operands in the weights' dtype.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, Optional, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from bigdl_tpu.core.module import Module, ModuleList, Parameter
+from bigdl_tpu.nn.attention import GroupedQueryAttention
+from bigdl_tpu.nn.linear import Linear, LookupTable
+from bigdl_tpu.nn.moe import HeldExperts
+
+__all__ = ["HybridDecoder", "mimo_v2"]
+
+ROUTING = 4     # what an expert layer counts: HeldExperts.forward
+
+
+def _product(x, layer: Linear):
+    """``x [..., in] @ W.T`` with float32 out of the product."""
+    return jnp.einsum("...i,oi->...o", x, layer.weight,
+                      preferred_element_type=jnp.float32)
+
+
+class RMSNorm(Module):
+    """``x / rms(x) * gain`` in float32."""
+
+    def __init__(self, hidden_size: int, eps: float = 1e-5):
+        super().__init__()
+        self.eps = eps
+        self.weight = Parameter(jnp.ones(hidden_size))
+
+    def forward(self, x):
+        x = x.astype(jnp.float32)
+        ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+        return x * jax.lax.rsqrt(ms + self.eps) \
+            * self.weight.astype(jnp.float32)
+
+
+class GatedFFN(Module):
+    """``W_d(silu(W_g x) * W_u x)``, no bias; float32 out."""
+
+    def __init__(self, hidden_size: int, filter_size: int):
+        super().__init__()
+        self.gate = Linear(hidden_size, filter_size, with_bias=False)
+        self.up = Linear(hidden_size, filter_size, with_bias=False)
+        self.down = Linear(filter_size, hidden_size, with_bias=False)
+
+    def forward(self, x):
+        x = x.astype(self.gate.weight.dtype)
+        a = jax.nn.silu(_product(x, self.gate)) * _product(x, self.up)
+        return _product(a.astype(x.dtype), self.down)
+
+
+class HybridBlock(Module):
+    def __init__(self, hidden_size: int, attn: GroupedQueryAttention,
+                 ffn: Module, eps: float):
+        super().__init__()
+        self.attn_norm = RMSNorm(hidden_size, eps)
+        self.attn = attn
+        self.ffn_norm = RMSNorm(hidden_size, eps)
+        self.ffn = ffn
+        self.sparse = isinstance(ffn, HeldExperts)
+
+    def forward(self, x, index=0, cache=None, pad=None, slot=None,
+                active=None, valid=None):
+        """``x [B, T, H]`` float32 -> ``(y, kv, counts)``: see
+        :meth:`GroupedQueryAttention.forward` for ``index``, ``cache``,
+        ``pad``, ``slot`` and ``active``; ``valid [B, T]`` false keeps a
+        token from the experts; ``counts`` is what the expert layer did
+        (zeros for a dense layer)."""
+        n = self.attn_norm(x).astype(self.attn.q_layer.weight.dtype)
+        a, kv = self.attn.forward(n, index, cache, pad, slot, active)
+        h = x + a
+        n = self.ffn_norm(h)
+        if self.sparse:
+            f, counts = self.ffn.forward(n, valid)
+        else:
+            f, counts = self.ffn.forward(n), jnp.zeros((ROUTING,), jnp.int32)
+        return h + f, kv, counts
+
+
+class HybridDecoder(Module):
+    """``forward(tokens [B, T] int, 1-based; 0 = padding) -> logits
+    [B, T, vocab]`` float32 (column ``j`` scores token ``j + 1``).
+
+    ``layer_kinds[i]`` is ``"full"`` or ``"window"``; ``sparse[i]`` says
+    whether layer ``i``'s feed-forward is the expert layer."""
+
+    def __init__(self, vocab_size: int, hidden_size: int,
+                 layer_kinds: Sequence[str], sparse: Sequence[bool],
+                 num_heads: int, head_dim: int, v_head_dim: int,
+                 kv_heads: Dict[str, int], rope_theta: Dict[str, float],
+                 rotary_dim: int, window: int, window_sink: bool,
+                 value_scale: float, dense_size: int, expert_size: int,
+                 num_experts: int, top_k: int,
+                 held: Optional[Tuple[int, int]] = None,
+                 eps: float = 1e-5, max_len: int = 512,
+                 normalize_top_k: bool = True):
+        super().__init__()
+        if len(layer_kinds) != len(sparse):
+            raise ValueError("one kind and one sparse flag a layer")
+        self.hidden_size = hidden_size
+        self.max_len = max_len
+        self.embedding = LookupTable(vocab_size, hidden_size)
+        self.embedding.weight = Parameter(
+            self.embedding.weight * hidden_size ** -0.5)
+        blocks = []
+        for kind, is_sparse in zip(layer_kinds, sparse):
+            if kind not in ("full", "window"):
+                raise ValueError(f"layer kind {kind!r}: 'full' or 'window'")
+            win = kind == "window"
+            attn = GroupedQueryAttention(
+                hidden_size, num_heads, kv_heads[kind], head_dim, v_head_dim,
+                window=window if win else None,
+                rope_theta=rope_theta[kind], rotary_dim=rotary_dim,
+                sink=win and window_sink, value_scale=value_scale)
+            ffn = HeldExperts(hidden_size, expert_size, num_experts, top_k,
+                              held, normalize_top_k) if is_sparse \
+                else GatedFFN(hidden_size, dense_size)
+            blocks.append(HybridBlock(hidden_size, attn, ffn, eps))
+        self.blocks = ModuleList(blocks)
+        self.final_norm = RMSNorm(hidden_size, eps)
+        self.lm_head = Linear(hidden_size, vocab_size, with_bias=False)
+
+    # ---- what the slot pool asks ------------------------------------------
+
+    def cache_layers(self) -> Tuple[Tuple[str, int], ...]:
+        """Each layer's cache: ``("full", max_len)`` or ``("ring",
+        window)``.  A ring is allocated with room for a prefill chunk
+        beside the window (:meth:`init_cache`, ``ring_margin``)."""
+        return tuple(("ring", blk.attn.window) if blk.attn.window is not None
+                     else ("full", self.max_len) for blk in self.blocks)
+
+    def expert_layers(self) -> int:
+        return sum(1 for blk in self.blocks if blk.sparse)
+
+    def init_cache(self, batch: int, dtype=jnp.float32,
+                   ring_margin: int = 1) -> Dict[str, Any]:
+        """Per-layer keys and values by :meth:`cache_layers`, each with
+        its layer's heads and widths, and the padding flags by
+        position."""
+        return {
+            "layers": [{"self": blk.attn.init_cache(
+                batch, self.max_len, dtype, ring_margin)}
+                for blk in self.blocks],
+            "pad": jnp.zeros((batch, self.max_len), bool),
+        }
+
+    @staticmethod
+    def _mask_untrained_logit(logits):
+        """The untied head has no untrained row: nothing to mask."""
+        return logits
+
+    # ---- the four passes ---------------------------------------------------
+
+    def _embed(self, tokens):
+        return self.embedding.forward(jnp.maximum(tokens, 1)).astype(
+            jnp.float32)
+
+    def _logits(self, x):
+        w = self.lm_head.weight
+        return _product(self.final_norm(x).astype(w.dtype), self.lm_head)
+
+    def forward(self, tokens):
+        _B, T = tokens.shape
+        if T > self.max_len:
+            raise ValueError(
+                f"sequence length {T} exceeds max_len={self.max_len}")
+        pad = tokens == 0
+        x = self._embed(tokens)
+        for blk in self.blocks:
+            x, _, _ = blk.forward(x, pad=pad, valid=~pad)
+        return self._logits(x)
+
+    def prefill_kv(self, ptoks):
+        """Compact per-layer keys and values ``[B, Hkv, T, d]`` of every
+        position of ``ptoks``, the ``[B, T]`` padding flags, and the
+        expert layers' ``routing``: what a bucketed prefill scatters into
+        slots."""
+        pad = ptoks == 0
+        x = self._embed(ptoks)
+        layers, routing = [], jnp.zeros((ROUTING,), jnp.int32)
+        for blk in self.blocks:
+            x, kv, counts = blk.forward(x, pad=pad, valid=~pad)
+            layers.append(kv)
+            routing = routing + counts
+        return layers, pad, routing
+
+    def prefill_chunk(self, toks, index, caches, slot=None):
+        """Write keys, values and padding flags of ``toks [B, W]`` at
+        positions ``index .. index+W`` of a cache filled below ``index``
+        (row ``slot`` of a pool when given, ``B == 1``), attending the
+        cache and itself; returns ``(caches, routing)``.  A ring must
+        have ``W - 1`` places beside its window: the chunk is written
+        before it is attended."""
+        _B, W = toks.shape
+        for blk, cache in zip(self.blocks, caches["layers"]):
+            win, R = blk.attn.window, cache["self"]["k"].shape[2] - 1
+            if win is not None and R < min(self.max_len, win + W - 1):
+                raise ValueError(
+                    f"a chunk of {W} positions needs a ring of "
+                    f"{win + W - 1} places, the cache has {R} "
+                    f"(init_cache(ring_margin={W}))")
+        pad = jax.lax.dynamic_update_slice(
+            caches["pad"], toks == 0, (0 if slot is None else slot, index))
+        x = self._embed(toks)
+        new_layers, routing = [], jnp.zeros((ROUTING,), jnp.int32)
+        for blk, cache in zip(self.blocks, caches["layers"]):
+            x, kv, counts = blk.forward(x, index, cache["self"], pad, slot,
+                                        valid=toks != 0)
+            new_layers.append({"self": kv})
+            routing = routing + counts
+        return dict(caches, layers=new_layers, pad=pad), routing
+
+    def decode_step(self, tokens, index, caches, with_logits=True,
+                    active=None):
+        """One token a row: ``tokens [B, 1]`` at ``index`` (a scalar, or
+        ``[B]``: a position per row) -> ``(logits [B, vocab], caches,
+        routing)``.  ``active [B]`` false (with ``index [B]``) marks a row
+        that only rides along: it writes where nothing reads and the
+        experts do not see it."""
+        per_row = jnp.ndim(index) == 1
+        flag = tokens == 0
+        if per_row:
+            if active is not None:
+                # a full row's last position is beyond every prefill
+                # query's mask and rewritten by its occupant's own decode
+                # before it is attended; a ring sends the row to its
+                # spare place (GroupedQueryAttention.forward)
+                index = jnp.where(active, index,
+                                  jnp.int32(self.max_len - 1))
+            pad = caches["pad"]
+            for b in range(tokens.shape[0]):
+                pad = jax.lax.dynamic_update_slice(pad, flag[b:b + 1],
+                                                   (b, index[b]))
+        else:
+            pad = jax.lax.dynamic_update_slice(caches["pad"], flag,
+                                               (0, index))
+        valid = ~flag if active is None else ~flag & active[:, None]
+        x = self._embed(tokens)
+        new_layers, routing = [], jnp.zeros((ROUTING,), jnp.int32)
+        for blk, cache in zip(self.blocks, caches["layers"]):
+            x, kv, counts = blk.forward(x, index, cache["self"], pad,
+                                        active=active, valid=valid)
+            new_layers.append({"self": kv})
+            routing = routing + counts
+        new_caches = dict(caches, layers=new_layers, pad=pad)
+        if not with_logits:
+            return None, new_caches, routing
+        return self._logits(x)[:, 0], new_caches, routing
+
+    def generate(self, prompt, max_new_tokens: int, eos_id=None,
+                 chunk: int = 64):
+        """Greedy continuation ``prompt [B, Tp] -> [B, Tp +
+        max_new_tokens]``: the prompt in chunks of ``chunk`` through
+        :meth:`prefill_chunk`, then a scan of decode steps; positions
+        after ``eos_id`` are 0."""
+        B, Tp = prompt.shape
+        if Tp + max_new_tokens > self.max_len:
+            raise ValueError(
+                f"prompt {Tp} + {max_new_tokens} new tokens exceeds "
+                f"max_len={self.max_len}")
+        prompt = jnp.asarray(prompt, jnp.int32)
+        caches = self.init_cache(B, ring_margin=chunk)
+        for s in range(0, Tp - 1, chunk):
+            caches, _ = self.prefill_chunk(
+                prompt[:, s:min(s + chunk, Tp - 1)], s, caches)
+
+        def gen_step(carry, t):
+            tok, caches, done = carry
+            logits, caches, _ = self.decode_step(tok, t, caches)
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32) + 1
+            nxt = jnp.where(done, 0, nxt)
+            if eos_id is not None:
+                done = done | (nxt == eos_id)
+            return (nxt[:, None], caches, done), nxt
+
+        (_, _, _), toks = jax.lax.scan(
+            gen_step, (prompt[:, -1:], caches, jnp.zeros((B,), bool)),
+            Tp - 1 + jnp.arange(max_new_tokens))
+        return jnp.concatenate([prompt, toks.T], axis=1)
+
+
+def mimo_v2(config: Dict[str, Any], max_len: int) -> HybridDecoder:
+    """The model from the keys of a public ``mimo_v2`` ``config.json``
+    plus the chip's share: ``experts_held`` (how many of
+    ``n_routed_experts`` live here, from ``experts_offset``, default 0)
+    and ``vocab_size`` as sliced.  ``hybrid_layer_pattern`` and
+    ``moe_layer_freq`` are read for the first ``num_hidden_layers``
+    layers."""
+    c = config
+    if c.get("attention_bias") or c.get("n_shared_experts") \
+            or c.get("add_full_attention_sink_bias") \
+            or c.get("tie_word_embeddings"):
+        raise ValueError("mimo_v2: attention bias, shared experts, a sink "
+                         "on full layers and a tied head are not built")
+    if c.get("scoring_func", "sigmoid") != "sigmoid" \
+            or c.get("topk_method", "noaux_tc") != "noaux_tc" \
+            or c.get("n_group", 1) != 1 or c.get("topk_group", 1) != 1 \
+            or c.get("routed_scaling_factor") not in (None, 1, 1.0):
+        raise ValueError("mimo_v2: sigmoid scores, a noaux_tc selection "
+                         "bias, one group and no scaling factor are what "
+                         "is built")
+    n = c["num_hidden_layers"]
+    kinds = ["window" if p else "full"
+             for p in list(c["hybrid_layer_pattern"])[:n]]
+    sparse = [bool(f) for f in list(c["moe_layer_freq"])[:n]]
+    if c.get("swa_head_dim", c["head_dim"]) != c["head_dim"] \
+            or c.get("swa_v_head_dim", c["v_head_dim"]) != c["v_head_dim"] \
+            or c.get("swa_num_attention_heads",
+                     c["num_attention_heads"]) != c["num_attention_heads"]:
+        raise ValueError("mimo_v2: window and full layers share their "
+                         "query heads and head widths here")
+    return HybridDecoder(
+        vocab_size=c["vocab_size"], hidden_size=c["hidden_size"],
+        layer_kinds=kinds, sparse=sparse,
+        num_heads=c["num_attention_heads"], head_dim=c["head_dim"],
+        v_head_dim=c["v_head_dim"],
+        kv_heads={"full": c["num_key_value_heads"],
+                  "window": c["swa_num_key_value_heads"]},
+        rope_theta={"full": float(c["rope_theta"]),
+                    "window": float(c["swa_rope_theta"])},
+        rotary_dim=2 * (int(c["partial_rotary_factor"] * c["head_dim"]) // 2),
+        window=c["sliding_window"],
+        window_sink=bool(c.get("add_swa_attention_sink_bias", False)),
+        value_scale=float(c.get("attention_value_scale") or 1.0),
+        dense_size=c["intermediate_size"],
+        expert_size=c["moe_intermediate_size"],
+        num_experts=c["n_routed_experts"], top_k=c["num_experts_per_tok"],
+        held=(c.get("experts_offset", 0),
+              c.get("experts_held", c["n_routed_experts"])),
+        eps=c.get("layernorm_epsilon", 1e-5), max_len=max_len,
+        normalize_top_k=c.get("norm_topk_prob", True))

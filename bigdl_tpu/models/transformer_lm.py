@@ -227,6 +227,12 @@ class TransformerLM(Module):
 
     # ---- incremental decoding (KV cache) -------------------------------
 
+    def cache_layers(self):
+        """Each layer's cache as the serving slot pool allocates it:
+        here every layer keeps a ``full`` row of ``max_len`` positions
+        (a windowed layer would declare ``("ring", window)``)."""
+        return (("full", self.max_len),) * len(self.blocks)
+
     def init_cache(self, batch: int, dtype=jnp.float32):
         """Per-block KV caches sized to ``max_len``, plus the per-slot
         padding flags the full forward expresses via padding_bias (one
@@ -238,7 +244,8 @@ class TransformerLM(Module):
             "pad": jnp.zeros((batch, self.max_len), bool),
         }
 
-    def decode_step(self, tokens, index, caches, with_logits=True):
+    def decode_step(self, tokens, index, caches, with_logits=True,
+                    active=None):
         """One token step: ``tokens [B, 1]`` at position ``index`` →
         (logits [B, vocab+1], new caches).  Equivalent to column
         ``index`` of the full forward incl. its padding mask (tested),
@@ -251,8 +258,20 @@ class TransformerLM(Module):
         whose rows are requests at positions of their own.  Same
         mathematics either way; the rank of ``index`` only decides how
         the new key and value reach the cache (see
-        :meth:`_decode_step_rows`)."""
+        :meth:`_decode_step_rows`).
+
+        ``active [B]`` false (with ``index [B]``) marks a row that only
+        rides along, as an idle lane of the slot pool does: every row
+        writes its position's K/V, so such a row must write somewhere
+        provably unread.  ``max_len - 1`` is beyond every prefill
+        query's mask and is always freshly rewritten by an occupant's
+        own decode before it is attended — a stale index would instead
+        clobber a co-scheduled chunked prefill's freshly written
+        positions (test_decode_does_not_disturb_inactive_rows)."""
         if jnp.ndim(index) == 1:
+            if active is not None:
+                index = jnp.where(active, index,
+                                  jnp.int32(self.max_len - 1))
             return self._decode_step_rows(tokens, index, caches,
                                           with_logits)
         pad = jax.lax.dynamic_update_slice(

@@ -35,6 +35,8 @@ __all__ = [
     "TransformerDecoderLayer", "Transformer", "SequenceBeamSearch",
     "position_encoding", "padding_bias", "causal_bias",
     "incremental_bias", "chunk_incremental_bias", "shift_right_3d",
+    "GroupedQueryAttention", "grouped_attention", "rotary_half",
+    "cache_positions",
 ]
 
 
@@ -229,6 +231,349 @@ class Attention(Module):
         d = self.hidden_size // self.num_heads
         shape = (batch, self.num_heads, max_length, d)
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+
+# ---------------------------------------------------------------------------
+# Grouped-query attention over a cache whose layers differ in length
+# ---------------------------------------------------------------------------
+
+# float32 scores of one product, [B, heads, Tq, Tk], above which the
+# product goes one key/value head (and, if still over, one block of
+# queries) at a time: a whole 6,144-token sequence against itself would
+# hold 9 GiB of scores at 64 heads
+SCORE_BYTES = 512 << 20
+
+
+def rotary_half(x, positions, theta: float, rotary_dim: int):
+    """Rotate the first ``rotary_dim`` dims of ``x [..., T, d]`` by its
+    ``positions [..., T]``, pairing dim ``j`` with ``j + rotary_dim/2``
+    (the half-split rotary embedding: the pair turns by
+    ``position * theta**(-2j/rotary_dim)``); the dims past
+    ``rotary_dim`` pass unchanged (``partial_rotary_factor``).
+    Computed in float32."""
+    half = rotary_dim // 2
+    inv = jnp.exp(jnp.arange(half, dtype=jnp.float32)
+                  * (-math.log(theta) / half))
+    ang = positions.astype(jnp.float32)[..., None] * inv     # [..., T, half]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32)
+    a, b = xf[..., :half], xf[..., half:rotary_dim]
+    out = jnp.concatenate([a * cos - b * sin, b * cos + a * sin,
+                           xf[..., rotary_dim:]], axis=-1)
+    return out.astype(x.dtype)
+
+
+def cache_positions(length: int, last, ring: bool):
+    """The position each of a cache row's ``length`` places holds once
+    position ``last`` (a scalar, or ``[B]``) has been written.  A
+    ``full`` row holds position ``j`` at place ``j``.  A ``ring`` row is
+    ``length - 1`` ring places and one spare: position ``p`` lies at
+    place ``p % (length - 1)``, so place ``j`` holds the newest position
+    congruent to it, ``last - (last - j) % (length - 1)`` (negative:
+    nothing written yet), and the spare place holds nothing, ever — it
+    is where a row that only rides along writes.  Returns
+    ``[1|B, length]``."""
+    j = jnp.arange(length, dtype=jnp.int32)
+    last = jnp.reshape(jnp.asarray(last, jnp.int32), (-1, 1))
+    if not ring:
+        return jnp.broadcast_to(j, (last.shape[0], length))
+    return jnp.where(j == length - 1, -1,
+                     last - jnp.mod(last - j, length - 1))
+
+
+def grouped_attention(q, k, v, q_pos, k_pos, window: Optional[int] = None,
+                      pad=None, sink=None):
+    """Causal attention of ``q [B, Hq, Tq, d]`` over ``k [B, Hkv, Tk, d]``
+    and ``v [B, Hkv, Tk, dv]`` with ``Hq`` a multiple of ``Hkv``: query
+    head ``h`` reads key/value head ``h // (Hq // Hkv)``.  The queries
+    are grouped onto their key/value head inside the product; keys and
+    values are never expanded to ``Hq`` heads, and their widths may
+    differ.
+
+    ``q_pos [1|B, Tq]`` and ``k_pos [1|B, Tk]`` give the position of
+    every query and of what every key place holds (negative: nothing);
+    a query attends ``0 <= q_pos - k_pos`` and, with ``window``,
+    ``q_pos - k_pos < window``.  ``pad [1|B, Tk]`` masks padding keys.
+    ``sink [Hq]`` adds ``exp(sink[h])`` to every softmax denominator of
+    head ``h``: a place that takes weight and gives no value.  Scores
+    and softmax are float32, and so is the result ``[B, Hq, Tq, dv]``."""
+    B, Hq, Tq, d = q.shape
+    Hkv, Tk, dv = k.shape[1], k.shape[2], v.shape[3]
+    G = Hq // Hkv
+    dist = q_pos[:, :, None] - k_pos[:, None, :]             # [1|B, Tq, Tk]
+    ok = (dist >= 0) & (k_pos[:, None, :] >= 0)
+    if window is not None:
+        ok = ok & (dist < window)
+    if pad is not None:
+        ok = ok & ~pad[:, None, :]
+    bias = jnp.where(ok, 0.0, _NEG_INF).astype(jnp.float32)
+    scale = jnp.float32(1.0 / math.sqrt(d))
+    if sink is not None:
+        sink = sink.astype(jnp.float32).reshape(Hkv, G)
+
+    def heads(qh, kh, vh, bias_t, sink_g):
+        # qh [..., G, t, d]; kh [..., Tk, d]; vh [..., Tk, dv];
+        # bias_t [..., 1, t, Tk]; sink_g [..., G]
+        s = jnp.einsum("...gtd,...kd->...gtk", qh, kh,
+                       preferred_element_type=jnp.float32) * scale + bias_t
+        m = jnp.max(s, axis=-1, keepdims=True)
+        if sink_g is not None:
+            sk = sink_g[..., None, None]
+            m = jnp.maximum(m, sk)
+        # the row maxima are made before they are used: fused into the
+        # subtraction, the TPU compiler turns max-then-broadcast into a
+        # reduce-window as wide as the row (a 256-token chunk over a
+        # 6,144-place row took 27 ms a layer in that one fusion, of the
+        # chunk program's 73)
+        m = jax.lax.optimization_barrier(m)
+        e = jnp.exp(s - m)
+        den = jnp.sum(e, axis=-1, keepdims=True)
+        if sink_g is not None:
+            den = den + jnp.exp(sk - m)
+        # normalised after the product with the values: one pass fewer
+        # over the scores
+        ctx = jnp.einsum("...gtk,...kd->...gtd", e.astype(vh.dtype), vh,
+                         preferred_element_type=jnp.float32)
+        return ctx / den
+
+    qg = q.reshape(B, Hkv, G, Tq, d)
+    if B * Hq * Tq * Tk * 4 <= SCORE_BYTES:
+        return heads(qg, k, v, bias[:, None, None], sink).reshape(
+            B, Hq, Tq, dv)
+    # one key/value head at a time, the queries in blocks of tb
+    tb = Tq
+    while B * G * tb * Tk * 4 > SCORE_BYTES and tb % 2 == 0:
+        tb //= 2
+    nb = Tq // tb
+    qb = qg.reshape(B, Hkv, G, nb, tb, d).transpose(1, 3, 0, 2, 4, 5)
+    bias_b = bias.reshape(bias.shape[0], nb, tb, Tk).transpose(1, 0, 2, 3)
+    kt, vt = k.transpose(1, 0, 2, 3), v.transpose(1, 0, 2, 3)
+    sinks = jnp.zeros((Hkv, 0)) if sink is None else sink
+
+    def one_head(args):
+        qh, kh, vh, sg = args                    # qh [nb, B, G, tb, d]
+        sg = None if sink is None else sg
+        return jax.lax.map(lambda a: heads(a[0], kh, vh, a[1][:, None], sg),
+                           (qh, bias_b))
+    out = jax.lax.map(one_head, (qb, kt, vt, sinks))  # [Hkv,nb,B,G,tb,dv]
+    return out.transpose(2, 0, 3, 1, 4, 5).reshape(B, Hq, Tq, dv)
+
+
+def _write_rows(leaf, new, place):
+    """``new [B, h, 1, d]`` into ``leaf [B, h, L, d]``, row ``b`` at
+    ``place[b]``: one ``dynamic_update_slice`` a row (a static loop), so
+    a donated leaf is updated in place in whatever layout it has (see
+    ``TransformerLM._decode_step_rows``).  Nothing is read back: a
+    read-modify-write of the row's old value made the TPU compiler keep
+    the leaf slots-minor and copy it in and out on every step."""
+    new = new.astype(leaf.dtype)
+    for b in range(new.shape[0]):
+        leaf = jax.lax.dynamic_update_slice(leaf, new[b:b + 1],
+                                            (b, 0, place[b], 0))
+    return leaf
+
+
+def _write_window(leaf, new, row, start, ring: bool):
+    """``new [B, h, W, d]`` (``W`` consecutive positions from ``start``)
+    into rows ``row .. row+B`` of ``leaf [S, h, L, d]``; returns the leaf
+    and those rows as they now are.
+
+    A ``full`` leaf takes one window at ``start``, and the rows are
+    sliced back out.  A ring takes position ``p`` at place
+    ``p % (L - 1)`` (the last place is the spare) and the window may
+    wrap, so the rows are read whole, the chunk's places replaced, and
+    the rows written back whole: a ring row is short (the window and a
+    chunk), and a window read back out of the leaf at a traced place
+    made the TPU compiler relayout the whole leaf on every call."""
+    new = new.astype(leaf.dtype)
+    B, h, W, d = new.shape
+    L = leaf.shape[2]
+    if not ring:
+        leaf = jax.lax.dynamic_update_slice(leaf, new, (row, 0, start, 0))
+        return leaf, jax.lax.dynamic_slice(leaf, (row, 0, 0, 0),
+                                           (B, h, L, d))
+    R = L - 1
+    if W > R:
+        raise ValueError(f"a chunk of {W} positions does not fit a ring "
+                         f"of {R}")
+    rows = jax.lax.dynamic_slice(leaf, (row, 0, 0, 0), (B, h, L, d))
+    # place j takes chunk offset (j - start) mod R where that is < W
+    place = jnp.arange(L)
+    off = jnp.mod(place - start, R)
+    mine = ((off < W) & (place < R))[None, None, :, None]
+    rows = jnp.where(mine, jnp.take(new, jnp.minimum(off, W - 1), axis=2),
+                     rows)
+    return jax.lax.dynamic_update_slice(leaf, rows, (row, 0, 0, 0)), rows
+
+
+class AttentionSink(Module):
+    """One learned logit a query head: a place in every softmax that
+    takes weight and gives no value (``add_swa_attention_sink_bias``)."""
+
+    def __init__(self, num_heads: int):
+        super().__init__()
+        self.bias = Parameter(jnp.zeros(num_heads))
+
+
+class GroupedQueryAttention(Module):
+    """Causal self-attention with fewer key/value heads than query
+    heads, keys ``head_dim`` wide and values ``v_head_dim``, for the two
+    layer kinds of a hybrid pattern:
+
+    * a **window** layer (``window`` given) attends the last ``window``
+      positions, may carry a learned ``sink`` logit per head, and its
+      cache may be a ring;
+    * a **full** layer attends everything before it.
+
+    With ``rotary_dim`` the first ``rotary_dim`` dims of every query and
+    key head are rotated by position (:func:`rotary_half`, base
+    ``rope_theta``: a layer kind has its own).  The context is scaled by
+    ``value_scale`` before the output projection.  No bias, no q/k norm,
+    scores over ``sqrt(head_dim)``.  :meth:`forward` is the one entry: a
+    full forward, a prefill that returns compact keys and values, a
+    prefill chunk and a decode step differ only in whether a cache is
+    passed and what ``index`` is."""
+
+    def __init__(self, hidden_size: int, num_heads: int, num_kv_heads: int,
+                 head_dim: int, v_head_dim: Optional[int] = None,
+                 window: Optional[int] = None, rope_theta: float = 10000.0,
+                 rotary_dim: int = 0, sink: bool = False,
+                 value_scale: float = 1.0):
+        super().__init__()
+        if num_heads % num_kv_heads:
+            raise ValueError("num_heads must be a multiple of num_kv_heads")
+        if rotary_dim % 2 or rotary_dim > head_dim:
+            raise ValueError(f"rotary_dim {rotary_dim} must be even and at "
+                             f"most head_dim {head_dim}")
+        self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
+        self.head_dim = head_dim
+        self.v_head_dim = head_dim if v_head_dim is None else v_head_dim
+        self.window = None if window is None else int(window)
+        self.rope_theta, self.rotary_dim = float(rope_theta), int(rotary_dim)
+        self.value_scale = float(value_scale)
+        self.q_layer = Linear(hidden_size, num_heads * head_dim,
+                              with_bias=False)
+        self.k_layer = Linear(hidden_size, num_kv_heads * head_dim,
+                              with_bias=False)
+        self.v_layer = Linear(hidden_size, num_kv_heads * self.v_head_dim,
+                              with_bias=False)
+        self.output_layer = Linear(num_heads * self.v_head_dim, hidden_size,
+                                   with_bias=False)
+        if sink:
+            self.sink = AttentionSink(num_heads)
+        self.has_sink = bool(sink)
+
+    def cache_length(self, max_len: int, ring_margin: int = 1) -> int:
+        """Places of one cache row.  A ``full`` row holds ``max_len``
+        positions.  A ring holds the window and what a prefill chunk of
+        up to ``ring_margin`` positions needs beside it (the chunk is
+        written before it is attended, and must not overwrite keys its
+        first query still needs): ``window + ring_margin - 1`` ring
+        places, ``max_len`` at most, and the spare place
+        (:func:`cache_positions`)."""
+        if self.window is None:
+            return max_len
+        return 1 + min(max_len, self.window + max(int(ring_margin), 1) - 1)
+
+    def init_cache(self, batch: int, max_len: int, dtype=jnp.float32,
+                   ring_margin: int = 1):
+        length = self.cache_length(max_len, ring_margin)
+        return {"k": jnp.zeros((batch, self.num_kv_heads, length,
+                                self.head_dim), dtype),
+                "v": jnp.zeros((batch, self.num_kv_heads, length,
+                                self.v_head_dim), dtype)}
+
+    def _heads(self, x, layer, n, d):
+        # float32 out of the product: rotated, then rounded once
+        y = jnp.einsum("bti,oi->bto", x, layer.weight,
+                       preferred_element_type=jnp.float32)
+        b, t, _ = y.shape
+        return y.reshape(b, t, n, d).transpose(0, 2, 1, 3)
+
+    def forward(self, x, index=0, cache=None, pad=None, slot=None,
+                active=None):
+        """``x [B, T, H]`` (normed) at positions ``index .. index+T-1``
+        -> ``(y [B, T, H] float32, kv)``.
+
+        * ``cache=None``: the sequence attends itself; ``kv`` is its
+          compact ``{"k": [B, Hkv, T, d], "v": [B, Hkv, T, dv]}`` (keys
+          rotated), what a bucketed prefill scatters into slots.
+          ``pad [B, T]``.
+        * ``cache`` given, scalar ``index``: the new keys and values are
+          written at ``index ..`` of every row (of row ``slot`` alone
+          when given: the pool's chunked prefill, ``B == 1``) and the
+          queries attend the row.  ``pad [B|S, max_len]`` by position.
+        * ``cache`` given, ``index [B]``, ``T == 1``: the pool's decode
+          step, row ``b`` written and masked at ``index[b]``.  A row
+          whose ``active[b]`` is false only rides along: in a ring it
+          writes the spare place (in a ``full`` row the caller gives it
+          an ``index`` that is rewritten before it is read).
+
+        ``kv`` is then the updated cache."""
+        B, T, _ = x.shape
+        ring = self.window is not None
+        per_row = jnp.ndim(index) == 1
+        q = self._heads(x, self.q_layer, self.num_heads, self.head_dim)
+        k = self._heads(x, self.k_layer, self.num_kv_heads,
+                        self.head_dim)
+        v = self._heads(x, self.v_layer, self.num_kv_heads,
+                        self.v_head_dim)
+        index = jnp.asarray(index, jnp.int32)
+        q_pos = (index[:, None] if per_row else index[None, None]) \
+            + jnp.arange(T, dtype=jnp.int32)[None, :]       # [1|B, T]
+        if self.rotary_dim:
+            q = rotary_half(q, q_pos[:, None, :], self.rope_theta,
+                            self.rotary_dim)
+            k = rotary_half(k, q_pos[:, None, :], self.rope_theta,
+                            self.rotary_dim)
+        q, k, v = (a.astype(x.dtype) for a in (q, k, v))
+        if cache is None:
+            kv = {"k": k, "v": v}
+            keys, vals, k_pos = k, v, q_pos
+        else:
+            L = cache["k"].shape[2]
+            if per_row:
+                if T != 1:
+                    raise ValueError("a position per row takes T == 1")
+                place = index
+                if ring:
+                    place = jnp.mod(index, L - 1)
+                    if active is not None:
+                        place = jnp.where(active, place, L - 1)
+                kv = {n: _write_rows(cache[n], new, place)
+                      for n, new in (("k", k), ("v", v))}
+                keys, vals = kv["k"], kv["v"]
+                last = index
+            else:
+                if slot is None and cache["k"].shape[0] != B:
+                    raise ValueError("a cache of other rows than x "
+                                     "takes a slot")
+                row = 0 if slot is None else slot
+                kv, rows = {}, {}
+                for n, new in (("k", k), ("v", v)):
+                    kv[n], rows[n] = _write_window(cache[n], new, row,
+                                                   index, ring)
+                keys, vals = rows["k"], rows["v"]
+                last = index + (T - 1)
+            k_pos = cache_positions(L, last, ring)
+            if pad is not None:
+                # pad is by position; a ring place holds position k_pos
+                if slot is not None:
+                    pad = jax.lax.dynamic_slice(
+                        pad, (slot, 0), (1, pad.shape[1]))
+                if ring:
+                    pad = jnp.take_along_axis(
+                        pad, jnp.broadcast_to(
+                            jnp.maximum(k_pos, 0),
+                            (pad.shape[0], L)), axis=1)
+        ctx = grouped_attention(
+            q, keys, vals, q_pos, k_pos, self.window, pad,
+            self.sink.bias if self.has_sink else None)
+        ctx = (ctx * self.value_scale).astype(x.dtype)
+        ctx = ctx.transpose(0, 2, 1, 3).reshape(B, T, -1)
+        y = jnp.einsum("bti,oi->bto", ctx, self.output_layer.weight,
+                       preferred_element_type=jnp.float32)
+        return y, kv
 
 
 class FeedForwardNetwork(Module):

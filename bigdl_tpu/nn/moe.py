@@ -9,7 +9,7 @@ Design: top-k token routing with load-balancing auxiliary loss (the
 standard Shazeer/Switch recipe).  Three execution paths:
 
 * dense (single device / no expert axis): every expert runs over all
-  tokens via ``vmap`` over stacked expert parameters; outputs combine
+  tokens via ``vmap`` over the stacked expert parameters; outputs combine
   with the routing weights.  O(E·T) compute — exact, used for tests and
   small E.
 * expert-parallel all_to_all (``set_mesh(..., capacity_factor=f)``) —
@@ -24,6 +24,15 @@ standard Shazeer/Switch recipe).  Three execution paths:
   computes its local experts' contribution over fully-replicated
   activations and psums.  Exact (no capacity drops) but O(B·T·H)
   replicated memory — right for small E / small batches only.
+
+The experts' parameters are **stacked leaves** (a leading expert axis),
+stacked once when the layer is built: no path re-stacks a Python list of
+modules on every call.
+
+:class:`HeldExperts` is the serving-side layer: one chip's share of a
+sigmoid-routed layer of gated experts, no capacity and no drop; a batched
+product over every held stack for a pool's step or chunk, a grouped
+product for a longer call.
 """
 
 from __future__ import annotations
@@ -35,24 +44,42 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from bigdl_tpu.core.module import Module, ModuleList, Parameter
+from bigdl_tpu.core.module import Module, Parameter
 from bigdl_tpu.telemetry import collectives as _coll
 from bigdl_tpu.nn.linear import Linear
 from bigdl_tpu.utils.rng import next_key
 from bigdl_tpu.parallel.mesh import pin_replicated, shard_map_compat
 
-__all__ = ["MoE"]
+__all__ = ["MoE", "HeldExperts", "route_top_k"]
 
 # Per-device (inside-shard_map) buffer shapes of the most recent a2a
 # trace — a debug/test hook (module attrs would pollute the pytree).
 LAST_A2A_SHAPES = {}
 
 
+def route_top_k(scores, k: int, normalize: bool = True, bias=None):
+    """Routing from scores to weights, the one place it is decided:
+    ``scores [..., E]`` (softmax probabilities, sigmoids: any
+    non-negative score) -> ``(experts [..., k] int32, weights [..., k])``.
+    The ``k`` largest of ``scores + bias`` are chosen (``bias [E]``: a
+    selection bias that balances load and never reaches the weights);
+    each weight is the chosen expert's own score and, with
+    ``normalize``, over the sum of the chosen (``norm_topk_prob``)."""
+    ranked = scores if bias is None else scores + bias
+    _, idx = jax.lax.top_k(ranked, k)
+    vals = jnp.take_along_axis(scores, idx, axis=-1)
+    if normalize:
+        vals = vals / jnp.sum(vals, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), vals
+
+
 class MoE(Module):
     """Top-k routed mixture of experts over position-wise expert modules.
 
     experts: list of identical Modules mapping [..., H] -> [..., H]
-    (e.g. FeedForwardNetwork).  ``forward(x)`` takes [B, T, H].
+    (e.g. FeedForwardNetwork), stacked here once into ``self.experts``:
+    one module of the same class whose every leaf has a leading expert
+    axis (apply it under ``vmap``).  ``forward(x)`` takes [B, T, H].
     After a forward, ``self.aux_loss`` holds the load-balancing loss
     (mean over tokens of E · Σ_e f_e · p_e) to be added to the training
     objective by the caller.
@@ -64,7 +91,8 @@ class MoE(Module):
         self.hidden_size = hidden_size
         self.top_k = top_k
         self.num_experts = len(experts)
-        self.experts = ModuleList(experts)
+        self.experts = jax.tree_util.tree_map(
+            lambda *leaves: jnp.stack(leaves), *list(experts))
         self.gate = Linear(hidden_size, self.num_experts, with_bias=False)
         self.aux_loss = jnp.zeros(())
         # overflow-drop fraction of the last a2a forward (0 on the
@@ -114,15 +142,11 @@ class MoE(Module):
         that already ran the gate avoid running it twice."""
         if probs is None:
             probs = self._gate_probs(x)
-        mask = self._topk_mask(probs)
-        weights = jnp.where(mask, probs, 0.0)
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
-        self._set_aux_loss(probs, mask)
+        idx, vals = route_top_k(probs, self.top_k)
+        hot = jax.nn.one_hot(idx, self.num_experts, dtype=probs.dtype)
+        weights = jnp.einsum("...k,...ke->...e", vals, hot)
+        self._set_aux_loss(probs, jnp.sum(hot, axis=-2) > 0)
         return weights.astype(x.dtype)
-
-    def _stacked_experts(self):
-        return jax.tree_util.tree_map(
-            lambda *leaves: jnp.stack(leaves), *list(self.experts))
 
     @staticmethod
     def _apply_stacked(stacked, x):
@@ -142,7 +166,7 @@ class MoE(Module):
             return self.forward_on_mesh(x, self.expert_mesh,
                                         self.expert_axis)
         weights = self._route(x)  # [B, T, E]
-        outs = self._apply_stacked(self._stacked_experts(), x)  # [E,B,T,H]
+        outs = self._apply_stacked(self.experts, x)  # [E,B,T,H]
         return jnp.einsum("ebth,bte->bth", outs, weights)
 
     # -- expert-parallel paths --------------------------------------------
@@ -157,8 +181,7 @@ class MoE(Module):
         exceeds the capacity are dropped (their combine weight is 0 —
         the residual stream carries them unchanged)."""
         S, E = probs.shape
-        top_vals, top_idx = jax.lax.top_k(probs, self.top_k)
-        denom = jnp.sum(top_vals, axis=-1, keepdims=True)  # renormalize
+        top_idx, top_w = route_top_k(probs, self.top_k)   # renormalized
         dispatch = jnp.zeros((S, E, capacity), jnp.float32)
         combine = jnp.zeros((S, E, capacity), jnp.float32)
         counts = jnp.zeros((E,), jnp.int32)
@@ -175,7 +198,7 @@ class MoE(Module):
                         * jax.nn.one_hot(pos, capacity)[:, None, :]
                         * keep[:, None, None])           # [S, E, C]
             dispatch = dispatch + slot_hot
-            w = (top_vals[:, slot] / denom[:, 0])
+            w = top_w[:, slot]
             combine = combine + slot_hot * w[:, None, None]
         # fraction of routed (token, slot) assignments that overflowed
         # this shard's per-expert capacity — the telemetry the reference
@@ -215,7 +238,7 @@ class MoE(Module):
         self._set_aux_loss(probs, self._topk_mask(probs))
         xf = x.reshape(s_total, H)
         pf = probs.reshape(s_total, E)
-        stacked = self._stacked_experts()
+        stacked = self.experts
 
         moe = self
 
@@ -260,7 +283,7 @@ class MoE(Module):
         n = mesh.shape[axis]
         assert self.num_experts % n == 0, (self.num_experts, n)
         weights = self._route(x)
-        stacked = self._stacked_experts()
+        stacked = self.experts
 
         def shard_fn(stacked_local, x_rep, w_rep):
             # stacked_local leaves: [E/n, ...]; w_rep [B, T, E]
@@ -281,3 +304,153 @@ class MoE(Module):
         x = pin_replicated(x, mesh)
         weights = pin_replicated(weights, mesh)
         return fn(stacked, x, weights)
+
+
+def grouped_product(rows, stack, sizes):
+    """``rows [M, in]`` sorted by group, ``stack [E, in, out]``, ``sizes
+    [E]`` rows a group -> float32 ``[M, out]``: rows of group ``e`` times
+    ``stack[e]``.  Rows past ``sum(sizes)`` are not computed, and what
+    they hold is undefined."""
+    return jax.lax.ragged_dot(rows, stack, sizes,
+                              preferred_element_type=jnp.float32)
+
+
+class Router(Module):
+    """The router of :class:`HeldExperts`: ``weight [E, H]`` scores every
+    expert, ``bias [E]`` moves which are chosen and never the weights."""
+
+    def __init__(self, hidden_size: int, num_experts: int):
+        super().__init__()
+        self.weight = Parameter(jax.random.normal(
+            next_key(), (num_experts, hidden_size)) * hidden_size ** -0.5)
+        self.bias = Parameter(jnp.zeros(num_experts))
+
+
+class HeldExperts(Module):
+    """One chip's share of a sigmoid-routed layer of gated experts.
+
+    ``s = sigmoid(x W_r)`` over all ``num_experts`` (the router keeps its
+    published width), the ``top_k`` largest of ``s + b`` chosen (``b`` a
+    selection bias), ``w_e = s_e`` over the sum of the chosen; expert
+    ``e`` is ``W_d(silu(W_g x) * W_u x)``.  This layer holds experts
+    ``first .. first + count`` of them as stacked leaves
+    ``[count, in, out]`` (the layout both products below take as it lies:
+    compiled for a v5e, a stack ``[count, out, in]`` is copied whole, 268
+    MB at 16 x 2048 x 4096, on every call), and computes their part of
+    the result for the tokens routed to them.  No capacity, no drop; what
+    the absent experts would add is left out, and nothing stands in for
+    the exchange that would bring other chips' tokens.
+
+    **Two products over the stack, chosen by the call's token count**
+    (static under ``jit``).  Up to ``DENSE_TOKENS`` tokens (a slot pool's
+    decode step and its prefill chunks) every token goes through every
+    held expert in one batched product and the routing weights, zero for
+    an expert a token did not choose, pick the result: each held stack is
+    read once a call whatever the routing, as it is in the deployment the
+    share stands for, whose pooled sequences leave no held expert idle; a
+    call's cost then does not follow which experts its few tokens chose.
+    Above it (a whole sequence at once) the token-to-expert pairs are
+    grouped by held expert and go through one grouped product
+    (``jax.lax.ragged_dot``), so an expert costs the tokens that chose
+    it.  The count is where the two cross on a v5e at 16 held of 256
+    experts of 4,096 x 2,048 (PERF.md section 6, PR 30): one layer took
+    1.12 against 1.16 ms at 32 tokens, 1.40 against 2.83 at 256, 2.49
+    against 3.22 at 512 and 5.00 against 4.59 at 1,024.
+
+    ``forward(x [..., H], valid=None) -> (y [..., H] float32, counts)``:
+    ``x`` is routed as it comes (float32 from a float32 norm) and cast to
+    the experts' dtype for their products; ``valid [...]`` false keeps a
+    token away from every expert (a slot pool's idle lanes, padding);
+    ``counts`` is int32 ``[4]``: 1 (this call), the token-to-expert pairs
+    routed, those that landed on a held expert, and the held experts that
+    had at least one."""
+
+    DENSE_TOKENS = 512
+
+    def __init__(self, hidden_size: int, expert_size: int, num_experts: int,
+                 top_k: int, held: Optional[tuple] = None,
+                 normalize: bool = True):
+        super().__init__()
+        first, count = (0, num_experts) if held is None else held
+        if not 0 <= first < first + count <= num_experts:
+            raise ValueError(f"held share {held} outside 0..{num_experts}")
+        self.num_experts, self.top_k = num_experts, top_k
+        self.first, self.count = int(first), int(count)
+        self.normalize = normalize
+
+        def stack(fan_in, fan_out):
+            return Parameter(jax.random.normal(
+                next_key(), (count, fan_in, fan_out)) * fan_in ** -0.5)
+        self.w_gate = stack(hidden_size, expert_size)
+        self.w_up = stack(hidden_size, expert_size)
+        self.w_down = stack(expert_size, hidden_size)
+        self.router = Router(hidden_size, num_experts)
+
+    def route(self, x):
+        """``x [T, H] -> (experts [T, k], weights [T, k] float32)``.  The
+        router runs in float32 whatever the experts are served in: it is
+        a sliver of the layer's work, and a score rounded across the
+        ``top_k``-th place sends a token to another expert."""
+        with jax.named_scope("moe/route"):
+            logits = jnp.einsum(
+                "th,eh->te", x.astype(jnp.float32),
+                self.router.weight.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST)
+            return route_top_k(jax.nn.sigmoid(logits), self.top_k,
+                               self.normalize,
+                               self.router.bias.astype(jnp.float32))
+
+    def _every_stack(self, x, local, weights, held):
+        """Every token through every held expert: ``x [T, H]`` in the
+        experts' dtype -> ``(y [T, H] float32, held experts chosen)``."""
+        with jax.named_scope("moe/experts"):
+            dot = functools.partial(jnp.einsum,
+                                    preferred_element_type=jnp.float32)
+            xs = jnp.broadcast_to(x, (self.count,) + x.shape)
+            act = jax.nn.silu(dot("eth,ehf->etf", xs, self.w_gate)) \
+                * dot("eth,ehf->etf", xs, self.w_up)
+            out = dot("etf,efh->eth", act.astype(x.dtype), self.w_down)
+        with jax.named_scope("moe/combine"):
+            chose = held[..., None] & (
+                local[..., None] == jnp.arange(self.count))  # [T, k, count]
+            w = jnp.sum(jnp.where(chose, weights[..., None], 0.0), axis=1)
+            return (jnp.einsum("eth,te->th", out, w),
+                    jnp.sum(jnp.any(chose, axis=(0, 1))))
+
+    def _grouped(self, x, local, weights, held):
+        """The pairs grouped by held expert: same arguments and result."""
+        T, k = local.shape
+        with jax.named_scope("moe/experts"):
+            # every pair gets a group: its held expert, or the one past
+            # the last (never computed); sorted, each expert's rows lie
+            # together and the rows of no held expert come last
+            group = jnp.where(held, local, self.count).reshape(-1)
+            order = jnp.argsort(group, stable=True)
+            sizes = jnp.zeros((self.count + 1,), jnp.int32).at[group].add(1)
+            rows = x[order // k]                              # [T*k, H]
+            dot = functools.partial(grouped_product,
+                                    sizes=sizes[:self.count])
+            act = jax.nn.silu(dot(rows, self.w_gate)) * dot(rows, self.w_up)
+            out = dot(act.astype(x.dtype), self.w_down)
+        with jax.named_scope("moe/combine"):
+            w = (weights * held).reshape(-1)[order]
+            # rows past the held groups are whatever the product left
+            out = jnp.where(w[:, None] > 0, out * w[:, None], 0.0)
+            return (out[jnp.argsort(order)].reshape(T, k, -1).sum(axis=1),
+                    jnp.sum(sizes[:self.count] > 0))
+
+    def forward(self, x, valid=None):
+        lead, H = x.shape[:-1], x.shape[-1]
+        x = x.reshape(-1, H)
+        experts, weights = self.route(x)
+        T, k = experts.shape
+        local = experts - self.first
+        routed = jnp.ones((T, 1), bool) if valid is None \
+            else valid.reshape(-1, 1)
+        held = (local >= 0) & (local < self.count) & routed
+        product = self._every_stack if T <= self.DENSE_TOKENS \
+            else self._grouped
+        y, chosen = product(x.astype(self.w_gate.dtype), local, weights, held)
+        counts = jnp.stack([jnp.int32(1), jnp.sum(routed) * k, jnp.sum(held),
+                            chosen]).astype(jnp.int32)
+        return y.reshape(lead + (H,)), counts

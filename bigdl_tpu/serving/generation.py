@@ -144,6 +144,16 @@ class SlotPool:
     changes (``_dirty``).  The pooled caches live on device and are
     donated through every update.
 
+    **The cache is a list by layer**, each layer with its own rows, heads
+    and widths, as the model declares them (``cache_layers()``,
+    ``init_cache``): a ``full`` layer keeps ``max_len`` positions a slot;
+    a ``ring`` layer (windowed attention) keeps the window and room for a
+    prefill chunk beside it (``ring_margin``: a chunk is written before
+    it is attended), position ``p`` at place ``p % ring``, and one spare
+    place where idle lanes write.  Keys and values of a layer may differ
+    in width.  ``TransformerLM`` is the case "every layer full, one
+    shape".  All six programs below take the list.
+
     **How the pool lies on the chip.**  A K or V leaf is
     ``[S, heads, max_len, head_dim]``.  With a head size under the 128
     lanes of a TPU tile (OPT's 64) the compiler stores it as
@@ -162,31 +172,60 @@ class SlotPool:
     ``docs/performance.md`` says how to read such a program's HLO."""
 
     def __init__(self, model, slots: int, dtype=None,
-                 prefill_batch: int = 4):
+                 prefill_batch: int = 4, ring_margin: int = 1):
+        import jax
         import jax.numpy as jnp
         if getattr(model, "seq_parallel", False):
             raise ValueError(
                 "sequence-parallel models cannot serve from a slot pool "
                 "(the ring path has no decode cache); build a dense copy")
-        for attr in ("init_cache", "decode_step", "prefill_kv",
-                     "prefill_chunk", "max_len", "_mask_untrained_logit"):
+        for attr in ("init_cache", "cache_layers", "decode_step",
+                     "prefill_kv", "prefill_chunk", "max_len",
+                     "_mask_untrained_logit"):
             if not hasattr(model, attr):
                 raise TypeError(
                     f"slot-pool generation needs a model with the "
-                    f"incremental-decode API (init_cache/decode_step/"
-                    f"prefill_kv/prefill_chunk): "
+                    f"incremental-decode API (init_cache/cache_layers/"
+                    f"decode_step/prefill_kv/prefill_chunk): "
                     f"{type(model).__name__} lacks {attr!r}")
         if slots < 1:
             raise ValueError(f"slots must be >= 1, got {slots}")
         # private eval-mode copy: serving must not flip the caller's
         # training flags, and dropout in decode would break greedy
-        # equivalence with generate() on an eval'd model
-        self.model = model.clone().eval_mode()
+        # equivalence with generate() on an eval'd model.  The copy is of
+        # the module STRUCTURE and shares the leaves: ``model.clone()`` is
+        # a deep copy, and a deep copy of a jax.Array is a second buffer
+        # on the device — a model that fills the chip would stand on it
+        # twice.  Arrays are immutable, so sharing them is safe.
+        leaves, treedef = jax.tree_util.tree_flatten(model)
+        self.model = jax.tree_util.tree_unflatten(treedef,
+                                                  leaves).eval_mode()
         self.slots = int(slots)
         self.dtype = jnp.float32 if dtype is None else dtype
         self.prefill_batch = max(1, int(prefill_batch))
         self.max_len = int(model.max_len)
-        self.caches = self.model.init_cache(self.slots, self.dtype)
+        # each layer's cache as the model declares it: ("full", max_len)
+        # or ("ring", window).  A ring is allocated with room for a
+        # prefill chunk of ``ring_margin`` positions beside its window:
+        # the chunk is written before it is attended, and must leave its
+        # first query's keys in place.  The default is room for one
+        # position, what a pool that is never handed a chunk needs (the
+        # scheduler passes its ``prefill_chunk``).
+        self.cache_layers = tuple(model.cache_layers())
+        self.has_ring = any(k == "ring" for k, _ in self.cache_layers)
+        self.ring_margin = int(ring_margin)
+        self.caches = self.model.init_cache(
+            self.slots, self.dtype,
+            **({"ring_margin": self.ring_margin} if self.has_ring else {}))
+        # a model with expert layers returns what they did from every
+        # pass (int32 [4]: calls, pairs routed, pairs on held experts,
+        # held experts with a token).  The counts gather on the device
+        # (prefill programs add theirs) and ride the next decode step's
+        # read-back behind the tokens: no transfer of their own.
+        self.expert_layers = int(model.expert_layers()) \
+            if hasattr(model, "expert_layers") else 0
+        self._routing = jnp.zeros((4 if self.expert_layers else 0,),
+                                  jnp.int32)
         self.tok = np.zeros((self.slots,), np.int32)
         self.index = np.zeros((self.slots,), np.int32)
         self.active = np.zeros((self.slots,), bool)
@@ -218,6 +257,7 @@ class SlotPool:
     def _build_programs(self):
         import jax
         import jax.numpy as jnp
+        from bigdl_tpu.nn.attention import _write_window
         counts = self.trace_counts
 
         # The model is an ARGUMENT of every program that runs it, never
@@ -226,7 +266,9 @@ class SlotPool:
         # 32k vocabulary, slow to compile and too large for the
         # persistent compilation cache to keep.
 
-        def _decode(model, caches, tok, index, active):
+        experts = self.expert_layers > 0
+
+        def _decode(model, caches, tok, index, active, routing):
             counts["decode"] += 1
 
             # ONE batched step over the S slots, each written and masked
@@ -234,17 +276,14 @@ class SlotPool:
             # vmap of a batch-1 step: see the class docstring.
             #
             # Every lane writes its position's K/V (S is shape-stable),
-            # so an INACTIVE lane must write somewhere provably unread:
-            # max_len-1 is beyond every prefill query's mask and is
-            # always freshly rewritten by an occupant's own decode
-            # before it is attended — a stale index would instead
-            # clobber a co-scheduled chunked prefill's freshly written
-            # positions (caught by test_decode_does_not_disturb_
-            # inactive_rows)
-            safe_index = jnp.where(active, index,
-                                   jnp.int32(model.max_len - 1))
-            logits, new_caches = model.decode_step(tok[:, None],
-                                                   safe_index, caches)
+            # so the model is told which lanes are idle and sends them
+            # where nothing reads: the last position of a full row, the
+            # spare place of a ring (a ring has no position that a
+            # prompt longer than the window may not just have written).
+            # A model with expert layers returns what they did as a
+            # third value.
+            logits, new_caches, *did = model.decode_step(
+                tok[:, None], index, caches, active=active)
             nxt = jnp.argmax(model._mask_untrained_logit(logits),
                              axis=-1).astype(jnp.int32) + 1
             # the feed advances IN-GRAPH so step N+1 can be dispatched
@@ -254,8 +293,13 @@ class SlotPool:
             # slots emit argmax+1 >= 1, never 0)
             new_tok = jnp.where(active, nxt, tok)
             new_index = jnp.where(active, index + 1, index)
-            return new_caches, new_tok, new_index, \
-                jnp.where(active, nxt, 0)
+            # what the expert layers did, here and in the prefill
+            # programs since the last step, rides behind the S tokens:
+            # one read-back a step, whatever it carries
+            emit = jnp.concatenate(
+                [jnp.where(active, nxt, 0), routing + sum(did)])
+            return new_caches, new_tok, new_index, emit, \
+                jnp.zeros_like(routing)
 
         self._decode_jit = jax.jit(_decode, donate_argnums=(1, 2, 3))
 
@@ -270,23 +314,38 @@ class SlotPool:
             t = int(pads.shape[1])
             counts["scatter"][t + 1] = counts["scatter"].get(t + 1, 0) + 1
             new_layers = []
-            for kv, cache in zip(layers_kv, caches["layers"]):
+            # a ring keeps each row's newest positions, position p at
+            # place p % R: place j takes the newest REAL position
+            # congruent to it (trailing bucket padding is not one), by a
+            # gather; a place with none yet takes anything, its position
+            # reads as unwritten (attention.cache_positions)
+            last = jnp.sum(~pads, axis=1).astype(jnp.int32) - 1     # [B]
+            for (kind, _), kv, cache in zip(self.cache_layers, layers_kv,
+                                            caches["layers"]):
                 old = cache["self"]
-                # rows for padded prefill lanes carry slot_id == S:
-                # mode="drop" discards the out-of-range scatter instead
-                # of writing a real slot
-                new_layers.append({"self": {
-                    "k": old["k"].at[slot_ids, :, :t, :].set(
-                        kv["k"].astype(old["k"].dtype), mode="drop"),
-                    "v": old["v"].at[slot_ids, :, :t, :].set(
-                        kv["v"].astype(old["v"].dtype), mode="drop"),
-                }})
+                new = {}
+                for n in ("k", "v"):
+                    src, n_places = kv[n].astype(old[n].dtype), t
+                    if kind == "ring":
+                        n_places = old[n].shape[2] - 1
+                        j = jnp.arange(n_places, dtype=jnp.int32)
+                        pos = last[:, None] - jnp.mod(last[:, None] - j,
+                                                      n_places)
+                        src = jnp.take_along_axis(
+                            src, jnp.clip(pos, 0, t - 1)[:, None, :, None],
+                            axis=2)
+                    # rows for padded prefill lanes carry slot_id == S:
+                    # mode="drop" discards the out-of-range scatter
+                    # instead of writing a real slot
+                    new[n] = old[n].at[slot_ids, :, :n_places, :].set(
+                        src, mode="drop")
+                new_layers.append({"self": new})
             pad = caches["pad"].at[slot_ids, :t].set(pads, mode="drop")
             return {"layers": new_layers, "pad": pad}
 
         self._scatter_jit = jax.jit(_scatter, donate_argnums=(0,))
 
-        def _chunk_prefill(model, caches, slot_id, toks, index):
+        def _chunk_prefill(model, caches, slot_id, toks, index, routing):
             w = int(toks.shape[0])
             counts["chunk_prefill"][w] = \
                 counts["chunk_prefill"].get(w, 0) + 1
@@ -295,8 +354,11 @@ class SlotPool:
             # pool absorbs in place) and reads the row's keys by slice;
             # slot_id and index are traced, so the program is keyed by
             # chunk width alone
-            return model.prefill_chunk(toks[None], index, caches,
-                                       slot=slot_id)
+            out = model.prefill_chunk(toks[None], index, caches,
+                                      slot=slot_id)
+            if experts:
+                return out[0], routing + out[1]
+            return out, routing
 
         self._chunk_jit = jax.jit(_chunk_prefill, donate_argnums=(1,))
 
@@ -304,16 +366,13 @@ class SlotPool:
             g = int(pad.shape[0])
             counts["kv_copy"][g] = counts["kv_copy"].get(g, 0) + 1
             new_layers = []
-            for kv, cache in zip(layers_kv, caches["layers"]):
+            for (kind, _), kv, cache in zip(self.cache_layers, layers_kv,
+                                            caches["layers"]):
                 old = cache["self"]
                 new_layers.append({"self": {
-                    "k": jax.lax.dynamic_update_slice(
-                        old["k"], kv["k"][None].astype(old["k"].dtype),
-                        (slot_id, 0, index, 0)),
-                    "v": jax.lax.dynamic_update_slice(
-                        old["v"], kv["v"][None].astype(old["v"].dtype),
-                        (slot_id, 0, index, 0)),
-                }})
+                    n: _write_window(old[n], kv[n][None], slot_id, index,
+                                     kind == "ring")[0]
+                    for n in ("k", "v")}})
             new_pad = jax.lax.dynamic_update_slice(
                 caches["pad"], pad[None], (slot_id, index))
             return {"layers": new_layers, "pad": new_pad}
@@ -324,17 +383,24 @@ class SlotPool:
             counts["kv_extract"][width] = \
                 counts["kv_extract"].get(width, 0) + 1
             layers = []
-            for cache in caches["layers"]:
-                old = cache["self"]
-                _, h, _, d = old["k"].shape
-                layers.append({
-                    "k": jax.lax.dynamic_slice(
-                        old["k"], (slot_id, 0, index, 0),
-                        (1, h, width, d))[0],
-                    "v": jax.lax.dynamic_slice(
-                        old["v"], (slot_id, 0, index, 0),
-                        (1, h, width, d))[0],
-                })
+            for (kind, _), cache in zip(self.cache_layers,
+                                        caches["layers"]):
+                out = {}
+                for n, leaf in cache["self"].items():
+                    _, h, places, d = leaf.shape
+                    if kind == "ring":
+                        # by place: right only while the ring still
+                        # holds these positions (the scheduler keeps the
+                        # prefix cache off a model with rings)
+                        row = jax.lax.dynamic_slice(
+                            leaf, (slot_id, 0, 0, 0), (1, h, places, d))[0]
+                        out[n] = jnp.take(row, jnp.mod(
+                            index + jnp.arange(width), places - 1), axis=1)
+                    else:
+                        out[n] = jax.lax.dynamic_slice(
+                            leaf, (slot_id, 0, index, 0),
+                            (1, h, width, d))[0]
+                layers.append(out)
             pad = jax.lax.dynamic_slice(caches["pad"], (slot_id, index),
                                         (1, width))[0]
             return layers, pad
@@ -360,6 +426,22 @@ class SlotPool:
         return sum(int(leaf.size) * leaf.dtype.itemsize
                    for leaf in jax.tree_util.tree_leaves(self.caches))
 
+    def cache_nbytes_by_kind(self) -> Dict[str, int]:
+        """Bytes of the keys and values by the kind of their layer's
+        cache (``full`` | ``ring``)."""
+        import jax
+        out = {"full": 0, "ring": 0}
+        for (kind, _), layer in zip(self.cache_layers,
+                                    self.caches["layers"]):
+            out[kind] += sum(int(leaf.size) * leaf.dtype.itemsize
+                             for leaf in jax.tree_util.tree_leaves(layer))
+        return out
+
+    def _routing_aval(self):
+        import jax
+        return jax.ShapeDtypeStruct(self._routing.shape,
+                                    self._routing.dtype)
+
     def _cache_avals(self):
         import jax
         return jax.tree_util.tree_map(
@@ -374,7 +456,8 @@ class SlotPool:
             self.model, self._cache_avals(),
             jax.ShapeDtypeStruct(s, jnp.int32),
             jax.ShapeDtypeStruct(s, jnp.int32),
-            jax.ShapeDtypeStruct(s, jnp.bool_)).compile()
+            jax.ShapeDtypeStruct(s, jnp.bool_),
+            self._routing_aval()).compile()
 
     def decode_hlo_text(self) -> str:
         """Optimized HLO of the pooled decode step at the live pool
@@ -390,7 +473,8 @@ class SlotPool:
         scalar = jax.ShapeDtypeStruct((), jnp.int32)
         return self._chunk_jit.lower(
             self.model, self._cache_avals(), scalar,
-            jax.ShapeDtypeStruct((width,), jnp.int32), scalar).compile()
+            jax.ShapeDtypeStruct((width,), jnp.int32), scalar,
+            self._routing_aval()).compile()
 
     def kv_copy_compiled(self, granularity: int):
         """Compiled prefix KV-copy program at ``granularity``."""
@@ -399,11 +483,10 @@ class SlotPool:
         scalar = jax.ShapeDtypeStruct((), jnp.int32)
         layers = []
         for cache in self.caches["layers"]:
-            old = cache["self"]
-            _, h, _, d = old["k"].shape
-            aval = jax.ShapeDtypeStruct((h, granularity, d),
-                                        old["k"].dtype)
-            layers.append({"k": aval, "v": aval})
+            layers.append({
+                n: jax.ShapeDtypeStruct(
+                    (leaf.shape[1], granularity, leaf.shape[3]), leaf.dtype)
+                for n, leaf in cache["self"].items()})
         pad = jax.ShapeDtypeStruct((granularity,), jnp.bool_)
         return self._kv_copy_jit.lower(
             self._cache_avals(), scalar, layers, pad, scalar).compile()
@@ -447,6 +530,17 @@ class SlotPool:
     def release(self, slot: int) -> None:
         self._seed_slot(slot, 0, 0, False)
 
+    def free(self) -> None:
+        """Let go of everything this pool holds on the device: the
+        caches, the device feed and the model (its leaves are shared
+        with whoever built it, and live on only while that caller still
+        holds them).  A freed pool runs no further program."""
+        self.caches = None
+        self.model = None
+        self._dev = None
+        self._routing = None
+        self._open_handle = None
+
     def invalidate_feed(self) -> None:
         """Drop the device feed (e.g. after a failed dispatch may have
         consumed its donated buffers); the next dispatch rebuilds it
@@ -474,8 +568,10 @@ class SlotPool:
                 padded[n:] = padded[0]
             ids = np.full((self.prefill_batch,), self.slots, np.int32)
             ids[:n] = np.asarray(slot_ids, np.int32)
-            layers_kv, pads = self._prefill_jit(
+            layers_kv, pads, *did = self._prefill_jit(
                 self.model, jnp.asarray(padded[:, :-1]))
+            if did:     # an expert model's routing: see __init__
+                self._routing = self._routing + did[0]
             self.caches = self._scatter_jit(
                 self.caches, jnp.asarray(ids), layers_kv, pads)
         for p, s in zip(prompts, slot_ids):
@@ -490,10 +586,10 @@ class SlotPool:
         ``[index, index+len(toks))`` of ``slot``'s cache row, attending
         to everything already written below ``index``."""
         import jax.numpy as jnp
-        self.caches = self._chunk_jit(
+        self.caches, self._routing = self._chunk_jit(
             self.model, self.caches, np.int32(slot),
             jnp.asarray(np.ascontiguousarray(toks, np.int32)),
-            np.int32(index))
+            np.int32(index), self._routing)
 
     def kv_copy_into(self, slot: int,
                      chain: Sequence[PrefixChunk]) -> None:
@@ -531,8 +627,9 @@ class SlotPool:
                          jnp.asarray(self.active))
             self._dirty = False
         tok_d, idx_d, act_d = self._dev
-        self.caches, new_tok, new_idx, emit = self._decode_jit(
-            self.model, self.caches, tok_d, idx_d, act_d)
+        self.caches, new_tok, new_idx, emit, self._routing = \
+            self._decode_jit(self.model, self.caches, tok_d, idx_d, act_d,
+                             self._routing)
         self._dev = (new_tok, new_idx, act_d)
         self._emit_active = self.active.copy()
         self._touched[:] = False
@@ -556,6 +653,7 @@ class SlotPool:
         if self._open_handle is handle:
             self._open_handle = None
         out = np.asarray(handle.emit)
+        out, handle.routing = out[:self.slots], out[self.slots:]
         feed = out.astype(np.int32)
         self.tok = np.where(was, feed, self.tok).astype(np.int32)
         self.index = np.where(was, self.index + 1,
@@ -576,11 +674,14 @@ class _StepHandle:
     epoch (finalized at the NEXT dispatch — until then the pool's live
     epoch applies)."""
 
-    __slots__ = ("emit", "mask")
+    __slots__ = ("emit", "mask", "routing")
 
     def __init__(self, emit):
         self.emit = emit
         self.mask: Optional[np.ndarray] = None
+        # read back with the tokens: what the expert layers did since
+        # the previous step (empty for a model without them)
+        self.routing: Optional[np.ndarray] = None
 
 
 class _ActiveSlot:
@@ -614,7 +715,9 @@ _ENGINE_COUNTERS = _ENGINE_PHASES + (
     "iterations", "decode_dispatches", "pipeline_drains",
     "gaps_plain", "gaps_prefill", "gap_seconds_plain",
     "gap_seconds_prefill", "prefill_positions", "prefill_prompt_tokens",
-    "admitted", "queue_wait_seconds")
+    "admitted", "queue_wait_seconds",
+    "moe_layer_calls", "moe_pairs_total", "moe_pairs_held",
+    "moe_active_experts")
 
 
 def _fold_counts(acc: Dict[str, float], eng: Dict[str, float]) -> None:
@@ -713,7 +816,9 @@ class GenerationScheduler:
                  prefix_cache: Optional[PrefixKVCache] = None,
                  role: str = "mixed"):
         self.pool = SlotPool(model, slots, dtype=dtype,
-                             prefill_batch=prefill_batch)
+                             prefill_batch=prefill_batch,
+                             ring_margin=int(prefill_chunk))
+        self._cache_bytes = self.pool.cache_nbytes_by_kind()
         self.default_eos_id = eos_id
         if role not in ("mixed", "prefill"):
             raise ValueError(
@@ -736,6 +841,12 @@ class GenerationScheduler:
                 None if not prefix_cache_bytes
                 else PrefixKVCache(int(prefix_cache_bytes),
                                    int(prefix_granularity)))
+        if self._prefix_cache is not None and self.pool.has_ring:
+            raise ValueError(
+                "the prefix cache copies and extracts keys and values by "
+                "position, which a ring does not keep: a model with "
+                "ring (windowed) cache layers is served with the prefix "
+                "cache off")
         if role == "prefill" and self._prefix_cache is None:
             raise ValueError(
                 "a prefill-role engine publishes its K/V through the "
@@ -815,11 +926,13 @@ class GenerationScheduler:
         slot (decoding OR mid-prefill) always finish — a multi-step
         decode is never abandoned half-emitted."""
         with self._lock:
-            if self._shutdown:
-                return
-            self._shutdown = True
-        self._queue.close(discard=not drain)
-        if self._thread is not None:
+            first, self._shutdown = not self._shutdown, True
+        if first:
+            self._queue.close(discard=not drain)
+        # after a kill() too: the engine thread is then on its way out,
+        # and whoever waits here finds the pool freed
+        if self._thread is not None \
+                and self._thread is not threading.current_thread():
             self._thread.join(timeout)
             if self._thread.is_alive():
                 logger.warning(
@@ -1019,6 +1132,18 @@ class GenerationScheduler:
                 "prefill_prompt_tokens": eng["prefill_prompt_tokens"],
                 "admitted": eng["admitted"],
                 "queue_wait_seconds": float(eng["queue_wait_seconds"]),
+                # what the expert layers did, in decode and prefill
+                # programs alike: calls of an expert layer, the
+                # token-to-expert pairs they routed, those that landed
+                # on an expert held here, and the held experts that had
+                # a token, summed over the calls (all zero for a model
+                # without expert layers)
+                "moe_layer_calls": eng["moe_layer_calls"],
+                "moe_pairs_total": eng["moe_pairs_total"],
+                "moe_pairs_held": eng["moe_pairs_held"],
+                "moe_active_experts": eng["moe_active_experts"],
+                "cache_bytes_full": self._cache_bytes["full"],
+                "cache_bytes_window": self._cache_bytes["ring"],
             }
         cache = self._prefix_cache
         out["prefix_cache"] = None if cache is None else cache.stats()
@@ -1054,6 +1179,13 @@ class GenerationScheduler:
         finally:
             self._mark("other")
             self._fold()
+            with self._lock:
+                killed = self._die_exc is not None
+            if killed:
+                # a replica killed hard gives the chip back: whoever still
+                # holds the engine (a router's table, a load generator)
+                # must not keep its weights and caches on the device
+                self.pool.free()
 
     def _loop(self) -> None:
         while True:
@@ -1686,6 +1818,11 @@ class GenerationScheduler:
         # their neighbours under the one-deep pipeline.
         dt = None if self._t_readback is None else now - self._t_readback
         self._t_readback = now
+        if len(emit.routing):
+            for key, n in zip(("moe_layer_calls", "moe_pairs_total",
+                               "moe_pairs_held", "moe_active_experts"),
+                              emit.routing):
+                self._acc[key] += int(n)
         if dt is not None:
             kind = "prefill" if after_prefill else "plain"
             self._acc["gaps_" + kind] += 1

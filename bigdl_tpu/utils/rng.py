@@ -36,7 +36,10 @@ class RandomGenerator:
         import jax
         with self._lock:
             if self._key is None:
-                self._key = jax.random.key(self.seed)
+                # a model may be built first under a trace (``jax.eval_shape``
+                # for its shapes): the stream's key must not be that trace's
+                with jax.ensure_compile_time_eval():
+                    self._key = jax.random.key(self.seed)
             self._count += 1
             return jax.random.fold_in(self._key, self._count)
 

@@ -512,6 +512,8 @@ ELSEWHERE = {
     "TableOperation": "test_t7_table_metrics.py",
     # parallel / moe
     "MoE": "test_parallel.py",
+    "HeldExperts": "test_hybrid_decoder.py",
+    "GroupedQueryAttention": "test_hybrid_decoder.py",
     # containers & recurrent variants exercised with numerics elsewhere
     "Sequential": "test_optim.py",
     "ConvLSTMPeephole3D": "test_sparse_tree_misc.py",

@@ -243,10 +243,11 @@ def _lower_pool_program(pool, program, sharding=None):
     if program == "decode":
         return pool._decode_jit.lower(
             model, caches, sds((s,), jnp.int32), sds((s,), jnp.int32),
-            sds((s,), jnp.bool_))
+            sds((s,), jnp.bool_), aval(pool._routing))
     if program == "chunk_prefill":
         return pool._chunk_jit.lower(
-            model, caches, scalar, sds((64,), jnp.int32), scalar)
+            model, caches, scalar, sds((64,), jnp.int32), scalar,
+            aval(pool._routing))
     if program == "scatter":
         b = pool.prefill_batch
         return pool._scatter_jit.lower(
@@ -287,3 +288,134 @@ def test_pool_decode_step_lowers_to_no_scatter_over_the_pool(pool):
     assert not [t for t in operands if t.endswith(
         "x%dx%dxf32>" % (POOL_MAX_LEN, POOL_HEAD_DIM))], operands
     assert "stablehlo.dynamic_update_slice" in text
+
+
+# ---- the pool of mixed cache layers, at the benchmark's cut -----------------
+# (benchmark/configs/mimo-v2.5.json: 64 query heads; full layers of 4
+# key/value heads over 32 slots of 6,144 positions, window layers of 8 over
+# rings of the 128 window and a 256-token prefill chunk; keys 192 wide and
+# values 128; 16 held experts of 256: 5.42 B parameters in bfloat16.)
+# Nothing is allocated: the model and the caches are shapes, and the pool's
+# programs are built around them.
+
+def _cut():
+    """MiMo-V2.5's published widths, cut to one chip's share of a 16-chip
+    layer group as the benchmark's cell serves it (layers 0-10 of 48, 16 of
+    256 experts held, an eighth of the vocabulary), in the keys
+    ``mimo_v2`` reads."""
+    n = 11
+    return {
+        "vocab_size": 19072, "hidden_size": 4096, "num_hidden_layers": n,
+        "hybrid_layer_pattern": [0, 1, 1, 1, 1, 0, 1, 1, 1, 1, 1],
+        "moe_layer_freq": [0] + [1] * (n - 1),
+        "num_attention_heads": 64, "head_dim": 192, "v_head_dim": 128,
+        "num_key_value_heads": 4, "swa_num_key_value_heads": 8,
+        "rope_theta": 1e7, "swa_rope_theta": 1e4,
+        "partial_rotary_factor": 0.334, "sliding_window": 128,
+        "add_swa_attention_sink_bias": True, "attention_value_scale": 0.707,
+        "intermediate_size": 16384, "moe_intermediate_size": 2048,
+        "n_routed_experts": 256, "experts_held": 16,
+        "num_experts_per_tok": 8, "norm_topk_prob": True,
+        "layernorm_epsilon": 1e-5,
+        "serving": {"slots": 32, "max_len": 6144, "prefill_chunk": 256}}
+
+
+def _lower_cut_program(program, sharding):
+    from bigdl_tpu.models import mimo_v2
+    from bigdl_tpu.serving.generation import SlotPool
+    cfg = _cut()
+    s = cfg["serving"]
+    slots, chunk = s["slots"], s["prefill_chunk"]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    abstract = jax.eval_shape(lambda: mimo_v2(cfg, s["max_len"]))
+    model = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, jnp.bfloat16), abstract)
+    caches = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+            lambda: abstract.init_cache(slots, jnp.bfloat16,
+                                        ring_margin=chunk)))
+    # the pool's programs around a model that is shapes only
+    pool = object.__new__(SlotPool)
+    pool.slots = slots
+    pool.cache_layers = tuple(abstract.cache_layers())
+    pool.expert_layers = abstract.expert_layers()
+    pool.trace_counts = {"decode": 0, "prefill": {}, "scatter": {},
+                         "chunk_prefill": {}, "kv_copy": {}, "kv_extract": {}}
+    pool._build_programs()
+    routing = sds((4,), jnp.int32)
+    if program == "decode":
+        lowered = pool._decode_jit.lower(
+            model, caches, sds((slots,), jnp.int32), sds((slots,), jnp.int32),
+            sds((slots,), jnp.bool_), routing)
+    else:
+        lowered = pool._chunk_jit.lower(
+            model, caches, sds((), jnp.int32), sds((chunk,), jnp.int32),
+            sds((), jnp.int32), routing)
+    return lowered, cfg, caches
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk_prefill"])
+def test_cut_pool_program_copies_no_leaf_and_expands_no_key_on_v5e(
+        v5e, program):
+    """The decode step and the chunk program of the 5.42 B cut, compiled
+    for the described v5e: no copy or transpose of a whole cache leaf (a
+    ring's or a full layer's, keys or values), no ``scatter`` over one
+    and, in the decode step, no ``while``; no keys or values at the 64
+    query heads; the experts' products on the held stacks as they lie (no
+    copy of a stack); and weights, caches and temporaries inside the
+    chip."""
+    lowered, cfg, caches = _lower_cut_program(
+        program, SingleDeviceSharding(v5e.devices[0]))
+    s = cfg["serving"]
+    shapes = sorted({leaf.shape for layer in caches["layers"]
+                     for leaf in layer["self"].values()})
+    ring = cfg["sliding_window"] + s["prefill_chunk"]
+    assert shapes == [(32, 4, s["max_len"], 128), (32, 4, s["max_len"], 192),
+                      (32, 8, ring, 128), (32, 8, ring, 192)]
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "dynamic-update-slice" in text
+    leaf = "(?:%s)" % "|".join(
+        r"bf16\[%d,%d,%d,%d\]" % shape for shape in shapes)
+    assert not re.findall(
+        r"= %s\S* (?:copy|copy-start|transpose|scatter)\(" % leaf, text)
+    heads = cfg["num_attention_heads"]
+    expanded = r"bf16\[\d+,(?:%d|4,16|8,8),(?:%d|%d),(?:128|192)\]" % (
+        heads, s["max_len"], ring)
+    assert not re.findall(expanded, text)
+    held, h, f = (cfg["experts_held"], cfg["hidden_size"],
+                  cfg["moe_intermediate_size"])
+    stack = r"bf16\[%d,(?:%d,%d|%d,%d)\]" % (held, h, f, f, h)
+    assert not re.findall(r"= %s\S* (?:copy|copy-start|transpose)\(" % stack,
+                          text)
+    # 32 tokens a decode step, 256 a chunk: both take the batched product
+    # over every held stack (HeldExperts.DENSE_TOKENS), not the grouped one
+    assert len(re.findall(stack, text)) >= 3 * sum(cfg["moe_layer_freq"])
+    assert "ragged-dot" not in text
+    mem = compiled.memory_analysis()
+    held_bytes = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 10e9 < held_bytes < 15.5 * 2 ** 30, held_bytes
+    if program == "decode":
+        assert " while(" not in text
+
+
+def test_cut_pool_decode_step_lowers_to_no_scatter_over_a_cache_leaf():
+    """Runs anywhere: the cut's decode step as JAX hands it to the
+    compiler (StableHLO) writes every cache leaf with
+    ``dynamic_update_slice`` and none with a ``scatter`` (PR 27's test,
+    on the pool whose layers differ)."""
+    lowered, cfg, caches = _lower_cut_program("decode", None)
+    text = lowered.as_text()
+    operands = re.findall(
+        r'"stablehlo\.scatter"\(.*?\}\) : \((tensor<[^>]*>)', text, re.S)
+    leaves = {"x".join(str(n) for n in leaf.shape[1:]) + "xbf16>"
+              for layer in caches["layers"]
+              for leaf in layer["self"].values()}
+    assert not [t for t in operands if any(t.endswith(e) for e in leaves)], \
+        operands
+    n_leaves = 2 * cfg["num_hidden_layers"]
+    assert text.count("stablehlo.dynamic_update_slice") \
+        >= n_leaves * cfg["serving"]["slots"]
