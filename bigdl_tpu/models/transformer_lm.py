@@ -233,6 +233,16 @@ class TransformerLM(Module):
         (a windowed layer would declare ``("ring", window)``)."""
         return (("full", self.max_len),) * len(self.blocks)
 
+    def decode_key_block(self, caches):
+        """Places of a row that the per-row decode step's attention
+        reads at a time, or None where it reads every row whole whatever
+        is live (``ops.decode_key_block``): the serving pool counts what
+        its decode program reads by this."""
+        from bigdl_tpu.ops import attention_kernels
+        leaf = caches["layers"][0]["self"]
+        return attention_kernels.decode_key_block(
+            leaf["k"].shape, leaf["v"].shape, leaf["k"].dtype)
+
     def init_cache(self, batch: int, dtype=jnp.float32):
         """Per-block KV caches sized to ``max_len``, plus the per-slot
         padding flags the full forward expresses via padding_bias (one
@@ -269,10 +279,14 @@ class TransformerLM(Module):
         clobber a co-scheduled chunked prefill's freshly written
         positions (test_decode_does_not_disturb_inactive_rows)."""
         if jnp.ndim(index) == 1:
+            # what a row's query may attend: its positions up to its own,
+            # and nothing for a row that only rides along
+            lengths = index + 1
             if active is not None:
+                lengths = jnp.where(active, lengths, 0)
                 index = jnp.where(active, index,
                                   jnp.int32(self.max_len - 1))
-            return self._decode_step_rows(tokens, index, caches,
+            return self._decode_step_rows(tokens, index, lengths, caches,
                                           with_logits)
         pad = jax.lax.dynamic_update_slice(
             caches["pad"], tokens == 0, (0, index))
@@ -295,8 +309,11 @@ class TransformerLM(Module):
         logits = jnp.einsum("bth,vh->btv", x, self.embedding.weight)
         return logits[:, 0], new_caches
 
-    def _decode_step_rows(self, tokens, index, caches, with_logits):
-        """:meth:`decode_step` with a position per row (``index [B]``).
+    def _decode_step_rows(self, tokens, index, lengths, caches,
+                          with_logits):
+        """:meth:`decode_step` with a position per row (``index [B]``;
+        ``lengths [B]`` is ``index + 1``, or 0 for a row that only rides
+        along and whose result nobody reads).
 
         Each row's new key, value and padding flag go into the cache
         with a ``dynamic_update_slice`` of their own at
@@ -319,9 +336,13 @@ class TransformerLM(Module):
         cache-sized copy".
 
         Attention is inlined as in :meth:`prefill_chunk` (the K/V
-        written are the K/V attended), expecting eval mode."""
+        written are the K/V attended), expecting eval mode.  It goes
+        through ``ops.decode_attention``: on a TPU a kernel that reads,
+        of each row, the blocks that hold live positions; elsewhere the
+        XLA product over the whole row under ``incremental_bias``'s
+        mask."""
         from bigdl_tpu.nn.attention import _residual_dropout
-        from bigdl_tpu.ops import dot_product_attention
+        from bigdl_tpu.ops import attention_kernels
         rows = range(tokens.shape[0])
 
         def write(leaf, new):
@@ -340,7 +361,6 @@ class TransformerLM(Module):
         pos = jnp.take(position_encoding(self.max_len, self.hidden_size,
                                          dtype=x.dtype), index, axis=0)
         x = x + pos[:, None]
-        bias = incremental_bias(self.max_len, index, pad, x.dtype)
         new_layers = []
         for blk, cache in zip(self.blocks, caches["layers"]):
             attn = blk.self_attn
@@ -350,7 +370,7 @@ class TransformerLM(Module):
             v = write(old["v"], attn._split_heads(attn.v_layer(xn)))
             new_layers.append({"self": {"k": k, "v": v}})
             q = attn._split_heads(attn.q_layer(xn))
-            ctxt = dot_product_attention(q, k, v, bias)
+            ctxt = attention_kernels.decode_attention(q, k, v, lengths, pad)
             y = attn.output_layer(attn._combine_heads(ctxt))
             x = x + _residual_dropout(y, blk.ffn_dropout, blk.training)
             y = blk.ffn(blk.ffn_norm(x))

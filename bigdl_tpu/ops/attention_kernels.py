@@ -49,7 +49,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["dot_product_attention", "flash_attention",
-           "flash_attention_partial", "xla_attention"]
+           "flash_attention_partial", "xla_attention", "decode_attention",
+           "decode_key_block", "ragged_decode_attention"]
 
 _NEG_INF = -1e9  # matches the reference's attention mask fill
                  # (nn/TransformerOperation.scala attentionBiasLowerTriangle)
@@ -900,6 +901,206 @@ def flash_attention(q, k, v, bias=None, *, causal: bool = False,
 
 
 # ---------------------------------------------------------------------------
+# Ragged decode attention — one query a row over a ``full`` cache row
+# ---------------------------------------------------------------------------
+
+_LANES = 128
+_DECODE_VMEM = 8 * 2 ** 20   # the streamed K and V blocks, double-buffered
+
+
+def _ragged_decode_kernel(lens_ref, src_ref, lo_ref, hi_ref, q_ref, k_ref,
+                          v_ref, bias_ref, o_ref, s_ref, m_ref, l_ref,
+                          a_ref, acc_ref, *, scale: float, block: int,
+                          group: int):
+    """One (slot, key block) program.  ``k_ref [Hkv, d, block]`` and
+    ``v_ref [Hkv, dv, block]`` hold positions on the lanes, as the pool
+    stores them, so a head's scores are a sum over sublanes of
+    ``K * q`` and its context a sum over lanes of ``V * p``: both on the
+    vector unit, in float32, one query head at a time (a product with
+    one row a head has nothing for the MXU to do).  ``q_ref [d, Hq]``
+    and ``o_ref [dv, Hq]`` keep the width on the sublanes for the same
+    reason.  The context gathers lane-wise in ``acc_ref [Hq, dv, 128]``
+    and is summed over the lanes once, at the slot's last block."""
+    del src_ref, lo_ref, hi_ref          # the index maps read them
+    b, j = pl.program_id(0), pl.program_id(1)
+    length = lens_ref[b]
+    hkv, hq = k_ref.shape[0], q_ref.shape[1]
+    last = j == pl.num_programs(1) - 1
+
+    @pl.when(jnp.logical_and(j == 0, length > 0))
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(j * block < length)
+    def _body():
+        for h in range(hkv):
+            k_h = k_ref[h].astype(jnp.float32)                 # [d, block]
+            for g in range(h * group, (h + 1) * group):
+                s_ref[g:g + 1, :] = jnp.sum(
+                    k_h * q_ref[:, g:g + 1], axis=0, keepdims=True)
+        pos = j * block + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block), 1)
+        s = s_ref[...] * scale + bias_ref[...]                 # [Hq, block]
+        s = jnp.where(pos < length, s, _NEG_INF)
+        m_prev = m_ref[...]                                    # [Hq, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[...] = m_new
+        # the weights in the values' precision, as xla_attention has them
+        s_ref[...] = p.astype(v_ref.dtype).astype(jnp.float32)
+        a_ref[...] = jnp.broadcast_to(alpha, a_ref.shape)
+        for h in range(hkv):
+            v_h = v_ref[h].astype(jnp.float32)                 # [dv, block]
+            for g in range(h * group, (h + 1) * group):
+                pv = v_h * s_ref[g:g + 1, :]
+                part = pv[:, :_LANES]
+                for c in range(1, block // _LANES):
+                    part = part + pv[:, c * _LANES:(c + 1) * _LANES]
+                acc_ref[g] = acc_ref[g] * a_ref[g:g + 1, :] + part
+
+    @pl.when(jnp.logical_and(last, length > 0))
+    def _finish():
+        inv = 1.0 / l_ref[...]
+        for g in range(hq):
+            o_ref[:, g:g + 1] = (
+                jnp.sum(acc_ref[g], axis=1, keepdims=True)
+                * inv[g:g + 1, :]).astype(o_ref.dtype)
+
+    # a row that only rides along: zeros, and none of the work above (a
+    # finish is a lane sum a query head: 3.7 us a row at OPT's 32, v5e)
+    @pl.when(jnp.logical_and(last, length == 0))
+    def _idle():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def ragged_decode_attention(q, k, v, lengths, pad=None, *,
+                            scale: Optional[float] = None,
+                            block_k: Optional[int] = None,
+                            interpret: bool = False):
+    """Attention of one query a row over the live places of its cache
+    row, as a Pallas TPU kernel: ``softmax(q k^T * scale + mask) v`` over
+    positions ``< lengths[b]`` that ``pad`` does not flag.
+
+    q: ``[B, Hq, 1, d]``; k: ``[B, Hkv, T, d]`` and v: ``[B, Hkv, T, dv]``
+    as the serving pool holds them (``Hq`` a multiple of ``Hkv``, query
+    head ``g`` reading key head ``g // (Hq / Hkv)``); lengths: ``[B]``
+    int32, 0 for a row that only rides along (it returns zeros); pad:
+    ``[B, T]`` bool or None.  Returns ``[B, Hq, 1, dv]`` in q's dtype.
+
+    The grid is (row, key block) and ``lengths`` goes ahead as scalar
+    prefetch: the block index of a step past a row's last live block is
+    that block's again, so no DMA is issued for it, and its arithmetic
+    is skipped; a row with nothing live stays on its predecessor's block.
+    What is read is each live length rounded up to ``block_k``.
+
+    The kernel is handed K and V with positions minor, ``[B, Hkv, d, T]``:
+    for a head size under the 128 lanes that is how a TPU stores the
+    leaf (``SlotPool``), so the ``swapaxes`` below is a change of name
+    and not of bytes (``tests/test_tpu_compile.py`` holds the compiled
+    decode step to that).  Same mathematics as :func:`xla_attention`
+    with ``incremental_bias``: products and sums in float32, softmax in
+    float32, the weights rounded to the values' precision; only the order
+    of summation differs (a row whose live places are *all* flagged
+    averages those, where the XLA product averages the whole row)."""
+    b, hq, tq, d = q.shape
+    _, hkv, t, dv = v.shape
+    if tq != 1 or hq % hkv or k.shape != (b, hkv, t, d):
+        raise ValueError(f"decode attention takes one query a row and "
+                         f"Hq a multiple of Hkv: q {q.shape}, k {k.shape}, "
+                         f"v {v.shape}")
+    block = block_k or _decode_block(k.shape, v.shape, k.dtype)
+    if not block or t % block or block % _LANES:
+        raise ValueError(f"no key block for rows of {t} places "
+                         f"(block_k={block_k})")
+    if scale is None:
+        scale = 1.0 / (d ** 0.5)
+    return _ragged_decode(q, k, v, lengths, pad, scale=float(scale),
+                          block=int(block), interpret=bool(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "block", "interpret"))
+def _ragged_decode(q, k, v, lengths, pad, *, scale, block, interpret):
+    """:func:`ragged_decode_attention` on checked arguments.  A function
+    of its own under ``jit`` so that the layers of a model, which call it
+    on the same shapes, share one trace and one lowering of the kernel:
+    its body is unrolled over the heads, and traced a layer at a time it
+    added 9 s to every start of OPT's decode program."""
+    b, hq, _, d = q.shape
+    _, hkv, t, dv = v.shape
+    lengths = lengths.astype(jnp.int32)
+    # the blocks a row's steps read: its own, first to last live; a row
+    # with nothing live stays where the live row before it ended (or
+    # where the first live row will start), so that it moves nothing
+    rows = jnp.arange(b, dtype=jnp.int32)
+    live = lengths > 0
+    last = jnp.maximum(lengths - 1, 0) // block
+    before = jax.lax.cummax(jnp.where(live, rows, -1))
+    src = jnp.where(before >= 0, before, jnp.argmax(live).astype(jnp.int32))
+    hi = jnp.where(before >= 0, last[src], 0)
+    lo = jnp.where(live, 0, hi)
+    if pad is None:
+        bias = jnp.zeros((b, 1, t), jnp.float32)
+    else:
+        bias = jnp.where(pad, _NEG_INF, 0.0).astype(jnp.float32)[:, None]
+
+    def kv_map(bi, j, lens, src, lo, hi):
+        return src[bi], 0, 0, jnp.minimum(jnp.maximum(j, lo[bi]), hi[bi])
+
+    def bias_map(bi, j, *refs):
+        row, _, _, blk = kv_map(bi, j, *refs)
+        return row, 0, blk
+
+    row_map = lambda bi, j, *refs: (bi, 0, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(b, t // block),
+        in_specs=[pl.BlockSpec((None, d, hq), row_map),
+                  pl.BlockSpec((None, hkv, d, block), kv_map),
+                  pl.BlockSpec((None, hkv, dv, block), kv_map),
+                  pl.BlockSpec((None, 1, block), bias_map)],
+        out_specs=pl.BlockSpec((None, dv, hq), row_map),
+        scratch_shapes=[_scratch((hq, block)), _scratch((hq, 1)),
+                        _scratch((hq, 1)), _scratch((hq, _LANES)),
+                        _scratch((hq, dv, _LANES))],
+    )
+    out = pl.pallas_call(
+        functools.partial(_ragged_decode_kernel, scale=scale, block=block,
+                          group=hq // hkv),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, dv, hq), q.dtype),
+        interpret=interpret,
+        **_dimsem("parallel", "arbitrary"),
+    )(lengths, src, lo, hi,
+      jnp.swapaxes(q[:, :, 0, :], 1, 2).astype(jnp.float32),
+      jnp.swapaxes(k, 2, 3), jnp.swapaxes(v, 2, 3), bias)
+    return jnp.swapaxes(out, 1, 2)[:, :, None, :]
+
+
+def _decode_block(k_shape, v_shape, dtype) -> Optional[int]:
+    """Places of a cache row that :func:`ragged_decode_attention` reads
+    at a time: the largest of 256 and 128 that divides the row and whose
+    K and V blocks fit the kernel's share of VMEM twice over (they are
+    double-buffered).  256 and not more: a live length is read rounded up
+    to the block, and a step of the grid costs about a third of a
+    microsecond, live or not.  None where the row does not tile."""
+    _, hkv, t, d = k_shape
+    dv = v_shape[-1]
+    size = jnp.dtype(dtype).itemsize
+    sub = 32 // size                              # sublanes of a tile
+    if d % sub or dv % sub:
+        return None
+    for block in (256, 128):
+        need = 2 * hkv * (d + dv) * block * size
+        if t % block == 0 and need <= _DECODE_VMEM:
+            return block
+    return None
+
+
+# ---------------------------------------------------------------------------
 # Dispatch
 # ---------------------------------------------------------------------------
 
@@ -935,6 +1136,51 @@ def dot_product_attention(q, k, v, bias=None, *, causal: bool = False,
             return kernel(q, k, v, bias)
         return _per_shard(kernel, mesh, q, k, v, bias)
     return xla_attention(q, k, v, bias, causal=causal, scale=scale)
+
+
+def decode_key_block(k_shape, v_shape, dtype, *,
+                     force: Optional[str] = None) -> Optional[int]:
+    """How :func:`decode_attention` reads cache rows of these shapes: the
+    key block of the ragged kernel, or None for the XLA product over every
+    place of every row.  The kernel is chosen on a TPU when the rows tile
+    (``force`` ∈ {"ragged", "xla", None} overrides, as in
+    :func:`dot_product_attention`).  The serving pool asks this too, to
+    count what its decode program reads."""
+    if force == "xla" or (force is None and not _on_tpu()):
+        return None
+    block = _decode_block(k_shape, v_shape, dtype)
+    if block is None and force == "ragged":
+        raise ValueError(f"rows {tuple(k_shape)} / {tuple(v_shape)} do "
+                         f"not tile for the ragged decode kernel")
+    return block
+
+
+def decode_attention(q, k, v, lengths, pad=None, *,
+                     scale: Optional[float] = None,
+                     force: Optional[str] = None):
+    """Attention of one query a row (``q [B, Hq, 1, d]``) over a cache
+    ``k [B, Hkv, T, d]``, ``v [B, Hkv, T, dv]`` of which row ``b`` holds
+    ``lengths[b]`` live places, less those ``pad [B, T]`` flags: the
+    decode step of a pool whose rows stand at positions of their own.
+
+    On a TPU, rows that tile go through :func:`ragged_decode_attention`,
+    which reads live blocks only; everywhere else the masked XLA product
+    reads all ``T`` places (:func:`xla_attention` under the bias
+    ``nn.attention.incremental_bias`` makes).  A row with ``lengths`` 0
+    only rides along and its result is read by nobody: the kernel returns
+    zeros for it, the XLA product what its whole row holds, as the pool's
+    idle lanes always did."""
+    block = decode_key_block(k.shape, v.shape, k.dtype, force=force)
+    if block is not None:
+        return ragged_decode_attention(q, k, v, lengths, pad, scale=scale,
+                                       block_k=block,
+                                       interpret=not _on_tpu())
+    t = k.shape[2]
+    invalid = jnp.arange(t) >= jnp.where(lengths > 0, lengths, t)[:, None]
+    if pad is not None:
+        invalid = invalid | pad
+    bias = jnp.where(invalid, _NEG_INF, 0.0)[:, None, None, :]
+    return xla_attention(q, k, v, bias, scale=scale)
 
 
 def _per_shard(kernel, mesh, q, k, v, bias):

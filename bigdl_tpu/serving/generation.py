@@ -169,7 +169,11 @@ class SlotPool:
     step (41.9 of a 54.4 ms step at 6 slots of 2048; PERF.md, PR 27).
     ``tests/test_tpu_compile.py`` compiles the four programs for a
     described v5e and fails on a pool-sized ``copy``;
-    ``docs/performance.md`` says how to read such a program's HLO."""
+    ``docs/performance.md`` says how to read such a program's HLO.  On a
+    TPU the decode step's attention reads a full row through a kernel
+    that fetches live key blocks only (``ops.ragged_decode_attention``,
+    handed each leaf positions-minor, as it lies); ``key_block`` is its
+    block, and ``decode_dispatch`` counts by it what a step reads."""
 
     def __init__(self, model, slots: int, dtype=None,
                  prefill_batch: int = 4, ring_margin: int = 1):
@@ -226,6 +230,11 @@ class SlotPool:
             if hasattr(model, "expert_layers") else 0
         self._routing = jnp.zeros((4 if self.expert_layers else 0,),
                                   jnp.int32)
+        # places of a full row that the decode program's attention reads
+        # at a time, where the model's step reads live blocks only; None
+        # where it reads every row whole (decode_dispatch counts by it)
+        self.key_block = model.decode_key_block(self.caches) \
+            if hasattr(model, "decode_key_block") else None
         self.tok = np.zeros((self.slots,), np.int32)
         self.index = np.zeros((self.slots,), np.int32)
         self.active = np.zeros((self.slots,), bool)
@@ -626,6 +635,16 @@ class SlotPool:
             self._dev = (jnp.asarray(self.tok), jnp.asarray(self.index),
                          jnp.asarray(self.active))
             self._dirty = False
+        # what this step attends, from the mirrors: an active slot's
+        # positions up to its own.  The mirrors stand one step behind the
+        # device for the slots whose last step is still unread.
+        lengths = self.index[self.active] + 1
+        if self._open_handle is not None:
+            lengths = lengths + self._open_handle.mask[self.active]
+        block = self.key_block
+        positions = (int(lengths.sum()),
+                     int((-(-lengths // block) * block).sum()) if block
+                     else self.slots * self.max_len)
         tok_d, idx_d, act_d = self._dev
         self.caches, new_tok, new_idx, emit, self._routing = \
             self._decode_jit(self.model, self.caches, tok_d, idx_d, act_d,
@@ -633,7 +652,7 @@ class SlotPool:
         self._dev = (new_tok, new_idx, act_d)
         self._emit_active = self.active.copy()
         self._touched[:] = False
-        handle = _StepHandle(emit)
+        handle = _StepHandle(emit, positions)
         self._open_handle = handle
         return handle
 
@@ -674,10 +693,13 @@ class _StepHandle:
     epoch (finalized at the NEXT dispatch — until then the pool's live
     epoch applies)."""
 
-    __slots__ = ("emit", "mask", "routing")
+    __slots__ = ("emit", "mask", "routing", "positions")
 
-    def __init__(self, emit):
+    def __init__(self, emit, positions=(0, 0)):
         self.emit = emit
+        # (live, read): the cache positions this step's attention may
+        # attend, and those its program reads to do so, a full layer
+        self.positions = positions
         self.mask: Optional[np.ndarray] = None
         # read back with the tokens: what the expert layers did since
         # the previous step (empty for a model without them)
@@ -716,6 +738,7 @@ _ENGINE_COUNTERS = _ENGINE_PHASES + (
     "gaps_plain", "gaps_prefill", "gap_seconds_plain",
     "gap_seconds_prefill", "prefill_positions", "prefill_prompt_tokens",
     "admitted", "queue_wait_seconds",
+    "decode_positions_live", "decode_positions_read",
     "moe_layer_calls", "moe_pairs_total", "moe_pairs_held",
     "moe_active_experts")
 
@@ -1132,6 +1155,14 @@ class GenerationScheduler:
                 "prefill_prompt_tokens": eng["prefill_prompt_tokens"],
                 "admitted": eng["admitted"],
                 "queue_wait_seconds": float(eng["queue_wait_seconds"]),
+                # cache positions, a full layer, summed over the decode
+                # steps dispatched: those the active slots' queries could
+                # attend, and those the decode program read to attend
+                # them (each length rounded up to the key block where the
+                # step reads live blocks only, every slot's whole row
+                # where it does not)
+                "decode_positions_live": eng["decode_positions_live"],
+                "decode_positions_read": eng["decode_positions_read"],
                 # what the expert layers did, in decode and prefill
                 # programs alike: calls of an expert layer, the
                 # token-to-expert pairs they routed, those that landed
@@ -1798,6 +1829,8 @@ class GenerationScheduler:
             return
         self._prefill_since_dispatch = 0
         self._acc["decode_dispatches"] += 1
+        self._acc["decode_positions_live"] += emit.positions[0]
+        self._acc["decode_positions_read"] += emit.positions[1]
         self._pending = (emit, n_active, after_prefill)
         if prev is not None:
             # THE async-readback overlap: step N's host-side emit work

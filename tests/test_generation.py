@@ -725,3 +725,98 @@ def test_run_mixed_workload_speedup_and_equivalence(lm):
     assert out["greedy_equal_checked"]
     assert out["speedup_vs_sequential"] > 1.5
     assert out["total_new_tokens"] == sum(max_news)
+
+
+# ---------------------------------------------------------------------------
+# the ragged decode kernel through the pool (interpreted), and its counters
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def lm384():
+    """Rows of three key blocks of 128, heads of 8: the smallest the
+    ragged decode kernel tiles."""
+    set_seed(3)
+    return transformer_lm(vocab_size=50, hidden_size=32, num_layers=2,
+                          num_heads=4, filter_size=64,
+                          max_len=384).eval_mode()
+
+
+def _force_ragged(monkeypatch):
+    """What a TPU process chooses by itself, asked for on the CPU (the
+    kernel then runs interpreted): ``decode_key_block`` is the one place
+    both the model's decode step and the pool's counters ask."""
+    import functools
+    from bigdl_tpu.ops import attention_kernels
+    monkeypatch.setattr(
+        attention_kernels, "decode_key_block",
+        functools.partial(attention_kernels.decode_key_block,
+                          force="ragged"))
+
+
+def test_ragged_decode_kernel_through_the_pool_emits_generates_tokens(
+        lm384, monkeypatch):
+    """Requests of mixed lengths, one of which crosses a key block while
+    it decodes and one of which starts from a single token, share a pool
+    whose decode step attends through the kernel: each emits what solo
+    ``generate()`` (the XLA product over the whole row) emits."""
+    _force_ragged(monkeypatch)
+    rng = np.random.default_rng(5)
+    lengths, max_news = [5, 124, 1, 200, 130, 40], [6, 8, 5, 4, 7, 6]
+    prompts = [rng.integers(1, 51, n).astype(np.int32) for n in lengths]
+    eng = GenerationScheduler(lm384, slots=3, prefill_batch=2)
+    try:
+        assert eng.pool.key_block == 128
+        futs = [eng.submit_async(p, m) for p, m in zip(prompts, max_news)]
+        rows = [f.result(timeout=300) for f in futs]
+        st = eng.stats()
+    finally:
+        eng.shutdown()
+    for p, m, row in zip(prompts, max_news, rows):
+        np.testing.assert_array_equal(row, solo(lm384, p, m))
+    assert 0 < st["decode_positions_live"] <= st["decode_positions_read"]
+    # no step read a slot's whole row, let alone every slot's
+    assert st["decode_positions_read"] \
+        < st["decode_dispatches"] * 3 * 384
+
+
+@pytest.mark.parametrize("ragged", [False, True], ids=["xla", "ragged"])
+def test_decode_position_counters_follow_a_known_schedule(
+        lm384, monkeypatch, ragged):
+    """One request alone, 120 prompt tokens and 12 new: dispatch ``i``
+    attends ``120 + i`` positions (the last prompt token is fed by the
+    first step).  With the kernel the program reads each length rounded
+    up to the key block of 128, so one block until the length passes 128
+    and two after; the XLA product reads both slots' whole rows every
+    step.  The engine may dispatch one step more than it emits (the
+    pipeline is one deep): the counters count dispatches."""
+    if ragged:
+        _force_ragged(monkeypatch)
+    prompt = np.arange(1, 121, dtype=np.int32) % 50 + 1
+    eng = GenerationScheduler(lm384, slots=2)
+    try:
+        before = eng.stats()
+        eng.submit(prompt, 12)
+        # the engine folds its counters at the next pass at the latest
+        deadline = time.time() + 10
+        while time.time() < deadline:
+            st = eng.stats()
+            if st["decode_dispatches"] >= 12 and eng.pool.n_active() == 0:
+                break
+            time.sleep(0.01)
+        time.sleep(0.05)
+        st = eng.stats()
+    finally:
+        eng.shutdown()
+    assert before["decode_positions_live"] == 0 \
+        and before["decode_positions_read"] == 0
+    n = st["decode_dispatches"]
+    assert n in (12, 13)
+    lengths = [120 + i for i in range(n)]
+    assert st["decode_positions_live"] == sum(lengths)
+    if ragged:
+        assert st["decode_positions_read"] == sum(
+            128 * -(-length // 128) for length in lengths)
+        assert st["decode_positions_read"] == 128 * 9 + 256 * (n - 9)
+    else:
+        assert st["decode_positions_read"] == n * 2 * 384
+    assert st["decode_positions_live"] <= st["decode_positions_read"]

@@ -31,7 +31,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from bigdl_tpu.ops import conv_bn_kernels as ck
-from bigdl_tpu.ops.attention_kernels import flash_attention
+from bigdl_tpu.ops.attention_kernels import (flash_attention,
+                                             ragged_decode_attention)
 
 
 @pytest.fixture(scope="module")
@@ -131,9 +132,29 @@ def _conv3_case(shape, backward):
     return pytest.param(build, id=name)
 
 
+def _decode_case(slots, hq, hkv, t, d, dv, dtype):
+    """The ragged decode kernel over a pool leaf ``[slots, hkv, t, d]``,
+    handed over as the pool holds it."""
+    def build(sds):
+        args = [sds((slots, hq, 1, d), jnp.bfloat16),
+                sds((slots, hkv, t, d), dtype), sds((slots, hkv, t, dv), dtype),
+                sds((slots,), jnp.int32), sds((slots, t), jnp.bool_)]
+        return ragged_decode_attention, args
+    name = "ragged_decode-%dx%dq%dkv-%dx%dx%d-%s" % (
+        slots, hq, hkv, t, d, dv, jnp.dtype(dtype).name)
+    return pytest.param(build, id=name)
+
+
+# OPT-1.3B's pool leaf as the benchmark serves it, and the grouped full
+# layer of the mimo-v2.5 cut (64 query heads over 4, values narrower), which
+# no program hands the kernel yet
+DECODE_CASES = [_decode_case(6, 32, 32, 2048, 64, 64, jnp.float32),
+                _decode_case(32, 64, 4, 6144, 192, 128, jnp.bfloat16)]
+
 CASES = (
     [_flash_case(s, bias, bwd) for s in FLASH_SHAPES
      for bias in (False, True) for bwd in (False, True)]
+    + DECODE_CASES
     + [_matmul_case(s, bwd) for s in MATMUL_SHAPES for bwd in (False, True)]
     + [_conv3_case(s, bwd) for s in CONV3_SHAPES for bwd in (False, True)]
 )
@@ -200,8 +221,9 @@ def _pool_leaf_ops(text, ops):
     """Instructions of the optimized HLO ``text`` named in ``ops`` whose
     result has a pool leaf's shape, with or without the unit axis a
     vmapped program inserts behind the slots."""
-    leaf = r"f32\[%d,(?:1,)?%d,%d,%d\]" % (
-        POOL_SLOTS, POOL_HEADS, POOL_MAX_LEN, POOL_HEAD_DIM)
+    leaf = r"f32\[%d,(?:1,)?%d,(?:%d,%d|%d,%d)\]" % (
+        POOL_SLOTS, POOL_HEADS, POOL_MAX_LEN, POOL_HEAD_DIM,
+        POOL_HEAD_DIM, POOL_MAX_LEN)
     return re.findall(r"= %s\S* (?:%s)\(" % (leaf, "|".join(ops)), text)
 
 
@@ -211,6 +233,10 @@ def pool():
     whose leaves the TPU compiler stores positions-minor (``{2,3,1,0}``:
     64 would fill half a lane tile), which is what made the vmapped
     decode step transpose them.  Two heads, one layer: 12 MB."""
+    return _fixture_pool()
+
+
+def _fixture_pool():
     from bigdl_tpu.models import transformer_lm
     from bigdl_tpu.serving.generation import SlotPool
     lm = transformer_lm(vocab_size=30, num_layers=1,
@@ -273,6 +299,31 @@ def test_pool_program_holds_no_pool_sized_copy_on_v5e(v5e, pool, program):
     assert not _pool_leaf_ops(text, ["copy", "copy-start"])
     if program == "decode":
         assert " while(" not in text
+
+
+def test_pool_decode_with_the_ragged_kernel_relayouts_no_leaf_on_v5e(
+        v5e, monkeypatch):
+    """The decode step as a TPU process traces it — attention through the
+    ragged kernel, which wants its operands row-major — still holds no
+    copy, transpose or ``while`` of a pool leaf's size: the kernel is
+    handed keys and values positions-minor, which is how the leaf lies, so
+    the change of axes is a ``bitcast``.  A fresh pool: the fixture's
+    decode step may already be traced the other way.  Which path a
+    process takes it asks ``_on_tpu()``; here the test answers."""
+    from bigdl_tpu.ops import attention_kernels
+    monkeypatch.setattr(attention_kernels, "_on_tpu", lambda: True)
+    pool = _fixture_pool()
+    assert pool.key_block == 256
+    text = _lower_pool_program(
+        pool, "decode", SingleDeviceSharding(v5e.devices[0])
+    ).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == len(pool.caches["layers"])
+    assert "dynamic-update-slice" in text
+    assert not _pool_leaf_ops(text, ["copy", "copy-start", "transpose"])
+    assert len(_pool_leaf_ops(text, ["bitcast"])) \
+        == 2 * len(pool.caches["layers"])
+    assert " while(" not in text
 
 
 def test_pool_decode_step_lowers_to_no_scatter_over_the_pool(pool):
