@@ -8,8 +8,9 @@ rising rates, then a saturated segment.
 Not the driver's command; run once when a mix is defined, and write the
 knee into the traffic file as a number.  For each rate it offers that
 mix for ``--segment`` seconds without draining in between and prints the
-backlog (requests admitted and not finished) at the segment's start and
-end, the requests and tokens completed, and the token-gap percentiles.
+backlog (requests admitted and not finished) and the queue's depth at
+the segment's start and end, the requests and tokens completed, and the
+token-gap percentiles.
 The knee is the highest rate at which the backlog at the end is no
 larger than at the start; the last, saturated segment gives the service
 rate (requests finished per second with the queue never empty), which
@@ -33,8 +34,9 @@ def main(argv=None) -> int:
     ap.add_argument("--traffic", required=True)
     ap.add_argument("--rates", required=True)
     ap.add_argument("--segment", type=float, default=40.0)
-    ap.add_argument("--saturated", type=float, default=5.0,
-                    help="rate of the last segment, far above any knee")
+    ap.add_argument("--saturated", type=float, default=None,
+                    help="rate of the last segment, far above any knee "
+                         "(default: twice the highest of --rates)")
     ap.add_argument("--seed", type=int, default=2_718_281_828)
     ap.add_argument("--control", default="")
     ap.add_argument("--slots", type=int, default=0,
@@ -56,7 +58,9 @@ def main(argv=None) -> int:
     device.require_tpu(1)
     device.enable_compile_cache()
     kind = manifest.load_kind(cfg["kind"])
-    rates = [float(r) for r in args.rates.split(",")] + [args.saturated]
+    rates = [float(r) for r in args.rates.split(",")]
+    rates.append(2.0 * max(rates) if args.saturated is None
+                 else args.saturated)
     plans = []
     for i, rate in enumerate(rates):
         s = copy.deepcopy(spec)
@@ -73,6 +77,7 @@ def main(argv=None) -> int:
     for rate, plan in zip(rates, plans):
         load = serve_cell.Load(server, plan, kind, cfg["serving"])
         b0 = engine.admitted_outstanding()
+        q0 = engine.queue_depth()
         s0 = engine.stats()
         t0 = load.start()
         time.sleep(args.segment)
@@ -86,7 +91,8 @@ def main(argv=None) -> int:
         toks = sm.tokens_in_window(recs, t0, t1)
         result.say(
             "sweep.segment", rate_rps=rate, offered=len(plan),
-            backlog_start=b0, backlog_end=b1, queue_end=engine.queue_depth(),
+            backlog_start=b0, backlog_end=b1, queue_start=q0,
+            queue_end=engine.queue_depth(),
             finished=s1["requests_done"] - s0["requests_done"],
             finished_per_s=(s1["requests_done"] - s0["requests_done"]) / (t1 - t0),
             tokens_per_s=(toks["generated"] + toks["prompt"]) / (t1 - t0),
