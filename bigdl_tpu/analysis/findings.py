@@ -90,9 +90,9 @@ def render_human(findings: List[Finding],
 
 def render_json(findings: List[Finding],
                 meta: Optional[Dict[str, Any]] = None) -> str:
-    """The machine report (``ANALYSIS_r<N>.json``): counts + every
-    finding including suppressed ones, so lint debt is a tracked
-    trajectory, not just a pass/fail bit."""
+    """The machine report (``--json``): counts + every finding
+    including suppressed ones, so lint debt is countable, not just a
+    pass/fail bit."""
     doc = {
         "schema": "graftlint_report",
         "version": 1,

@@ -10,7 +10,7 @@ Drives the REAL ``Optimizer.optimize()`` loop (mesh, donation, async
 readback) on synthetic device-cached data and prints one JSON line:
 records/sec and ms/iteration from the Optimizer's completion-to-
 completion window telemetry (the first window bears trace+compile and
-is excluded — same methodology as bench.py).
+is excluded).
 """
 
 from __future__ import annotations

@@ -2008,7 +2008,7 @@ class Optimizer:
         drain_state = {"last_ready": 0.0}
         # (n_iterations, completion_to_completion_s, data_stage_s) per
         # flushed window — lets harnesses compute steady-state step time
-        # with the compile-bearing first window excluded (bench.py)
+        # with the compile-bearing first window excluded
         self.window_timings: List[Tuple[int, float, float]] = []
         # richer per-window phase records for telemetry.perf step-time
         # attribution (data-wait / host-staging / device-compute /

@@ -23,9 +23,9 @@ instead:
   leaves individually at EOS / max-tokens without disturbing the
   co-resident slots.
 
-GENSERVE_r01 measured the remaining wall: prefill dominated the round
-(6.47 s prefill vs 2.63 s decode; mean queue-to-first-token 7.52 s of a
-9.14 s run).  Two cooperating optimizations attack it:
+Prefill is the remaining wall of a round of mixed traffic: a request
+waits for its whole prompt before its first token, and co-resident
+streams wait with it.  Two cooperating optimizations attack it:
 
 * a **prefix KV cache** (prefix_cache.py): prefill K/V is cached at a
   fixed chunk granularity keyed by the full token prefix; on admit the
@@ -103,9 +103,7 @@ from bigdl_tpu.serving.reliability import (
 )
 from bigdl_tpu.telemetry import request_trace, tracing
 
-__all__ = ["GenerationRequest", "SlotPool", "GenerationScheduler",
-           "run_mixed_workload", "run_shared_prefix_workload",
-           "run_cadence_probe"]
+__all__ = ["GenerationRequest", "SlotPool", "GenerationScheduler"]
 
 logger = logging.getLogger(__name__)
 
@@ -1990,326 +1988,3 @@ class GenerationScheduler:
                 self._tps_tokens / elapsed)
             self._tps_tokens = 0
             self._tps_t0 = now
-
-
-# ---------------------------------------------------------------------------
-# Acceptance harnesses (shared by bench.py, the smoke script, and tests)
-# ---------------------------------------------------------------------------
-
-def run_mixed_workload(model, prompts: Sequence[np.ndarray],
-                       max_news: Sequence[int], slots: int = 8,
-                       eos_id=None, compare_sequential: bool = True,
-                       prefill_batch: int = 4,
-                       sequential_sample: Optional[int] = None,
-                       prefill_chunk: int = 64,
-                       prefill_chunk_budget: int = 1,
-                       prefix_cache_bytes: Optional[int] = None,
-                       prefix_granularity: int = 32
-                       ) -> Dict[str, object]:
-    """Drive a mixed-length workload through the continuous-batching
-    engine, optionally race the sequential ``generate()`` baseline, and
-    check greedy equivalence per request.  Returns a measurement dict
-    (tokens/s counts only NEW tokens, not prompt tokens).
-
-    ``sequential_sample`` caps the baseline at the first K requests —
-    the comparison is rate-based (tokens/s), so a sampled baseline
-    stays fair while keeping a budgeted bench phase affordable (the
-    sequential path re-traces ``generate()`` per (Tp, max_new) shape;
-    that cost is PART of what continuous batching removes)."""
-    import jax.numpy as jnp
-    engine = GenerationScheduler(model, slots=slots, eos_id=eos_id,
-                                 prefill_batch=prefill_batch,
-                                 queue_capacity=max(len(prompts), 1),
-                                 prefill_chunk=prefill_chunk,
-                                 prefill_chunk_budget=prefill_chunk_budget,
-                                 prefix_cache_bytes=prefix_cache_bytes,
-                                 prefix_granularity=prefix_granularity)
-    try:
-        t0 = time.perf_counter()
-        futs = [engine.submit_async(p, m)
-                for p, m in zip(prompts, max_news)]
-        rows = [f.result(timeout=600) for f in futs]
-        cont_s = time.perf_counter() - t0
-        stats = engine.stats()
-    finally:
-        engine.shutdown()
-    total_new = int(stats["tokens_emitted"])
-    out: Dict[str, object] = {
-        "requests": len(prompts),
-        "slots": slots,
-        "total_new_tokens": total_new,
-        "continuous_seconds": round(cont_s, 4),
-        "continuous_tokens_per_sec": round(total_new / cont_s, 2),
-        "slot_occupancy_mean": round(
-            float(stats["slot_occupancy_mean"]), 3),
-        "queue_to_first_token_s_mean": round(
-            float(stats["queue_to_first_token_s_mean"]), 4),
-        "queue_to_first_token_s_p50": round(
-            float(stats["queue_to_first_token_s_p50"]), 4),
-        "queue_to_first_token_s_p99": round(
-            float(stats["queue_to_first_token_s_p99"]), 4),
-        "inter_token_s_p50": round(float(stats["inter_token_s_p50"]), 5),
-        "inter_token_s_p99": round(float(stats["inter_token_s_p99"]), 5),
-        "prefill_seconds": round(float(stats["prefill_seconds"]), 4),
-        "decode_seconds": round(float(stats["decode_seconds"]), 4),
-    }
-    if stats.get("prefix_cache"):
-        out["prefix_cache"] = stats["prefix_cache"]
-    if compare_sequential:
-        k = (len(prompts) if sequential_sample is None
-             else min(int(sequential_sample), len(prompts)))
-        em = model.clone().eval_mode()
-        seq_rows = []
-        t0 = time.perf_counter()
-        for p, m in zip(prompts[:k], max_news[:k]):
-            seq_rows.append(np.asarray(em.generate(
-                jnp.asarray(p, jnp.int32)[None], m, eos_id=eos_id))[0])
-        seq_s = time.perf_counter() - t0
-        # count the baseline's ACTUALLY-emitted tokens, not its budget:
-        # with an eos_id, post-EOS positions are 0 (a real token is
-        # argmax+1 >= 1), and crediting the full budget would inflate
-        # the baseline rate and understate the speedup
-        seq_new = sum(int(np.count_nonzero(r[len(p):]))
-                      for p, r in zip(prompts[:k], seq_rows))
-        equal = all(np.array_equal(a, b)
-                    for a, b in zip(rows[:k], seq_rows))
-        out.update({
-            "sequential_requests": k,
-            "sequential_seconds": round(seq_s, 4),
-            "sequential_tokens_per_sec": round(seq_new / seq_s, 2),
-            "speedup_vs_sequential": round(
-                (total_new / cont_s) / (seq_new / seq_s), 2),
-            # equivalence is verified on exactly the requests the
-            # baseline decoded — the key says so, so a sampled run
-            # cannot record a full-set equivalence claim it never
-            # checked (the full-set property lives in
-            # tests/test_generation.py, where every row is compared)
-            "greedy_equal_checked": bool(equal),
-            "greedy_checked_requests": k,
-        })
-    return out
-
-
-def run_shared_prefix_workload(model, n_requests: int = 32,
-                               prefix_len: int = 96,
-                               tail: Tuple[int, int] = (8, 33),
-                               max_new: int = 16, slots: int = 8,
-                               seed: int = 11,
-                               prefix_cache_bytes: int = 1 << 26,
-                               prefix_granularity: int = 32,
-                               prefill_chunk: int = 64,
-                               prefill_chunk_budget: int = 2,
-                               oracle_sample: int = 2
-                               ) -> Dict[str, object]:
-    """The prefix-reuse acceptance probe: every request shares a
-    ``prefix_len``-token system prompt and carries a unique tail, run
-    through the engine twice — prefix cache ON then OFF — over the SAME
-    request set.  Reports queue-to-first-token quantiles (captured
-    client-side per request) for both runs: the cache's win is TTFT,
-    the shared prefill is paid once instead of per request.  Asserts
-    the two runs' rows are identical and checks a sample against the
-    solo ``generate()`` oracle.
-
-    Both runs are measured at STEADY STATE: two warm-up waves run
-    first — one that populates the cache (all misses) and one that
-    exercises the hit path — so every chunk width and the copy program
-    are compiled before the measured burst.  A cold engine mixes
-    one-time XLA compiles into the comparison and (on the miss wave)
-    measures the stampede, not the reuse; the claim under test is what
-    a LONG-RUNNING server sees on a repeated system prompt."""
-    import jax.numpy as jnp
-    rng = np.random.default_rng(seed)
-    vocab = int(model.embedding.weight.shape[0]) - 1
-    prefix = rng.integers(1, vocab + 1, prefix_len).astype(np.int32)
-    prompts = [np.concatenate([
-        prefix, rng.integers(1, vocab + 1,
-                             rng.integers(*tail)).astype(np.int32)])
-        for _ in range(n_requests)]
-    mean_len = float(np.mean([len(p) for p in prompts]))
-    runs: Dict[str, Dict] = {}
-    rows: Dict[str, List[np.ndarray]] = {}
-    for label, cache_bytes in (("cache", prefix_cache_bytes),
-                               ("nocache", None)):
-        engine = GenerationScheduler(
-            model, slots=slots,
-            queue_capacity=n_requests + 2 * slots,
-            prefill_chunk=prefill_chunk,
-            prefill_chunk_budget=prefill_chunk_budget,
-            prefix_cache_bytes=cache_bytes,
-            prefix_granularity=prefix_granularity)
-        try:
-            # warm waves: populate (misses), then hit-path programs
-            for _wave in range(2):
-                warm = [engine.submit_async(p, max_new)
-                        for p in prompts[:slots]]
-                [f.result(timeout=600) for f in warm]
-            before = engine.stats()
-            ttfts: List[float] = []
-            futs = []
-            t0 = time.perf_counter()
-            for p in prompts:
-                t_sub = time.perf_counter()
-                seen = []
-
-                def first_token(_tok, t_sub=t_sub, seen=seen):
-                    if not seen:
-                        seen.append(True)
-                        ttfts.append(time.perf_counter() - t_sub)
-
-                futs.append(engine.submit_async(
-                    p, max_new, on_token=first_token))
-            rows[label] = [f.result(timeout=600) for f in futs]
-            wall = time.perf_counter() - t0
-            stats = engine.stats()
-        finally:
-            engine.shutdown()
-        new_tokens = (int(stats["tokens_emitted"])
-                      - int(before["tokens_emitted"]))
-        q = np.quantile(np.asarray(ttfts), [0.5, 0.99])
-        # cumulative cache counters are differenced against the warm
-        # waves like every other field — the artifact reports what the
-        # MEASURED burst did, not engine-lifetime totals
-        cache_delta = None
-        if stats.get("prefix_cache") is not None:
-            cache_delta = dict(stats["prefix_cache"])
-            prior = before.get("prefix_cache") or {}
-            for key in ("lookups", "hits", "misses", "chunks_hit",
-                        "bytes_reused", "inserts", "evictions"):
-                cache_delta[key] -= prior.get(key, 0)
-            cache_delta["hit_rate"] = (
-                cache_delta["hits"] / cache_delta["lookups"]
-                if cache_delta["lookups"] else 0.0)
-        runs[label] = {
-            "seconds": round(wall, 4),
-            "tokens_per_sec": round(new_tokens / wall, 2),
-            "queue_to_first_token_s_p50": round(float(q[0]), 4),
-            "queue_to_first_token_s_p99": round(float(q[1]), 4),
-            "prefill_seconds": round(
-                float(stats["prefill_seconds"])
-                - float(before["prefill_seconds"]), 4),
-            "prefill_calls": (int(stats["prefill_calls"])
-                              - int(before["prefill_calls"])),
-            "prefix_chunks_copied": (
-                int(stats["prefix_chunks_copied"])
-                - int(before["prefix_chunks_copied"])),
-            "prefix_cache": cache_delta,
-        }
-    rows_equal = all(np.array_equal(a, b)
-                     for a, b in zip(rows["cache"], rows["nocache"]))
-    k = min(int(oracle_sample), n_requests)
-    em = model.clone().eval_mode()
-    oracle_equal = all(
-        np.array_equal(rows["cache"][i], np.asarray(em.generate(
-            jnp.asarray(prompts[i], jnp.int32)[None], max_new))[0])
-        for i in range(k))
-    p50_cache = runs["cache"]["queue_to_first_token_s_p50"]
-    p50_nocache = runs["nocache"]["queue_to_first_token_s_p50"]
-    return {
-        "requests": n_requests,
-        "prefix_len": prefix_len,
-        "shared_fraction": round(prefix_len / mean_len, 3),
-        "max_new": max_new,
-        "slots": slots,
-        "cache": runs["cache"],
-        "nocache": runs["nocache"],
-        "ttft_p50_speedup": round(
-            p50_nocache / p50_cache if p50_cache > 0 else 0.0, 2),
-        "rows_equal_cache_vs_nocache": bool(rows_equal),
-        "greedy_equal_checked": bool(oracle_equal),
-        "greedy_checked_requests": k,
-    }
-
-
-def run_cadence_probe(model, slots: int = 16, steady_requests: int = 12,
-                      warm_tokens: int = 12, steady_budget: int = 160,
-                      long_prompt_len: Optional[int] = None,
-                      long_max_new: int = 4, long_arrivals: int = 4,
-                      prefill_chunk: int = 8,
-                      prefill_chunk_budget: int = 1, seed: int = 13,
-                      bounded: bool = True) -> Dict[str, object]:
-    """The mixed-arrival cadence probe: short steady requests stream
-    tokens; once warm, a sustained stream of near-max-length prompts
-    arrives (each submitted as the previous completes).  Per-token gaps
-    of the steady streams are timestamped host-side via ``on_token``;
-    the report compares the steady-state gap (p50 before the first
-    long arrival) against the p99 while long prompts are in flight.
-
-    ``bounded=False`` reproduces the pre-chunking behavior (the whole
-    long prompt prefills in ONE program call between decode steps — the
-    prefill wall), the baseline the bounded run is judged against.
-
-    Physics of the knob: with a chunk budget of one, the worst
-    inter-token gap is one decode step plus ONE prefill increment, so
-    it is bounded by the chunk width — a chunk of ~``slots`` tokens
-    costs about one pooled decode step (same token count through the
-    same layers), putting the p99 near 2x the steady gap; the unbounded
-    baseline's worst gap is the entire prompt's prefill."""
-    rng = np.random.default_rng(seed)
-    vocab = int(model.embedding.weight.shape[0]) - 1
-    max_len = int(model.max_len)
-    long_len = int(long_prompt_len
-                   or (max_len - long_max_new - 1))
-    chunk = prefill_chunk if bounded else max_len
-    engine = GenerationScheduler(
-        model, slots=slots,
-        queue_capacity=steady_requests + long_arrivals + 1,
-        prefill_chunk=chunk, prefill_chunk_budget=prefill_chunk_budget)
-    times: List[List[float]] = [[] for _ in range(steady_requests)]
-
-    def recorder(i):
-        stamps = times[i]
-        return lambda _tok: stamps.append(time.perf_counter())
-
-    try:
-        # warm the long-prompt prefill program(s) first: the probe
-        # measures scheduling-induced stalls, and a one-time XLA
-        # compile in the measured window would masquerade as one
-        warm = rng.integers(1, vocab + 1, long_len).astype(np.int32)
-        engine.submit_async(warm, 1).result(timeout=600)
-        futs = []
-        for i in range(steady_requests):
-            p = rng.integers(1, vocab + 1, 6).astype(np.int32)
-            futs.append(engine.submit_async(p, steady_budget,
-                                            on_token=recorder(i)))
-        deadline = time.perf_counter() + 300
-        while any(len(t) < warm_tokens for t in times):
-            if time.perf_counter() > deadline:
-                raise TimeoutError("cadence probe never warmed up")
-            time.sleep(0.002)
-        t_inject = time.perf_counter()
-        for _ in range(long_arrivals):
-            long_prompt = rng.integers(1, vocab + 1, long_len) \
-                .astype(np.int32)
-            engine.submit_async(long_prompt,
-                                long_max_new).result(timeout=600)
-        t_end = time.perf_counter()
-        [f.result(timeout=600) for f in futs]
-    finally:
-        engine.shutdown()
-    before: List[float] = []
-    during: List[float] = []
-    for stamps in times:
-        for a, b in zip(stamps, stamps[1:]):
-            if b <= t_inject:
-                before.append(b - a)
-            elif b <= t_end:
-                during.append(b - a)
-    steady_p50 = float(np.quantile(before, 0.5)) if before else 0.0
-    post_p99 = float(np.quantile(during, 0.99)) if during else 0.0
-    post_max = float(np.max(during)) if during else 0.0
-    return {
-        "bounded": bool(bounded),
-        "prefill_chunk": chunk,
-        "prefill_chunk_budget": prefill_chunk_budget,
-        "long_prompt_len": long_len,
-        "long_arrivals": long_arrivals,
-        "steady_requests": steady_requests,
-        "slots": slots,
-        "gaps_before": len(before),
-        "gaps_during": len(during),
-        "steady_gap_p50_s": round(steady_p50, 5),
-        "mixed_gap_p99_s": round(post_p99, 5),
-        "mixed_gap_max_s": round(post_max, 5),
-        "p99_over_steady_p50": round(
-            post_p99 / steady_p50 if steady_p50 > 0 else 0.0, 2),
-    }

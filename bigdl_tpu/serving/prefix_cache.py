@@ -1,10 +1,9 @@
 """Prefix KV cache: token-id-keyed reuse of prefill K/V across
 requests.
 
-GENSERVE_r01 measured prefill as the dominant cost of the
-continuous-batching round (6.47 s prefill vs 2.63 s decode on the CPU
-acceptance workload) — and production prompt streams repeat: the same
-system prompt / few-shot preamble heads most requests.  Recomputing its
+Prefill is the dominant cost of a continuous-batching round of mixed
+traffic, and production prompt streams repeat: the same system prompt /
+few-shot preamble heads most requests.  Recomputing its
 K/V per request is pure waste, because the K/V of position ``t`` depends
 only on tokens ``0..t`` (causal attention) — two prompts sharing a
 prefix share that prefix's K/V bit-for-bit.  This is the static-shape

@@ -4,9 +4,8 @@ and a periodic background exporter.
 * :func:`prometheus_text` — the text exposition format (0.0.4) a
   Prometheus scrape expects; served by ``examples/serve.py /metrics``.
 * :func:`json_snapshot` — one JSON-able dict of every metric (plus a
-  span-buffer summary); ``bench.py`` drops this next to its BENCH
-  artifact so perf regressions can be attributed to data-wait vs
-  compute without a TPU profile.
+  span-buffer summary), so a slow run can be attributed to data-wait
+  vs compute without a TPU profile.
 * :func:`publish_summary` — writes the snapshot through a
   ``visualization.Summary`` (see ``TelemetrySummary``) so telemetry
   lands in the same TensorBoard run as train/validation/serving
@@ -86,9 +85,8 @@ def prometheus_text(registry: Optional[TelemetryRegistry] = None) -> str:
 
 def json_snapshot(registry: Optional[TelemetryRegistry] = None) -> Dict:
     """One coherent JSON-able dict: every metric (collectors included)
-    plus summaries of the span ring buffer and the flight recorder —
-    the latter is how ``BENCH_telemetry.json`` carries a bench run's
-    retry/fault/checkpoint event history."""
+    plus summaries of the span ring buffer and the flight recorder
+    (the run's retry/fault/checkpoint event history)."""
     registry = registry or get_registry()
     spans = tracing.finished_spans()
     by_name: Dict[str, Dict] = {}

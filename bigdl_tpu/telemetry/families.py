@@ -69,14 +69,6 @@ def step_phase_seconds() -> Histogram:
                  0.5, 1.0, 2.5, 5.0, 10.0, 30.0, float("inf")))
 
 
-def step_mfu_vs_measured() -> Gauge:
-    return get_registry().gauge(
-        "step_mfu_vs_measured",
-        "Model FLOP utilization of the wall step time against the "
-        "same-run measured matmul roofline (set when a harness "
-        "computes an attribution report with a measured peak)")
-
-
 def step_unattributed_fraction() -> Gauge:
     return get_registry().gauge(
         "step_unattributed_fraction",
@@ -571,8 +563,7 @@ def fleet_deploy_freshness_seconds() -> Gauge:
 _PREREGISTER = (
     optimizer_data_wait_seconds, optimizer_step_seconds,
     optimizer_validation_seconds, optimizer_retries_total,
-    step_phase_seconds, step_mfu_vs_measured,
-    step_unattributed_fraction,
+    step_phase_seconds, step_unattributed_fraction,
     collective_bytes_total, collective_calls_total, fleet_step_skew,
     hbm_bytes_peak,
     training_nonfinite_total, training_anomalies_total, grad_norm,

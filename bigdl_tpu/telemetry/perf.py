@@ -1,10 +1,6 @@
-"""Perf attribution: where a step's wall time goes, and the envelope
-hardware evidence is written in.
+"""Perf attribution: where a step's wall time goes.
 
-Two halves, one subsystem (the layer every perf round reports through —
-ROADMAP item 1):
-
-**Attribution** — :func:`attribute_windows` decomposes the optimizer's
+:func:`attribute_windows` decomposes the optimizer's
 completion-timestamp stream (``Optimizer.window_records``, written by
 the loss-drain worker) into four measured phases plus an explicit
 *unattributed residual*:
@@ -29,24 +25,17 @@ rather than silently rescaled, so the published invariant is exact::
 
 :func:`attribution_report` pairs the decomposition with the analytic
 cost model (``utils/xla_cost.cost_breakdown``: compiled FLOPs + bytes
-accessed) to state MFU vs the public spec AND vs the same-run measured
-roofline (overall and device-only), plus a compute-bound vs HBM-bound
+accessed) to state MFU vs the public spec
+(overall and device-only), plus a compute-bound vs HBM-bound
 verdict from bytes/step against the device's HBM bandwidth.
-
-**Durable evidence** — a versioned :data:`RoundArtifact <ROUND_SCHEMA>`
-envelope (schema version, device kind, caller-passed timestamp, git
-rev, confirmed-on-device flag) that ``bench.py`` writes its serving and
-fleet records in.
 
 This module never imports jax.
 """
 
 from __future__ import annotations
 
-import json
 import logging
 import os
-import subprocess
 from typing import Any, Dict, List, Optional
 
 logger = logging.getLogger("bigdl_tpu.telemetry")
@@ -56,9 +45,6 @@ __all__ = [
     "roofline_verdict", "device_peak_flops", "device_hbm_bytes_per_s",
     "device_ici_bytes_per_s", "device_dcn_bytes_per_s",
     "optimizer_perf_status",
-    "ROUND_SCHEMA", "ROUND_ARTIFACT_VERSION", "git_revision",
-    "make_round_artifact", "write_round_artifact", "load_round_artifact",
-    "artifact_payload", "artifact_timestamp",
 ]
 
 # The measured phases, in pipeline order.  ``residual`` is not a phase:
@@ -294,7 +280,6 @@ def attribution_report(records: List[Dict[str, Any]],
                        flops_per_step: Optional[float] = None,
                        bytes_per_step: Optional[float] = None,
                        peak_spec_flops: Optional[float] = None,
-                       peak_measured_flops: Optional[float] = None,
                        hbm_bytes_per_s: Optional[float] = None,
                        device_kind: Optional[str] = None,
                        skip_first: int = 1,
@@ -304,19 +289,16 @@ def attribution_report(records: List[Dict[str, Any]],
                        dcn_bytes_per_s: Optional[float] = None) \
         -> Optional[Dict[str, Any]]:
     """The full perf-attribution table: phase decomposition + MFU
-    accounting + roofline verdict, as one JSON-able dict (what
-    ``bench.py`` embeds in ``BENCH_telemetry.json`` under
-    ``perf_attribution`` and merges into its result line).
+    accounting + roofline verdict, as one JSON-able dict.
 
-    MFU is stated four ways: ``vs_spec`` / ``vs_measured`` use the
+    MFU is stated two ways: ``vs_spec`` uses the
     wall step time (the headline — what a user experiences), while
-    ``device_vs_spec`` / ``device_vs_measured`` use only the measured
+    ``device_vs_spec`` uses only the measured
     device-compute phase (what the chip achieves while actually busy);
-    the gap between the two pairs is precisely what the host phases
+    the gap between the two is precisely what the host phases
     cost.  ``peak_*`` default from the :func:`device_peak_flops` /
     :func:`device_hbm_bytes_per_s` tables when ``device_kind`` is
-    given.  When telemetry is enabled, publishes the
-    ``step_mfu_vs_measured`` gauge as a side effect (the
+    given.  Publishes no gauge (the
     ``step_unattributed_fraction`` gauge stays per-window, written
     only by the drain worker — one writer, one semantic; the run
     aggregate lives in this report)."""
@@ -363,31 +345,23 @@ def attribution_report(records: List[Dict[str, Any]],
         report["dcn"] = dcn
     wall_step = report["wall_step_s"]
     device_step = report["phases_s"]["device_compute"]
-    mfu: Dict[str, Optional[float]] = {}
-    for tag, peak in (("vs_spec", peak_spec_flops),
-                      ("vs_measured", peak_measured_flops)):
-        if flops_per_step and peak and wall_step > 0:
-            mfu[tag] = flops_per_step / wall_step / peak
-        if flops_per_step and peak and device_step > 0:
-            mfu["device_" + tag] = flops_per_step / device_step / peak
+    mfu: Dict[str, float] = {}
+    if flops_per_step and peak_spec_flops and wall_step > 0:
+        mfu["vs_spec"] = flops_per_step / wall_step / peak_spec_flops
+    if flops_per_step and peak_spec_flops and device_step > 0:
+        mfu["device_vs_spec"] = (
+            flops_per_step / device_step / peak_spec_flops)
     if mfu:
         report["mfu"] = mfu
     roof = roofline_verdict(
         flops_per_step, bytes_per_step,
-        peak_measured_flops or peak_spec_flops, hbm_bytes_per_s,
+        peak_spec_flops, hbm_bytes_per_s,
         comm_bytes_per_step=comm_bytes_per_step,
         ici_bytes_per_s=ici_bytes_per_s,
         dcn_bytes_per_step=dcn_bytes_per_step,
         dcn_bytes_per_s=dcn_bytes_per_s)
     if roof is not None:
         report["roofline"] = roof
-    try:
-        from bigdl_tpu import telemetry
-        if telemetry.enabled() and mfu.get("vs_measured") is not None:
-            from bigdl_tpu.telemetry import families as _tm
-            _tm.step_mfu_vs_measured().set(mfu["vs_measured"])
-    except Exception:  # pragma: no cover - telemetry must never break
-        pass           # the harness computing the report
     return report
 
 
@@ -411,97 +385,3 @@ def optimizer_perf_status(opt) -> Optional[Dict[str, Any]]:
         "flops_per_step": getattr(opt, "compiled_flops_per_iteration",
                                   None),
     }
-
-
-# ---------------------------------------------------------------------------
-# RoundArtifact: durable, versioned hardware evidence
-# ---------------------------------------------------------------------------
-
-ROUND_SCHEMA = "bigdl_tpu.round_artifact"
-ROUND_ARTIFACT_VERSION = 1
-
-
-def git_revision(repo_root: Optional[str] = None) -> Optional[str]:
-    """Short git rev of the checkout rooted at ``repo_root``, or None —
-    without starting a process — where that directory is not one (the
-    chip tool's copy is a plain tree).  Provenance only."""
-    root = repo_root or os.getcwd()
-    if not os.path.exists(os.path.join(root, ".git")):
-        return None
-    try:
-        out = subprocess.run(
-            ["git", "rev-parse", "--short", "HEAD"],
-            capture_output=True, text=True, timeout=10, cwd=root)
-    except (OSError, subprocess.SubprocessError):
-        return None
-    rev = out.stdout.strip()
-    return rev if out.returncode == 0 and rev else None
-
-
-def make_round_artifact(payload: Dict[str, Any], *,
-                        kind: str,
-                        timestamp: float,
-                        device_kind: Optional[str] = None,
-                        platform: Optional[str] = None,
-                        confirmed_on_device: bool = False,
-                        source: Optional[str] = None,
-                        git_rev: Optional[str] = None) -> Dict[str, Any]:
-    """Wrap a measurement dict in the versioned evidence envelope.
-
-    ``timestamp`` is passed in by the caller, never sampled here: it is
-    the time of the measurement, not of the write."""
-    if platform is None:
-        platform = payload.get("platform")
-    if device_kind is None:
-        device_kind = payload.get("device_kind")
-    return {
-        "schema": ROUND_SCHEMA,
-        "schema_version": ROUND_ARTIFACT_VERSION,
-        "kind": kind,
-        "timestamp": float(timestamp),
-        "device_kind": device_kind,
-        "platform": platform,
-        "git_rev": git_rev,
-        "confirmed_on_device": bool(confirmed_on_device),
-        "source": source,
-        "payload": payload,
-    }
-
-
-def write_round_artifact(path: str, artifact: Dict[str, Any]) -> str:
-    with open(path, "w", encoding="utf-8") as f:
-        json.dump(artifact, f, indent=1, default=str)
-    return path
-
-
-def load_round_artifact(path: str) -> Optional[Dict[str, Any]]:
-    """Parse ``path`` as JSON, or None when it is missing or corrupt."""
-    try:
-        with open(path, "r", encoding="utf-8") as f:
-            return json.load(f)
-    except (OSError, ValueError):
-        return None
-
-
-def _is_envelope(doc: Dict[str, Any]) -> bool:
-    return isinstance(doc, dict) and doc.get("schema") == ROUND_SCHEMA
-
-
-def artifact_payload(doc: Dict[str, Any]) -> Dict[str, Any]:
-    """The measurement dict inside an artifact — envelope-aware, so
-    legacy flat ``BENCH_measured_*.json`` files read identically."""
-    if _is_envelope(doc):
-        payload = doc.get("payload")
-        return payload if isinstance(payload, dict) else {}
-    return doc if isinstance(doc, dict) else {}
-
-
-def artifact_timestamp(doc: Dict[str, Any],
-                       default: Optional[float] = None) -> Optional[float]:
-    """The measurement's own timestamp: envelope field, else the
-    payload's, else ``default`` (callers pass file mtime)."""
-    for source in (doc, artifact_payload(doc)):
-        ts = source.get("timestamp")
-        if isinstance(ts, (int, float)):
-            return float(ts)
-    return default

@@ -3,7 +3,7 @@ from outside.
 
 A ResNet-50 training step takes about a minute to compile for a TPU; a
 process that starts with an empty cache pays that every time.  Every
-entry point that owns a process (``chip_smoke.py``, ``bench.py``, the
+entry point that owns a process (``chip_smoke.py``, the
 ``bigdl_tpu.examples`` console scripts, ``python -m bigdl_tpu.serving``)
 calls :func:`enable_compile_cache` before its first compile.  Nothing
 calls it at library import: a host application that imports

@@ -7,12 +7,10 @@
 #   scripts/lint.sh --ast-only      # skip the HLO compiles (fast)
 #   scripts/lint.sh --budget-only   # ONLY the budget matrix (cached)
 #
-# Writes the machine report to ANALYSIS_r<N>.json at the repo root —
-# N from $BIGDL_TPU_ROUND when the round driver sets it, else the next
-# free number — so lint debt is a tracked trajectory beside the
-# BENCH_r<N> artifacts, not just a pass/fail bit.  With --budget the
-# budget verdicts (matrix per probe, parity ratios, reshard findings)
-# land in the same artifact.
+# Writes the machine report (every finding, suppressed ones included)
+# to ${TMPDIR:-/tmp}/graftlint_report.json, never into the checkout.
+# With --budget the budget verdicts (matrix per probe, parity ratios,
+# reshard findings) land in the same report.
 #
 # The deliberately-broken negative legs run in
 # tests/test_static_analysis.py; run them by hand with:
@@ -36,26 +34,7 @@ for arg in "$@"; do
   esac
 done
 
-# Report artifact: a FATAL (ship-gate) run claims ANALYSIS_r<N>.json
-# ($BIGDL_TPU_ROUND, else the next free number) — the committed
-# trajectory.  The warn-only ride-along writes ANALYSIS_latest.json
-# instead: tier1 reruns must neither mint new round artifacts nor
-# overwrite a committed full-gate round report with a reduced
-# (--ast-only) one.
-if [ -n "$warn" ] && [ -z "${BIGDL_TPU_ROUND:-}" ]; then
-  report="ANALYSIS_latest.json"
-else
-  if [ -n "${BIGDL_TPU_ROUND:-}" ]; then
-    n=$(printf '%02d' "$BIGDL_TPU_ROUND")
-  else
-    n=1
-    while [ -e "ANALYSIS_r$(printf '%02d' "$n").json" ]; do
-      n=$((n + 1))
-    done
-    n=$(printf '%02d' "$n")
-  fi
-  report="ANALYSIS_r${n}.json"
-fi
+report="${TMPDIR:-/tmp}/graftlint_report.json"
 
 env JAX_PLATFORMS=cpu python -m bigdl_tpu.analysis \
   $hlo $budget $warn --json "$report"

@@ -26,9 +26,9 @@ fi
 python "$(dirname "$0")/metrics_lint.py" --warn-only || true
 # graftlint static-analysis suite (trace safety, lock discipline +
 # lock order, thread lifecycle, collective accounting, clock
-# discipline): AST passes only here — warn-only ride-along writing the
-# ANALYSIS_r<N>.json debt artifact; run `scripts/lint.sh` standalone
-# for the fatal form incl. the compiled-HLO invariant passes
+# discipline): AST passes only here — warn-only ride-along; run
+# `scripts/lint.sh` standalone for the fatal form incl. the
+# compiled-HLO invariant passes
 bash "$(dirname "$0")/lint.sh" --warn-only --ast-only \
   | tail -n 2 || true
 # parallelism-conformance budget matrix (composition x collective-byte
@@ -75,17 +75,6 @@ if bash "$(dirname "$0")/comm_smoke.sh" >"$comm_log" 2>&1; then
   tail -n 1 "$comm_log"
 else
   echo "comm_smoke: FAILED (non-fatal ride-along; see $comm_log)"
-fi
-# continuous-batching generation smoke (mixed-length workload >= 3x the
-# sequential generate() baseline, greedy rows bit-identical, O(1)
-# compile counts, slot-pool cache donation via the HLO alias map):
-# warn-only ride-along; run scripts/serving_gen_smoke.sh standalone for
-# the fatal form
-gen_log=$(mktemp /tmp/serving_gen_smoke.XXXXXX.log)
-if bash "$(dirname "$0")/serving_gen_smoke.sh" >"$gen_log" 2>&1; then
-  tail -n 1 "$gen_log"
-else
-  echo "serving_gen_smoke: FAILED (non-fatal ride-along; see $gen_log)"
 fi
 # elastic-resume smoke (chaos reshard 8 -> 2x4 / 4x2 with loss
 # trajectories equal to the uninterrupted oracle, reshard

@@ -19,7 +19,7 @@ from bigdl_tpu.models import transformer_lm
 from bigdl_tpu.serving import (
     GenerationScheduler, ModelServer, QueueFullError, ServerClosedError,
 )
-from bigdl_tpu.serving.generation import SlotPool, run_mixed_workload
+from bigdl_tpu.serving.generation import SlotPool
 from bigdl_tpu.utils import set_seed
 
 
@@ -703,28 +703,6 @@ def test_engine_spans_reach_the_profiler_with_telemetry_off(lm, monkeypatch):
     assert seen.count("serving/decode_dispatch") == stats["decode_steps"]
     assert seen.count("serving/readback") == stats["decode_steps"]
     assert seen.count("serving/emit") == stats["decode_steps"]
-
-
-# ---------------------------------------------------------------------------
-# workload harness (shared with bench.py + serving_gen_smoke.sh)
-# ---------------------------------------------------------------------------
-
-@pytest.mark.slow
-def test_run_mixed_workload_speedup_and_equivalence(lm):
-    """The acceptance harness end-to-end at reduced scale: continuous
-    batching beats sequential generate() and stays bit-identical.  The
-    full 32-request, >=3x assertion lives in serving_gen_smoke.sh and
-    the bench generate_serving phase."""
-    rng = np.random.default_rng(10)
-    prompts = [rng.integers(1, 51, rng.integers(4, 25)).astype(np.int32)
-               for _ in range(10)]
-    max_news = [int(rng.integers(6, 20)) for _ in range(10)]
-    out = run_mixed_workload(lm, prompts, max_news, slots=4)
-    # no sequential_sample: every row was compared against its oracle
-    assert out["greedy_checked_requests"] == len(prompts)
-    assert out["greedy_equal_checked"]
-    assert out["speedup_vs_sequential"] > 1.5
-    assert out["total_new_tokens"] == sum(max_news)
 
 
 # ---------------------------------------------------------------------------

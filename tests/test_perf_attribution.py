@@ -1,12 +1,9 @@
 """Perf-attribution layer (telemetry.perf): step-time decomposition
 (phases + residual summing to wall), MFU/roofline accounting, the
-RoundArtifact envelope, the xla_cost cost_breakdown satellite, and the
+xla_cost cost_breakdown satellite, and the
 optimizer's window-record capture end-to-end — including the
 stalled-pipeline chaos run attributing the gap to data-wait.
 """
-
-import json
-import os
 
 import numpy as np
 import pytest
@@ -181,12 +178,12 @@ class TestAttributionReport:
         recs = [_rec()] + [_rec() for _ in range(2)]
         rep = perf.attribution_report(
             recs, flops_per_step=50e12, bytes_per_step=100e9,
-            peak_spec_flops=100e12, peak_measured_flops=80e12,
+            peak_spec_flops=100e12,
             hbm_bytes_per_s=100e9)
         assert rep["mfu"]["vs_spec"] == pytest.approx(0.5)
         assert rep["mfu"]["device_vs_spec"] == pytest.approx(1.0)
-        assert rep["mfu"]["vs_measured"] == pytest.approx(50 / 80)
-        # memory floor 1.0 s vs compute floor 0.625 s (vs the measured
+        assert set(rep["mfu"]) == {"vs_spec", "device_vs_spec"}
+        # memory floor 1.0 s vs compute floor 0.5 s (vs the spec
         # peak): HBM bound
         assert rep["roofline"]["verdict"] == "hbm_bound"
         assert rep["flops_per_step"] == 50e12
@@ -203,19 +200,19 @@ class TestAttributionReport:
                                               "compute_bound")
         assert rep["device_kind"] == "TPU v5 lite"
 
-    def test_report_publishes_mfu_gauge_only(self):
+    def test_report_publishes_no_gauge(self):
         telemetry.enable()
         telemetry.reset()
+        before = telemetry.prometheus_text()
         recs = [_rec(), _rec()]
         rep = perf.attribution_report(
-            recs, flops_per_step=40e12, peak_measured_flops=80e12)
-        assert rep["mfu"]["vs_measured"] == pytest.approx(0.5)
-        assert families.step_mfu_vs_measured().value() == \
-            pytest.approx(0.5)
+            recs, flops_per_step=40e12, peak_spec_flops=80e12)
+        assert rep["mfu"]["vs_spec"] == pytest.approx(0.5)
         # the residual gauge has exactly ONE writer (the drain worker,
         # per window) — a report must not overwrite it with the run
         # aggregate, or a scrape's value depends on who ran last
         assert families.step_unattributed_fraction().value() == 0.0
+        assert telemetry.prometheus_text() == before
 
     def test_report_without_cost_model(self):
         rep = perf.attribution_report([_rec(), _rec()])
@@ -292,45 +289,6 @@ class TestCostBreakdown:
         c = _FakeCompiled({"flops": 9.0, "bytes accessed": 0.0})
         assert compiled_flops(c) == 9.0
         assert compiled_bytes(c) == 0.0  # zero, not None (PR-4 fix)
-
-
-# --------------------------------------------------------------------------
-# RoundArtifact: versioned durable evidence
-# --------------------------------------------------------------------------
-
-class TestRoundArtifact:
-    def test_round_trip_and_caller_timestamp(self, tmp_path):
-        payload = {"metric": "m", "value": 123.4, "platform": "tpu",
-                   "device_kind": "TPU v5 lite"}
-        art = perf.make_round_artifact(
-            payload, kind="bench", timestamp=1234.5,
-            confirmed_on_device=True, source="test", git_rev="abc123")
-        assert art["schema"] == perf.ROUND_SCHEMA
-        assert art["schema_version"] == perf.ROUND_ARTIFACT_VERSION
-        assert art["timestamp"] == 1234.5  # caller's clock, verbatim
-        assert art["device_kind"] == "TPU v5 lite"  # from payload
-        assert art["platform"] == "tpu"
-        path = str(tmp_path / "BENCH_measured_x.json")
-        perf.write_round_artifact(path, art)
-        loaded = perf.load_round_artifact(path)
-        assert loaded == json.loads(json.dumps(art))
-        assert perf.artifact_payload(loaded)["value"] == 123.4
-        assert perf.artifact_timestamp(loaded) == 1234.5
-
-    def test_git_revision_spawns_nothing_outside_a_checkout(
-            self, tmp_path, monkeypatch):
-        # the chip tool's copy of the tree is not a git repository
-        def boom(*a, **k):
-            raise AssertionError("git_revision started a process")
-        monkeypatch.setattr(perf.subprocess, "run", boom)
-        assert perf.git_revision(str(tmp_path)) is None
-
-    def test_git_revision_in_a_checkout(self):
-        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        if not os.path.exists(os.path.join(root, ".git")):
-            pytest.skip("tests are not running from a git checkout")
-        rev = perf.git_revision(root)
-        assert rev and len(rev) >= 7
 
 
 # --------------------------------------------------------------------------

@@ -1,8 +1,8 @@
 """Prefix KV-cache reuse + chunked prefill (serving/prefix_cache.py,
 serving/generation.py): LRU byte budgeting, hit/miss/eviction
 semantics, chunked-prefill equivalence across chunk boundaries, the
-decode-must-not-disturb-inactive-rows pin, cadence/TTFT reservoirs, and
-the acceptance harnesses at smoke scale.
+decode-must-not-disturb-inactive-rows pin, and the cadence/TTFT
+reservoirs.
 
 The load-bearing assertion throughout: greedy rows stay BIT-IDENTICAL
 to solo ``model.generate()`` — cache hit or miss, chunked or bucketed
@@ -14,10 +14,7 @@ import numpy as np
 import pytest
 
 from bigdl_tpu.models import transformer_lm
-from bigdl_tpu.serving.generation import (
-    GenerationScheduler, SlotPool, run_cadence_probe,
-    run_shared_prefix_workload,
-)
+from bigdl_tpu.serving.generation import GenerationScheduler, SlotPool
 from bigdl_tpu.serving.prefix_cache import PrefixKVCache
 from bigdl_tpu.utils import set_seed
 
@@ -121,10 +118,12 @@ def test_chunked_prefill_equivalence_straddling_boundaries(lm):
         eng.shutdown()
 
 
-def test_prefix_hit_longer_than_suffix_bucket(lm):
+@pytest.mark.parametrize("sharers", [0, 6])
+def test_prefix_hit_longer_than_suffix_bucket(lm, sharers):
     """A cached prefix longer than the remaining suffix's bucket: the
     copy path must seed positions beyond where the suffix prefill
-    writes, and the row stays bit-identical."""
+    writes, and the row stays bit-identical — also for six sharers in
+    flight together over two slots, each of which hits."""
     rng = np.random.default_rng(4)
     prefix = rng.integers(1, 51, 32).astype(np.int32)
     eng = GenerationScheduler(lm, slots=2, prefill_chunk=16,
@@ -144,6 +143,12 @@ def test_prefix_hit_longer_than_suffix_bucket(lm):
         # its suffix bucket (<= 4) is far shorter than the hit
         assert st["prefix_cache"]["hits"] == 1
         assert st["prefix_chunks_copied"] == 4
+        more = [np.concatenate([prefix, rng.integers(1, 51, 2 + i)
+                                .astype(np.int32)]) for i in range(sharers)]
+        futs = [eng.submit_async(p, 4) for p in more]
+        for p, f in zip(more, futs):
+            np.testing.assert_array_equal(f.result(120), solo(lm, p, 4))
+        assert eng.stats()["prefix_cache"]["hits"] == 1 + sharers
     finally:
         eng.shutdown()
 
@@ -314,30 +319,3 @@ def test_cache_and_seed_programs_compile_once(lm):
     assert counts["kv_extract"] == {8: 1}
     assert counts["chunk_prefill"] and \
         all(n == 1 for n in counts["chunk_prefill"].values()), counts
-
-
-# ---------------------------------------------------------------------------
-# acceptance harnesses at smoke scale
-# ---------------------------------------------------------------------------
-
-def test_shared_prefix_workload_harness(lm):
-    out = run_shared_prefix_workload(
-        lm, n_requests=6, prefix_len=24, tail=(2, 7), max_new=3,
-        slots=2, prefix_cache_bytes=1 << 24, prefix_granularity=8,
-        prefill_chunk=8, oracle_sample=1)
-    assert out["rows_equal_cache_vs_nocache"]
-    assert out["greedy_equal_checked"]
-    assert out["cache"]["prefix_cache"]["hits"] > 0
-    assert out["ttft_p50_speedup"] > 0
-    assert out["shared_fraction"] > 0.5
-
-
-def test_cadence_probe_harness(lm):
-    out = run_cadence_probe(lm, slots=2, steady_requests=1,
-                            warm_tokens=4, steady_budget=30,
-                            long_prompt_len=40, long_max_new=2,
-                            long_arrivals=1, prefill_chunk=8)
-    assert out["bounded"] and out["prefill_chunk"] == 8
-    assert out["gaps_before"] > 0 and out["gaps_during"] > 0
-    assert out["steady_gap_p50_s"] > 0
-    assert out["mixed_gap_p99_s"] > 0
