@@ -40,6 +40,7 @@ from bigdl_tpu.core.module import Module, ModuleList, Parameter
 from bigdl_tpu.nn.attention import GroupedQueryAttention
 from bigdl_tpu.nn.linear import Linear, LookupTable
 from bigdl_tpu.nn.moe import HeldExperts
+from bigdl_tpu.ops import cache_kernels
 
 __all__ = ["HybridDecoder", "mimo_v2"]
 
@@ -177,6 +178,20 @@ class HybridDecoder(Module):
             "pad": jnp.zeros((batch, self.max_len), bool),
         }
 
+    def cache_write_programs(self, caches) -> int:
+        """Device programs that write ``caches`` in one per-row decode
+        step (:meth:`decode_step` with ``index [B]``): a layer's keys and
+        values in one where ``ops.cache_row_writer`` takes its leaves, a
+        ``dynamic_update_slice`` a row and leaf where not, and one select
+        over the padding flags.  The serving pool counts by this."""
+        rows = caches["pad"].shape[0]
+        programs = 1
+        for layer in caches["layers"]:
+            k, v = layer["self"]["k"], layer["self"]["v"]
+            programs += 1 if cache_kernels.cache_row_writer(
+                k.shape, v.shape, k.dtype) is not None else 2 * rows
+        return programs
+
     @staticmethod
     def _mask_untrained_logit(logits):
         """The untied head has no untrained row: nothing to mask."""
@@ -260,10 +275,11 @@ class HybridDecoder(Module):
                 # spare place (GroupedQueryAttention.forward)
                 index = jnp.where(active, index,
                                   jnp.int32(self.max_len - 1))
-            pad = caches["pad"]
-            for b in range(tokens.shape[0]):
-                pad = jax.lax.dynamic_update_slice(pad, flag[b:b + 1],
-                                                   (b, index[b]))
+            # one select over the flags, not a write a row
+            with jax.named_scope("cache/write"):
+                here = jnp.arange(self.max_len, dtype=jnp.int32)[None, :] \
+                    == index[:, None]
+                pad = jnp.where(here, flag, caches["pad"])
         else:
             pad = jax.lax.dynamic_update_slice(caches["pad"], flag,
                                                (0, index))

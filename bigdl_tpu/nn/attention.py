@@ -27,7 +27,8 @@ from bigdl_tpu.core.module import Module, ModuleList, Parameter, \
     next_rng_key
 from bigdl_tpu.nn.linear import Linear
 from bigdl_tpu.nn.normalization import LayerNormalization
-from bigdl_tpu.ops import dot_product_attention
+from bigdl_tpu.ops import attention_kernels, cache_kernels, \
+    dot_product_attention
 from bigdl_tpu.ops.attention_kernels import _NEG_INF
 
 __all__ = [
@@ -359,18 +360,34 @@ def grouped_attention(q, k, v, q_pos, k_pos, window: Optional[int] = None,
     return out.transpose(2, 0, 3, 1, 4, 5).reshape(B, Hq, Tq, dv)
 
 
-def _write_rows(leaf, new, place):
-    """``new [B, h, 1, d]`` into ``leaf [B, h, L, d]``, row ``b`` at
-    ``place[b]``: one ``dynamic_update_slice`` a row (a static loop), so
-    a donated leaf is updated in place in whatever layout it has (see
-    ``TransformerLM._decode_step_rows``).  Nothing is read back: a
-    read-modify-write of the row's old value made the TPU compiler keep
-    the leaf slots-minor and copy it in and out on every step."""
-    new = new.astype(leaf.dtype)
-    for b in range(new.shape[0]):
-        leaf = jax.lax.dynamic_update_slice(leaf, new[b:b + 1],
-                                            (b, 0, place[b], 0))
-    return leaf
+def _write_rows(cache, k, v, place):
+    """``k [B, h, 1, d]`` and ``v [B, h, 1, dv]`` into ``cache``'s leaves
+    ``[B, h, L, d]`` and ``[B, h, L, dv]``, row ``b`` at ``place[b]``;
+    returns the written ``{"k", "v"}``.  On a TPU, leaves that tile are
+    written by one program a layer (``ops.write_cache_rows``, which takes
+    each leaf as it lies); everywhere else by one
+    ``dynamic_update_slice`` a row and leaf (a static loop), so a donated
+    leaf is updated in place in whatever layout it has (see
+    ``TransformerLM._decode_step_rows``).  Either way nothing is read
+    back whole: a read-modify-write of the row's old value made the TPU
+    compiler keep the leaf slots-minor and copy it in and out on every
+    step."""
+    tiles = cache_kernels.cache_row_writer(
+        cache["k"].shape, cache["v"].shape, cache["k"].dtype)
+    with jax.named_scope("cache/write"):
+        if tiles is not None:
+            leaves = cache_kernels.write_cache_rows(
+                cache["k"], cache["v"], k, v, place, tiles=tiles,
+                interpret=not attention_kernels._on_tpu())
+            return dict(zip(("k", "v"), leaves))
+        kv = {}
+        for n, new in (("k", k), ("v", v)):
+            leaf, new = cache[n], new.astype(cache[n].dtype)
+            for b in range(new.shape[0]):
+                leaf = jax.lax.dynamic_update_slice(leaf, new[b:b + 1],
+                                                    (b, 0, place[b], 0))
+            kv[n] = leaf
+        return kv
 
 
 def _write_window(leaf, new, row, start, ring: bool):
@@ -540,8 +557,7 @@ class GroupedQueryAttention(Module):
                     place = jnp.mod(index, L - 1)
                     if active is not None:
                         place = jnp.where(active, place, L - 1)
-                kv = {n: _write_rows(cache[n], new, place)
-                      for n, new in (("k", k), ("v", v))}
+                kv = _write_rows(cache, k, v, place)
                 keys, vals = kv["k"], kv["v"]
                 last = index
             else:

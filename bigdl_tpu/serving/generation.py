@@ -233,6 +233,13 @@ class SlotPool:
         # where it reads every row whole (decode_dispatch counts by it)
         self.key_block = model.decode_key_block(self.caches) \
             if hasattr(model, "decode_key_block") else None
+        # device programs that write the cache in one decode step: one
+        # dynamic_update_slice a slot and leaf (keys, values, flags),
+        # unless the model says that its step writes with fewer
+        self.cache_write_programs = int(
+            model.cache_write_programs(self.caches)
+            if hasattr(model, "cache_write_programs") else
+            self.slots * len(jax.tree_util.tree_leaves(self.caches)))
         self.tok = np.zeros((self.slots,), np.int32)
         self.index = np.zeros((self.slots,), np.int32)
         self.active = np.zeros((self.slots,), bool)
@@ -737,6 +744,7 @@ _ENGINE_COUNTERS = _ENGINE_PHASES + (
     "gap_seconds_prefill", "prefill_positions", "prefill_prompt_tokens",
     "admitted", "queue_wait_seconds",
     "decode_positions_live", "decode_positions_read",
+    "cache_write_programs",
     "moe_layer_calls", "moe_pairs_total", "moe_pairs_held",
     "moe_active_experts")
 
@@ -1161,6 +1169,9 @@ class GenerationScheduler:
                 # where it does not)
                 "decode_positions_live": eng["decode_positions_live"],
                 "decode_positions_read": eng["decode_positions_read"],
+                # device programs that wrote the cache in those steps
+                # (SlotPool.cache_write_programs a step)
+                "cache_write_programs": eng["cache_write_programs"],
                 # what the expert layers did, in decode and prefill
                 # programs alike: calls of an expert layer, the
                 # token-to-expert pairs they routed, those that landed
@@ -1829,6 +1840,7 @@ class GenerationScheduler:
         self._acc["decode_dispatches"] += 1
         self._acc["decode_positions_live"] += emit.positions[0]
         self._acc["decode_positions_read"] += emit.positions[1]
+        self._acc["cache_write_programs"] += pool.cache_write_programs
         self._pending = (emit, n_active, after_prefill)
         if prev is not None:
             # THE async-readback overlap: step N's host-side emit work

@@ -19,6 +19,7 @@ so two processes that compile for a described chip cannot overlap; the
 CPU-platform workers of test_distributed_multiprocess.py never load it.
 """
 
+import functools
 import os
 import re
 
@@ -30,6 +31,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+from bigdl_tpu.ops import cache_kernels
 from bigdl_tpu.ops import conv_bn_kernels as ck
 from bigdl_tpu.ops.attention_kernels import (flash_attention,
                                              ragged_decode_attention)
@@ -151,10 +153,32 @@ def _decode_case(slots, hq, hkv, t, d, dv, dtype):
 DECODE_CASES = [_decode_case(6, 32, 32, 2048, 64, 64, jnp.float32),
                 _decode_case(32, 64, 4, 6144, 192, 128, jnp.bfloat16)]
 
+def _row_write_case(slots, heads, t, d, dv, dtype):
+    """The row-write kernel over a layer's two leaves, each handed over as
+    the chip stores it (``cache_row_writer`` says how)."""
+    def build(sds):
+        k, v = (slots, heads, t, d), (slots, heads, t, dv)
+        tiles = cache_kernels.cache_row_writer(k, v, dtype, force="kernel")
+        args = [sds(k, dtype), sds(v, dtype), sds((slots, heads, 1, d), dtype),
+                sds((slots, heads, 1, dv), dtype), sds((slots,), jnp.int32)]
+        return functools.partial(cache_kernels.write_cache_rows,
+                                 tiles=tiles), args
+    name = "row_write-%dx%d-%dx%dx%d-%s" % (
+        slots, heads, t, d, dv, jnp.dtype(dtype).name)
+    return pytest.param(build, id=name)
+
+
+# the mimo-v2.5 cut's full layer and ring (keys positions-minor, values
+# width-minor), and OPT-1.3B's float32 leaf (both positions-minor), which
+# no program hands the kernel yet
+ROW_WRITE_CASES = [_row_write_case(32, 4, 6144, 192, 128, jnp.bfloat16),
+                   _row_write_case(32, 8, 384, 192, 128, jnp.bfloat16),
+                   _row_write_case(6, 32, 2048, 64, 64, jnp.float32)]
+
 CASES = (
     [_flash_case(s, bias, bwd) for s in FLASH_SHAPES
      for bias in (False, True) for bwd in (False, True)]
-    + DECODE_CASES
+    + DECODE_CASES + ROW_WRITE_CASES
     + [_matmul_case(s, bwd) for s in MATMUL_SHAPES for bwd in (False, True)]
     + [_conv3_case(s, bwd) for s in CONV3_SHAPES for bwd in (False, True)]
 )
@@ -410,14 +434,21 @@ def _lower_cut_program(program, sharding):
 
 @pytest.mark.parametrize("program", ["decode", "chunk_prefill"])
 def test_cut_pool_program_copies_no_leaf_and_expands_no_key_on_v5e(
-        v5e, program):
-    """The decode step and the chunk program of the 5.42 B cut, compiled
-    for the described v5e: no copy or transpose of a whole cache leaf (a
-    ring's or a full layer's, keys or values), no ``scatter`` over one
-    and, in the decode step, no ``while``; no keys or values at the 64
-    query heads; the experts' products on the held stacks as they lie (no
-    copy of a stack); and weights, caches and temporaries inside the
-    chip."""
+        v5e, program, monkeypatch):
+    """The decode step and the chunk program of the 5.42 B cut, as a TPU
+    process traces them, compiled for the described v5e: no copy or
+    transpose of a whole cache leaf (a ring's or a full layer's, keys or
+    values, as it reads or as the row-write kernel is handed it), no
+    ``scatter`` over one and, in the decode step, no ``while``; no keys or
+    values at the 64 query heads; the experts' products on the held stacks
+    as they lie (no copy of a stack); and weights, caches and temporaries
+    inside the chip.  The decode step writes its cache with one kernel
+    call a layer (``ops.write_cache_rows``, keys positions-minor as they
+    lie: a ``bitcast``) and no ``dynamic-update-slice`` into a leaf or the
+    flags; the chunk program writes windows, as it did.  Which path a process takes it
+    asks ``_on_tpu()``; here the test answers."""
+    from bigdl_tpu.ops import attention_kernels
+    monkeypatch.setattr(attention_kernels, "_on_tpu", lambda: True)
     lowered, cfg, caches = _lower_cut_program(
         program, SingleDeviceSharding(v5e.devices[0]))
     s = cfg["serving"]
@@ -428,11 +459,22 @@ def test_cut_pool_program_copies_no_leaf_and_expands_no_key_on_v5e(
                       (32, 8, ring, 128), (32, 8, ring, 192)]
     compiled = lowered.compile()
     text = compiled.as_text()
-    assert "dynamic-update-slice" in text
+    # a leaf as it reads, and with its last two axes swapped
     leaf = "(?:%s)" % "|".join(
-        r"bf16\[%d,%d,%d,%d\]" % shape for shape in shapes)
+        r"bf16\[%d,%d,(?:%d,%d|%d,%d)\]" % (a, b, c, d, d, c)
+        for a, b, c, d in shapes)
     assert not re.findall(
         r"= %s\S* (?:copy|copy-start|transpose|scatter)\(" % leaf, text)
+    writers = text.count('custom_call_target="tpu_custom_call"')
+    if program == "decode":
+        assert writers == cfg["num_hidden_layers"]
+        assert not re.findall(
+            r"= (?:%s|pred\[32,%d\])\S* dynamic-update-slice\(" % (
+                leaf, s["max_len"]), text)
+        assert " while(" not in text
+    else:
+        assert writers == 0
+        assert "dynamic-update-slice" in text
     heads = cfg["num_attention_heads"]
     expanded = r"bf16\[\d+,(?:%d|4,16|8,8),(?:%d|%d),(?:128|192)\]" % (
         heads, s["max_len"], ring)
@@ -449,15 +491,14 @@ def test_cut_pool_program_copies_no_leaf_and_expands_no_key_on_v5e(
     mem = compiled.memory_analysis()
     held_bytes = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 10e9 < held_bytes < 15.5 * 2 ** 30, held_bytes
-    if program == "decode":
-        assert " while(" not in text
 
 
 def test_cut_pool_decode_step_lowers_to_no_scatter_over_a_cache_leaf():
     """Runs anywhere: the cut's decode step as JAX hands it to the
-    compiler (StableHLO) writes every cache leaf with
-    ``dynamic_update_slice`` and none with a ``scatter`` (PR 27's test,
-    on the pool whose layers differ)."""
+    compiler (StableHLO) on the path every backend can take writes every
+    cache leaf with one ``dynamic_update_slice`` a slot and none with a
+    ``scatter`` (PR 27's test, on the pool whose layers differ); the
+    padding flags go in one select."""
     lowered, cfg, caches = _lower_cut_program("decode", None)
     text = lowered.as_text()
     operands = re.findall(
@@ -469,4 +510,5 @@ def test_cut_pool_decode_step_lowers_to_no_scatter_over_a_cache_leaf():
         operands
     n_leaves = 2 * cfg["num_hidden_layers"]
     assert text.count("stablehlo.dynamic_update_slice") \
-        >= n_leaves * cfg["serving"]["slots"]
+        == n_leaves * cfg["serving"]["slots"]
+    assert "tpu_custom_call" not in text
