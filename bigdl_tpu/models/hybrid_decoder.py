@@ -1,5 +1,6 @@
 """Decoder-only language model built from a per-layer list of kinds:
-window and full grouped-query attention layers, a leading dense gated
+window and full grouped-query attention layers, layers whose attention
+runs in parallel with a state-space mixer, a leading dense gated
 feed-forward layer, and a held share of sigmoid-routed gated experts.
 
 The block of ``mimo_v2`` (MiMo-V2.5), pre-norm and sequential:
@@ -14,14 +15,25 @@ names the feed-forward: 0 a dense gated layer
 ``W_d(silu(W_g n) * W_u n)``, 1 :class:`bigdl_tpu.nn.HeldExperts`.  Final
 RMSNorm, an untied head, the embedding not scaled.
 
+The block of ``falcon_h1`` (Falcon-H1) is **parallel**: both mixers read
+the same normed input and their results are summed,
+``h = x + SSM(n * m_ssm_in) * m_ssm_out + Attn(n * m_attn_in) * m_attn_out``
+with ``n = RMSNorm(x)``, then ``y = h + FFN(RMSNorm(h))``; ``SSM`` is
+:class:`bigdl_tpu.nn.ssm.Mamba2Mixer`, ``Attn`` a full layer whose keys
+are scaled, ``FFN`` the dense gated layer with a multiplier inside the
+gate and one on the result; the embedding and the logits are scaled too
+(the architecture's µP multipliers, constants of its ``config.json``).
+
 It keeps the repo's conventions (``TransformerLM``): token ids are
 1-based with 0 as padding, and generation emits ``argmax + 1`` (the
 untied head has exactly ``vocab_size`` rows: none is untrained).  It has
 the incremental API the serving slot pool drives — ``init_cache``,
 ``decode_step`` with a position per row, ``prefill_kv``,
 ``prefill_chunk``, ``max_len``, ``_mask_untrained_logit`` — and declares
-each layer's cache (:meth:`cache_layers`): a ``full`` row of ``max_len``
-positions, or a ``ring`` of the window.  A model with expert layers also
+each layer's caches (:meth:`cache_layers`): a ``full`` row of ``max_len``
+positions or a ``ring`` of the window, and beside the row of a parallel
+layer a ``state`` (no positions: the mixer's recurrence and the last
+inputs of its convolution).  A model with expert layers also
 returns what they did (``routing``, int32 ``[4]``) from every pass the
 pool runs.
 
@@ -40,9 +52,10 @@ from bigdl_tpu.core.module import Module, ModuleList, Parameter
 from bigdl_tpu.nn.attention import GroupedQueryAttention
 from bigdl_tpu.nn.linear import Linear, LookupTable
 from bigdl_tpu.nn.moe import HeldExperts
+from bigdl_tpu.nn.ssm import Mamba2Mixer
 from bigdl_tpu.ops import cache_kernels
 
-__all__ = ["HybridDecoder", "mimo_v2"]
+__all__ = ["HybridDecoder", "mimo_v2", "falcon_h1"]
 
 ROUTING = 4     # what an expert layer counts: HeldExperts.forward
 
@@ -69,18 +82,33 @@ class RMSNorm(Module):
 
 
 class GatedFFN(Module):
-    """``W_d(silu(W_g x) * W_u x)``, no bias; float32 out."""
+    """``W_d(silu(W_g x * m_gate) * W_u x) * m_down``, no bias; float32
+    out.  The two multipliers are constants (1: the plain gated layer)."""
 
-    def __init__(self, hidden_size: int, filter_size: int):
+    def __init__(self, hidden_size: int, filter_size: int,
+                 gate_multiplier: float = 1.0, down_multiplier: float = 1.0):
         super().__init__()
+        self.gate_multiplier = float(gate_multiplier)
+        self.down_multiplier = float(down_multiplier)
         self.gate = Linear(hidden_size, filter_size, with_bias=False)
         self.up = Linear(hidden_size, filter_size, with_bias=False)
         self.down = Linear(filter_size, hidden_size, with_bias=False)
 
     def forward(self, x):
         x = x.astype(self.gate.weight.dtype)
-        a = jax.nn.silu(_product(x, self.gate)) * _product(x, self.up)
-        return _product(a.astype(x.dtype), self.down)
+        g = _product(x, self.gate)
+        if self.gate_multiplier != 1.0:
+            g = g * self.gate_multiplier
+        a = jax.nn.silu(g) * _product(x, self.up)
+        y = _product(a.astype(x.dtype), self.down)
+        return y if self.down_multiplier == 1.0 else y * self.down_multiplier
+
+
+def _fresh_state(state, fresh):
+    """``state`` (a mixer's, a few rows) as zeros where ``fresh`` (a
+    scalar) says that its sequence starts here."""
+    return jax.tree_util.tree_map(
+        lambda leaf: jnp.where(fresh, jnp.zeros_like(leaf), leaf), state)
 
 
 class HybridBlock(Module):
@@ -93,30 +121,108 @@ class HybridBlock(Module):
         self.ffn = ffn
         self.sparse = isinstance(ffn, HeldExperts)
 
+    def init_cache(self, batch: int, max_len: int, dtype, ring_margin: int):
+        return {"self": self.attn.init_cache(batch, max_len, dtype,
+                                             ring_margin)}
+
+    def cache_kinds(self, max_len: int):
+        return ("ring", self.attn.window) if self.attn.window is not None \
+            else ("full", max_len)
+
     def forward(self, x, index=0, cache=None, pad=None, slot=None,
                 active=None, valid=None):
-        """``x [B, T, H]`` float32 -> ``(y, kv, counts)``: see
-        :meth:`GroupedQueryAttention.forward` for ``index``, ``cache``,
-        ``pad``, ``slot`` and ``active``; ``valid [B, T]`` false keeps a
-        token from the experts; ``counts`` is what the expert layer did
-        (zeros for a dense layer)."""
+        """``x [B, T, H]`` float32 -> ``(y, caches, counts)``: see
+        :meth:`GroupedQueryAttention.forward` for ``index``, ``pad``,
+        ``slot`` and ``active``; ``cache`` is the layer's caches
+        (:meth:`init_cache`) or None, and ``caches`` what they became
+        (with None: the compact keys and values); ``valid [B, T]`` false
+        keeps a token from the experts; ``counts`` is what the expert
+        layer did (zeros for a dense layer)."""
         n = self.attn_norm(x).astype(self.attn.q_layer.weight.dtype)
-        a, kv = self.attn.forward(n, index, cache, pad, slot, active)
-        h = x + a
+        a, kv = self.attn.forward(
+            n, index, None if cache is None else cache["self"], pad, slot,
+            active)
+        y, counts = self._feed_forward(x + a, valid)
+        return y, (kv if cache is None else {"self": kv}), counts
+
+    def _feed_forward(self, h, valid):
         n = self.ffn_norm(h)
         if self.sparse:
             f, counts = self.ffn.forward(n, valid)
         else:
             f, counts = self.ffn.forward(n), jnp.zeros((ROUTING,), jnp.int32)
-        return h + f, kv, counts
+        return h + f, counts
+
+
+class ParallelBlock(HybridBlock):
+    """A full attention layer and a state-space mixer on the same normed
+    input, summed: the layer keeps a row of keys and values **and** a
+    state.  ``multipliers``: ``attention_in``, ``attention_out``,
+    ``ssm_in``, ``ssm_out``."""
+
+    def __init__(self, hidden_size: int, attn: GroupedQueryAttention,
+                 ssm: Mamba2Mixer, ffn: Module, eps: float,
+                 multipliers: Dict[str, float]):
+        super().__init__(hidden_size, attn, ffn, eps)
+        self.ssm = ssm
+        self.multipliers = {k: float(multipliers.get(k, 1.0)) for k in (
+            "attention_in", "attention_out", "ssm_in", "ssm_out")}
+
+    def init_cache(self, batch: int, max_len: int, dtype, ring_margin: int):
+        return {"self": self.attn.init_cache(batch, max_len, dtype,
+                                             ring_margin),
+                "ssm": self.ssm.init_state(batch, dtype)}
+
+    def cache_kinds(self, max_len: int):
+        return {"self": ("full", max_len), "ssm": ("state", None)}
+
+    def forward(self, x, index=0, cache=None, pad=None, slot=None,
+                active=None, valid=None):
+        """As :meth:`HybridBlock.forward`.  The state has no positions:
+        a chunk (scalar ``index``) reads row ``slot``'s state, or starts
+        from zeros when ``index`` is 0 (whoever held the row before is
+        forgotten), and writes back the state after its last valid
+        token; a per-row step leaves the state of a row that is not
+        ``active`` as it was, and starts a row at position 0 from
+        zeros."""
+        m = self.multipliers
+        n = self.attn_norm(x)
+        dtype = self.attn.q_layer.weight.dtype
+        a, kv = self.attn.forward(
+            (n * m["attention_in"]).astype(dtype), index,
+            None if cache is None else cache["self"], pad, slot, active)
+        u = (n * m["ssm_in"]).astype(dtype)
+        if cache is None:
+            s, state = self.ssm.forward(u, None, valid)
+        elif jnp.ndim(index) == 1:
+            s, state = self.ssm.step(u, cache["ssm"], active, index == 0)
+        else:
+            pooled = cache["ssm"]
+            rows = pooled if slot is None else jax.tree_util.tree_map(
+                lambda leaf: jax.lax.dynamic_slice_in_dim(leaf, slot, 1),
+                pooled)
+            s, state = self.ssm.forward(
+                u, _fresh_state(rows, jnp.asarray(index) == 0), valid)
+            if slot is not None:
+                state = jax.tree_util.tree_map(
+                    lambda leaf, row: jax.lax.dynamic_update_slice_in_dim(
+                        leaf, row.astype(leaf.dtype), slot, 0),
+                    pooled, state)
+        h = x + s * m["ssm_out"] + a * m["attention_out"]
+        y, counts = self._feed_forward(h, valid)
+        return y, {"self": kv, "ssm": state}, counts
 
 
 class HybridDecoder(Module):
     """``forward(tokens [B, T] int, 1-based; 0 = padding) -> logits
     [B, T, vocab]`` float32 (column ``j`` scores token ``j + 1``).
 
-    ``layer_kinds[i]`` is ``"full"`` or ``"window"``; ``sparse[i]`` says
-    whether layer ``i``'s feed-forward is the expert layer."""
+    ``layer_kinds[i]`` is ``"full"``, ``"window"`` or ``"parallel"`` (a
+    full layer beside a state-space mixer built from ``ssm``, the
+    arguments of :class:`Mamba2Mixer`); ``sparse[i]`` says whether layer
+    ``i``'s feed-forward is the expert layer.  ``multipliers`` are
+    constants by name (absent: 1): ``embedding``, ``lm_head``, ``key``,
+    ``mlp_gate``, ``mlp_down``, and a parallel block's four."""
 
     def __init__(self, vocab_size: int, hidden_size: int,
                  layer_kinds: Sequence[str], sparse: Sequence[bool],
@@ -127,41 +233,61 @@ class HybridDecoder(Module):
                  num_experts: int, top_k: int,
                  held: Optional[Tuple[int, int]] = None,
                  eps: float = 1e-5, max_len: int = 512,
-                 normalize_top_k: bool = True):
+                 normalize_top_k: bool = True,
+                 ssm: Optional[Dict[str, Any]] = None,
+                 multipliers: Optional[Dict[str, float]] = None):
         super().__init__()
         if len(layer_kinds) != len(sparse):
             raise ValueError("one kind and one sparse flag a layer")
+        mult = dict(multipliers or {})
         self.hidden_size = hidden_size
         self.max_len = max_len
+        self.embedding_multiplier = float(mult.get("embedding", 1.0))
+        self.lm_head_multiplier = float(mult.get("lm_head", 1.0))
         self.embedding = LookupTable(vocab_size, hidden_size)
         self.embedding.weight = Parameter(
             self.embedding.weight * hidden_size ** -0.5)
         blocks = []
         for kind, is_sparse in zip(layer_kinds, sparse):
-            if kind not in ("full", "window"):
-                raise ValueError(f"layer kind {kind!r}: 'full' or 'window'")
+            if kind not in ("full", "window", "parallel"):
+                raise ValueError(f"layer kind {kind!r}: 'full', 'window' "
+                                 f"or 'parallel'")
             win = kind == "window"
+            attends = "window" if win else "full"   # a parallel layer: full
             attn = GroupedQueryAttention(
-                hidden_size, num_heads, kv_heads[kind], head_dim, v_head_dim,
-                window=window if win else None,
-                rope_theta=rope_theta[kind], rotary_dim=rotary_dim,
-                sink=win and window_sink, value_scale=value_scale)
+                hidden_size, num_heads, kv_heads[attends], head_dim,
+                v_head_dim, window=window if win else None,
+                rope_theta=rope_theta[attends], rotary_dim=rotary_dim,
+                sink=win and window_sink, value_scale=value_scale,
+                key_scale=mult.get("key", 1.0))
             ffn = HeldExperts(hidden_size, expert_size, num_experts, top_k,
                               held, normalize_top_k) if is_sparse \
-                else GatedFFN(hidden_size, dense_size)
-            blocks.append(HybridBlock(hidden_size, attn, ffn, eps))
+                else GatedFFN(hidden_size, dense_size,
+                              mult.get("mlp_gate", 1.0),
+                              mult.get("mlp_down", 1.0))
+            if kind == "parallel":
+                if ssm is None:
+                    raise ValueError("a parallel layer needs ssm=")
+                blocks.append(ParallelBlock(
+                    hidden_size, attn, Mamba2Mixer(hidden_size, eps=eps,
+                                                   **ssm), ffn, eps, mult))
+            else:
+                blocks.append(HybridBlock(hidden_size, attn, ffn, eps))
         self.blocks = ModuleList(blocks)
         self.final_norm = RMSNorm(hidden_size, eps)
         self.lm_head = Linear(hidden_size, vocab_size, with_bias=False)
 
     # ---- what the slot pool asks ------------------------------------------
 
-    def cache_layers(self) -> Tuple[Tuple[str, int], ...]:
-        """Each layer's cache: ``("full", max_len)`` or ``("ring",
-        window)``.  A ring is allocated with room for a prefill chunk
-        beside the window (:meth:`init_cache`, ``ring_margin``)."""
-        return tuple(("ring", blk.attn.window) if blk.attn.window is not None
-                     else ("full", self.max_len) for blk in self.blocks)
+    def cache_layers(self) -> Tuple[Any, ...]:
+        """Each layer's caches.  A layer with one, its keys and values
+        (``"self"``), declares it ``("full", max_len)`` or ``("ring",
+        window)``; a ring is allocated with room for a prefill chunk
+        beside the window (:meth:`init_cache`, ``ring_margin``).  A
+        parallel layer declares two by name: ``{"self": ("full",
+        max_len), "ssm": ("state", None)}``, a state having no
+        positions."""
+        return tuple(blk.cache_kinds(self.max_len) for blk in self.blocks)
 
     def expert_layers(self) -> int:
         return sum(1 for blk in self.blocks if blk.sparse)
@@ -172,9 +298,8 @@ class HybridDecoder(Module):
         its layer's heads and widths, and the padding flags by
         position."""
         return {
-            "layers": [{"self": blk.attn.init_cache(
-                batch, self.max_len, dtype, ring_margin)}
-                for blk in self.blocks],
+            "layers": [blk.init_cache(batch, self.max_len, dtype,
+                                      ring_margin) for blk in self.blocks],
             "pad": jnp.zeros((batch, self.max_len), bool),
         }
 
@@ -182,14 +307,17 @@ class HybridDecoder(Module):
         """Device programs that write ``caches`` in one per-row decode
         step (:meth:`decode_step` with ``index [B]``): a layer's keys and
         values in one where ``ops.cache_row_writer`` takes its leaves, a
-        ``dynamic_update_slice`` a row and leaf where not, and one select
-        over the padding flags.  The serving pool counts by this."""
+        ``dynamic_update_slice`` a row and leaf where not, one select
+        over the padding flags, and for a state two whatever the rows
+        (the recurrence's update and the select that shifts the
+        convolution's inputs).  The serving pool counts by this."""
         rows = caches["pad"].shape[0]
         programs = 1
         for layer in caches["layers"]:
             k, v = layer["self"]["k"], layer["self"]["v"]
             programs += 1 if cache_kernels.cache_row_writer(
                 k.shape, v.shape, k.dtype) is not None else 2 * rows
+            programs += 2 * ("ssm" in layer)
         return programs
 
     @staticmethod
@@ -200,12 +328,16 @@ class HybridDecoder(Module):
     # ---- the four passes ---------------------------------------------------
 
     def _embed(self, tokens):
-        return self.embedding.forward(jnp.maximum(tokens, 1)).astype(
+        x = self.embedding.forward(jnp.maximum(tokens, 1)).astype(
             jnp.float32)
+        return x if self.embedding_multiplier == 1.0 \
+            else x * self.embedding_multiplier
 
     def _logits(self, x):
         w = self.lm_head.weight
-        return _product(self.final_norm(x).astype(w.dtype), self.lm_head)
+        y = _product(self.final_norm(x).astype(w.dtype), self.lm_head)
+        return y if self.lm_head_multiplier == 1.0 \
+            else y * self.lm_head_multiplier
 
     def forward(self, tokens):
         _B, T = tokens.shape
@@ -220,9 +352,10 @@ class HybridDecoder(Module):
 
     def prefill_kv(self, ptoks):
         """Compact per-layer keys and values ``[B, Hkv, T, d]`` of every
-        position of ``ptoks``, the ``[B, T]`` padding flags, and the
-        expert layers' ``routing``: what a bucketed prefill scatters into
-        slots."""
+        position of ``ptoks`` (a parallel layer: ``{"self": those, "ssm":
+        the state after each row's last real token}``), the ``[B, T]``
+        padding flags, and the expert layers' ``routing``: what a
+        bucketed prefill scatters into slots."""
         pad = ptoks == 0
         x = self._embed(ptoks)
         layers, routing = [], jnp.zeros((ROUTING,), jnp.int32)
@@ -252,9 +385,9 @@ class HybridDecoder(Module):
         x = self._embed(toks)
         new_layers, routing = [], jnp.zeros((ROUTING,), jnp.int32)
         for blk, cache in zip(self.blocks, caches["layers"]):
-            x, kv, counts = blk.forward(x, index, cache["self"], pad, slot,
+            x, kv, counts = blk.forward(x, index, cache, pad, slot,
                                         valid=toks != 0)
-            new_layers.append({"self": kv})
+            new_layers.append(kv)
             routing = routing + counts
         return dict(caches, layers=new_layers, pad=pad), routing
 
@@ -287,9 +420,9 @@ class HybridDecoder(Module):
         x = self._embed(tokens)
         new_layers, routing = [], jnp.zeros((ROUTING,), jnp.int32)
         for blk, cache in zip(self.blocks, caches["layers"]):
-            x, kv, counts = blk.forward(x, index, cache["self"], pad,
+            x, kv, counts = blk.forward(x, index, cache, pad,
                                         active=active, valid=valid)
-            new_layers.append({"self": kv})
+            new_layers.append(kv)
             routing = routing + counts
         new_caches = dict(caches, layers=new_layers, pad=pad)
         if not with_logits:
@@ -378,3 +511,59 @@ def mimo_v2(config: Dict[str, Any], max_len: int) -> HybridDecoder:
               c.get("experts_held", c["n_routed_experts"])),
         eps=c.get("layernorm_epsilon", 1e-5), max_len=max_len,
         normalize_top_k=c.get("norm_topk_prob", True))
+
+
+_FALCON_H1_REFUSED = ("attention_bias", "mlp_bias", "projectors_bias",
+                      "mamba_proj_bias", "rope_scaling",
+                      "tie_word_embeddings", "attn_layer_indices")
+
+
+def falcon_h1(config: Dict[str, Any], max_len: int) -> HybridDecoder:
+    """The model from the keys of a public ``falcon_h1`` ``config.json``:
+    every layer a parallel block (grouped-query attention over full rows
+    beside a Mamba-2 mixer), a dense gated feed-forward with its two
+    multipliers, an untied head, and the µP multipliers as constants.
+    What is not built is refused: biases on the projections, a scaled
+    rotary embedding, a tied head, attention on some layers only, a
+    mixer without its gated norm or with the norm before the gate."""
+    c = config
+    for key in _FALCON_H1_REFUSED:
+        if c.get(key):
+            raise ValueError(f"falcon_h1: {key}={c[key]!r} is not built")
+    if not c.get("mamba_rms_norm", True) or c.get("mamba_norm_before_gate") \
+            or not c.get("mamba_conv_bias", True) \
+            or c.get("hidden_act", "silu") != "silu":
+        raise ValueError("falcon_h1: a gated grouped RMS norm after the "
+                         "gate, a bias on the convolution and silu are "
+                         "what is built")
+    heads, width = c["mamba_n_heads"], c["mamba_d_head"]
+    if heads * width != c["mamba_d_ssm"]:
+        raise ValueError("falcon_h1: mamba_d_ssm is mamba_n_heads heads of "
+                         "mamba_d_head")
+    n = c["num_hidden_layers"]
+    gate, down = c.get("mlp_multipliers") or (1.0, 1.0)
+    return HybridDecoder(
+        vocab_size=c["vocab_size"], hidden_size=c["hidden_size"],
+        layer_kinds=["parallel"] * n, sparse=[False] * n,
+        num_heads=c["num_attention_heads"], head_dim=c["head_dim"],
+        v_head_dim=c["head_dim"],
+        kv_heads={"full": c["num_key_value_heads"]},
+        rope_theta={"full": float(c["rope_theta"])},
+        rotary_dim=c["head_dim"], window=0, window_sink=False,
+        value_scale=1.0, dense_size=c["intermediate_size"], expert_size=0,
+        num_experts=0, top_k=0, eps=c.get("rms_norm_eps", 1e-5),
+        max_len=max_len,
+        ssm=dict(heads=heads, head_dim=width, groups=c["mamba_n_groups"],
+                 state_size=c["mamba_d_state"],
+                 conv_width=c["mamba_d_conv"],
+                 chunk=c.get("mamba_chunk_size", 128),
+                 multipliers=c.get("ssm_multipliers") or (1.0,) * 5),
+        multipliers={
+            "embedding": c.get("embedding_multiplier", 1.0),
+            "lm_head": c.get("lm_head_multiplier", 1.0),
+            "key": c.get("key_multiplier", 1.0),
+            "mlp_gate": gate, "mlp_down": down,
+            "attention_in": c.get("attention_in_multiplier", 1.0),
+            "attention_out": c.get("attention_out_multiplier", 1.0),
+            "ssm_in": c.get("ssm_in_multiplier", 1.0),
+            "ssm_out": c.get("ssm_out_multiplier", 1.0)})

@@ -444,7 +444,8 @@ class GroupedQueryAttention(Module):
 
     With ``rotary_dim`` the first ``rotary_dim`` dims of every query and
     key head are rotated by position (:func:`rotary_half`, base
-    ``rope_theta``: a layer kind has its own).  The context is scaled by
+    ``rope_theta``: a layer kind has its own).  The keys are scaled by
+    ``key_scale`` as they leave their projection and the context by
     ``value_scale`` before the output projection.  No bias, no q/k norm,
     scores over ``sqrt(head_dim)``.  :meth:`forward` is the one entry: a
     full forward, a prefill that returns compact keys and values, a
@@ -455,7 +456,7 @@ class GroupedQueryAttention(Module):
                  head_dim: int, v_head_dim: Optional[int] = None,
                  window: Optional[int] = None, rope_theta: float = 10000.0,
                  rotary_dim: int = 0, sink: bool = False,
-                 value_scale: float = 1.0):
+                 value_scale: float = 1.0, key_scale: float = 1.0):
         super().__init__()
         if num_heads % num_kv_heads:
             raise ValueError("num_heads must be a multiple of num_kv_heads")
@@ -468,6 +469,7 @@ class GroupedQueryAttention(Module):
         self.window = None if window is None else int(window)
         self.rope_theta, self.rotary_dim = float(rope_theta), int(rotary_dim)
         self.value_scale = float(value_scale)
+        self.key_scale = float(key_scale)
         self.q_layer = Linear(hidden_size, num_heads * head_dim,
                               with_bias=False)
         self.k_layer = Linear(hidden_size, num_kv_heads * head_dim,
@@ -535,6 +537,8 @@ class GroupedQueryAttention(Module):
                         self.head_dim)
         v = self._heads(x, self.v_layer, self.num_kv_heads,
                         self.v_head_dim)
+        if self.key_scale != 1.0:
+            k = k * self.key_scale
         index = jnp.asarray(index, jnp.int32)
         q_pos = (index[:, None] if per_row else index[None, None]) \
             + jnp.arange(T, dtype=jnp.int32)[None, :]       # [1|B, T]

@@ -133,6 +133,13 @@ class GenerationRequest:
         self.t_enqueue = time.perf_counter()
 
 
+def _caches_of(layer) -> Dict[str, Tuple[str, Optional[int]]]:
+    """A layer's caches by name, from what the model declares of it
+    (``cache_layers()``): one ``(kind, places)``, its keys and values
+    (``"self"``), or several by name."""
+    return layer if isinstance(layer, dict) else {"self": layer}
+
+
 class SlotPool:
     """S fixed KV-cache slots plus the jitted shape-stable programs that
     advance them.  Host-side per-slot decode state (current token,
@@ -150,7 +157,22 @@ class SlotPool:
     it is attended), position ``p`` at place ``p % ring``, and one spare
     place where idle lanes write.  Keys and values of a layer may differ
     in width.  ``TransformerLM`` is the case "every layer full, one
-    shape".  All six programs below take the list.
+    shape".  A layer may keep more than one cache, declared by name
+    (``{"self": ("full", max_len), "ssm": ("state", None)}``): a
+    ``state`` has **no positions**, one fixed-size value a slot that
+    every token of the slot's sequence rewrites (a state-space mixer's
+    recurrence and the last inputs of its convolution).  What a row cache
+    gets for free a state has to be given: the chunk program starts a
+    slot's state from zeros when it is handed position 0 (the reset at
+    admission, inside the program that exists), the decode step leaves
+    the state of an idle lane as it was (a slot between two of its
+    prefill chunks rides every step; a row can send such a lane where
+    nothing reads, a state cannot), and a prompt's last chunk is padded
+    at its end and not moved back over positions already written (a
+    position scanned twice would be in the state twice).  A state cannot
+    be copied or extracted by position, so ``kv_copy`` and ``kv_extract``
+    are not offered on such a model (the scheduler refuses it a prefix
+    cache).  All six programs below take the list.
 
     **How the pool lies on the chip.**  A K or V leaf is
     ``[S, heads, max_len, head_dim]``.  With a head size under the 128
@@ -214,7 +236,11 @@ class SlotPool:
         # position, what a pool that is never handed a chunk needs (the
         # scheduler passes its ``prefill_chunk``).
         self.cache_layers = tuple(model.cache_layers())
-        self.has_ring = any(k == "ring" for k, _ in self.cache_layers)
+        kinds = [[kind for kind, _ in _caches_of(layer).values()]
+                 for layer in self.cache_layers]
+        self.has_ring = any("ring" in of_layer for of_layer in kinds)
+        self.state_layers = sum("state" in of_layer for of_layer in kinds)
+        self.has_state = self.state_layers > 0
         self.ring_margin = int(ring_margin)
         self.caches = self.model.init_cache(
             self.slots, self.dtype,
@@ -311,7 +337,8 @@ class SlotPool:
             # programs since the last step, rides behind the S tokens:
             # one read-back a step, whatever it carries
             emit = jnp.concatenate(
-                [jnp.where(active, nxt, 0), routing + sum(did)])
+                [jnp.where(active, nxt, 0),
+                 routing + did[0] if experts else routing])
             return new_caches, new_tok, new_index, emit, \
                 jnp.zeros_like(routing)
 
@@ -334,9 +361,13 @@ class SlotPool:
             # gather; a place with none yet takes anything, its position
             # reads as unwritten (attention.cache_positions)
             last = jnp.sum(~pads, axis=1).astype(jnp.int32) - 1     # [B]
-            for (kind, _), kv, cache in zip(self.cache_layers, layers_kv,
-                                            caches["layers"]):
-                old = cache["self"]
+
+            def rows(kind, old, kv):
+                if kind == "state":
+                    # the state after each row's last real token, whole
+                    return {n: old[n].at[slot_ids].set(
+                        kv[n].astype(old[n].dtype), mode="drop")
+                        for n in old}
                 new = {}
                 for n in ("k", "v"):
                     src, n_places = kv[n].astype(old[n].dtype), t
@@ -353,7 +384,14 @@ class SlotPool:
                     # instead of writing a real slot
                     new[n] = old[n].at[slot_ids, :, :n_places, :].set(
                         src, mode="drop")
-                new_layers.append({"self": new})
+                return new
+
+            for decl, kv, cache in zip(self.cache_layers, layers_kv,
+                                       caches["layers"]):
+                kv = kv if isinstance(decl, dict) else {"self": kv}
+                new_layers.append({
+                    name: rows(kind, cache[name], kv[name])
+                    for name, (kind, _) in _caches_of(decl).items()})
             pad = caches["pad"].at[slot_ids, :t].set(pads, mode="drop")
             return {"layers": new_layers, "pad": pad}
 
@@ -367,12 +405,14 @@ class SlotPool:
             # the slot's row (a small dynamic_update_slice the donated
             # pool absorbs in place) and reads the row's keys by slice;
             # slot_id and index are traced, so the program is keyed by
-            # chunk width alone
+            # chunk width alone.  A state has no window to write: the
+            # model reads the slot's state (zeros when index is 0: the
+            # slot's new occupant), scans the chunk's real tokens and
+            # writes the state back
             out = model.prefill_chunk(toks[None], index, caches,
                                       slot=slot_id)
-            if experts:
-                return out[0], routing + out[1]
-            return out, routing
+            new_caches, *did = out if isinstance(out, tuple) else (out,)
+            return new_caches, routing + did[0] if experts else routing
 
         self._chunk_jit = jax.jit(_chunk_prefill, donate_argnums=(1,))
 
@@ -441,14 +481,15 @@ class SlotPool:
                    for leaf in jax.tree_util.tree_leaves(self.caches))
 
     def cache_nbytes_by_kind(self) -> Dict[str, int]:
-        """Bytes of the keys and values by the kind of their layer's
-        cache (``full`` | ``ring``)."""
+        """Bytes of the layers' caches by kind (``full`` | ``ring`` |
+        ``state``)."""
         import jax
-        out = {"full": 0, "ring": 0}
-        for (kind, _), layer in zip(self.cache_layers,
-                                    self.caches["layers"]):
-            out[kind] += sum(int(leaf.size) * leaf.dtype.itemsize
-                             for leaf in jax.tree_util.tree_leaves(layer))
+        out = {"full": 0, "ring": 0, "state": 0}
+        for decl, layer in zip(self.cache_layers, self.caches["layers"]):
+            for name, (kind, _) in _caches_of(decl).items():
+                out[kind] += sum(
+                    int(leaf.size) * leaf.dtype.itemsize
+                    for leaf in jax.tree_util.tree_leaves(layer[name]))
         return out
 
     def _routing_aval(self):
@@ -575,7 +616,10 @@ class SlotPool:
         if bucket > 1:
             padded = np.zeros((self.prefill_batch, bucket), np.int32)
             for i, p in enumerate(prompts):
-                padded[i, :len(p)] = p
+                # the decode step feeds the last prompt token.  A row
+                # rewrites its position then; a state would hold it twice
+                keep = len(p) - 1 if self.has_state else len(p)
+                padded[i, :keep] = p[:keep]
             if n < self.prefill_batch:
                 # dead lanes repeat row 0 (any valid prompt); their
                 # scatter is dropped via the out-of-range slot id
@@ -584,7 +628,7 @@ class SlotPool:
             ids[:n] = np.asarray(slot_ids, np.int32)
             layers_kv, pads, *did = self._prefill_jit(
                 self.model, jnp.asarray(padded[:, :-1]))
-            if did:     # an expert model's routing: see __init__
+            if self.expert_layers:      # their routing: see __init__
                 self._routing = self._routing + did[0]
             self.caches = self._scatter_jit(
                 self.caches, jnp.asarray(ids), layers_kv, pads)
@@ -609,15 +653,24 @@ class SlotPool:
                      chain: Sequence[PrefixChunk]) -> None:
         """Copy a matched prefix-cache chain into ``slot``'s row (one
         device-side scatter per chunk, compiled once per granularity)."""
+        self._refuse_state("copied into")
         for chunk in chain:
             self.caches = self._kv_copy_jit(
                 self.caches, np.int32(slot), chunk.layers, chunk.pad,
                 np.int32(chunk.index))
 
+    def _refuse_state(self, what: str) -> None:
+        if self.has_state:
+            raise ValueError(
+                f"keys and values by position cannot be {what} a slot "
+                f"that also keeps a state: the state is of the whole "
+                f"sequence and has no positions to take a part of")
+
     def kv_extract(self, slot: int, index: int, width: int):
         """Read back ``width`` positions of ``slot``'s K/V row starting
         at ``index`` (compact per-layer arrays + pad flags) — what the
         prefix cache stores.  Does NOT donate the pool caches."""
+        self._refuse_state("extracted from")
         return self._kv_extract_jit(self.caches, np.int32(slot),
                                     np.int32(index), int(width))
 
@@ -746,7 +799,9 @@ _ENGINE_COUNTERS = _ENGINE_PHASES + (
     "decode_positions_live", "decode_positions_read",
     "cache_write_programs",
     "moe_layer_calls", "moe_pairs_total", "moe_pairs_held",
-    "moe_active_experts")
+    "moe_active_experts",
+    "ssm_layer_calls", "ssm_scan_positions", "ssm_scan_positions_real",
+    "state_resets")
 
 
 def _fold_counts(acc: Dict[str, float], eng: Dict[str, float]) -> None:
@@ -876,6 +931,13 @@ class GenerationScheduler:
                 "position, which a ring does not keep: a model with "
                 "ring (windowed) cache layers is served with the prefix "
                 "cache off")
+        if self._prefix_cache is not None and self.pool.has_state:
+            raise ValueError(
+                "the prefix cache copies and extracts keys and values by "
+                "position; a state (a state-space layer's) is of a whole "
+                "sequence, has no positions, and is not snapshotted at "
+                "chunk boundaries: a model with state cache layers is "
+                "served with the prefix cache off")
         if role == "prefill" and self._prefix_cache is None:
             raise ValueError(
                 "a prefill-role engine publishes its K/V through the "
@@ -1182,8 +1244,19 @@ class GenerationScheduler:
                 "moe_pairs_total": eng["moe_pairs_total"],
                 "moe_pairs_held": eng["moe_pairs_held"],
                 "moe_active_experts": eng["moe_active_experts"],
+                # what the state layers did (all zero for a model without
+                # them): calls of a state layer, by decode steps and
+                # prefill programs alike; the positions the prefill scans
+                # ran over, bucket padding and dead lanes included, and
+                # the real ones among them; admissions whose first
+                # prefill program started a slot's state from zeros
+                "ssm_layer_calls": eng["ssm_layer_calls"],
+                "ssm_scan_positions": eng["ssm_scan_positions"],
+                "ssm_scan_positions_real": eng["ssm_scan_positions_real"],
+                "state_resets": eng["state_resets"],
                 "cache_bytes_full": self._cache_bytes["full"],
                 "cache_bytes_window": self._cache_bytes["ring"],
+                "cache_bytes_state": self._cache_bytes["state"],
             }
         cache = self._prefix_cache
         out["prefix_cache"] = None if cache is None else cache.stats()
@@ -1454,6 +1527,9 @@ class GenerationScheduler:
             t_slot = time.perf_counter()
             self._acc["admitted"] += 1
             self._acc["queue_wait_seconds"] += t_slot - req.t_enqueue
+            # the slot's first program starts its state from zeros: the
+            # first chunk, the scatter, or a one-token prompt's first step
+            self._acc["state_resets"] += pool.has_state
             if req.trace is not None:
                 request_trace.record_span(
                     "request/queue", req.t_enqueue, t_slot,
@@ -1676,8 +1752,13 @@ class GenerationScheduler:
             # lanes and bucket padding included
             self._acc["prefill_positions"] += \
                 pool.prefill_batch * (bucket - 1)
+            self._acc["ssm_layer_calls"] += pool.state_layers
+            self._acc["ssm_scan_positions"] += \
+                pool.state_layers * pool.prefill_batch * (bucket - 1)
         for st in sts:
             self._acc["prefill_prompt_tokens"] += st.end_pos - st.next_pos
+            self._acc["ssm_scan_positions_real"] += \
+                pool.state_layers * (st.end_pos - st.next_pos)
             st.next_pos = st.end_pos
             self._store_prefix(st)
             self._release_claims(st)
@@ -1703,7 +1784,8 @@ class GenerationScheduler:
         ``prefill_chunk``; the final partial chunk picks the smallest
         bucket covering the remainder and SUFFIX-ALIGNS it (recomputing
         a little overlap, which rewrites identical K/V) so it never
-        writes past the prefill region and carries no padded lanes."""
+        writes past the prefill region and carries no padded lanes.  On a
+        model that keeps a state it is padded at its end instead."""
         pool = self.pool
         p = st.req.prompt
         end = st.end_pos
@@ -1713,12 +1795,22 @@ class GenerationScheduler:
             toks = p[s:s + w]
         else:
             w = pick_bucket(r, self._chunk_buckets)
-            s = max(end - w, 0)
+            if pool.has_state:
+                # a state holds every position it was handed, once: the
+                # last chunk starts where the one before ended and pads
+                # its tail (or, where that would pass the row's end,
+                # takes the widest bucket that is all prompt)
+                s = st.next_pos
+                if s + w > pool.max_len:
+                    w = max(b for b in self._chunk_buckets if b <= r)
+            else:
+                s = max(end - w, 0)
             toks = p[s:min(s + w, end)]
             if len(toks) < w:
-                # only a first-and-only chunk can be short (s == 0):
-                # pad the tail; those positions are re-written by decode
-                # before they are ever attended
+                # short only for a first-and-only chunk (s == 0) or the
+                # last chunk of a state model: pad the tail; those
+                # positions are re-written by decode before they are ever
+                # attended, and padding advances no state
                 toks = np.concatenate(
                     [toks, np.zeros(w - len(toks), np.int32)])
         try:
@@ -1748,6 +1840,10 @@ class GenerationScheduler:
         self._prefill_since_dispatch += 1
         self._acc["prefill_positions"] += w
         self._acc["prefill_prompt_tokens"] += new_pos - st.next_pos
+        self._acc["ssm_layer_calls"] += pool.state_layers
+        self._acc["ssm_scan_positions"] += pool.state_layers * w
+        self._acc["ssm_scan_positions_real"] += \
+            pool.state_layers * (new_pos - st.next_pos)
         st.next_pos = new_pos
         with self._lock:
             self._prefill_calls += 1
@@ -1841,6 +1937,7 @@ class GenerationScheduler:
         self._acc["decode_positions_live"] += emit.positions[0]
         self._acc["decode_positions_read"] += emit.positions[1]
         self._acc["cache_write_programs"] += pool.cache_write_programs
+        self._acc["ssm_layer_calls"] += pool.state_layers
         self._pending = (emit, n_active, after_prefill)
         if prev is not None:
             # THE async-readback overlap: step N's host-side emit work

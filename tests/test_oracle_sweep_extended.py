@@ -514,6 +514,7 @@ ELSEWHERE = {
     "MoE": "test_parallel.py",
     "HeldExperts": "test_hybrid_decoder.py",
     "GroupedQueryAttention": "test_hybrid_decoder.py",
+    "Mamba2Mixer": "test_state_space.py",
     # containers & recurrent variants exercised with numerics elsewhere
     "Sequential": "test_optim.py",
     "ConvLSTMPeephole3D": "test_sparse_tree_misc.py",

@@ -512,3 +512,104 @@ def test_cut_pool_decode_step_lowers_to_no_scatter_over_a_cache_leaf():
     assert text.count("stablehlo.dynamic_update_slice") \
         == n_leaves * cfg["serving"]["slots"]
     assert "tpu_custom_call" not in text
+
+
+# ---- the pool that keeps a state beside its rows, at the benchmark's cut ----
+# (benchmark/configs/falcon-h1-34b.json: every layer grouped-query attention
+# over 48 slots of 3,584 positions beside a Mamba-2 mixer whose recurrence
+# keeps 4 MiB of float32 a slot and layer; six layers and the whole
+# vocabulary, 5.26 B parameters in bfloat16.)  Shapes only, as above.
+
+def _state_cut():
+    return {
+        "vocab_size": 261120, "hidden_size": 5120, "num_hidden_layers": 6,
+        "num_attention_heads": 20, "num_key_value_heads": 4, "head_dim": 128,
+        "rope_theta": 1e11, "intermediate_size": 21504, "rms_norm_eps": 1e-5,
+        "mamba_n_heads": 32, "mamba_d_head": 128, "mamba_d_ssm": 4096,
+        "mamba_n_groups": 2, "mamba_d_state": 256, "mamba_d_conv": 4,
+        "mamba_chunk_size": 128,
+        "ssm_multipliers": [0.354, 0.25, 0.177, 0.5, 0.354],
+        "mlp_multipliers": [0.177, 0.0112], "embedding_multiplier": 5.66,
+        "lm_head_multiplier": 0.0078, "key_multiplier": 0.011,
+        "attention_out_multiplier": 0.0375, "ssm_in_multiplier": 0.25,
+        "ssm_out_multiplier": 0.088,
+        "serving": {"slots": 48, "max_len": 3584, "prefill_chunk": 256}}
+
+
+def _lower_state_cut_program(program, sharding):
+    from bigdl_tpu.models import falcon_h1
+    from bigdl_tpu.serving.generation import SlotPool
+    cfg = _state_cut()
+    s = cfg["serving"]
+    slots, chunk = s["slots"], s["prefill_chunk"]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    abstract = jax.eval_shape(lambda: falcon_h1(cfg, s["max_len"]))
+    model = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, jnp.bfloat16), abstract)
+    caches = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+            lambda: abstract.init_cache(slots, jnp.bfloat16,
+                                        ring_margin=chunk)))
+    pool = object.__new__(SlotPool)
+    pool.slots = slots
+    pool.cache_layers = tuple(abstract.cache_layers())
+    pool.expert_layers = abstract.expert_layers()
+    pool.trace_counts = {"decode": 0, "prefill": {}, "scatter": {},
+                         "chunk_prefill": {}, "kv_copy": {}, "kv_extract": {}}
+    pool._build_programs()
+    routing = sds((0,), jnp.int32)
+    if program == "chunk_prefill":
+        lowered = pool._chunk_jit.lower(
+            model, caches, sds((), jnp.int32), sds((chunk,), jnp.int32),
+            sds((), jnp.int32), routing)
+    else:
+        lowered = pool._decode_jit.lower(
+            model, caches, sds((slots,), jnp.int32), sds((slots,), jnp.int32),
+            sds((slots,), jnp.bool_), routing)
+    return lowered, cfg, caches
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk_prefill"])
+def test_state_pool_program_moves_each_state_in_place_on_v5e(
+        v5e, program, monkeypatch):
+    """The decode step and the chunk program of the falcon-h1-34b cut, as
+    a TPU process traces them, compiled for the described v5e.  A layer's
+    pooled state is 201 MB of float32 and a step must read it once and
+    write it once: no ``copy``, ``transpose`` or ``scatter`` of a state
+    (or of a row leaf) anywhere.  The decode step updates each state by
+    one fusion a layer (the ``tpu_custom_call``s are the row writers')
+    and holds no ``while``: should a fusion choice ever split it or copy
+    the state, this is where it shows.  The chunk program writes a
+    slot's state by a ``dynamic-update-slice`` and scans in a loop a
+    layer.  Weights, pool and temporaries fit the chip."""
+    from bigdl_tpu.ops import attention_kernels
+    monkeypatch.setattr(attention_kernels, "_on_tpu", lambda: True)
+    lowered, cfg, caches = _lower_state_cut_program(
+        program, SingleDeviceSharding(v5e.devices[0]))
+    layers = cfg["num_hidden_layers"]
+    state = caches["layers"][0]["ssm"]["ssm"]
+    assert (state.shape, state.dtype) == ((48, 32, 256, 128), jnp.float32)
+    assert caches["layers"][0]["ssm"]["conv"].shape == (48, 3, 5120)
+    assert caches["layers"][0]["self"]["k"].shape == (48, 4, 3584, 128)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    leaf = r"(?:f32\[48,32,256,128\]|bf16\[48,4,(?:3584,128|128,3584)\])"
+    assert not re.findall(
+        r"= %s\S* (?:copy|copy-start|transpose|scatter)\(" % leaf, text)
+    calls = text.count('custom_call_target="tpu_custom_call"')
+    whiles = len(re.findall(r" while\(", text))
+    updates = re.findall(
+        r"= f32\[48,32,256,128\]\S* dynamic-update-slice\(", text)
+    if program == "decode":
+        assert (calls, whiles, len(updates)) == (layers, 0, 0)
+        assert len(re.findall(r"f32\[48,32,256,128\]\S*\) fusion\(",
+                              text)) == layers
+    else:
+        assert (calls, whiles, len(updates)) == (0, layers, layers)
+    mem = compiled.memory_analysis()
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 13.5e9 < held < 15.5 * 2 ** 30 if program != "chunk_prefill" \
+        else 10e9 < held < 15.5 * 2 ** 30, held
