@@ -303,6 +303,17 @@ class HybridDecoder(Module):
             "pad": jnp.zeros((batch, self.max_len), bool),
         }
 
+    def decode_key_block(self, caches) -> Optional[int]:
+        """Places of a full row that the per-row decode step's attention
+        reads at a time, or None where it reads every row whole whatever
+        is live (``GroupedQueryAttention.decode_key_block``; rings are
+        read whole either way): the serving pool counts what its decode
+        program reads of its full rows by this."""
+        blocks = {blk.attn.decode_key_block(layer["self"])
+                  for blk, layer in zip(self.blocks, caches["layers"])
+                  if blk.attn.window is None}
+        return blocks.pop() if len(blocks) == 1 else None
+
     def cache_write_programs(self, caches) -> int:
         """Device programs that write ``caches`` in one per-row decode
         step (:meth:`decode_step` with ``index [B]``): a layer's keys and

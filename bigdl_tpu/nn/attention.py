@@ -502,6 +502,19 @@ class GroupedQueryAttention(Module):
                 "v": jnp.zeros((batch, self.num_kv_heads, length,
                                 self.v_head_dim), dtype)}
 
+    def decode_key_block(self, cache) -> Optional[int]:
+        """Places of a row that the per-row decode step (``index [B]``)
+        over ``cache`` attends at a time, through
+        ``ops.ragged_decode_attention``; None where it attends every
+        place of every row through :func:`grouped_attention`: a window
+        layer (its ring is short, position-mapped, and may carry a sink),
+        rows that do not tile, and every backend but a TPU
+        (``ops.decode_key_block``)."""
+        if self.window is not None or self.has_sink:
+            return None
+        return attention_kernels.decode_key_block(
+            cache["k"].shape, cache["v"].shape, cache["k"].dtype)
+
     def _heads(self, x, layer, n, d):
         # float32 out of the product: rotated, then rounded once
         y = jnp.einsum("bti,oi->bto", x, layer.weight,
@@ -548,6 +561,7 @@ class GroupedQueryAttention(Module):
             k = rotary_half(k, q_pos[:, None, :], self.rope_theta,
                             self.rotary_dim)
         q, k, v = (a.astype(x.dtype) for a in (q, k, v))
+        block = None
         if cache is None:
             kv = {"k": k, "v": v}
             keys, vals, k_pos = k, v, q_pos
@@ -564,6 +578,7 @@ class GroupedQueryAttention(Module):
                 kv = _write_rows(cache, k, v, place)
                 keys, vals = kv["k"], kv["v"]
                 last = index
+                block = self.decode_key_block(kv)
             else:
                 if slot is None and cache["k"].shape[0] != B:
                     raise ValueError("a cache of other rows than x "
@@ -586,9 +601,20 @@ class GroupedQueryAttention(Module):
                         pad, jnp.broadcast_to(
                             jnp.maximum(k_pos, 0),
                             (pad.shape[0], L)), axis=1)
-        ctx = grouped_attention(
-            q, keys, vals, q_pos, k_pos, self.window, pad,
-            self.sink.bias if self.has_sink else None)
+        if block is not None:
+            # the pool's step over full rows: live key blocks only.  The
+            # queries go in float32 (they hold x's precision) so that the
+            # context comes back in float32, as grouped_attention's does
+            lengths = index + 1
+            if active is not None:
+                lengths = jnp.where(active, lengths, 0)
+            ctx = attention_kernels.ragged_decode_attention(
+                q.astype(jnp.float32), keys, vals, lengths, pad,
+                block_k=block, interpret=not attention_kernels._on_tpu())
+        else:
+            ctx = grouped_attention(
+                q, keys, vals, q_pos, k_pos, self.window, pad,
+                self.sink.bias if self.has_sink else None)
         ctx = (ctx * self.value_scale).astype(x.dtype)
         ctx = ctx.transpose(0, 2, 1, 3).reshape(B, T, -1)
         y = jnp.einsum("bti,oi->bto", ctx, self.output_layer.weight,

@@ -977,6 +977,66 @@ def _ragged_decode_kernel(lens_ref, src_ref, lo_ref, hi_ref, q_ref, k_ref,
         o_ref[...] = jnp.zeros_like(o_ref)
 
 
+def _ragged_decode_mxu_kernel(lens_ref, src_ref, lo_ref, hi_ref, q_ref,
+                              k_ref, v_ref, bias_ref, o_ref, m_ref, l_ref,
+                              acc_ref, *, scale: float, block: int,
+                              tiles):
+    """One (slot, key block) program where a key head serves a group of
+    query heads, or a leaf lies width-minor.  ``q_ref [Hkv, G', d]`` holds
+    each key head's queries (``G'``: the group padded to the sublane
+    tile, in the keys' dtype) and a head's scores are the product
+    ``[G', d] x [d, block]`` on the MXU, accumulated in float32; its
+    context likewise ``[G', block] x [block, dv]``.  Each leaf's block is
+    taken as the leaf lies (``tiles``: :func:`cache_kernels.cache_row_tiles`
+    of the keys and of the values): width-minor ``[Hkv, block, width]``
+    (``"sublanes"``) or positions-minor ``[Hkv, width, block]``
+    (``"lanes"``), which only says which axis a product contracts.
+    ``o_ref [Hkv, G', dv]``; the softmax state ``m_ref``, ``l_ref
+    [Hkv, G', 1]`` and ``acc_ref [Hkv, G', dv]`` are float32."""
+    del src_ref, lo_ref, hi_ref          # the index maps read them
+    b, j = pl.program_id(0), pl.program_id(1)
+    length = lens_ref[b]
+    last = j == pl.num_programs(1) - 1
+    k_dims = (((1,), (1 if tiles[0] == "sublanes" else 0,)), ((), ()))
+    v_dims = (((1,), (0 if tiles[1] == "sublanes" else 1,)), ((), ()))
+
+    @pl.when(jnp.logical_and(j == 0, length > 0))
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(j * block < length)
+    def _body():
+        live = j * block + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block), 1) < length
+        bias = bias_ref[...]                                   # [1, block]
+        for h in range(k_ref.shape[0]):
+            s = jax.lax.dot_general(
+                q_ref[h], k_ref[h], k_dims,
+                preferred_element_type=jnp.float32)            # [G', block]
+            s = jnp.where(live, s * scale + bias, _NEG_INF)
+            m_prev = m_ref[h]                                  # [G', 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
+            m_ref[h] = m_new
+            # the weights in the values' precision, as grouped_attention
+            # has them
+            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[h], v_dims,
+                preferred_element_type=jnp.float32)            # [G', dv]
+
+    @pl.when(jnp.logical_and(last, length > 0))
+    def _finish():
+        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+    @pl.when(jnp.logical_and(last, length == 0))
+    def _idle():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
 def ragged_decode_attention(q, k, v, lengths, pad=None, *,
                             scale: Optional[float] = None,
                             block_k: Optional[int] = None,
@@ -997,15 +1057,30 @@ def ragged_decode_attention(q, k, v, lengths, pad=None, *,
     is skipped; a row with nothing live stays on its predecessor's block.
     What is read is each live length rounded up to ``block_k``.
 
-    The kernel is handed K and V with positions minor, ``[B, Hkv, d, T]``:
-    for a head size under the 128 lanes that is how a TPU stores the
-    leaf (``SlotPool``), so the ``swapaxes`` below is a change of name
-    and not of bytes (``tests/test_tpu_compile.py`` holds the compiled
-    decode step to that).  Same mathematics as :func:`xla_attention`
-    with ``incremental_bias``: products and sums in float32, softmax in
-    float32, the weights rounded to the values' precision; only the order
-    of summation differs (a row whose live places are *all* flagged
-    averages those, where the XLA product averages the whole row)."""
+    **Each leaf is handed over as it lies on the chip**
+    (:func:`cache_kernels.cache_row_tiles`): positions-minor,
+    ``[B, Hkv, width, T]``, where the width is under the 128 lanes (the
+    ``swapaxes`` below is then a change of name and not of bytes), and as
+    it reads where the width fills them.  A custom call fixes its
+    operands' layout, so a leaf handed over the other way would be copied
+    whole on every step; ``tests/test_tpu_compile.py`` holds the compiled
+    decode steps to "no copy of a cache leaf".
+
+    **The body follows the operands.**  One query head a key head over
+    leaves that both lie positions-minor (OPT's float32 pool) runs on the
+    vector unit, a head at a time (:func:`_ragged_decode_kernel`: a
+    product with one row has nothing for the MXU to do).  Grouped heads,
+    or a width-minor leaf, take :func:`_ragged_decode_mxu_kernel`: a
+    group's queries against a key block are a product for the MXU, and
+    ``q`` is rounded to the keys' dtype for it (a bfloat16 cache is read
+    by bfloat16 queries, as the XLA product reads it).
+
+    Same mathematics as :func:`xla_attention` with ``incremental_bias``
+    and as ``nn.attention.grouped_attention``: products and sums in
+    float32, softmax in float32, the weights rounded to the values'
+    precision; only the order of summation differs (a row whose live
+    places are *all* flagged averages those, where the XLA product
+    averages the whole row)."""
     b, hq, tq, d = q.shape
     _, hkv, t, dv = v.shape
     if tq != 1 or hq % hkv or k.shape != (b, hkv, t, d):
@@ -1029,8 +1104,10 @@ def _ragged_decode(q, k, v, lengths, pad, *, scale, block, interpret):
     on the same shapes, share one trace and one lowering of the kernel:
     its body is unrolled over the heads, and traced a layer at a time it
     added 9 s to every start of OPT's decode program."""
+    from bigdl_tpu.ops.cache_kernels import cache_row_tiles
     b, hq, _, d = q.shape
     _, hkv, t, dv = v.shape
+    group = hq // hkv
     lengths = lengths.astype(jnp.int32)
     # the blocks a row's steps read: its own, first to last live; a row
     # with nothing live stays where the live row before it ended (or
@@ -1047,53 +1124,89 @@ def _ragged_decode(q, k, v, lengths, pad, *, scale, block, interpret):
     else:
         bias = jnp.where(pad, _NEG_INF, 0.0).astype(jnp.float32)[:, None]
 
-    def kv_map(bi, j, lens, src, lo, hi):
+    def lanes_map(bi, j, lens, src, lo, hi):
         return src[bi], 0, 0, jnp.minimum(jnp.maximum(j, lo[bi]), hi[bi])
 
     def bias_map(bi, j, *refs):
-        row, _, _, blk = kv_map(bi, j, *refs)
+        row, _, _, blk = lanes_map(bi, j, *refs)
         return row, 0, blk
 
-    row_map = lambda bi, j, *refs: (bi, 0, 0)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(b, t // block),
-        in_specs=[pl.BlockSpec((None, d, hq), row_map),
-                  pl.BlockSpec((None, hkv, d, block), kv_map),
-                  pl.BlockSpec((None, hkv, dv, block), kv_map),
-                  pl.BlockSpec((None, 1, block), bias_map)],
-        out_specs=pl.BlockSpec((None, dv, hq), row_map),
-        scratch_shapes=[_scratch((hq, block)), _scratch((hq, 1)),
-                        _scratch((hq, 1)), _scratch((hq, _LANES)),
-                        _scratch((hq, dv, _LANES))],
-    )
-    out = pl.pallas_call(
-        functools.partial(_ragged_decode_kernel, scale=scale, block=block,
-                          group=hq // hkv),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, dv, hq), q.dtype),
+    def sublanes_map(bi, j, *refs):
+        row, _, _, blk = lanes_map(bi, j, *refs)
+        return row, 0, blk, 0
+
+    def leaf(a, how):
+        """A leaf as it lies, and the block of it a step reads."""
+        width = a.shape[3]
+        if how == "lanes":
+            return jnp.swapaxes(a, 2, 3), pl.BlockSpec(
+                (None, hkv, width, block), lanes_map)
+        return a, pl.BlockSpec((None, hkv, block, width), sublanes_map)
+
+    tiles = tuple(cache_row_tiles(a.shape, a.dtype) for a in (k, v))
+    (k, k_spec), (v, v_spec) = leaf(k, tiles[0]), leaf(v, tiles[1])
+    bias_spec = pl.BlockSpec((None, 1, block), bias_map)
+    dtype = q.dtype
+    q = q[:, :, 0, :]
+    vector_unit = group == 1 and tiles == ("lanes", "lanes")
+    if vector_unit:
+        kernel = functools.partial(_ragged_decode_kernel, group=group)
+        # the width on the sublanes: [B, d, Hq] in and [B, dv, Hq] out
+        q = jnp.swapaxes(q, 1, 2).astype(jnp.float32)
+        out = (b, dv, hq)
+        scratch = [(hq, block), (hq, 1), (hq, 1), (hq, _LANES),
+                   (hq, dv, _LANES)]
+    else:
+        kernel = functools.partial(_ragged_decode_mxu_kernel, tiles=tiles)
+        # a key head's queries together, padded to whole sublane tiles
+        sub = 32 // k.dtype.itemsize
+        gp = -(-group // sub) * sub
+        q = jnp.pad(q.astype(k.dtype).reshape(b, hkv, group, d),
+                    ((0, 0), (0, 0), (0, gp - group), (0, 0)))
+        out = (b, hkv, gp, dv)
+        scratch = [(hkv, gp, 1), (hkv, gp, 1), (hkv, gp, dv)]
+
+    def row(bi, j, *refs):
+        return (bi,) + (0,) * (len(out) - 1)
+
+    res = pl.pallas_call(
+        functools.partial(kernel, scale=scale, block=block),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(b, t // block),
+            in_specs=[pl.BlockSpec((None,) + q.shape[1:], row),
+                      k_spec, v_spec, bias_spec],
+            out_specs=pl.BlockSpec((None,) + out[1:], row),
+            scratch_shapes=[_scratch(s) for s in scratch]),
+        out_shape=jax.ShapeDtypeStruct(out, dtype),
         interpret=interpret,
         **_dimsem("parallel", "arbitrary"),
-    )(lengths, src, lo, hi,
-      jnp.swapaxes(q[:, :, 0, :], 1, 2).astype(jnp.float32),
-      jnp.swapaxes(k, 2, 3), jnp.swapaxes(v, 2, 3), bias)
-    return jnp.swapaxes(out, 1, 2)[:, :, None, :]
+    )(lengths, src, lo, hi, q, k, v, bias)
+    if vector_unit:
+        return jnp.swapaxes(res, 1, 2)[:, :, None, :]
+    return res[:, :, :group].reshape(b, hq, 1, dv)
 
 
 def _decode_block(k_shape, v_shape, dtype) -> Optional[int]:
     """Places of a cache row that :func:`ragged_decode_attention` reads
-    at a time: the largest of 256 and 128 that divides the row and whose
-    K and V blocks fit the kernel's share of VMEM twice over (they are
-    double-buffered).  256 and not more: a live length is read rounded up
-    to the block, and a step of the grid costs about a third of a
-    microsecond, live or not.  None where the row does not tile."""
+    at a time, chosen from the shapes: the largest of 512, 256 and 128
+    that divides the row and whose K and V blocks fit the kernel's share
+    of VMEM twice over (they are double-buffered).  A row pays for every
+    step of its grid, live or not (~0.2 us), a live step has a floor
+    under its fetch (~1.3 us), and a live length is read rounded up to
+    the block: OPT's 16 KB a place fill the VMEM share at 256; leaves of
+    a few key heads (2 KB a place) take 512, which one layer alone read
+    a quarter faster than 256 on a v5e.  Not more: a row's first block
+    is fetched in the open (the step before it is too short to hide it),
+    and at 1,024 that cost what the fewer steps saved while the rounding
+    grew by a tenth.  None where the row does not tile."""
     _, hkv, t, d = k_shape
     dv = v_shape[-1]
     size = jnp.dtype(dtype).itemsize
     sub = 32 // size                              # sublanes of a tile
     if d % sub or dv % sub:
         return None
-    for block in (256, 128):
+    for block in (512, 256, 128):
         need = 2 * hkv * (d + dv) * block * size
         if t % block == 0 and need <= _DECODE_VMEM:
             return block

@@ -626,3 +626,50 @@ def test_a_killed_engine_gives_the_device_back():
     kept.submit_async(np.asarray([3, 4, 5], np.int32), 4).result(120)
     kept.shutdown()
     assert kept.pool.caches is not None and kept.pool.model is not None
+
+
+@pytest.mark.parametrize("ragged", [False, True], ids=["whole", "ragged"])
+def test_the_pool_counts_what_the_step_reads_of_its_full_rows(
+        ragged, monkeypatch):
+    """``stats()["decode_positions_read"]`` over ``["decode_positions_live"]``
+    for a pool of window and full layers.  One request alone, 120 prompt
+    tokens and 12 new, in rows of 384: dispatch ``i`` attends ``120 + i``
+    positions.  Where the full layers' step goes through the ragged decode
+    kernel (what a TPU process chooses: forced here, interpreted) the pool
+    counts each active slot's length rounded up to the key block of 128,
+    full rows only (the rings are read whole either way and are not
+    positions of a row); where it reads whole rows, ``slots x max_len``
+    every step.  The tokens are the same either way."""
+    import functools
+    import time
+    from bigdl_tpu.ops import attention_kernels
+    if ragged:
+        monkeypatch.setattr(
+            attention_kernels, "decode_key_block",
+            functools.partial(attention_kernels.decode_key_block,
+                              force="ragged"))
+    max_len, slots, new = 384, 2, 12
+    m = mimo_v2(CFG, max_len).eval_mode()
+    assert m.decode_key_block(m.init_cache(slots)) == (128 if ragged else None)
+    prompt = np.arange(1, 121, dtype=np.int32) % VOCAB + 1
+    engine = GenerationScheduler(m, slots=slots, prefill_chunk=24)
+    try:
+        assert engine.pool.key_block == (128 if ragged else None)
+        row = engine.submit_async(prompt, new).result(timeout=300)
+        deadline = time.time() + 10
+        while time.time() < deadline and engine.pool.n_active():
+            time.sleep(0.01)
+        time.sleep(0.05)
+        st = engine.stats()
+    finally:
+        engine.shutdown()
+    n = st["decode_dispatches"]
+    assert n in (new, new + 1)          # the pipeline is one step deep
+    lengths = [120 + i for i in range(n)]
+    assert st["decode_positions_live"] == sum(lengths)
+    if ragged:
+        assert st["decode_positions_read"] == 128 * 9 + 256 * (n - 9)
+    else:
+        assert st["decode_positions_read"] == n * slots * max_len
+    want = np.asarray(m.generate(jnp.asarray(prompt)[None], new, chunk=24))
+    assert np.array_equal(row, want[0])

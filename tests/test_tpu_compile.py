@@ -147,10 +147,13 @@ def _decode_case(slots, hq, hkv, t, d, dv, dtype):
     return pytest.param(build, id=name)
 
 
-# OPT-1.3B's pool leaf as the benchmark serves it, and the grouped full
-# layer of the mimo-v2.5 cut (64 query heads over 4, values narrower), which
-# no program hands the kernel yet
+# the pool leaves the benchmark serves: OPT-1.3B's (one query head a key
+# head, float32, both leaves positions-minor: the vector-unit body), the
+# falcon-h1-34b cut's (20 query heads over 4, both leaves width-minor) and
+# the mimo-v2.5 cut's full layer (64 over 4, keys of 192 positions-minor,
+# values of 128 width-minor); the last two take the MXU body
 DECODE_CASES = [_decode_case(6, 32, 32, 2048, 64, 64, jnp.float32),
+                _decode_case(48, 20, 4, 3584, 128, 128, jnp.bfloat16),
                 _decode_case(32, 64, 4, 6144, 192, 128, jnp.bfloat16)]
 
 def _row_write_case(slots, heads, t, d, dv, dtype):
@@ -337,7 +340,7 @@ def test_pool_decode_with_the_ragged_kernel_relayouts_no_leaf_on_v5e(
     from bigdl_tpu.ops import attention_kernels
     monkeypatch.setattr(attention_kernels, "_on_tpu", lambda: True)
     pool = _fixture_pool()
-    assert pool.key_block == 256
+    assert pool.key_block == 512            # two heads of 64: 1 KB a place
     text = _lower_pool_program(
         pool, "decode", SingleDeviceSharding(v5e.devices[0])
     ).compile().as_text()
@@ -348,6 +351,39 @@ def test_pool_decode_with_the_ragged_kernel_relayouts_no_leaf_on_v5e(
     assert len(_pool_leaf_ops(text, ["bitcast"])) \
         == 2 * len(pool.caches["layers"])
     assert " while(" not in text
+
+
+def test_opt_pool_leaf_keeps_the_vector_unit_body_and_its_block_on_v5e(v5e):
+    """OPT-1.3B's pool leaf as the benchmark serves it (6 slots, 32 heads
+    of 64, 2,048 places, float32) goes through the call it has had since
+    PR 31, whatever bodies the kernel has gained for other leaves: the
+    queries with their width on the sublanes (``[6, 64, 32]`` in, the same
+    out: the vector-unit body's operands, where the MXU body takes
+    ``[6, 32, 8, 64]``), both leaves positions-minor by a ``bitcast``, a
+    key block of 256 (eight steps a row), and the blocks and scratch of
+    that body and block in VMEM (the MXU body would hold a twentieth of
+    the scratch, a block of 128 half the blocks)."""
+    from bigdl_tpu.ops import attention_kernels
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    fn, args = DECODE_CASES[0].values[0](
+        lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                  sharding=one_chip))
+    k, v = args[1], args[2]
+    assert attention_kernels._decode_block(k.shape, v.shape, k.dtype) == 256
+    text = _compile(fn, args).as_text()
+    call, = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert re.search(r"= bf16\[6,64,32\]\S* custom-call\(", call)
+    assert ("f32[6,64,32]{2,1,0}, f32[6,32,64,2048]{3,2,1,0}, "
+            "f32[6,32,64,2048]{3,2,1,0}, f32[6,1,2048]{2,1,0}") in call
+    assert len(re.findall(r"= f32\[6,32,64,2048\]\S* bitcast\(", text)) == 2
+    assert not re.findall(
+        r"= f32\[6,32,(?:64,2048|2048,64)\]\S* (?:copy|transpose)\(", text)
+    used, = re.findall(r'"used_scoped_memory_configs":\[\{"memory_space":"1",'
+                       r'"offset":"0","size":"(\d+)"\}\]', call)
+    # K and V blocks of 256 places twice over: 8 MiB; the body's scratch
+    # (scores, weights and a lane-wise context a query head): 1.1 MiB
+    assert 9.0e6 < int(used) < 9.8e6, used
 
 
 def test_pool_decode_step_lowers_to_no_scatter_over_the_pool(pool):
@@ -393,6 +429,18 @@ def _cut():
         "num_experts_per_tok": 8, "norm_topk_prob": True,
         "layernorm_epsilon": 1e-5,
         "serving": {"slots": 32, "max_len": 6144, "prefill_chunk": 256}}
+
+
+def _kernel_calls(text):
+    """The program's Pallas calls by kernel: ``(row writers, ragged
+    decode attentions)``, told apart by the ``jit`` each was traced
+    under."""
+    calls = re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="[^"]*jit\((\w+)\)'
+        r'/pallas_call"', text)
+    assert set(calls) <= {"_write_cache_rows", "_ragged_decode"}, calls
+    assert len(calls) == text.count('custom_call_target="tpu_custom_call"')
+    return (calls.count("_write_cache_rows"), calls.count("_ragged_decode"))
 
 
 def _lower_cut_program(program, sharding):
@@ -445,8 +493,10 @@ def test_cut_pool_program_copies_no_leaf_and_expands_no_key_on_v5e(
     inside the chip.  The decode step writes its cache with one kernel
     call a layer (``ops.write_cache_rows``, keys positions-minor as they
     lie: a ``bitcast``) and no ``dynamic-update-slice`` into a leaf or the
-    flags; the chunk program writes windows, as it did.  Which path a process takes it
-    asks ``_on_tpu()``; here the test answers."""
+    flags, and its two full layers attend through the ragged decode
+    kernel, which takes the same leaves the same way; the chunk program
+    writes windows, as it did.  Which path a process takes it asks
+    ``_on_tpu()``; here the test answers."""
     from bigdl_tpu.ops import attention_kernels
     monkeypatch.setattr(attention_kernels, "_on_tpu", lambda: True)
     lowered, cfg, caches = _lower_cut_program(
@@ -465,15 +515,15 @@ def test_cut_pool_program_copies_no_leaf_and_expands_no_key_on_v5e(
         for a, b, c, d in shapes)
     assert not re.findall(
         r"= %s\S* (?:copy|copy-start|transpose|scatter)\(" % leaf, text)
-    writers = text.count('custom_call_target="tpu_custom_call"')
     if program == "decode":
-        assert writers == cfg["num_hidden_layers"]
+        full = cfg["hybrid_layer_pattern"].count(0)
+        assert _kernel_calls(text) == (cfg["num_hidden_layers"], full)
         assert not re.findall(
             r"= (?:%s|pred\[32,%d\])\S* dynamic-update-slice\(" % (
                 leaf, s["max_len"]), text)
         assert " while(" not in text
     else:
-        assert writers == 0
+        assert _kernel_calls(text) == (0, 0)
         assert "dynamic-update-slice" in text
     heads = cfg["num_attention_heads"]
     expanded = r"bf16\[\d+,(?:%d|4,16|8,8),(?:%d|%d),(?:128|192)\]" % (
@@ -580,9 +630,11 @@ def test_state_pool_program_moves_each_state_in_place_on_v5e(
     pooled state is 201 MB of float32 and a step must read it once and
     write it once: no ``copy``, ``transpose`` or ``scatter`` of a state
     (or of a row leaf) anywhere.  The decode step updates each state by
-    one fusion a layer (the ``tpu_custom_call``s are the row writers')
-    and holds no ``while``: should a fusion choice ever split it or copy
-    the state, this is where it shows.  The chunk program writes a
+    one fusion a layer (the ``tpu_custom_call``s are the row writers' and
+    the ragged decode kernel's, one of each a layer, both taking the
+    width-minor leaves as they read) and holds no ``while``: should a
+    fusion choice ever split it or copy the state, this is where it
+    shows.  The chunk program writes a
     slot's state by a ``dynamic-update-slice`` and scans in a loop a
     layer.  Weights, pool and temporaries fit the chip."""
     from bigdl_tpu.ops import attention_kernels
@@ -599,16 +651,16 @@ def test_state_pool_program_moves_each_state_in_place_on_v5e(
     leaf = r"(?:f32\[48,32,256,128\]|bf16\[48,4,(?:3584,128|128,3584)\])"
     assert not re.findall(
         r"= %s\S* (?:copy|copy-start|transpose|scatter)\(" % leaf, text)
-    calls = text.count('custom_call_target="tpu_custom_call"')
+    calls = _kernel_calls(text)
     whiles = len(re.findall(r" while\(", text))
     updates = re.findall(
         r"= f32\[48,32,256,128\]\S* dynamic-update-slice\(", text)
     if program == "decode":
-        assert (calls, whiles, len(updates)) == (layers, 0, 0)
+        assert (calls, whiles, len(updates)) == ((layers, layers), 0, 0)
         assert len(re.findall(r"f32\[48,32,256,128\]\S*\) fusion\(",
                               text)) == layers
     else:
-        assert (calls, whiles, len(updates)) == (0, layers, layers)
+        assert (calls, whiles, len(updates)) == ((0, 0), layers, layers)
     mem = compiled.memory_analysis()
     held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 13.5e9 < held < 15.5 * 2 ** 30 if program != "chunk_prefill" \
