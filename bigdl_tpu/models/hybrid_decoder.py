@@ -1,7 +1,8 @@
 """Decoder-only language model built from a per-layer list of kinds:
 window and full grouped-query attention layers, layers whose attention
-runs in parallel with a state-space mixer, a leading dense gated
-feed-forward layer, and a held share of sigmoid-routed gated experts.
+runs in parallel with a state-space mixer, latent-attention layers, a
+leading dense gated feed-forward layer, and a held share of
+sigmoid-routed gated experts (with or without a shared expert).
 
 The block of ``mimo_v2`` (MiMo-V2.5), pre-norm and sequential:
 ``h = x + Attn_i(RMSNorm(x))``, ``y = h + FFN_i(RMSNorm(h))``.
@@ -24,6 +25,13 @@ are scaled, ``FFN`` the dense gated layer with a multiplier inside the
 gate and one on the result; the embedding and the logits are scaled too
 (the architecture's µP multipliers, constants of its ``config.json``).
 
+The block of ``sarvam_mla`` (sarvam-105b) is sequential like
+``mimo_v2``'s, every layer a **latent** layer
+(:class:`bigdl_tpu.nn.latent_attention.LatentAttention`: one compressed
+row a position shared by all heads, YaRN rotary frequencies), the first
+a dense gated layer and the rest expert layers whose routed sum is
+scaled by ``routed_scaling_factor`` and stands beside a shared expert.
+
 It keeps the repo's conventions (``TransformerLM``): token ids are
 1-based with 0 as padding, and generation emits ``argmax + 1`` (the
 untied head has exactly ``vocab_size`` rows: none is untrained).  It has
@@ -31,11 +39,12 @@ the incremental API the serving slot pool drives — ``init_cache``,
 ``decode_step`` with a position per row, ``prefill_kv``,
 ``prefill_chunk``, ``max_len``, ``_mask_untrained_logit`` — and declares
 each layer's caches (:meth:`cache_layers`): a ``full`` row of ``max_len``
-positions or a ``ring`` of the window, and beside the row of a parallel
-layer a ``state`` (no positions: the mixer's recurrence and the last
-inputs of its convolution).  A model with expert layers also
-returns what they did (``routing``, int32 ``[4]``) from every pass the
-pool runs.
+positions, a ``ring`` of the window or a ``latent`` row (``max_len``
+positions of one head: the rotary key and the compressed row), and
+beside the row of a parallel layer a ``state`` (no positions: the
+mixer's recurrence and the last inputs of its convolution).  A model
+with expert layers also returns what they did (``routing``, int32
+``[4]``) from every pass the pool runs.
 
 The residual stream, the norms, the scores and the router are float32;
 the matrix products take their operands in the weights' dtype.
@@ -50,12 +59,13 @@ import jax.numpy as jnp
 
 from bigdl_tpu.core.module import Module, ModuleList, Parameter
 from bigdl_tpu.nn.attention import GroupedQueryAttention
+from bigdl_tpu.nn.latent_attention import LatentAttention
 from bigdl_tpu.nn.linear import Linear, LookupTable
 from bigdl_tpu.nn.moe import HeldExperts
 from bigdl_tpu.nn.ssm import Mamba2Mixer
 from bigdl_tpu.ops import cache_kernels
 
-__all__ = ["HybridDecoder", "mimo_v2", "falcon_h1"]
+__all__ = ["HybridDecoder", "mimo_v2", "falcon_h1", "sarvam_mla"]
 
 ROUTING = 4     # what an expert layer counts: HeldExperts.forward
 
@@ -126,6 +136,8 @@ class HybridBlock(Module):
                                              ring_margin)}
 
     def cache_kinds(self, max_len: int):
+        if isinstance(self.attn, LatentAttention):
+            return ("latent", max_len)
         return ("ring", self.attn.window) if self.attn.window is not None \
             else ("full", max_len)
 
@@ -217,10 +229,13 @@ class HybridDecoder(Module):
     """``forward(tokens [B, T] int, 1-based; 0 = padding) -> logits
     [B, T, vocab]`` float32 (column ``j`` scores token ``j + 1``).
 
-    ``layer_kinds[i]`` is ``"full"``, ``"window"`` or ``"parallel"`` (a
+    ``layer_kinds[i]`` is ``"full"``, ``"window"``, ``"parallel"`` (a
     full layer beside a state-space mixer built from ``ssm``, the
-    arguments of :class:`Mamba2Mixer`); ``sparse[i]`` says whether layer
-    ``i``'s feed-forward is the expert layer.  ``multipliers`` are
+    arguments of :class:`Mamba2Mixer`) or ``"latent"`` (built from
+    ``latent``, the arguments of :class:`LatentAttention`); ``sparse[i]``
+    says whether layer ``i``'s feed-forward is the expert layer, whose
+    routed sum is scaled by ``expert_scale`` and which has a shared
+    expert of ``shared_size`` where that is not 0.  ``multipliers`` are
     constants by name (absent: 1): ``embedding``, ``lm_head``, ``key``,
     ``mlp_gate``, ``mlp_down``, and a parallel block's four."""
 
@@ -235,7 +250,9 @@ class HybridDecoder(Module):
                  eps: float = 1e-5, max_len: int = 512,
                  normalize_top_k: bool = True,
                  ssm: Optional[Dict[str, Any]] = None,
-                 multipliers: Optional[Dict[str, float]] = None):
+                 multipliers: Optional[Dict[str, float]] = None,
+                 latent: Optional[Dict[str, Any]] = None,
+                 shared_size: int = 0, expert_scale: float = 1.0):
         super().__init__()
         if len(layer_kinds) != len(sparse):
             raise ValueError("one kind and one sparse flag a layer")
@@ -249,19 +266,28 @@ class HybridDecoder(Module):
             self.embedding.weight * hidden_size ** -0.5)
         blocks = []
         for kind, is_sparse in zip(layer_kinds, sparse):
-            if kind not in ("full", "window", "parallel"):
-                raise ValueError(f"layer kind {kind!r}: 'full', 'window' "
-                                 f"or 'parallel'")
+            if kind not in ("full", "window", "parallel", "latent"):
+                raise ValueError(f"layer kind {kind!r}: 'full', 'window', "
+                                 f"'parallel' or 'latent'")
             win = kind == "window"
             attends = "window" if win else "full"   # a parallel layer: full
-            attn = GroupedQueryAttention(
-                hidden_size, num_heads, kv_heads[attends], head_dim,
-                v_head_dim, window=window if win else None,
-                rope_theta=rope_theta[attends], rotary_dim=rotary_dim,
-                sink=win and window_sink, value_scale=value_scale,
-                key_scale=mult.get("key", 1.0))
-            ffn = HeldExperts(hidden_size, expert_size, num_experts, top_k,
-                              held, normalize_top_k) if is_sparse \
+            if kind == "latent":
+                if latent is None:
+                    raise ValueError("a latent layer needs latent=")
+                attn = LatentAttention(hidden_size, num_heads, eps=eps,
+                                       **latent)
+            else:
+                attn = GroupedQueryAttention(
+                    hidden_size, num_heads, kv_heads[attends], head_dim,
+                    v_head_dim, window=window if win else None,
+                    rope_theta=rope_theta[attends], rotary_dim=rotary_dim,
+                    sink=win and window_sink, value_scale=value_scale,
+                    key_scale=mult.get("key", 1.0))
+            ffn = HeldExperts(
+                hidden_size, expert_size, num_experts, top_k, held,
+                normalize_top_k,
+                shared=GatedFFN(hidden_size, shared_size) if shared_size
+                else None, scale=expert_scale) if is_sparse \
                 else GatedFFN(hidden_size, dense_size,
                               mult.get("mlp_gate", 1.0),
                               mult.get("mlp_down", 1.0))
@@ -281,9 +307,11 @@ class HybridDecoder(Module):
 
     def cache_layers(self) -> Tuple[Any, ...]:
         """Each layer's caches.  A layer with one, its keys and values
-        (``"self"``), declares it ``("full", max_len)`` or ``("ring",
-        window)``; a ring is allocated with room for a prefill chunk
-        beside the window (:meth:`init_cache`, ``ring_margin``).  A
+        (``"self"``), declares it ``("full", max_len)``, ``("ring",
+        window)`` or ``("latent", max_len)``; a ring is allocated with
+        room for a prefill chunk beside the window (:meth:`init_cache`,
+        ``ring_margin``); a latent row is a full row of one head whose
+        leaf ``"k"`` is the rotary key and ``"v"`` the compressed row.  A
         parallel layer declares two by name: ``{"self": ("full",
         max_len), "ssm": ("state", None)}``, a state having no
         positions."""
@@ -306,9 +334,10 @@ class HybridDecoder(Module):
     def decode_key_block(self, caches) -> Optional[int]:
         """Places of a full row that the per-row decode step's attention
         reads at a time, or None where it reads every row whole whatever
-        is live (``GroupedQueryAttention.decode_key_block``; rings are
-        read whole either way): the serving pool counts what its decode
-        program reads of its full rows by this."""
+        is live (``GroupedQueryAttention.decode_key_block``, and
+        ``LatentAttention``'s for a latent row; rings are read whole
+        either way): the serving pool counts what its decode program
+        reads of its full rows by this."""
         blocks = {blk.attn.decode_key_block(layer["self"])
                   for blk, layer in zip(self.blocks, caches["layers"])
                   if blk.attn.window is None}
@@ -483,15 +512,18 @@ def mimo_v2(config: Dict[str, Any], max_len: int) -> HybridDecoder:
     if c.get("attention_bias") or c.get("n_shared_experts") \
             or c.get("add_full_attention_sink_bias") \
             or c.get("tie_word_embeddings"):
-        raise ValueError("mimo_v2: attention bias, shared experts, a sink "
-                         "on full layers and a tied head are not built")
+        raise ValueError("mimo_v2: attention bias, a sink on full layers "
+                         "and a tied head are not built, and a mimo_v2 "
+                         "config has no shared experts (HeldExperts has "
+                         "them: sarvam_mla)")
     if c.get("scoring_func", "sigmoid") != "sigmoid" \
             or c.get("topk_method", "noaux_tc") != "noaux_tc" \
             or c.get("n_group", 1) != 1 or c.get("topk_group", 1) != 1 \
             or c.get("routed_scaling_factor") not in (None, 1, 1.0):
         raise ValueError("mimo_v2: sigmoid scores, a noaux_tc selection "
-                         "bias, one group and no scaling factor are what "
-                         "is built")
+                         "bias and one group are what is built, and a "
+                         "mimo_v2 config has no routed_scaling_factor "
+                         "(HeldExperts has one: sarvam_mla)")
     n = c["num_hidden_layers"]
     kinds = ["window" if p else "full"
              for p in list(c["hybrid_layer_pattern"])[:n]]
@@ -578,3 +610,76 @@ def falcon_h1(config: Dict[str, Any], max_len: int) -> HybridDecoder:
             "attention_out": c.get("attention_out_multiplier", 1.0),
             "ssm_in": c.get("ssm_in_multiplier", 1.0),
             "ssm_out": c.get("ssm_out_multiplier", 1.0)})
+
+
+_SARVAM_REFUSED = ("q_lora_rank", "tie_word_embeddings", "attention_bias")
+
+
+def sarvam_mla(config: Dict[str, Any], max_len: int) -> HybridDecoder:
+    """The model from the keys of a public ``sarvam_mla`` ``config.json``
+    (sarvam-105b) plus the chip's share: ``experts_held`` (how many of
+    ``num_experts`` live here, from ``experts_offset``, default 0) and
+    ``vocab_size`` as sliced.  Every layer a latent-attention layer
+    (``kv_lora_rank``, ``qk_nope_head_dim``, ``qk_rope_head_dim``,
+    ``v_head_dim``, YaRN from ``rope_scaling``); the first
+    ``first_k_dense_replace`` (1) a dense gated layer of
+    ``intermediate_size``, the others ``num_experts`` sigmoid-routed
+    experts of ``moe_intermediate_size`` with a selection bias
+    (``moe_router_enable_expert_bias``), ``num_experts_per_tok`` a token,
+    weights normalised over the chosen, the routed sum times
+    ``routed_scaling_factor``, beside ``num_shared_experts`` shared
+    experts as one gated layer.  ``use_qk_norm`` is read as the norm on
+    the compressed row (there is no query latent to norm, and the rotary
+    key is not normed).  What is not built is refused: a low-rank query,
+    a tied head, a bias, expert groups, another ``rope_scaling`` than
+    ``deepseek_yarn``, more or fewer leading dense layers than one, a
+    router without its selection bias."""
+    c = config
+    for key in _SARVAM_REFUSED:
+        if c.get(key):
+            raise ValueError(f"sarvam_mla: {key}={c[key]!r} is not built")
+    for key in ("n_group", "topk_group"):
+        if c.get(key, 1) not in (None, 1):
+            raise ValueError(f"sarvam_mla: {key}={c[key]!r}: one group of "
+                             f"experts is what is built")
+    scaling = c.get("rope_scaling") or {}
+    if scaling and scaling.get("type", scaling.get("rope_type")) \
+            != "deepseek_yarn":
+        raise ValueError(f"sarvam_mla: rope_scaling {scaling!r}: "
+                         f"deepseek_yarn is what is built")
+    if c.get("first_k_dense_replace", 1) != 1:
+        raise ValueError("sarvam_mla: one leading dense layer is what is "
+                         "built (first_k_dense_replace 1)")
+    if not c.get("moe_router_enable_expert_bias", True) \
+            or not c.get("use_qk_norm", True) \
+            or c.get("hidden_act", "silu") != "silu":
+        raise ValueError("sarvam_mla: a router with its selection bias, "
+                         "the norm on the compressed row (use_qk_norm) "
+                         "and silu are what is built")
+    if c.get("q_head_dim", c["qk_nope_head_dim"] + c["qk_rope_head_dim"]) \
+            != c["qk_nope_head_dim"] + c["qk_rope_head_dim"]:
+        raise ValueError("sarvam_mla: q_head_dim is qk_nope_head_dim + "
+                         "qk_rope_head_dim")
+    n = c["num_hidden_layers"]
+    return HybridDecoder(
+        vocab_size=c["vocab_size"], hidden_size=c["hidden_size"],
+        layer_kinds=["latent"] * n, sparse=[i >= 1 for i in range(n)],
+        num_heads=c["num_attention_heads"], head_dim=0, v_head_dim=0,
+        kv_heads={}, rope_theta={}, rotary_dim=0, window=0,
+        window_sink=False, value_scale=1.0,
+        dense_size=c["intermediate_size"],
+        expert_size=c["moe_intermediate_size"],
+        num_experts=c["num_experts"], top_k=c["num_experts_per_tok"],
+        held=(c.get("experts_offset", 0),
+              c.get("experts_held", c["num_experts"])),
+        eps=c.get("rms_norm_eps", 1e-6), max_len=max_len,
+        normalize_top_k=c.get("norm_topk_prob", True),
+        latent=dict(nope_dim=c["qk_nope_head_dim"],
+                    rope_dim=c["qk_rope_head_dim"],
+                    v_head_dim=c["v_head_dim"],
+                    latent_dim=c["kv_lora_rank"],
+                    rope_theta=float(c.get("rope_theta", 10000.0)),
+                    rope_scaling=scaling or None),
+        shared_size=c.get("num_shared_experts", 0)
+        * c["moe_intermediate_size"],
+        expert_scale=float(c.get("routed_scaling_factor") or 1.0))

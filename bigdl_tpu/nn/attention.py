@@ -37,7 +37,7 @@ __all__ = [
     "position_encoding", "padding_bias", "causal_bias",
     "incremental_bias", "chunk_incremental_bias", "shift_right_3d",
     "GroupedQueryAttention", "grouped_attention", "rotary_half",
-    "cache_positions",
+    "rotary_pairs", "yarn_frequencies", "yarn_mscale", "cache_positions",
 ]
 
 
@@ -264,6 +264,52 @@ def rotary_half(x, positions, theta: float, rotary_dim: int):
     return out.astype(x.dtype)
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude correction ``0.1 * mscale * ln(factor) + 1`` (1
+    where nothing is stretched)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_frequencies(rotary_dim: int, theta: float, factor: float,
+                     original_max: int, beta_fast: float = 32.0,
+                     beta_slow: float = 1.0):
+    """The ``rotary_dim / 2`` rotary frequencies of a YaRN-stretched
+    context (``rope_scaling`` of type ``deepseek_yarn``), float32.
+
+    Pair ``j`` turns by ``f_j = theta**(-2j/rotary_dim)`` a position
+    unstretched and by ``f_j / factor`` interpolated; it takes the one
+    below ``lo``, the other above ``hi`` and a linear blend between,
+    ``lo`` and ``hi`` being the pairs that make ``beta_fast`` and
+    ``beta_slow`` turns over ``original_max`` positions (rounded down
+    and up to whole pairs, kept inside the width): fast pairs keep their
+    resolution, slow ones are stretched to the longer context."""
+    half = rotary_dim // 2
+
+    def pair_of(turns):
+        return rotary_dim * math.log(original_max / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    lo = max(math.floor(pair_of(beta_fast)), 0)
+    hi = min(math.ceil(pair_of(beta_slow)), rotary_dim - 1)
+    j = jnp.arange(half, dtype=jnp.float32)
+    plain = jnp.exp(j * (-math.log(theta) / half))
+    ramp = jnp.clip((j - lo) / (hi - lo if hi > lo else 0.001), 0.0, 1.0)
+    return plain / factor * ramp + plain * (1.0 - ramp)
+
+
+def rotary_pairs(x, positions, frequencies, magnitude: float = 1.0):
+    """Rotate ``x [..., T, d]`` by its ``positions [..., T]``, pairing dim
+    ``2j`` with ``2j + 1``: the pair turns by ``position *
+    frequencies[j]`` (``[d / 2]``) and is scaled by ``magnitude`` (YaRN's
+    correction of cos and sin).  Computed in float32."""
+    ang = positions.astype(jnp.float32)[..., None] * frequencies
+    cos, sin = jnp.cos(ang) * magnitude, jnp.sin(ang) * magnitude
+    xf = x.astype(jnp.float32)
+    pairs = xf.reshape(xf.shape[:-1] + (xf.shape[-1] // 2, 2))
+    a, b = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([a * cos - b * sin, b * cos + a * sin], axis=-1)
+    return out.reshape(xf.shape).astype(x.dtype)
+
+
 def cache_positions(length: int, last, ring: bool):
     """The position each of a cache row's ``length`` places holds once
     position ``last`` (a scalar, or ``[B]``) has been written.  A
@@ -283,7 +329,7 @@ def cache_positions(length: int, last, ring: bool):
 
 
 def grouped_attention(q, k, v, q_pos, k_pos, window: Optional[int] = None,
-                      pad=None, sink=None):
+                      pad=None, sink=None, scale: Optional[float] = None):
     """Causal attention of ``q [B, Hq, Tq, d]`` over ``k [B, Hkv, Tk, d]``
     and ``v [B, Hkv, Tk, dv]`` with ``Hq`` a multiple of ``Hkv``: query
     head ``h`` reads key/value head ``h // (Hq // Hkv)``.  The queries
@@ -296,8 +342,9 @@ def grouped_attention(q, k, v, q_pos, k_pos, window: Optional[int] = None,
     a query attends ``0 <= q_pos - k_pos`` and, with ``window``,
     ``q_pos - k_pos < window``.  ``pad [1|B, Tk]`` masks padding keys.
     ``sink [Hq]`` adds ``exp(sink[h])`` to every softmax denominator of
-    head ``h``: a place that takes weight and gives no value.  Scores
-    and softmax are float32, and so is the result ``[B, Hq, Tq, dv]``."""
+    head ``h``: a place that takes weight and gives no value.  The scores
+    are scaled by ``scale`` (default ``d ** -0.5``).  Scores and softmax
+    are float32, and so is the result ``[B, Hq, Tq, dv]``."""
     B, Hq, Tq, d = q.shape
     Hkv, Tk, dv = k.shape[1], k.shape[2], v.shape[3]
     G = Hq // Hkv
@@ -308,7 +355,7 @@ def grouped_attention(q, k, v, q_pos, k_pos, window: Optional[int] = None,
     if pad is not None:
         ok = ok & ~pad[:, None, :]
     bias = jnp.where(ok, 0.0, _NEG_INF).astype(jnp.float32)
-    scale = jnp.float32(1.0 / math.sqrt(d))
+    scale = jnp.float32(1.0 / math.sqrt(d) if scale is None else scale)
     if sink is not None:
         sink = sink.astype(jnp.float32).reshape(Hkv, G)
 

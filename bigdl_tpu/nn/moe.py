@@ -341,6 +341,14 @@ class HeldExperts(Module):
     the absent experts would add is left out, and nothing stands in for
     the exchange that would bring other chips' tokens.
 
+    ``y = scale * sum_chosen w_e E_e(x) + E_shared(x)``: ``scale`` (a
+    constant, ``routed_scaling_factor``) multiplies the routed sum, and
+    ``shared`` (a module ``x -> y``, the gated layer of the shared
+    experts' width, or None) is applied to every valid token whatever the
+    router says.  Every chip of the deployment computes the shared expert
+    alike and it is counted once: it is no pair, and ``counts`` does not
+    see it.
+
     **Two products over the stack, chosen by the call's token count**
     (static under ``jit``).  Up to ``DENSE_TOKENS`` tokens (a slot pool's
     decode step and its prefill chunks) every token goes through every
@@ -369,7 +377,8 @@ class HeldExperts(Module):
 
     def __init__(self, hidden_size: int, expert_size: int, num_experts: int,
                  top_k: int, held: Optional[tuple] = None,
-                 normalize: bool = True):
+                 normalize: bool = True, shared: Optional[Module] = None,
+                 scale: float = 1.0):
         super().__init__()
         first, count = (0, num_experts) if held is None else held
         if not 0 <= first < first + count <= num_experts:
@@ -377,6 +386,10 @@ class HeldExperts(Module):
         self.num_experts, self.top_k = num_experts, top_k
         self.first, self.count = int(first), int(count)
         self.normalize = normalize
+        self.scale = float(scale)
+        self.has_shared = shared is not None
+        if shared is not None:
+            self.shared = shared
 
         def stack(fan_in, fan_out):
             return Parameter(jax.random.normal(
@@ -451,6 +464,11 @@ class HeldExperts(Module):
         product = self._every_stack if T <= self.DENSE_TOKENS \
             else self._grouped
         y, chosen = product(x.astype(self.w_gate.dtype), local, weights, held)
+        if self.scale != 1.0:
+            y = y * self.scale
+        if self.has_shared:
+            with jax.named_scope("moe/shared"):
+                y = y + jnp.where(routed, self.shared.forward(x), 0.0)
         counts = jnp.stack([jnp.int32(1), jnp.sum(routed) * k, jnp.sum(held),
                             chosen]).astype(jnp.int32)
         return y.reshape(lead + (H,)), counts

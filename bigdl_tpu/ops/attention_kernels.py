@@ -978,9 +978,8 @@ def _ragged_decode_kernel(lens_ref, src_ref, lo_ref, hi_ref, q_ref, k_ref,
 
 
 def _ragged_decode_mxu_kernel(lens_ref, src_ref, lo_ref, hi_ref, q_ref,
-                              k_ref, v_ref, bias_ref, o_ref, m_ref, l_ref,
-                              acc_ref, *, scale: float, block: int,
-                              tiles):
+                              *refs, scale: float, block: int, tiles,
+                              value_scores: bool = False):
     """One (slot, key block) program where a key head serves a group of
     query heads, or a leaf lies width-minor.  ``q_ref [Hkv, G', d]`` holds
     each key head's queries (``G'``: the group padded to the sublane
@@ -992,12 +991,23 @@ def _ragged_decode_mxu_kernel(lens_ref, src_ref, lo_ref, hi_ref, q_ref,
     (``"sublanes"``) or positions-minor ``[Hkv, width, block]``
     (``"lanes"``), which only says which axis a product contracts.
     ``o_ref [Hkv, G', dv]``; the softmax state ``m_ref``, ``l_ref
-    [Hkv, G', 1]`` and ``acc_ref [Hkv, G', dv]`` are float32."""
+    [Hkv, G', 1]`` and ``acc_ref [Hkv, G', dv]`` are float32.
+
+    ``value_scores``: **the value block also scores** (a latent row,
+    whose one head serves every query head).  A second operand ``qv_ref
+    [Hkv, G', dv]`` then follows ``q_ref``: the part of each query that
+    reads the values (the query absorbed into the latent space), ``q_ref``
+    being the part that reads the keys (the rotary part); a place's score
+    is the sum of the two products, and the context is the weights
+    against the same value block, which is fetched once."""
     del src_ref, lo_ref, hi_ref          # the index maps read them
+    qv_ref = refs[0] if value_scores else None
+    k_ref, v_ref, bias_ref, o_ref, m_ref, l_ref, acc_ref = refs[value_scores:]
     b, j = pl.program_id(0), pl.program_id(1)
     length = lens_ref[b]
     last = j == pl.num_programs(1) - 1
     k_dims = (((1,), (1 if tiles[0] == "sublanes" else 0,)), ((), ()))
+    s_dims = (((1,), (1 if tiles[1] == "sublanes" else 0,)), ((), ()))
     v_dims = (((1,), (0 if tiles[1] == "sublanes" else 1,)), ((), ()))
 
     @pl.when(jnp.logical_and(j == 0, length > 0))
@@ -1015,6 +1025,10 @@ def _ragged_decode_mxu_kernel(lens_ref, src_ref, lo_ref, hi_ref, q_ref,
             s = jax.lax.dot_general(
                 q_ref[h], k_ref[h], k_dims,
                 preferred_element_type=jnp.float32)            # [G', block]
+            if value_scores:
+                s = s + jax.lax.dot_general(
+                    qv_ref[h], v_ref[h], s_dims,
+                    preferred_element_type=jnp.float32)
             s = jnp.where(live, s * scale + bias, _NEG_INF)
             m_prev = m_ref[h]                                  # [G', 1]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -1097,13 +1111,55 @@ def ragged_decode_attention(q, k, v, lengths, pad=None, *,
                           block=int(block), interpret=bool(interpret))
 
 
+def latent_decode_attention(q_latent, q_rotary, rotary, latent, lengths,
+                            pad=None, *, scale: float,
+                            block_k: Optional[int] = None,
+                            interpret: bool = False):
+    """One query a row over the live places of a **latent row**: a cache
+    row of one head whose value leaf ``latent [B, 1, T, r]`` (the
+    compressed, normed row every head shares) is also most of its key,
+    beside a narrow key leaf ``rotary [B, 1, T, dr]`` (the rotated part,
+    kept apart).  ``q_latent [B, H, 1, r]`` is each head's query absorbed
+    into the latent space and ``q_rotary [B, H, 1, dr]`` its rotary part:
+    ``score = (q_latent . latent + q_rotary . rotary) * scale`` over
+    positions ``< lengths[b]`` that ``pad`` does not flag, and the result
+    ``[B, H, 1, r]`` is ``softmax(score) latent``, in ``q_latent``'s dtype.
+
+    :func:`ragged_decode_attention`'s grid, scalar prefetch and index
+    maps, so only live blocks are fetched and each once: the body
+    (:func:`_ragged_decode_mxu_kernel` with ``value_scores``) scores
+    against the value block it then weighs.  Both leaves are handed over as they lie (the latent
+    width-minor, the rotary part positions-minor).  The weights are
+    rounded to the row's dtype, sums are float32."""
+    b, h, tq, r = q_latent.shape
+    t, dr = rotary.shape[2], rotary.shape[3]
+    if tq != 1 or latent.shape != (b, 1, t, r) \
+            or rotary.shape != (b, 1, t, dr) \
+            or q_rotary.shape != (b, h, 1, dr):
+        raise ValueError(
+            f"latent decode attention takes one query a row over a row of "
+            f"one head: q {q_latent.shape} / {q_rotary.shape}, latent "
+            f"{latent.shape}, rotary {rotary.shape}")
+    block = block_k or _decode_block(rotary.shape, latent.shape,
+                                     latent.dtype)
+    if not block or t % block or block % _LANES:
+        raise ValueError(f"no key block for rows of {t} places "
+                         f"(block_k={block_k})")
+    return _ragged_decode(q_rotary, rotary, latent, lengths, pad, q_latent,
+                          scale=float(scale), block=int(block),
+                          interpret=bool(interpret))
+
+
 @functools.partial(jax.jit, static_argnames=("scale", "block", "interpret"))
-def _ragged_decode(q, k, v, lengths, pad, *, scale, block, interpret):
+def _ragged_decode(q, k, v, lengths, pad, qv=None, *, scale, block,
+                   interpret):
     """:func:`ragged_decode_attention` on checked arguments.  A function
     of its own under ``jit`` so that the layers of a model, which call it
     on the same shapes, share one trace and one lowering of the kernel:
     its body is unrolled over the heads, and traced a layer at a time it
-    added 9 s to every start of OPT's decode program."""
+    added 9 s to every start of OPT's decode program.  ``qv`` (``[B, Hq,
+    1, dv]``, or None) is the part of each query that scores against the
+    value block (:func:`latent_decode_attention`)."""
     from bigdl_tpu.ops.cache_kernels import cache_row_tiles
     b, hq, _, d = q.shape
     _, hkv, t, dv = v.shape
@@ -1148,21 +1204,27 @@ def _ragged_decode(q, k, v, lengths, pad, *, scale, block, interpret):
     bias_spec = pl.BlockSpec((None, 1, block), bias_map)
     dtype = q.dtype
     q = q[:, :, 0, :]
-    vector_unit = group == 1 and tiles == ("lanes", "lanes")
+    vector_unit = qv is None and group == 1 and tiles == ("lanes", "lanes")
     if vector_unit:
         kernel = functools.partial(_ragged_decode_kernel, group=group)
         # the width on the sublanes: [B, d, Hq] in and [B, dv, Hq] out
-        q = jnp.swapaxes(q, 1, 2).astype(jnp.float32)
+        queries = [jnp.swapaxes(q, 1, 2).astype(jnp.float32)]
         out = (b, dv, hq)
         scratch = [(hq, block), (hq, 1), (hq, 1), (hq, _LANES),
                    (hq, dv, _LANES)]
     else:
-        kernel = functools.partial(_ragged_decode_mxu_kernel, tiles=tiles)
+        kernel = functools.partial(_ragged_decode_mxu_kernel, tiles=tiles,
+                                   value_scores=qv is not None)
         # a key head's queries together, padded to whole sublane tiles
         sub = 32 // k.dtype.itemsize
         gp = -(-group // sub) * sub
-        q = jnp.pad(q.astype(k.dtype).reshape(b, hkv, group, d),
-                    ((0, 0), (0, 0), (0, gp - group), (0, 0)))
+
+        def grouped(a):
+            return jnp.pad(
+                a.astype(k.dtype).reshape(b, hkv, group, a.shape[-1]),
+                ((0, 0), (0, 0), (0, gp - group), (0, 0)))
+        queries = [grouped(q)] if qv is None \
+            else [grouped(q), grouped(qv[:, :, 0, :])]
         out = (b, hkv, gp, dv)
         scratch = [(hkv, gp, 1), (hkv, gp, 1), (hkv, gp, dv)]
 
@@ -1174,14 +1236,14 @@ def _ragged_decode(q, k, v, lengths, pad, *, scale, block, interpret):
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(b, t // block),
-            in_specs=[pl.BlockSpec((None,) + q.shape[1:], row),
-                      k_spec, v_spec, bias_spec],
+            in_specs=[pl.BlockSpec((None,) + a.shape[1:], row)
+                      for a in queries] + [k_spec, v_spec, bias_spec],
             out_specs=pl.BlockSpec((None,) + out[1:], row),
             scratch_shapes=[_scratch(s) for s in scratch]),
         out_shape=jax.ShapeDtypeStruct(out, dtype),
         interpret=interpret,
         **_dimsem("parallel", "arbitrary"),
-    )(lengths, src, lo, hi, q, k, v, bias)
+    )(lengths, src, lo, hi, *queries, k, v, bias)
     if vector_unit:
         return jnp.swapaxes(res, 1, 2)[:, :, None, :]
     return res[:, :, :group].reshape(b, hq, 1, dv)

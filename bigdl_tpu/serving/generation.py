@@ -157,7 +157,13 @@ class SlotPool:
     it is attended), position ``p`` at place ``p % ring``, and one spare
     place where idle lanes write.  Keys and values of a layer may differ
     in width.  ``TransformerLM`` is the case "every layer full, one
-    shape".  A layer may keep more than one cache, declared by name
+    shape".  A ``latent`` layer (latent attention) keeps ``max_len``
+    positions of **one** head shared by every query head: leaf ``"v"``
+    the compressed row (value, and most of the key), leaf ``"k"`` the
+    rotary part of the key; it is written and read by position as a
+    ``full`` row is, through the same programs, and only the accounting
+    tells it apart (``cache_nbytes_by_kind``).  A layer may keep more
+    than one cache, declared by name
     (``{"self": ("full", max_len), "ssm": ("state", None)}``): a
     ``state`` has **no positions**, one fixed-size value a slot that
     every token of the slot's sequence rewrites (a state-space mixer's
@@ -228,11 +234,11 @@ class SlotPool:
         self.dtype = jnp.float32 if dtype is None else dtype
         self.prefill_batch = max(1, int(prefill_batch))
         self.max_len = int(model.max_len)
-        # each layer's cache as the model declares it: ("full", max_len)
-        # or ("ring", window).  A ring is allocated with room for a
-        # prefill chunk of ``ring_margin`` positions beside its window:
-        # the chunk is written before it is attended, and must leave its
-        # first query's keys in place.  The default is room for one
+        # each layer's cache as the model declares it: ("full", max_len),
+        # ("latent", max_len) or ("ring", window).  A ring is allocated
+        # with room for a prefill chunk of ``ring_margin`` positions
+        # beside its window: the chunk is written before it is attended,
+        # and must leave its first query's keys in place.  The default is room for one
         # position, what a pool that is never handed a chunk needs (the
         # scheduler passes its ``prefill_chunk``).
         self.cache_layers = tuple(model.cache_layers())
@@ -482,9 +488,9 @@ class SlotPool:
 
     def cache_nbytes_by_kind(self) -> Dict[str, int]:
         """Bytes of the layers' caches by kind (``full`` | ``ring`` |
-        ``state``)."""
+        ``state`` | ``latent``)."""
         import jax
-        out = {"full": 0, "ring": 0, "state": 0}
+        out = {"full": 0, "ring": 0, "state": 0, "latent": 0}
         for decl, layer in zip(self.cache_layers, self.caches["layers"]):
             for name, (kind, _) in _caches_of(decl).items():
                 out[kind] += sum(
@@ -1257,6 +1263,7 @@ class GenerationScheduler:
                 "cache_bytes_full": self._cache_bytes["full"],
                 "cache_bytes_window": self._cache_bytes["ring"],
                 "cache_bytes_state": self._cache_bytes["state"],
+                "cache_bytes_latent": self._cache_bytes["latent"],
             }
         cache = self._prefix_cache
         out["prefix_cache"] = None if cache is None else cache.stats()

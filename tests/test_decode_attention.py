@@ -324,3 +324,124 @@ def test_grouped_query_attention_step_takes_the_kernel_on_full_rows_only(
     else:
         assert calls == [] and layer.decode_key_block(cache) is None
         assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+# ---- a latent row: one head for every query head, the value block scores ----
+# (H, r, dr) at a small size in sarvam-105b's shape class: the latent leaf
+# width-minor (a multiple of the 128 lanes), the rotary key positions-minor
+LATENT = (16, 256, 64)
+LATENT_SCALE = 0.11
+
+
+def _latent_operands(rows, dtype, seed):
+    h, r, dr = LATENT
+    rs = np.random.RandomState(seed)
+    q_latent = jnp.asarray(0.5 * rs.randn(rows, h, 1, r), jnp.float32)
+    q_rotary = jnp.asarray(rs.randn(rows, h, 1, dr), jnp.float32)
+    latent = jnp.asarray(rs.randn(rows, 1, T, r), dtype)
+    rotary = jnp.asarray(rs.randn(rows, 1, T, dr), dtype)
+    from bigdl_tpu.ops.cache_kernels import cache_row_tiles
+    assert (cache_row_tiles(rotary.shape, dtype),
+            cache_row_tiles(latent.shape, dtype)) == ("lanes", "sublanes")
+    return q_latent, q_rotary, rotary, latent
+
+
+def _latent_oracle(q_latent, q_rotary, rotary, latent, lengths, pad):
+    """The masked ``jax.numpy`` product the step takes off a TPU: one key
+    head of ``[latent ; rotary]`` for all the query heads, the latent as
+    the value, through ``grouped_attention``; the queries rounded to the
+    row's dtype, as the kernel rounds them."""
+    from bigdl_tpu.nn.attention import cache_positions, grouped_attention
+    dtype = latent.dtype
+    index = lengths - 1
+    return grouped_attention(
+        jnp.concatenate([q_latent, q_rotary], axis=-1).astype(dtype),
+        jnp.concatenate([latent, rotary], axis=-1), latent, index[:, None],
+        cache_positions(T, index, False), None, pad, scale=LATENT_SCALE)
+
+
+@pytest.mark.parametrize("padded", [False, True], ids=["nopad", "pad"])
+@pytest.mark.parametrize("block", [256, 128])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_latent_body_matches_the_masked_product(dtype, block, padded):
+    """Ragged lengths at every edge of a row's last live block and a row
+    that only rides along: the score is the sum of the product with the
+    value block and the product with the narrow key block, the context the
+    weights against the same value block."""
+    ql, qr, rotary, latent = _latent_operands(len(LENGTHS), dtype,
+                                              seed=block + padded)
+    lengths = jnp.asarray(LENGTHS, jnp.int32)
+    pad = np.zeros((len(LENGTHS), T), bool)
+    if padded:
+        pad[2, :3] = True
+        pad[4, BLOCK - 2:BLOCK + 1] = True
+        pad[5, T - 2] = True
+        pad[3, 300:] = True       # beyond what is live: changes nothing
+    pad = jnp.asarray(pad)
+    out = ak.latent_decode_attention(ql, qr, rotary, latent, lengths, pad,
+                                     scale=LATENT_SCALE, block_k=block,
+                                     interpret=True)
+    assert out.shape == ql.shape and out.dtype == jnp.float32
+    out = np.asarray(out)
+    assert not np.isnan(out).any()
+    assert (out[0] == 0).all()
+    want = np.asarray(_latent_oracle(ql, qr, rotary, latent, lengths, pad))
+    tol = 2e-5 if dtype == jnp.float32 else 6e-3
+    np.testing.assert_allclose(out[1:], want[1:], rtol=tol, atol=tol)
+    # the narrow key block is in the score: without it the result differs
+    blind = np.asarray(ak.latent_decode_attention(
+        ql, jnp.zeros_like(qr), rotary, latent, lengths, pad,
+        scale=LATENT_SCALE, block_k=block, interpret=True))
+    assert np.abs(blind[3:] - out[3:]).max() > 0.05
+
+
+def test_latent_body_in_a_pool_with_idle_rows():
+    ql, qr, rotary, latent = _latent_operands(6, jnp.bfloat16, seed=12)
+    lengths = jnp.asarray([0, 0, 300, 0, 17, 0], jnp.int32)
+    out = np.asarray(ak.latent_decode_attention(
+        ql, qr, rotary, latent, lengths, scale=LATENT_SCALE, interpret=True))
+    want = np.asarray(_latent_oracle(ql, qr, rotary, latent, lengths, None))
+    for row in (0, 1, 3, 5):
+        assert (out[row] == 0).all()
+    np.testing.assert_allclose(out[[2, 4]], want[[2, 4]], rtol=6e-3,
+                               atol=6e-3)
+
+
+def test_latent_call_fetches_each_block_once_and_refuses_other_shapes():
+    """sarvam-105b's pool as the benchmark serves it: the grid and index
+    maps are the ragged kernel's, the block is 512 places, the value leaf
+    is handed over once (as it lies) beside the rotary leaf with its last
+    axes swapped, and the body holds three products: two for the score,
+    one for the context."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+
+    def find(jaxpr, name):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == name:
+                yield eqn
+            for param in eqn.params.values():
+                for sub in param if isinstance(param, tuple) else (param,):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        yield from find(sub, name)
+    args = [jax.ShapeDtypeStruct(*a) for a in (
+        ((112, 64, 1, 512), f32), ((112, 64, 1, 64), f32),
+        ((112, 1, 7168, 64), bf16), ((112, 1, 7168, 512), bf16),
+        ((112,), jnp.int32), ((112, 7168), jnp.bool_))]
+    call, = find(jax.make_jaxpr(
+        lambda *a: ak.latent_decode_attention(*a, scale=0.135))(*args).jaxpr,
+        "pallas_call")
+    assert call.params["grid_mapping"].grid == (112, 14)
+    assert [v.aval.shape for v in call.invars[4:]] == [
+        (112, 1, 64, 64), (112, 1, 64, 512), (112, 1, 64, 7168),
+        (112, 1, 7168, 512), (112, 1, 7168)]
+    assert len(list(find(call.params["jaxpr"], "dot_general"))) == 3
+    ql, qr, rotary, latent = _latent_operands(2, jnp.bfloat16, seed=1)
+    lengths = jnp.asarray([5, 9], jnp.int32)
+    with pytest.raises(ValueError, match="one head"):
+        ak.latent_decode_attention(ql, qr, rotary, jnp.repeat(latent, 2, 1),
+                                   lengths, scale=1.0, interpret=True)
+    with pytest.raises(ValueError, match="no key block"):
+        ak.latent_decode_attention(ql, qr, rotary, latent, lengths,
+                                   scale=1.0, block_k=96, interpret=True)

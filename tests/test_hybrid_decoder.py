@@ -458,7 +458,8 @@ def test_the_pool_allocates_each_layers_cache_as_declared(model):
     by_kind = pool.cache_nbytes_by_kind()
     per_head = 3 * (24 + 16) * 4             # slots, k + v widths, float32
     assert by_kind == {"ring": 3 * (WINDOW + CHUNK) * 4 * per_head,
-                       "full": 2 * MAX_LEN * 2 * per_head, "state": 0}
+                       "full": 2 * MAX_LEN * 2 * per_head, "state": 0,
+                       "latent": 0}
     opt = SlotPool(_tiny_lm(), slots=2)
     assert opt.cache_layers == (("full", 32),) * 2 and not opt.has_ring
     assert opt.cache_nbytes_by_kind()["ring"] == 0

@@ -515,6 +515,7 @@ ELSEWHERE = {
     "HeldExperts": "test_hybrid_decoder.py",
     "GroupedQueryAttention": "test_hybrid_decoder.py",
     "Mamba2Mixer": "test_state_space.py",
+    "LatentAttention": "test_latent_attention.py",
     # containers & recurrent variants exercised with numerics elsewhere
     "Sequential": "test_optim.py",
     "ConvLSTMPeephole3D": "test_sparse_tree_misc.py",

@@ -665,3 +665,120 @@ def test_state_pool_program_moves_each_state_in_place_on_v5e(
     held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 13.5e9 < held < 15.5 * 2 ** 30 if program != "chunk_prefill" \
         else 10e9 < held < 15.5 * 2 ** 30, held
+
+
+# ---- the pool of latent rows, at the benchmark's cut -------------------------
+# (benchmark/configs/sarvam-105b.json: every layer latent attention over 112
+# slots of 7,168 positions, one compressed 512-wide row and one 64-wide
+# rotary key a position for all 64 heads; a dense first layer, then 16 held
+# of 128 experts beside a shared one; six layers and an eighth of the
+# vocabulary, 3.18 B parameters in bfloat16.)  Shapes only, as above.
+
+def _latent_cut():
+    return {
+        "vocab_size": 32768, "hidden_size": 4096, "num_hidden_layers": 6,
+        "num_attention_heads": 64, "qk_nope_head_dim": 128,
+        "qk_rope_head_dim": 64, "q_head_dim": 192, "v_head_dim": 128,
+        "kv_lora_rank": 512, "intermediate_size": 16384,
+        "moe_intermediate_size": 2048, "num_experts": 128, "experts_held": 16,
+        "num_experts_per_tok": 8, "num_shared_experts": 1,
+        "routed_scaling_factor": 2.5, "first_k_dense_replace": 1,
+        "moe_router_enable_expert_bias": True, "use_qk_norm": True,
+        "rms_norm_eps": 1e-6, "rope_theta": 10000,
+        "rope_scaling": {"type": "deepseek_yarn", "factor": 40,
+                         "original_max_position_embeddings": 4096,
+                         "beta_fast": 32, "beta_slow": 1, "mscale": 1,
+                         "mscale_all_dim": 1},
+        "serving": {"slots": 112, "max_len": 7168, "prefill_chunk": 256}}
+
+
+def _lower_latent_cut_program(program, sharding):
+    from bigdl_tpu.models import sarvam_mla
+    from bigdl_tpu.serving.generation import SlotPool
+    cfg = _latent_cut()
+    s = cfg["serving"]
+    slots, chunk = s["slots"], s["prefill_chunk"]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    abstract = jax.eval_shape(lambda: sarvam_mla(cfg, s["max_len"]))
+    model = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, jnp.bfloat16), abstract)
+    caches = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+            lambda: abstract.init_cache(slots, jnp.bfloat16)))
+    pool = object.__new__(SlotPool)
+    pool.slots = slots
+    pool.cache_layers = tuple(abstract.cache_layers())
+    pool.expert_layers = abstract.expert_layers()
+    pool.trace_counts = {"decode": 0, "prefill": {}, "scatter": {},
+                         "chunk_prefill": {}, "kv_copy": {}, "kv_extract": {}}
+    pool._build_programs()
+    routing = sds((4,), jnp.int32)
+    if program == "chunk_prefill":
+        lowered = pool._chunk_jit.lower(
+            model, caches, sds((), jnp.int32), sds((chunk,), jnp.int32),
+            sds((), jnp.int32), routing)
+    else:
+        lowered = pool._decode_jit.lower(
+            model, caches, sds((slots,), jnp.int32), sds((slots,), jnp.int32),
+            sds((slots,), jnp.bool_), routing)
+    return lowered, cfg, caches, abstract
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk_prefill"])
+def test_latent_pool_program_copies_no_latent_leaf_on_v5e(
+        v5e, program, monkeypatch):
+    """The decode step and the chunk program of the sarvam-105b cut, as a
+    TPU process traces them, compiled for the described v5e: no ``copy``,
+    ``transpose`` or ``scatter`` of a latent leaf or of a rotary leaf (as
+    it reads, or positions-minor as the kernels are handed it), and the
+    row never expanded to the 64 heads.  The decode step writes each
+    layer's row in **one** program (``ops.write_cache_rows``) and attends
+    it in one (the ragged decode kernel with the body in which the value
+    block also scores): two kernel calls a layer, no
+    ``dynamic-update-slice`` into a leaf or the flags, no ``while``; the
+    model answers the pool ``cache_write_programs`` = one a layer and the
+    flags' select, and a key block of 512.  The chunk program writes a
+    window and walks the row's live key blocks in one loop a layer.
+    Weights, rows and temporaries fit the chip, and fill 11 GB of it."""
+    from bigdl_tpu.ops import attention_kernels
+    monkeypatch.setattr(attention_kernels, "_on_tpu", lambda: True)
+    lowered, cfg, caches, abstract = _lower_latent_cut_program(
+        program, SingleDeviceSharding(v5e.devices[0]))
+    layers = cfg["num_hidden_layers"]
+    assert [{n: leaf.shape for n, leaf in layer["self"].items()}
+            for layer in caches["layers"]] == [
+        {"k": (112, 1, 7168, 64), "v": (112, 1, 7168, 512)}] * layers
+    assert abstract.cache_layers() == (("latent", 7168),) * layers
+    assert abstract.decode_key_block(caches) == 512
+    assert abstract.cache_write_programs(caches) == layers + 1
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    leaf = r"bf16\[112,1,(?:7168,512|512,7168|7168,64|64,7168)\]"
+    assert not re.findall(
+        r"= %s\S* (?:copy|copy-start|transpose|scatter)\(" % leaf, text)
+    # keys or values at the 64 heads over a whole row
+    assert not re.findall(r"bf16\[\d+,64,7168,(?:128|192|256)\]", text)
+    calls = _kernel_calls(text)
+    whiles = len(re.findall(r" while\(", text))
+    if program == "decode":
+        assert (calls, whiles) == ((layers, layers), 0)
+        assert not re.findall(
+            r"= (?:%s|pred\[112,7168\])\S* dynamic-update-slice\(" % leaf,
+            text)
+    else:
+        assert (calls, whiles) == ((0, 0), layers)
+        assert len(re.findall(r"= %s\S* dynamic-update-slice\(" % leaf,
+                              text)) == 2 * layers
+    # the experts' batched product on the held stacks as they lie
+    stack = r"bf16\[16,(?:4096,2048|2048,4096)\]"
+    assert not re.findall(r"= %s\S* (?:copy|copy-start|transpose)\(" % stack,
+                          text)
+    assert "ragged-dot" not in text
+    mem = compiled.memory_analysis()
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 10.5e9 < held < 15.5 * 2 ** 30, held
+    if program == "decode":
+        assert held > 11e9
