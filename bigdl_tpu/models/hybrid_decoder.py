@@ -150,12 +150,40 @@ class HybridBlock(Module):
         (with None: the compact keys and values); ``valid [B, T]`` false
         keeps a token from the experts; ``counts`` is what the expert
         layer did (zeros for a dense layer)."""
+        h, caches = self._mix(x, index, cache, pad, slot, active, valid)
+        y, counts = self._feed_forward(h, valid)
+        return y, caches, counts
+
+    def forward_step_and_chunk(self, cache, step, chunk):
+        """A pool's decode rows and a prefill chunk of one of its rows in
+        one pass -> ``(y, y_chunk, caches, counts)``.  ``step`` is ``(x
+        [S, 1, H], index [S], pad, active [S], valid [S, 1])`` and
+        ``chunk`` ``(x [1, W, H], index, pad, slot, valid [1, W])``, each
+        as :meth:`forward` takes them.  The chunk's mixer, then the rows'
+        on the caches it left (what :meth:`forward` on the chunk and then
+        on the rows does: ``slot`` itself may be among the rows that
+        decode), then **one** feed-forward over both residual streams
+        laid end to end, which reads the layer's feed-forward weights
+        once and counts as one call of the expert layer."""
+        x, index, pad, active, valid = step
+        xc, chunk_index, chunk_pad, slot, chunk_valid = chunk
+        hc, cache = self._mix(xc, chunk_index, cache, chunk_pad, slot, None,
+                              chunk_valid)
+        h, cache = self._mix(x, index, cache, pad, None, active, valid)
+        rows = x.shape[0]
+        y, counts = self._feed_forward(
+            jnp.concatenate([h.reshape(1, rows, -1), hc], axis=1),
+            jnp.concatenate([valid.reshape(1, rows), chunk_valid], axis=1))
+        return y[0, :rows, None], y[:, rows:], cache, counts
+
+    def _mix(self, x, index, cache, pad, slot, active, valid):
+        """The mixer on the normed input and its residual: ``(h,
+        caches)``."""
         n = self.attn_norm(x).astype(self.attn.q_layer.weight.dtype)
         a, kv = self.attn.forward(
             n, index, None if cache is None else cache["self"], pad, slot,
             active)
-        y, counts = self._feed_forward(x + a, valid)
-        return y, (kv if cache is None else {"self": kv}), counts
+        return x + a, (kv if cache is None else {"self": kv})
 
     def _feed_forward(self, h, valid):
         n = self.ffn_norm(h)
@@ -188,9 +216,8 @@ class ParallelBlock(HybridBlock):
     def cache_kinds(self, max_len: int):
         return {"self": ("full", max_len), "ssm": ("state", None)}
 
-    def forward(self, x, index=0, cache=None, pad=None, slot=None,
-                active=None, valid=None):
-        """As :meth:`HybridBlock.forward`.  The state has no positions:
+    def _mix(self, x, index, cache, pad, slot, active, valid):
+        """As :meth:`HybridBlock._mix`.  The state has no positions:
         a chunk (scalar ``index``) reads row ``slot``'s state, or starts
         from zeros when ``index`` is 0 (whoever held the row before is
         forgotten), and writes back the state after its last valid
@@ -220,9 +247,8 @@ class ParallelBlock(HybridBlock):
                     lambda leaf, row: jax.lax.dynamic_update_slice_in_dim(
                         leaf, row.astype(leaf.dtype), slot, 0),
                     pooled, state)
-        h = x + s * m["ssm_out"] + a * m["attention_out"]
-        y, counts = self._feed_forward(h, valid)
-        return y, {"self": kv, "ssm": state}, counts
+        return x + s * m["ssm_out"] + a * m["attention_out"], \
+            {"self": kv, "ssm": state}
 
 
 class HybridDecoder(Module):
@@ -405,13 +431,9 @@ class HybridDecoder(Module):
             routing = routing + counts
         return layers, pad, routing
 
-    def prefill_chunk(self, toks, index, caches, slot=None):
-        """Write keys, values and padding flags of ``toks [B, W]`` at
-        positions ``index .. index+W`` of a cache filled below ``index``
-        (row ``slot`` of a pool when given, ``B == 1``), attending the
-        cache and itself; returns ``(caches, routing)``.  A ring must
-        have ``W - 1`` places beside its window: the chunk is written
-        before it is attended."""
+    def _chunk_flags(self, toks, index, caches, slot):
+        """The padding flags with a chunk's written (refusing a chunk
+        wider than a ring has room for)."""
         _B, W = toks.shape
         for blk, cache in zip(self.blocks, caches["layers"]):
             win, R = blk.attn.window, cache["self"]["k"].shape[2] - 1
@@ -420,8 +442,35 @@ class HybridDecoder(Module):
                     f"a chunk of {W} positions needs a ring of "
                     f"{win + W - 1} places, the cache has {R} "
                     f"(init_cache(ring_margin={W}))")
-        pad = jax.lax.dynamic_update_slice(
+        return jax.lax.dynamic_update_slice(
             caches["pad"], toks == 0, (0 if slot is None else slot, index))
+
+    def _row_flags(self, tokens, index, pad, active):
+        """A per-row step's ``(index, flags, valid)``: an idle row sent
+        where nothing reads, every row's flag in one select."""
+        flag = tokens == 0
+        if active is not None:
+            # a full row's last position is beyond every prefill
+            # query's mask and rewritten by its occupant's own decode
+            # before it is attended; a ring sends the row to its
+            # spare place (GroupedQueryAttention.forward)
+            index = jnp.where(active, index, jnp.int32(self.max_len - 1))
+        # one select over the flags, not a write a row
+        with jax.named_scope("cache/write"):
+            here = jnp.arange(self.max_len, dtype=jnp.int32)[None, :] \
+                == index[:, None]
+            pad = jnp.where(here, flag, pad)
+        valid = ~flag if active is None else ~flag & active[:, None]
+        return index, pad, valid
+
+    def prefill_chunk(self, toks, index, caches, slot=None):
+        """Write keys, values and padding flags of ``toks [B, W]`` at
+        positions ``index .. index+W`` of a cache filled below ``index``
+        (row ``slot`` of a pool when given, ``B == 1``), attending the
+        cache and itself; returns ``(caches, routing)``.  A ring must
+        have ``W - 1`` places beside its window: the chunk is written
+        before it is attended."""
+        pad = self._chunk_flags(toks, index, caches, slot)
         x = self._embed(toks)
         new_layers, routing = [], jnp.zeros((ROUTING,), jnp.int32)
         for blk, cache in zip(self.blocks, caches["layers"]):
@@ -438,25 +487,14 @@ class HybridDecoder(Module):
         routing)``.  ``active [B]`` false (with ``index [B]``) marks a row
         that only rides along: it writes where nothing reads and the
         experts do not see it."""
-        per_row = jnp.ndim(index) == 1
-        flag = tokens == 0
-        if per_row:
-            if active is not None:
-                # a full row's last position is beyond every prefill
-                # query's mask and rewritten by its occupant's own decode
-                # before it is attended; a ring sends the row to its
-                # spare place (GroupedQueryAttention.forward)
-                index = jnp.where(active, index,
-                                  jnp.int32(self.max_len - 1))
-            # one select over the flags, not a write a row
-            with jax.named_scope("cache/write"):
-                here = jnp.arange(self.max_len, dtype=jnp.int32)[None, :] \
-                    == index[:, None]
-                pad = jnp.where(here, flag, caches["pad"])
+        if jnp.ndim(index) == 1:
+            index, pad, valid = self._row_flags(tokens, index,
+                                                caches["pad"], active)
         else:
+            flag = tokens == 0
             pad = jax.lax.dynamic_update_slice(caches["pad"], flag,
                                                (0, index))
-        valid = ~flag if active is None else ~flag & active[:, None]
+            valid = ~flag if active is None else ~flag & active[:, None]
         x = self._embed(tokens)
         new_layers, routing = [], jnp.zeros((ROUTING,), jnp.int32)
         for blk, cache in zip(self.blocks, caches["layers"]):
@@ -468,6 +506,34 @@ class HybridDecoder(Module):
         if not with_logits:
             return None, new_caches, routing
         return self._logits(x)[:, 0], new_caches, routing
+
+    def decode_step_with_chunk(self, tokens, index, caches, active, toks,
+                               chunk_index, slot):
+        """A pool's pass that carries a prefill chunk, as one walk of the
+        blocks: what ``prefill_chunk(toks, chunk_index, caches, slot)``
+        followed by ``decode_step(tokens, index, ., active=active)``
+        gives, ``(logits [B, vocab], caches, routing)``, with every
+        block's feed-forward run **once** over the ``B`` rows and the
+        chunk's ``W`` tokens together, so that the pass reads each
+        layer's feed-forward weights once
+        (:meth:`HybridBlock.forward_step_and_chunk`).  ``tokens [B, 1]``,
+        ``index [B]``, ``active [B]``; ``toks [1, W]`` into row ``slot``
+        at ``chunk_index``.  Chunk first, then step, layer by layer: row
+        ``slot`` may decode in the same pass (its last chunk), and then
+        attends what the chunk wrote.  An expert layer counts one call."""
+        chunk_pad = self._chunk_flags(toks, chunk_index, caches, slot)
+        index, pad, valid = self._row_flags(tokens, index, chunk_pad, active)
+        chunk_valid = toks != 0
+        x, xc = self._embed(tokens), self._embed(toks)
+        new_layers, routing = [], jnp.zeros((ROUTING,), jnp.int32)
+        for blk, cache in zip(self.blocks, caches["layers"]):
+            x, xc, kv, counts = blk.forward_step_and_chunk(
+                cache, (x, index, pad, active, valid),
+                (xc, chunk_index, chunk_pad, slot, chunk_valid))
+            new_layers.append(kv)
+            routing = routing + counts
+        return self._logits(x)[:, 0], \
+            dict(caches, layers=new_layers, pad=pad), routing
 
     def generate(self, prompt, max_new_tokens: int, eos_id=None,
                  chunk: int = 64):

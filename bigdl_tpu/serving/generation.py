@@ -58,6 +58,16 @@ prompt bucket, the chunk program once per chunk width, and the prefix
 copy/extract programs once per granularity (``trace_counts`` exposes
 the evidence; tests assert it).
 
+Where the model can walk its blocks once for a decode step and a chunk
+together (``decode_step_with_chunk``: ``HybridDecoder``), a chunk due in
+a pass in which slots decode **rides the pass's decode step**: one joint
+program in place of the chunk program followed by the step, in which
+each layer's feed-forward runs once over the decode rows and the chunk's
+rows, so the pass reads those weights once.  The joint program takes the
+lone chunk program's place at a width (the lone one is kept at the full
+width, for an idle pool's long prompt), so the budget is one program
+more.  A model without the entry runs the two programs in turn.
+
 Correctness bar (unchanged from the original engine, property-tested
 over randomized arrival schedules, cache hit or miss): greedy tokens
 per request are BIT-IDENTICAL to a solo ``model.generate()`` call,
@@ -272,6 +282,28 @@ class SlotPool:
             model.cache_write_programs(self.caches)
             if hasattr(model, "cache_write_programs") else
             self.slots * len(jax.tree_util.tree_leaves(self.caches)))
+        # a model that can walk its blocks once for a decode step and a
+        # prefill chunk together (``decode_step_with_chunk``: each layer's
+        # feed-forward runs once over both) gets the joint program: a
+        # chunk due in a pass in which slots decode rides the step
+        # (``decode_dispatch(chunk)``).  Such a pool holds its chunk
+        # programs compiled, by width, all of them from its first chunk
+        # on (``_chunk_programs``); a model without the entry keeps the
+        # two programs, run in turn
+        self.joint = hasattr(model, "decode_step_with_chunk")
+        # the widest chunk the pool is handed: what the rings have room
+        # for (the scheduler's ``prefill_chunk``); and the widths a
+        # prompt's last chunk draws from, the powers of two up to it.  A
+        # pool with the joint program keeps the upper four of them (a
+        # joint program costs a server's start half as much again as the
+        # lone one it replaces: four and the lone one cost less than the
+        # lone ones of every width did): a shorter remainder rides the
+        # narrowest, as it rides the next power of two elsewhere
+        self.chunk_width = min(self.ring_margin, self.max_len)
+        self.chunk_widths = tuple(
+            w for w in bucket_sizes(self.chunk_width)
+            if not self.joint or w >= self.chunk_width // 8)
+        self._chunk_compiled: Dict[int, Tuple] = {}
         self.tok = np.zeros((self.slots,), np.int32)
         self.index = np.zeros((self.slots,), np.int32)
         self.active = np.zeros((self.slots,), bool)
@@ -294,8 +326,9 @@ class SlotPool:
         # traces, so (with jit's cache) they equal compile counts —
         # tests pin decode == 1 and prefill/chunk/copy == one per width
         self.trace_counts: Dict[str, object] = {
-            "decode": 0, "prefill": {}, "scatter": {},
-            "chunk_prefill": {}, "kv_copy": {}, "kv_extract": {}}
+            "decode": 0, "decode_with_chunk": {}, "prefill": {},
+            "scatter": {}, "chunk_prefill": {}, "kv_copy": {},
+            "kv_extract": {}}
         self._build_programs()
 
     # -- compiled programs --------------------------------------------------
@@ -314,22 +347,10 @@ class SlotPool:
 
         experts = self.expert_layers > 0
 
-        def _decode(model, caches, tok, index, active, routing):
-            counts["decode"] += 1
-
-            # ONE batched step over the S slots, each written and masked
-            # at its own position (decode_step's per-row path) — never a
-            # vmap of a batch-1 step: see the class docstring.
-            #
-            # Every lane writes its position's K/V (S is shape-stable),
-            # so the model is told which lanes are idle and sends them
-            # where nothing reads: the last position of a full row, the
-            # spare place of a ring (a ring has no position that a
-            # prompt longer than the window may not just have written).
-            # A model with expert layers returns what they did as a
-            # third value.
-            logits, new_caches, *did = model.decode_step(
-                tok[:, None], index, caches, active=active)
+        def _advance(model, logits, new_caches, did, tok, index, active,
+                     routing):
+            """A step's results from its logits: the caches, the feed
+            advanced, what is read back, and the routing cleared."""
             nxt = jnp.argmax(model._mask_untrained_logit(logits),
                              axis=-1).astype(jnp.int32) + 1
             # the feed advances IN-GRAPH so step N+1 can be dispatched
@@ -348,7 +369,46 @@ class SlotPool:
             return new_caches, new_tok, new_index, emit, \
                 jnp.zeros_like(routing)
 
+        def _decode(model, caches, tok, index, active, routing):
+            counts["decode"] += 1
+
+            # ONE batched step over the S slots, each written and masked
+            # at its own position (decode_step's per-row path) — never a
+            # vmap of a batch-1 step: see the class docstring.
+            #
+            # Every lane writes its position's K/V (S is shape-stable),
+            # so the model is told which lanes are idle and sends them
+            # where nothing reads: the last position of a full row, the
+            # spare place of a ring (a ring has no position that a
+            # prompt longer than the window may not just have written).
+            # A model with expert layers returns what they did as a
+            # third value.
+            logits, new_caches, *did = model.decode_step(
+                tok[:, None], index, caches, active=active)
+            return _advance(model, logits, new_caches, did, tok, index,
+                            active, routing)
+
         self._decode_jit = jax.jit(_decode, donate_argnums=(1, 2, 3))
+
+        def _decode_with_chunk(model, caches, tok, index, active, routing,
+                               slot_id, toks, chunk_index):
+            w = int(toks.shape[0])
+            counts["decode_with_chunk"][w] = \
+                counts["decode_with_chunk"].get(w, 0) + 1
+            # the step above and the chunk program below as ONE walk of
+            # the model (``decode_step_with_chunk``): in every layer the
+            # chunk's mixer, the rows' mixer on the caches it left, and
+            # one feed-forward over both.  A decode step to everything
+            # that counts steps (its name begins as the step's does: the
+            # profile's readers find steps by it); keyed by chunk width
+            logits, new_caches, *did = model.decode_step_with_chunk(
+                tok[:, None], index, caches, active, toks[None],
+                chunk_index, slot_id)
+            return _advance(model, logits, new_caches, did, tok, index,
+                            active, routing)
+
+        self._decode_with_chunk_jit = jax.jit(_decode_with_chunk,
+                                              donate_argnums=(1, 2, 3))
 
         def _prefill(model, ptoks):
             t = int(ptoks.shape[1])
@@ -508,16 +568,26 @@ class SlotPool:
         return jax.tree_util.tree_map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), self.caches)
 
-    def decode_compiled(self):
-        """Compiled pooled decode step at the live pool shapes."""
+    def _feed_avals(self) -> Tuple:
         import jax
         import jax.numpy as jnp
         s = (self.slots,)
+        return (jax.ShapeDtypeStruct(s, jnp.int32),
+                jax.ShapeDtypeStruct(s, jnp.int32),
+                jax.ShapeDtypeStruct(s, jnp.bool_))
+
+    @staticmethod
+    def _chunk_avals(width: int) -> Tuple:
+        """``(slot, toks, index)`` of a chunk of ``width``."""
+        import jax
+        import jax.numpy as jnp
+        scalar = jax.ShapeDtypeStruct((), jnp.int32)
+        return scalar, jax.ShapeDtypeStruct((width,), jnp.int32), scalar
+
+    def decode_compiled(self):
+        """Compiled pooled decode step at the live pool shapes."""
         return self._decode_jit.lower(
-            self.model, self._cache_avals(),
-            jax.ShapeDtypeStruct(s, jnp.int32),
-            jax.ShapeDtypeStruct(s, jnp.int32),
-            jax.ShapeDtypeStruct(s, jnp.bool_),
+            self.model, self._cache_avals(), *self._feed_avals(),
             self._routing_aval()).compile()
 
     def decode_hlo_text(self) -> str:
@@ -526,16 +596,50 @@ class SlotPool:
         verify the cache donation really elides the full copy."""
         return self.decode_compiled().as_text()
 
+    def _chunk_lowered(self, width: int, caches=None):
+        return self._chunk_jit.lower(
+            self.model, caches or self._cache_avals(),
+            *self._chunk_avals(width), self._routing_aval())
+
     def chunk_prefill_compiled(self, width: int):
         """Compiled KV-carry-in chunk-prefill program at ``width`` —
         what the graftlint budget probe lowers."""
-        import jax
-        import jax.numpy as jnp
-        scalar = jax.ShapeDtypeStruct((), jnp.int32)
-        return self._chunk_jit.lower(
-            self.model, self._cache_avals(), scalar,
-            jax.ShapeDtypeStruct((width,), jnp.int32), scalar,
-            self._routing_aval()).compile()
+        return self._chunk_lowered(width).compile()
+
+    def _chunk_programs(self, width: int) -> Tuple:
+        """Of a pool with the joint program: the compiled programs that
+        can carry a chunk of ``width``, ``(joint, alone)``.  The joint
+        program takes the place of the lone one at a width and does not
+        stand beside it (a chunk beside an idle pool rides it with every
+        row idle); the lone program is kept (``alone`` is not None) at
+        the full width only, where an idle server's long prompt would
+        otherwise pay a dead step a chunk.
+
+        **The pool's first chunk compiles the programs of every width**
+        (``chunk_widths``), whichever sends it: a width that only idle
+        pools had seen must not compile its joint program when it first
+        meets decoding slots, inside a measured window.  They are lowered
+        here in turn and compiled (or loaded from the persistent cache)
+        meanwhile on a few threads."""
+        from concurrent.futures import ThreadPoolExecutor
+        held = self._chunk_compiled
+        if width not in held:
+            caches, feed = self._cache_avals(), self._feed_avals()
+            widths = {width} if held else {width, *self.chunk_widths}
+            with ThreadPoolExecutor(max_workers=4) as workers:
+                jobs = {}
+                for w in sorted(widths, reverse=True):
+                    lowered = [self._decode_with_chunk_jit.lower(
+                        self.model, caches, *feed, self._routing_aval(),
+                        *self._chunk_avals(w))]
+                    if w == self.chunk_width:
+                        lowered.append(self._chunk_lowered(w, caches))
+                    jobs[w] = [workers.submit(low.compile)
+                               for low in lowered]
+                for w, (joint, *alone) in jobs.items():
+                    held[w] = (joint.result(),
+                               alone[0].result() if alone else None)
+        return held[width]
 
     def kv_copy_compiled(self, granularity: int):
         """Compiled prefix KV-copy program at ``granularity``."""
@@ -599,6 +703,7 @@ class SlotPool:
         self.caches = None
         self.model = None
         self._dev = None
+        self._chunk_compiled.clear()
         self._routing = None
         self._open_handle = None
 
@@ -650,10 +755,27 @@ class SlotPool:
         ``[index, index+len(toks))`` of ``slot``'s cache row, attending
         to everything already written below ``index``."""
         import jax.numpy as jnp
-        self.caches, self._routing = self._chunk_jit(
-            self.model, self.caches, np.int32(slot),
-            jnp.asarray(np.ascontiguousarray(toks, np.int32)),
-            np.int32(index), self._routing)
+        toks = jnp.asarray(np.ascontiguousarray(toks, np.int32))
+        if not self.joint:
+            self.caches, self._routing = self._chunk_jit(
+                self.model, self.caches, np.int32(slot), toks,
+                np.int32(index), self._routing)
+            return
+        joint, alone = self._chunk_programs(int(toks.shape[0]))
+        if alone is not None:
+            self.caches, self._routing = alone(
+                self.model, self.caches, np.int32(slot), toks,
+                np.int32(index), self._routing)
+            return
+        # the joint program with every row idle: the rows write where
+        # nothing reads and emit nothing, whatever decodes meanwhile; the
+        # routing comes back behind their zeros
+        self.caches, _, _, emit, _ = joint(
+            self.model, self.caches, jnp.zeros((self.slots,), jnp.int32),
+            jnp.zeros((self.slots,), jnp.int32),
+            jnp.zeros((self.slots,), bool), self._routing,
+            np.int32(slot), toks, np.int32(index))
+        self._routing = emit[self.slots:]
 
     def kv_copy_into(self, slot: int,
                      chain: Sequence[PrefixChunk]) -> None:
@@ -682,12 +804,17 @@ class SlotPool:
 
     # -- decode (pipelined dispatch/readback) -------------------------------
 
-    def decode_dispatch(self) -> "_StepHandle":
+    def decode_dispatch(self, chunk: Optional[Tuple] = None) \
+            -> "_StepHandle":
         """Dispatch one pooled decode step and return its handle
         WITHOUT reading it back — the device feed advances in-graph
         (and membership seeds ride the same queue), so the next step
         can be dispatched before this one's host work.  Finalizes the
-        credit epoch of the still-outstanding previous step first."""
+        credit epoch of the still-outstanding previous step first.
+        ``chunk`` (``(toks, slot, index)`` as :meth:`chunk_prefill_into`
+        takes them; a pool with the joint program only) rides the step:
+        one program writes the chunk and then advances the slots, what
+        the chunk program followed by the step would have done."""
         import jax.numpy as jnp
         if self._open_handle is not None \
                 and self._open_handle.mask is None:
@@ -696,8 +823,13 @@ class SlotPool:
             # dispatch resets the epoch
             self._open_handle.mask = self._emit_active & ~self._touched
         if self._dirty or self._dev is None:
-            self._dev = (jnp.asarray(self.tok), jnp.asarray(self.index),
-                         jnp.asarray(self.active))
+            # copies, not views: the CPU backend may hand back an array
+            # that aliases the numpy buffer, the mirrors are written in
+            # place at every join and leave, and a step still in flight
+            # would see a slot become active with a stale feed (a state
+            # layer starts such a row from zeros)
+            self._dev = (jnp.array(self.tok), jnp.array(self.index),
+                         jnp.array(self.active))
             self._dirty = False
         # what this step attends, from the mirrors: an active slot's
         # positions up to its own.  The mirrors stand one step behind the
@@ -710,9 +842,17 @@ class SlotPool:
                      int((-(-lengths // block) * block).sum()) if block
                      else self.slots * self.max_len)
         tok_d, idx_d, act_d = self._dev
-        self.caches, new_tok, new_idx, emit, self._routing = \
-            self._decode_jit(self.model, self.caches, tok_d, idx_d, act_d,
-                             self._routing)
+        if chunk is None:
+            out = self._decode_jit(self.model, self.caches, tok_d, idx_d,
+                                   act_d, self._routing)
+        else:
+            toks, slot, at = chunk
+            out = self._chunk_programs(len(toks))[0](
+                self.model, self.caches, tok_d, idx_d, act_d, self._routing,
+                np.int32(slot),
+                jnp.asarray(np.ascontiguousarray(toks, np.int32)),
+                np.int32(at))
+        self.caches, new_tok, new_idx, emit, self._routing = out
         self._dev = (new_tok, new_idx, act_d)
         self._emit_active = self.active.copy()
         self._touched[:] = False
@@ -803,7 +943,7 @@ _ENGINE_COUNTERS = _ENGINE_PHASES + (
     "gap_seconds_prefill", "prefill_positions", "prefill_prompt_tokens",
     "admitted", "queue_wait_seconds",
     "decode_positions_live", "decode_positions_read",
-    "cache_write_programs",
+    "cache_write_programs", "chunks_joint", "chunks_alone",
     "moe_layer_calls", "moe_pairs_total", "moe_pairs_held",
     "moe_active_experts",
     "ssm_layer_calls", "ssm_scan_positions", "ssm_scan_positions_real",
@@ -864,7 +1004,11 @@ class GenerationScheduler:
     most ``prefill_chunk_budget`` prefill program calls run per engine
     iteration, bounding how long a long prompt can stall the token
     cadence of co-resident streams; with nothing decoding, pending
-    prefill drains at full speed.
+    prefill drains at full speed.  On a pool with the joint program
+    (``SlotPool.joint``) a chunk prepared while slots decode is not
+    dispatched by itself: the pass's decode dispatch carries it
+    (``chunks_joint`` in ``stats()``; ``chunks_alone`` counts the chunk
+    programs that went out by themselves).
 
     ``prefix_cache_bytes`` (None = off) enables the prefix KV cache at
     ``prefix_granularity`` token chunks with an LRU byte budget;
@@ -923,7 +1067,7 @@ class GenerationScheduler:
                 f"{prefill_chunk_budget}")
         self.prefill_chunk = min(int(prefill_chunk), self.pool.max_len)
         self.prefill_chunk_budget = int(prefill_chunk_budget)
-        self._chunk_buckets = bucket_sizes(self.prefill_chunk)
+        self._chunk_buckets = self.pool.chunk_widths
         if prefix_cache is not None:
             self._prefix_cache = prefix_cache
         else:
@@ -960,6 +1104,12 @@ class GenerationScheduler:
         self._follow_work: List[_ActiveSlot] = []
         # (step handle, n_active, prefill programs dispatched before it)
         self._pending: Optional[Tuple] = None
+        # a prefill chunk, ``(toks, slot, index)``, prepared in this pass
+        # for the pass's decode dispatch to carry (a pool with the joint
+        # program, slots decoding).  It never outlives its pass: the next
+        # prefill work item, or a pass that ends without a dispatch,
+        # sends it alone first (``_send_held_chunk``)
+        self._held_chunk: Optional[Tuple] = None
         self._lock = threading.Lock()
         self._outstanding = 0
         self._dedup_leaders = 0
@@ -1240,6 +1390,11 @@ class GenerationScheduler:
                 # device programs that wrote the cache in those steps
                 # (SlotPool.cache_write_programs a step)
                 "cache_write_programs": eng["cache_write_programs"],
+                # chunk programs (not bucketed prefills) that rode a
+                # decode step as one joint program, and that went out
+                # alone (a pool without the joint program: all of them)
+                "chunks_joint": eng["chunks_joint"],
+                "chunks_alone": eng["chunks_alone"],
                 # what the expert layers did, in decode and prefill
                 # programs alike: calls of an expert layer, the
                 # token-to-expert pairs they routed, those that landed
@@ -1362,6 +1517,7 @@ class GenerationScheduler:
             if pool.n_active():
                 self._dispatch_decode()
             else:
+                self._send_held_chunk()     # no dispatch to carry it
                 if self._pending is not None:
                     # the pool emptied with a step in flight: its
                     # read-back overlaps nothing
@@ -1393,6 +1549,7 @@ class GenerationScheduler:
         arrivals (positions are freshly written before read, so a
         poisoned cache cannot leak into a new occupant)."""
         self._pending = None
+        self._held_chunk = None     # its request fails with the others
         self._t_readback = None
         self._prefill_work.clear()
         self._follow_work.clear()   # followers are slot-resident: the
@@ -1714,6 +1871,8 @@ class GenerationScheduler:
         if self._follow_work:
             self._sweep_followers(tel)
         while self._prefill_work and (limit is None or done < limit):
+            # what comes next takes the caches as the chunk leaves them
+            self._send_held_chunk()
             item = self._prefill_work[0]
             if item[0] == "legacy":
                 self._prefill_work.popleft()
@@ -1809,7 +1968,7 @@ class GenerationScheduler:
                 # takes the widest bucket that is all prompt)
                 s = st.next_pos
                 if s + w > pool.max_len:
-                    w = max(b for b in self._chunk_buckets if b <= r)
+                    w = 1 << (r.bit_length() - 1)
             else:
                 s = max(end - w, 0)
             toks = p[s:min(s + w, end)]
@@ -1820,11 +1979,21 @@ class GenerationScheduler:
                 # attended, and padding advances no state
                 toks = np.concatenate(
                     [toks, np.zeros(w - len(toks), np.int32)])
+        # beside decoding slots a pool with the joint program carries the
+        # chunk in the pass's decode step (_dispatch_decode).  With a
+        # prefix cache a prompt's last chunk goes out alone, as every
+        # chunk does elsewhere: its keys are extracted right after it
+        hold = pool.joint and pool.n_active() > 0 and not (
+            self._prefix_cache is not None and s + w >= end)
         try:
             with tracing.span("serving/prefill", chunk=w, index=s,
                               slot=st.slot):
                 t0 = self._mark("prefill_dispatch")
-                pool.chunk_prefill_into(toks, st.slot, s)
+                if hold:
+                    self._held_chunk = (toks, st.slot, s)
+                else:
+                    pool.chunk_prefill_into(toks, st.slot, s)
+                    self._acc["chunks_alone"] += 1
                 t1 = self._mark("other")
         except Exception as e:  # noqa: BLE001 - fail this request only
             self._mark("other")
@@ -1907,6 +2076,17 @@ class GenerationScheduler:
         if prev is not None:
             self._emit_step(prev)
 
+    def _send_held_chunk(self) -> None:
+        """The chunk held for the pass's decode dispatch goes out alone,
+        now: something else is about to take the caches, or no dispatch
+        follows."""
+        chunk, self._held_chunk = self._held_chunk, None
+        if chunk is not None:
+            self._mark("prefill_dispatch")
+            self.pool.chunk_prefill_into(*chunk)
+            self._mark("other")
+            self._acc["chunks_alone"] += 1
+
     def _dispatch_decode(self) -> None:
         pool = self.pool
         prev = self._pending
@@ -1921,8 +2101,10 @@ class GenerationScheduler:
             prev = None
             drained = True
             if pool.n_active() == 0:
+                self._send_held_chunk()
                 return
         n_active = pool.n_active()
+        chunk, self._held_chunk = self._held_chunk, None
         # prefill programs dispatched since the previous decode dispatch
         # run on the device between that step and this one: the flag
         # rides this step's handle to the gap its read-back closes
@@ -1931,7 +2113,7 @@ class GenerationScheduler:
             with tracing.span("serving/decode_dispatch", n_active=n_active,
                               after_prefill=after_prefill, drained=drained):
                 self._mark("decode_dispatch")
-                emit = pool.decode_dispatch()
+                emit = pool.decode_dispatch(chunk)
                 self._mark("other")
         except Exception as e:  # noqa: BLE001 - fail the residents,
             # keep the engine thread alive for later arrivals
@@ -1945,6 +2127,7 @@ class GenerationScheduler:
         self._acc["decode_positions_read"] += emit.positions[1]
         self._acc["cache_write_programs"] += pool.cache_write_programs
         self._acc["ssm_layer_calls"] += pool.state_layers
+        self._acc["chunks_joint"] += chunk is not None
         self._pending = (emit, n_active, after_prefill)
         if prev is not None:
             # THE async-readback overlap: step N's host-side emit work

@@ -15,6 +15,7 @@ import time
 import numpy as np
 import pytest
 
+import joint_pass
 from bigdl_tpu.models import transformer_lm
 from bigdl_tpu.serving import (
     GenerationScheduler, ModelServer, QueueFullError, ServerClosedError,
@@ -330,11 +331,11 @@ def test_engine_survives_decode_failure(lm):
         calls = {"n": 0}
         orig = eng.pool.decode_dispatch
 
-        def boom():
+        def boom(chunk=None):
             calls["n"] += 1
             if calls["n"] == 1:
                 raise RuntimeError("device on fire")
-            return orig()
+            return orig(chunk)
 
         # engine is idle (blocked on the queue) here, so the patch
         # lands before any decode of p1 can start
@@ -594,6 +595,38 @@ def test_step_gap_is_flagged_prefill_when_a_chunk_preceded_its_step(lm):
     assert stats["step_gaps"]["plain"] >= 25
     assert stats["pipeline_drains"] == 1        # the pool emptied at the end
     assert all(v > 0.0 for v in stats["step_gap_seconds"].values())
+
+
+def test_a_pool_without_the_joint_entry_sends_a_chunk_and_a_step(lm):
+    """``TransformerLM`` has no ``decode_step_with_chunk``: a chunk due
+    while a slot decodes goes out as the chunk program, and the decode
+    step follows it, two programs a pass as ever; no joint program is
+    built and the counters say so."""
+    rng = np.random.default_rng(29)
+    eng = GenerationScheduler(lm, slots=2, prefill_chunk=8, start=False)
+    pool = eng.pool
+    assert not pool.joint
+    log = joint_pass.logged_pool_calls(pool)
+    eng.start()
+    a_prompt = rng.integers(1, 51, 4).astype(np.int32)
+    b_prompt = rng.integers(1, 51, 20).astype(np.int32)
+    try:
+        a, b = joint_pass.serve_beside_a_decoding_slot(
+            eng, a_prompt, [b_prompt], timeout=120)
+        eng.shutdown()
+        stats = eng.stats()
+    finally:
+        eng.shutdown()
+    np.testing.assert_array_equal(a, solo(lm, a_prompt, 30))
+    np.testing.assert_array_equal(b, solo(lm, b_prompt, 6))
+    # B's 19 positions: three chunks, each followed by the pass's step
+    assert log.count("alone") == 3 and "step+chunk" not in log
+    at = [i for i, entry in enumerate(log) if entry == "alone"]
+    assert all(log[i + 1] == "step" for i in at)
+    assert (stats["chunks_joint"], stats["chunks_alone"]) == (0, 3)
+    assert pool.trace_counts["decode_with_chunk"] == {}
+    assert pool.trace_counts["chunk_prefill"] == {8: 1, 4: 1}
+    assert stats["step_gaps"]["prefill"] == 3
 
 
 def test_prefill_counters_cover_every_prompt_once(lm):

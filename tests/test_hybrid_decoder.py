@@ -20,6 +20,7 @@ BENCH = os.path.join(os.path.dirname(os.path.dirname(
 if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
+import joint_pass                                             # noqa: E402
 from reference import hybrid_moe_lm as ref                   # noqa: E402
 
 from bigdl_tpu.models import mimo_v2, transformer_lm          # noqa: E402
@@ -144,6 +145,18 @@ def test_chunked_prefill_then_decode_equals_the_reference(
         index = jnp.full((2,), t, jnp.int32) if per_row else jnp.int32(t)
         logits, caches, _ = m.decode_step(tokens[:, t:t + 1], index, caches)
         assert close(logits, ref_logits[:, t]), t
+
+
+@pytest.mark.parametrize("scenario", joint_pass.SCENARIOS)
+def test_the_joint_pass_equals_the_chunk_program_then_the_step(
+        model, scenario):
+    """``decode_step_with_chunk`` (one walk of the blocks, each layer's
+    feed-forward once over the decode rows and the chunk's) against
+    ``prefill_chunk`` followed by ``decode_step`` on the same caches:
+    ``joint_pass.py`` has the four passes and the comparison."""
+    m, _ = model
+    joint_pass.assert_joint_pass_equals_chunk_then_step(
+        m, CHUNK, VOCAB, scenario)
 
 
 def _pool_prefill(pool, prompt, slot):
@@ -596,6 +609,111 @@ def test_the_engine_end_to_end_on_mixed_lengths(model):
     assert last["cache_bytes_window"] + last["cache_bytes_full"] \
         == sum(engine.pool.cache_nbytes_by_kind().values())
     assert last["cache_bytes_window"] > 0
+
+
+def test_a_chunk_beside_decoding_slots_rides_their_step(model):
+    """Mixed arrivals through the engine: B's and C's prompts arrive while
+    A decodes, so every one of their chunks is carried by a decode step
+    (one joint program a pass, never the chunk program by itself); the
+    rows are ``generate()``'s, the counters say what went where, and the
+    gap after a joint pass is a ``prefill`` gap."""
+    m, _ = model
+    rng = np.random.default_rng(5)
+    a, b, c = (rng.integers(1, VOCAB + 1, n).astype(np.int32)
+               for n in (3, 21, 14))
+    engine = GenerationScheduler(m, slots=3, prefill_chunk=CHUNK,
+                                 start=False)
+    assert engine.pool.joint
+    log = joint_pass.logged_pool_calls(engine.pool)
+    engine.start()
+    try:
+        rows = joint_pass.serve_beside_a_decoding_slot(engine, a, [b, c],
+                                                       new_first=40)
+        engine.shutdown()
+        stats = engine.stats()
+    finally:
+        engine.shutdown()
+    for p, row, new in zip((a, b, c), rows, (40, 6, 6)):
+        want = np.asarray(m.generate(jnp.asarray(p)[None], new, chunk=CHUNK))
+        assert np.array_equal(row, want[0]), len(p)
+    # B: 20 positions, five chunks of 4; C: 13, three and one of 1
+    assert "alone" not in log and log.count("step+chunk") == 9
+    assert (stats["chunks_joint"], stats["chunks_alone"]) == (9, 0)
+    assert stats["prefill_calls"] == 9 + 1      # and A's bucketed prefill
+    assert stats["step_gaps"]["prefill"] == 9
+    assert stats["step_gaps"]["plain"] == stats["decode_steps"] - 1 - 9
+    counts = engine.pool.trace_counts
+    # the pool's first chunk compiled every chunk program, the lone one
+    # at the full width among them, though nothing ran it
+    assert counts["decode_with_chunk"] == {1: 1, 2: 1, CHUNK: 1}
+    assert counts["chunk_prefill"] == {CHUNK: 1}
+    assert counts["decode"] == 1
+
+
+def test_chunks_beside_an_idle_pool_leave_nothing_to_compile_beside_a_busy_one(
+        model):
+    """The benchmark's warm-up sends every chunk width beside an idle
+    pool; a chunk that then meets decoding slots inside the window must
+    trace nothing.  Beside an idle pool the full width goes through the
+    lone chunk program and a narrower one through the joint program with
+    every row idle; both count as sent alone."""
+    import copy
+    m, _ = model
+    rng = np.random.default_rng(6)
+    engine = GenerationScheduler(m, slots=3, prefill_chunk=CHUNK)
+    try:
+        # one request at a time: widths 4; 4, 1; 4, 2; 4, 4 (3 padded up)
+        for n in (CHUNK + 1, CHUNK + 2, CHUNK + 3, CHUNK + 4):
+            p = rng.integers(1, VOCAB + 1, n).astype(np.int32)
+            row = engine.submit_async(p, 2).result(timeout=300)
+            want = np.asarray(m.generate(jnp.asarray(p)[None], 2,
+                                         chunk=CHUNK))
+            assert np.array_equal(row, want[0]), n
+        warm = engine.stats()
+        traced = copy.deepcopy(engine.pool.trace_counts)
+        assert (warm["chunks_joint"], warm["chunks_alone"]) == (0, 7)
+        assert traced["decode_with_chunk"] == {1: 1, 2: 1, CHUNK: 1}
+        assert traced["chunk_prefill"] == {CHUNK: 1}
+        a, b, c = (rng.integers(1, VOCAB + 1, n).astype(np.int32)
+                   for n in (CHUNK + 1, CHUNK + 3, 2 * CHUNK + 2))
+        rows = joint_pass.serve_beside_a_decoding_slot(engine, a, [b, c])
+        engine.shutdown()
+        stats = engine.stats()
+    finally:
+        engine.shutdown()
+    for p, row, new in zip((a, b, c), rows, (30, 6, 6)):
+        want = np.asarray(m.generate(jnp.asarray(p)[None], new, chunk=CHUNK))
+        assert np.array_equal(row, want[0]), len(p)
+    assert engine.pool.trace_counts == traced
+    # A's one chunk met an idle pool; B's two and C's three rode a step
+    assert stats["chunks_joint"] - warm["chunks_joint"] == 5
+    assert stats["chunks_alone"] - warm["chunks_alone"] == 1
+
+
+def test_a_joint_pool_keeps_the_upper_four_chunk_widths(model):
+    """A joint program costs a start more than the lone program it
+    replaces, so a pool with the joint program keeps four widths (the
+    full one down to its eighth) and a shorter remainder rides the
+    narrowest, moved back over the prompt; a pool without it keeps every
+    power of two.  The rows are still ``generate()``'s."""
+    m, _ = model
+    assert SlotPool(m, slots=2, ring_margin=32).chunk_widths == (4, 8, 16, 32)
+    assert SlotPool(_tiny_lm().eval_mode(), slots=2,
+                    ring_margin=32).chunk_widths == (1, 2, 4, 8, 16, 32)
+    engine = GenerationScheduler(m, slots=2, prefill_chunk=16)
+    rng = np.random.default_rng(8)
+    # 16 + 1, 16 + 3 and 16 + 6 positions: remainders under and over 2
+    prompts = [rng.integers(1, VOCAB + 1, n).astype(np.int32)
+               for n in (18, 20, 23)]
+    try:
+        rows = [engine.submit_async(p, 5).result(timeout=300)
+                for p in prompts]
+    finally:
+        engine.shutdown()
+    for p, row in zip(prompts, rows):
+        want = np.asarray(m.generate(jnp.asarray(p)[None], 5, chunk=16))
+        assert np.array_equal(row, want[0]), len(p)
+    assert set(engine.pool.trace_counts["decode_with_chunk"]) == {2, 4, 8, 16}
 
 
 def test_a_dense_models_engine_counts_no_expert_layers():

@@ -26,6 +26,7 @@ BENCH = os.path.join(os.path.dirname(os.path.dirname(
 if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
+import joint_pass                                             # noqa: E402
 from reference import latent_moe_lm as ref                    # noqa: E402
 
 from bigdl_tpu.models import mimo_v2, sarvam_mla              # noqa: E402
@@ -192,6 +193,18 @@ def test_a_chunk_attends_key_blocks_past_the_first(monkeypatch):
         logits, caches, _ = m.decode_step(toks[:, t:t + 1], jnp.int32(t),
                                           caches)
         assert close(logits, want[:, t]), t
+
+
+@pytest.mark.parametrize("scenario", joint_pass.SCENARIOS)
+def test_the_joint_pass_equals_the_chunk_program_then_the_step(
+        model, scenario):
+    """``decode_step_with_chunk`` (one walk of the blocks, each layer's
+    feed-forward once over the decode rows and the chunk's) against
+    ``prefill_chunk`` followed by ``decode_step`` on the same caches:
+    ``joint_pass.py`` has the four passes and the comparison."""
+    m, _ = model
+    joint_pass.assert_joint_pass_equals_chunk_then_step(
+        m, CHUNK, VOCAB, scenario)
 
 
 def _pool_prefill(pool, prompt, slot):
@@ -498,6 +511,39 @@ def test_the_engine_end_to_end_on_mixed_lengths(model):
     assert stats["moe_layer_calls"] % 2 == 0 and stats["moe_pairs_held"] > 0
     assert stats["cache_write_programs"] \
         == stats["decode_dispatches"] * (1 + LAYERS * 2 * 3)
+
+
+def test_with_a_prefix_cache_a_prompts_last_chunk_goes_out_alone(model):
+    """Latent rows may be cached by prefix.  B's chunks ride A's decode
+    steps, but its last goes out alone, before its rows are extracted for
+    the cache; C, the same prompt again, then copies them and prefills
+    nothing.  All three rows are ``generate()``'s."""
+    m, _ = model
+    rng = np.random.default_rng(9)
+    a = rng.integers(1, VOCAB + 1, 3).astype(np.int32)
+    b = rng.integers(1, VOCAB + 1, 3 * CHUNK + 1).astype(np.int32)
+    engine = GenerationScheduler(m, slots=3, prefill_chunk=CHUNK,
+                                 prefix_cache_bytes=1 << 20,
+                                 prefix_granularity=CHUNK, start=False)
+    log = joint_pass.logged_pool_calls(engine.pool)
+    engine.start()
+    try:
+        rows = joint_pass.serve_beside_a_decoding_slot(engine, a, [b],
+                                                       new_first=40)
+        rows.append(engine.submit_async(b, 6).result(timeout=300))
+        engine.shutdown()
+        stats = engine.stats()
+    finally:
+        engine.shutdown()
+    for p, row, new in zip((a, b, b), rows, (40, 6, 6)):
+        want = np.asarray(m.generate(jnp.asarray(p)[None], new, chunk=CHUNK))
+        assert np.array_equal(row, want[0]), len(p)
+    # B: 12 positions, three chunks, the last alone; C: three copies
+    assert [e for e in log if e != "step"][:3] == ["step+chunk"] * 2 \
+        + ["alone"]
+    assert (stats["chunks_joint"], stats["chunks_alone"]) == (2, 1)
+    assert stats["prefix_chunks_copied"] == 3
+    assert engine.pool.trace_counts["kv_extract"] == {CHUNK: 1}
 
 
 def test_the_pool_counts_what_the_kernels_step_reads_and_writes(monkeypatch):

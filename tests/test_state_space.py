@@ -26,6 +26,7 @@ BENCH = os.path.join(os.path.dirname(os.path.dirname(
 if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
+import joint_pass                                             # noqa: E402
 from reference import hybrid_ssm_lm as ref                    # noqa: E402
 
 from bigdl_tpu.models import falcon_h1, transformer_lm        # noqa: E402
@@ -305,6 +306,18 @@ def test_state_step_equals_a_scan_of_one_position(heads, groups):
     np.testing.assert_array_equal(np.asarray(got_s[1]), np.asarray(state[1]))
 
 
+@pytest.mark.parametrize("scenario", joint_pass.SCENARIOS)
+def test_the_joint_pass_equals_the_chunk_program_then_the_step(
+        model, scenario):
+    """``decode_step_with_chunk`` (one walk of the blocks, each layer's
+    feed-forward once over the decode rows and the chunk's) against
+    ``prefill_chunk`` followed by ``decode_step`` on the same caches:
+    ``joint_pass.py`` has the four passes and the comparison."""
+    m, _ = model
+    joint_pass.assert_joint_pass_equals_chunk_then_step(
+        m, CHUNK, VOCAB, scenario)
+
+
 # ---- the slot pool ------------------------------------------------------------
 
 def _pool_prefill(pool, prompt, slot, chunks_only=False):
@@ -523,6 +536,64 @@ def test_engine_serves_mixed_lengths_greedily(model, tokens):
     assert stats["cache_bytes_state"] \
         == engine.pool.cache_nbytes_by_kind()["state"] > 0
     assert stats["cache_bytes_window"] == 0
+
+
+def test_a_padded_last_chunk_rides_the_step_of_its_own_slots_first_token(
+        model, tokens):
+    """B's prompt of three chunks and a half arrives while A decodes: its
+    four chunks ride decode steps, the last padded at its end, and in
+    that pass B's slot decodes its first token from the state the chunk
+    left; C's prompt, a chunk and one, follows through the slot A or B
+    leaves.  The rows are ``generate()``'s."""
+    m, _ = model
+    row = np.asarray(tokens[1])
+    a, b, c = row[:5], row[:3 * CHUNK + CHUNK // 2], row[:CHUNK + 1]
+    engine = GenerationScheduler(m, slots=2, prefill_chunk=CHUNK,
+                                 prefill_batch=1, start=False)
+    log = joint_pass.logged_pool_calls(engine.pool)
+    engine.start()
+    try:
+        rows = joint_pass.serve_beside_a_decoding_slot(
+            engine, a, [b, c], new_first=12, new_later=8)
+        engine.shutdown()
+        stats = engine.stats()
+    finally:
+        engine.shutdown()
+    for p, got, new in zip((a, b, c), rows, (12, 8, 8)):
+        want = np.asarray(m.generate(jnp.asarray(p)[None], new, chunk=CHUNK))
+        np.testing.assert_array_equal(got, want[0])
+    # B's 27 positions: 8, 8, 8 and 4 (3 real); C's 8: one chunk; A's 4
+    # through the bucket of 8
+    assert stats["chunks_joint"] + stats["chunks_alone"] == 5
+    assert stats["chunks_joint"] == log.count("step+chunk") >= 4
+    assert stats["ssm_scan_positions"] == LAYERS * (28 + 8 + 7)
+    assert stats["ssm_scan_positions_real"] == LAYERS * (27 + 8 + 4)
+    assert stats["ssm_layer_calls"] == LAYERS * (
+        stats["decode_dispatches"] + stats["prefill_calls"])
+
+
+def test_a_short_remainder_rides_the_narrowest_width_padded(model, tokens):
+    """A pool with the joint program keeps four chunk widths: with chunks
+    of 16, a remainder of one or three positions rides the width of 2 or
+    4, padded at its end, and the state holds every real position once
+    (the rows are ``generate()``'s); the scans' padding is counted."""
+    m, _ = model
+    engine = GenerationScheduler(m, slots=2, prefill_chunk=16,
+                                 prefill_batch=1)
+    assert engine.pool.chunk_widths == (2, 4, 8, 16)
+    row = np.asarray(tokens[0])
+    try:
+        rows = [engine.submit_async(row[:n], 5).result(timeout=300)
+                for n in (18, 20)]
+        stats = engine.stats()
+    finally:
+        engine.shutdown()
+    for n, got in zip((18, 20), rows):
+        want = np.asarray(m.generate(tokens[:1, :n], 5, chunk=16))
+        np.testing.assert_array_equal(got, want[0])
+    # 17 positions: 16 + 2 (1 real); 19: 16 + 4 (3 real)
+    assert stats["ssm_scan_positions"] == LAYERS * (18 + 20)
+    assert stats["ssm_scan_positions_real"] == LAYERS * (17 + 19)
 
 
 def test_a_model_without_state_counts_none():

@@ -409,6 +409,27 @@ def test_pool_decode_step_lowers_to_no_scatter_over_the_pool(pool):
 # Nothing is allocated: the model and the caches are shapes, and the pool's
 # programs are built around them.
 
+POOL_MODEL_PROGRAMS = ["decode", "chunk_prefill", "decode_with_chunk"]
+TRACE_COUNTS = {"decode": 0, "prefill": {}, "scatter": {},
+                "chunk_prefill": {}, "kv_copy": {}, "kv_extract": {}}
+
+
+def _lower(pool, program, model, caches, routing, sds, slots, chunk):
+    """One of a pool's three programs that run the model, lowered on
+    shapes: the decode step, the chunk program, or the joint program (a
+    decode step that carries a chunk of the full width)."""
+    feed = (sds((slots,), jnp.int32), sds((slots,), jnp.int32),
+            sds((slots,), jnp.bool_))
+    a_chunk = (sds((), jnp.int32), sds((chunk,), jnp.int32),
+               sds((), jnp.int32))
+    if program == "decode":
+        return pool._decode_jit.lower(model, caches, *feed, routing)
+    if program == "chunk_prefill":
+        return pool._chunk_jit.lower(model, caches, *a_chunk, routing)
+    return pool._decode_with_chunk_jit.lower(model, caches, *feed, routing,
+                                             *a_chunk)
+
+
 def _cut():
     """MiMo-V2.5's published widths, cut to one chip's share of a 16-chip
     layer group as the benchmark's cell serves it (layers 0-10 of 48, 16 of
@@ -465,22 +486,14 @@ def _lower_cut_program(program, sharding):
     pool.slots = slots
     pool.cache_layers = tuple(abstract.cache_layers())
     pool.expert_layers = abstract.expert_layers()
-    pool.trace_counts = {"decode": 0, "prefill": {}, "scatter": {},
-                         "chunk_prefill": {}, "kv_copy": {}, "kv_extract": {}}
+    pool.trace_counts = dict(TRACE_COUNTS, decode_with_chunk={})
     pool._build_programs()
     routing = sds((4,), jnp.int32)
-    if program == "decode":
-        lowered = pool._decode_jit.lower(
-            model, caches, sds((slots,), jnp.int32), sds((slots,), jnp.int32),
-            sds((slots,), jnp.bool_), routing)
-    else:
-        lowered = pool._chunk_jit.lower(
-            model, caches, sds((), jnp.int32), sds((chunk,), jnp.int32),
-            sds((), jnp.int32), routing)
-    return lowered, cfg, caches
+    return _lower(pool, program, model, caches, routing, sds, slots,
+                  chunk), cfg, caches
 
 
-@pytest.mark.parametrize("program", ["decode", "chunk_prefill"])
+@pytest.mark.parametrize("program", POOL_MODEL_PROGRAMS)
 def test_cut_pool_program_copies_no_leaf_and_expands_no_key_on_v5e(
         v5e, program, monkeypatch):
     """The decode step and the chunk program of the 5.42 B cut, as a TPU
@@ -515,16 +528,23 @@ def test_cut_pool_program_copies_no_leaf_and_expands_no_key_on_v5e(
         for a, b, c, d in shapes)
     assert not re.findall(
         r"= %s\S* (?:copy|copy-start|transpose|scatter)\(" % leaf, text)
+    full = cfg["hybrid_layer_pattern"].count(0)
+    updates = re.findall(
+        r"= (?:%s|pred\[32,%d\])\S* dynamic-update-slice\(" % (
+            leaf, s["max_len"]), text)
     if program == "decode":
-        full = cfg["hybrid_layer_pattern"].count(0)
         assert _kernel_calls(text) == (cfg["num_hidden_layers"], full)
-        assert not re.findall(
-            r"= (?:%s|pred\[32,%d\])\S* dynamic-update-slice\(" % (
-                leaf, s["max_len"]), text)
+        assert not updates
         assert " while(" not in text
-    else:
+    elif program == "chunk_prefill":
         assert _kernel_calls(text) == (0, 0)
         assert "dynamic-update-slice" in text
+    else:
+        # the joint program: the step's kernels and the chunk's windows
+        # (two leaves a layer and the flags), and still no loop
+        assert _kernel_calls(text) == (cfg["num_hidden_layers"], full)
+        assert len(updates) == 2 * cfg["num_hidden_layers"] + 1
+        assert " while(" not in text
     heads = cfg["num_attention_heads"]
     expanded = r"bf16\[\d+,(?:%d|4,16|8,8),(?:%d|%d),(?:128|192)\]" % (
         heads, s["max_len"], ring)
@@ -607,22 +627,14 @@ def _lower_state_cut_program(program, sharding):
     pool.slots = slots
     pool.cache_layers = tuple(abstract.cache_layers())
     pool.expert_layers = abstract.expert_layers()
-    pool.trace_counts = {"decode": 0, "prefill": {}, "scatter": {},
-                         "chunk_prefill": {}, "kv_copy": {}, "kv_extract": {}}
+    pool.trace_counts = dict(TRACE_COUNTS, decode_with_chunk={})
     pool._build_programs()
     routing = sds((0,), jnp.int32)
-    if program == "chunk_prefill":
-        lowered = pool._chunk_jit.lower(
-            model, caches, sds((), jnp.int32), sds((chunk,), jnp.int32),
-            sds((), jnp.int32), routing)
-    else:
-        lowered = pool._decode_jit.lower(
-            model, caches, sds((slots,), jnp.int32), sds((slots,), jnp.int32),
-            sds((slots,), jnp.bool_), routing)
-    return lowered, cfg, caches
+    return _lower(pool, program, model, caches, routing, sds, slots,
+                  chunk), cfg, caches
 
 
-@pytest.mark.parametrize("program", ["decode", "chunk_prefill"])
+@pytest.mark.parametrize("program", POOL_MODEL_PROGRAMS)
 def test_state_pool_program_moves_each_state_in_place_on_v5e(
         v5e, program, monkeypatch):
     """The decode step and the chunk program of the falcon-h1-34b cut, as
@@ -655,12 +667,18 @@ def test_state_pool_program_moves_each_state_in_place_on_v5e(
     whiles = len(re.findall(r" while\(", text))
     updates = re.findall(
         r"= f32\[48,32,256,128\]\S* dynamic-update-slice\(", text)
+    fusions = len(re.findall(r"f32\[48,32,256,128\]\S*\) fusion\(", text))
     if program == "decode":
         assert (calls, whiles, len(updates)) == ((layers, layers), 0, 0)
-        assert len(re.findall(r"f32\[48,32,256,128\]\S*\) fusion\(",
-                              text)) == layers
-    else:
+        assert fusions == layers
+    elif program == "chunk_prefill":
         assert (calls, whiles, len(updates)) == ((0, 0), layers, layers)
+    else:
+        # the joint program: what the two hold, added; the rows' update
+        # still one fusion a layer over the state the chunk wrote into
+        assert (calls, whiles, len(updates)) == ((layers, layers), layers,
+                                                 layers)
+        assert fusions == layers
     mem = compiled.memory_analysis()
     held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 13.5e9 < held < 15.5 * 2 ** 30 if program != "chunk_prefill" \
@@ -712,22 +730,14 @@ def _lower_latent_cut_program(program, sharding):
     pool.slots = slots
     pool.cache_layers = tuple(abstract.cache_layers())
     pool.expert_layers = abstract.expert_layers()
-    pool.trace_counts = {"decode": 0, "prefill": {}, "scatter": {},
-                         "chunk_prefill": {}, "kv_copy": {}, "kv_extract": {}}
+    pool.trace_counts = dict(TRACE_COUNTS, decode_with_chunk={})
     pool._build_programs()
     routing = sds((4,), jnp.int32)
-    if program == "chunk_prefill":
-        lowered = pool._chunk_jit.lower(
-            model, caches, sds((), jnp.int32), sds((chunk,), jnp.int32),
-            sds((), jnp.int32), routing)
-    else:
-        lowered = pool._decode_jit.lower(
-            model, caches, sds((slots,), jnp.int32), sds((slots,), jnp.int32),
-            sds((slots,), jnp.bool_), routing)
-    return lowered, cfg, caches, abstract
+    return _lower(pool, program, model, caches, routing, sds, slots,
+                  chunk), cfg, caches, abstract
 
 
-@pytest.mark.parametrize("program", ["decode", "chunk_prefill"])
+@pytest.mark.parametrize("program", POOL_MODEL_PROGRAMS)
 def test_latent_pool_program_copies_no_latent_leaf_on_v5e(
         v5e, program, monkeypatch):
     """The decode step and the chunk program of the sarvam-105b cut, as a
@@ -763,15 +773,17 @@ def test_latent_pool_program_copies_no_latent_leaf_on_v5e(
     assert not re.findall(r"bf16\[\d+,64,7168,(?:128|192|256)\]", text)
     calls = _kernel_calls(text)
     whiles = len(re.findall(r" while\(", text))
+    updates = len(re.findall(
+        r"= (?:%s|pred\[112,7168\])\S* dynamic-update-slice\(" % leaf, text))
     if program == "decode":
-        assert (calls, whiles) == ((layers, layers), 0)
-        assert not re.findall(
-            r"= (?:%s|pred\[112,7168\])\S* dynamic-update-slice\(" % leaf,
-            text)
+        assert (calls, whiles, updates) == ((layers, layers), 0, 0)
+    elif program == "chunk_prefill":
+        assert (calls, whiles, updates) == ((0, 0), layers, 2 * layers + 1)
     else:
-        assert (calls, whiles) == ((0, 0), layers)
-        assert len(re.findall(r"= %s\S* dynamic-update-slice\(" % leaf,
-                              text)) == 2 * layers
+        # the joint program: the step's two kernels a layer beside the
+        # chunk's windows and its loop over the slot's live key blocks
+        assert (calls, whiles, updates) == ((layers, layers), layers,
+                                            2 * layers + 1)
     # the experts' batched product on the held stacks as they lie
     stack = r"bf16\[16,(?:4096,2048|2048,4096)\]"
     assert not re.findall(r"= %s\S* (?:copy|copy-start|transpose)\(" % stack,
@@ -780,5 +792,5 @@ def test_latent_pool_program_copies_no_latent_leaf_on_v5e(
     mem = compiled.memory_analysis()
     held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 10.5e9 < held < 15.5 * 2 ** 30, held
-    if program == "decode":
+    if program != "chunk_prefill":
         assert held > 11e9
