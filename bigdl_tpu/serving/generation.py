@@ -92,6 +92,7 @@ join and leave.  The properties that make it hold:
 
 from __future__ import annotations
 
+import gc
 import logging
 import threading
 import time
@@ -338,6 +339,16 @@ class SlotPool:
         import jax.numpy as jnp
         from bigdl_tpu.nn.attention import _write_window
         counts = self.trace_counts
+        self.traces = 0         # ``trace_counts`` summed
+
+        def traced(program: str, key: Optional[int] = None) -> None:
+            """Runs only while jax traces ``program`` (keyed by a width
+            where it has several): with jit's cache, a compile."""
+            self.traces += 1
+            if key is None:
+                counts[program] = counts.get(program, 0) + 1
+            else:
+                counts[program][key] = counts[program].get(key, 0) + 1
 
         # The model is an ARGUMENT of every program that runs it, never
         # a closure: closed over, its weights are baked into each
@@ -370,7 +381,7 @@ class SlotPool:
                 jnp.zeros_like(routing)
 
         def _decode(model, caches, tok, index, active, routing):
-            counts["decode"] += 1
+            traced("decode")
 
             # ONE batched step over the S slots, each written and masked
             # at its own position (decode_step's per-row path) — never a
@@ -393,8 +404,7 @@ class SlotPool:
         def _decode_with_chunk(model, caches, tok, index, active, routing,
                                slot_id, toks, chunk_index):
             w = int(toks.shape[0])
-            counts["decode_with_chunk"][w] = \
-                counts["decode_with_chunk"].get(w, 0) + 1
+            traced("decode_with_chunk", w)
             # the step above and the chunk program below as ONE walk of
             # the model (``decode_step_with_chunk``): in every layer the
             # chunk's mixer, the rows' mixer on the caches it left, and
@@ -412,14 +422,14 @@ class SlotPool:
 
         def _prefill(model, ptoks):
             t = int(ptoks.shape[1])
-            counts["prefill"][t + 1] = counts["prefill"].get(t + 1, 0) + 1
+            traced("prefill", t + 1)
             return model.prefill_kv(ptoks)
 
         self._prefill_jit = jax.jit(_prefill)
 
         def _scatter(caches, slot_ids, layers_kv, pads):
             t = int(pads.shape[1])
-            counts["scatter"][t + 1] = counts["scatter"].get(t + 1, 0) + 1
+            traced("scatter", t + 1)
             new_layers = []
             # a ring keeps each row's newest positions, position p at
             # place p % R: place j takes the newest REAL position
@@ -465,8 +475,7 @@ class SlotPool:
 
         def _chunk_prefill(model, caches, slot_id, toks, index, routing):
             w = int(toks.shape[0])
-            counts["chunk_prefill"][w] = \
-                counts["chunk_prefill"].get(w, 0) + 1
+            traced("chunk_prefill", w)
             # pooled mode: the model writes exactly the chunk window of
             # the slot's row (a small dynamic_update_slice the donated
             # pool absorbs in place) and reads the row's keys by slice;
@@ -484,7 +493,7 @@ class SlotPool:
 
         def _kv_copy(caches, slot_id, layers_kv, pad, index):
             g = int(pad.shape[0])
-            counts["kv_copy"][g] = counts["kv_copy"].get(g, 0) + 1
+            traced("kv_copy", g)
             new_layers = []
             for (kind, _), kv, cache in zip(self.cache_layers, layers_kv,
                                             caches["layers"]):
@@ -500,8 +509,7 @@ class SlotPool:
         self._kv_copy_jit = jax.jit(_kv_copy, donate_argnums=(0,))
 
         def _kv_extract(caches, slot_id, index, width):
-            counts["kv_extract"][width] = \
-                counts["kv_extract"].get(width, 0) + 1
+            traced("kv_extract", width)
             layers = []
             for (kind, _), cache in zip(self.cache_layers,
                                         caches["layers"]):
@@ -529,7 +537,7 @@ class SlotPool:
         self._kv_extract_jit = jax.jit(_kv_extract, static_argnums=(3,))
 
         def _seed(tok, index, active, slot, t, i, a):
-            counts["seed"] = counts.get("seed", 0) + 1
+            traced("seed")
             return (tok.at[slot].set(t), index.at[slot].set(i),
                     active.at[slot].set(a))
 
@@ -937,26 +945,32 @@ class _ActiveSlot:
 # queue or in the follower poll), so the seven sum to the thread's life.
 _ENGINE_PHASES = ("admit", "prefill_dispatch", "decode_dispatch",
                   "readback_wait", "emit", "other", "idle")
-_ENGINE_COUNTERS = _ENGINE_PHASES + (
+_ENGINE_COUNTERS = (
     "iterations", "decode_dispatches", "pipeline_drains",
     "gaps_plain", "gaps_prefill", "gap_seconds_plain",
     "gap_seconds_prefill", "prefill_positions", "prefill_prompt_tokens",
     "admitted", "queue_wait_seconds",
     "decode_positions_live", "decode_positions_read",
-    "cache_write_programs", "chunks_joint", "chunks_alone",
+    "chunks_joint", "chunks_alone",
     "moe_layer_calls", "moe_pairs_total", "moe_pairs_held",
     "moe_active_experts",
     "ssm_layer_calls", "ssm_scan_positions", "ssm_scan_positions_real",
     "state_resets")
 
 
-def _fold_counts(acc: Dict[str, float], eng: Dict[str, float]) -> None:
-    """Move what the engine thread gathered since the last fold into the
-    published sums.  The caller holds the scheduler's lock."""
+_NO_CHUNK = (0, -1, -1)     # a pass record's chunk fields without a chunk
+
+
+def _fold_counts(acc: Dict[str, float], life: Dict[str, float],
+                 eng: Dict[str, float]) -> None:
+    """Publish what the engine thread gathered: the counters since the
+    last fold move into the published sums, the phase seconds (running
+    totals) replace theirs.  The caller holds the scheduler's lock."""
     for k, v in acc.items():
         if v:
             eng[k] += v
             acc[k] = 0
+    eng.update(life)
 
 
 class _Reservoir:
@@ -987,6 +1001,97 @@ class _Reservoir:
             return {f"p{int(q * 100)}": 0.0 for q in qs}
         out = np.quantile(np.asarray(self.vals), list(qs))
         return {f"p{int(q * 100)}": float(v) for q, v in zip(qs, out)}
+
+
+# One record a decode step's read-back (``GenerationScheduler._emit_step``).
+# ``seq`` is the decode dispatch's number.  Then what went out between the
+# previous decode dispatch and this one, which the device ran inside the
+# gap this record closes: ``joint`` (the step carried a chunk), the lone
+# chunk programs and bucketed prefills, and of the chunk (the joint one,
+# else the last that went alone) its width, its first position in its row
+# and its slot (0, -1, -1 without one).  Then the step.  ``t`` is the
+# ``perf_counter()`` instant the read-back returned (what the step's tokens
+# are stamped with) and ``gap_s`` the step gap that instant closes (NaN
+# where a pause lies before it).  Last, where the engine thread was since
+# the previous record's ``t`` (the seven phases tile that time: they sum
+# to ``gap_s``), and what else happened in it: seconds of collector passes
+# (any generation, any thread) and programs traced.
+PASS_RECORD = np.dtype(
+    # what the dispatch knows (``_dispatch_decode``) ...
+    [("seq", "i8"), ("joint", "i1"), ("chunks_alone", "i4"),
+     ("bucketed", "i4"), ("chunk_width", "i4"), ("chunk_index", "i4"),
+     ("chunk_slot", "i4"), ("drained", "i1"), ("n_active", "i4"),
+     ("positions_live", "i8"), ("positions_read", "i8"),
+     # ... and what its read-back adds (``_emit_step``)
+     ("t", "f8"), ("gap_s", "f8"), ("emitted", "i4")]
+    + [(k, "f8") for k in _ENGINE_PHASES]
+    + [("gc_s", "f8"), ("compiles", "i4")])
+# the engine writes these as running totals (no arithmetic a pass); a
+# record's share is the difference from the record before it
+_RUNNING = _ENGINE_PHASES + ("gc_s", "compiles")
+
+
+class _PassRing:
+    """The engine's pass records, the newest ``capacity`` of them.  One
+    writer (the engine thread); any thread may read."""
+
+    def __init__(self, capacity: int = 16384):
+        self.capacity = int(capacity)
+        # one row more: the record before the oldest, to difference from
+        self._rows = np.zeros((self.capacity + 1,), PASS_RECORD)
+        self._begun = 0             # records whose write has begun,
+        self._done = 0              # and ended
+
+    def append(self, row: tuple) -> None:
+        """``row`` in PASS_RECORD's order, its ``_RUNNING`` fields as the
+        engine's running totals."""
+        n = self._done
+        self._begun = n + 1
+        self._rows[n % len(self._rows)] = row
+        self._done = n + 1
+
+    def dropped(self) -> int:
+        return max(self._done - self.capacity, 0)
+
+    def records(self, t0: Optional[float] = None,
+                t1: Optional[float] = None) -> np.ndarray:
+        """A copy of the records with ``t0 <= t < t1``, oldest first."""
+        hi = self._done
+        rows = self._rows.copy()
+        # a record written while the copy ran may be torn in it: keep
+        # those that ended before it and that none begun since overwrote
+        lo = max(self._begun - self.capacity, 0)
+        hi = max(hi, lo)
+        if lo:
+            rows = rows[np.arange(lo - 1, hi) % len(rows)]
+        else:       # before the first record every total was zero
+            rows = np.concatenate([np.zeros((1,), PASS_RECORD), rows[:hi]])
+        for k in _RUNNING:
+            rows[k][1:] = np.diff(rows[k])
+        rows = rows[1:]
+        keep = np.ones(len(rows), bool)
+        if t0 is not None:
+            keep &= rows["t"] >= t0
+        if t1 is not None:
+            keep &= rows["t"] < t1
+        return rows[keep]
+
+
+class PassLog(dict):
+    """What ``stats()`` carries under ``"pass_log"``.  To a serialiser it
+    is three numbers: ``seq`` of the newest record the snapshot's sums
+    hold, the ring's ``capacity``, and how many records it has
+    ``dropped``.  Behind them is the engine's ring, which outlives the
+    engine and its pool: :meth:`records` copies a window out of it."""
+
+    def __init__(self, ring: _PassRing, seq: int):
+        super().__init__(seq=seq, capacity=ring.capacity,
+                         dropped=ring.dropped())
+        self._ring = ring
+
+    def records(self, t0: Optional[float] = None,
+                t1: Optional[float] = None) -> np.ndarray:
+        return self._ring.records(t0, t1)
 
 
 class GenerationScheduler:
@@ -1102,7 +1207,8 @@ class GenerationScheduler:
         # dedup followers parked on another request's in-flight prefill
         # (engine-thread-only, like _slot_state/_prefill_work)
         self._follow_work: List[_ActiveSlot] = []
-        # (step handle, n_active, prefill programs dispatched before it)
+        # (step handle, n_active, prefill programs dispatched before it,
+        # the dispatch's fields of the pass record)
         self._pending: Optional[Tuple] = None
         # a prefill chunk, ``(toks, slot, index)``, prepared in this pass
         # for the pass's decode dispatch to carry (a pool with the joint
@@ -1118,19 +1224,35 @@ class GenerationScheduler:
         self._tokens_emitted = 0
         self._decode_steps = 0
         self._prefill_calls = 0
-        # always-on measurement of the engine thread.  ``_acc`` belongs
-        # to that thread alone: phase seconds (``_mark``) and counters
-        # gather there between folds.  ``_eng`` is the published sum
-        # that stats() reads; ``_fold_counts`` moves one into the other
-        # under the lock, once a pass in ``_emit_step``.
+        # always-on measurement of the engine thread.  ``_acc`` and
+        # ``_life`` belong to that thread alone: counters gather in the
+        # one between folds, phase seconds (``_mark``) in the other as
+        # running totals.  ``_eng`` is the published sum that stats()
+        # reads; ``_fold_counts`` brings it up to date under the lock,
+        # once a pass in ``_emit_step``.
         self._acc: Dict[str, float] = dict.fromkeys(_ENGINE_COUNTERS, 0)
-        self._eng: Dict[str, float] = dict.fromkeys(_ENGINE_COUNTERS, 0)
+        self._life: Dict[str, float] = dict.fromkeys(_ENGINE_PHASES, 0.0)
+        self._eng: Dict[str, float] = dict.fromkeys(
+            _ENGINE_PHASES + _ENGINE_COUNTERS, 0)
         self._phase_key = "other"
         self._phase_t = time.perf_counter()
         # read-back return of the previous decode step; None across a
         # pause in which the pool was empty (no step gap spans it)
         self._t_readback: Optional[float] = None
-        self._prefill_since_dispatch = 0
+        # the pass log (PASS_RECORD), engine-thread state as ``_acc`` is:
+        # ``_seq`` numbers the decode dispatches, and what went out since
+        # the last of them waits here for the next to carry it to the gap
+        # in which the device ran it
+        self._ring = _PassRing()
+        self._seq = 0
+        self._seq_folded = 0        # newest record in ``_eng``'s sums
+        self._alone_since = 0
+        self._bucketed_since = 0
+        self._chunk_alone = _NO_CHUNK   # (width, index, slot), the last
+        # seconds of collector passes, summed by ``_note_gc`` on whichever
+        # thread collects
+        self._gc_seconds = 0.0
+        self._gc_t0 = 0.0
         self._occupancy_sum = 0
         self._ttft_sum = 0.0
         self._ttft_n = 0
@@ -1156,6 +1278,7 @@ class GenerationScheduler:
     def start(self) -> "GenerationScheduler":
         if self._thread is not None:
             raise RuntimeError("generation scheduler already started")
+        gc.callbacks.append(self._note_gc)      # until ``_run`` ends
         self._thread = threading.Thread(
             target=self._run, name="bigdl-serving-generation", daemon=True)
         self._thread.start()
@@ -1331,6 +1454,7 @@ class GenerationScheduler:
             ttft_q = self._ttft_res.quantiles()
             itl_q = self._itl_res.quantiles()
             eng = dict(self._eng)
+            seq = self._seq_folded
             # seconds between the read-back returns of consecutive decode
             # steps: each second of decoding is counted once, however
             # many steps are in flight
@@ -1387,9 +1511,6 @@ class GenerationScheduler:
                 # where it does not)
                 "decode_positions_live": eng["decode_positions_live"],
                 "decode_positions_read": eng["decode_positions_read"],
-                # device programs that wrote the cache in those steps
-                # (SlotPool.cache_write_programs a step)
-                "cache_write_programs": eng["cache_write_programs"],
                 # chunk programs (not bucketed prefills) that rode a
                 # decode step as one joint program, and that went out
                 # alone (a pool without the joint program: all of them)
@@ -1420,6 +1541,9 @@ class GenerationScheduler:
                 "cache_bytes_state": self._cache_bytes["state"],
                 "cache_bytes_latent": self._cache_bytes["latent"],
             }
+        # one record a decode step over the newest 16,384 (PASS_RECORD):
+        # ``["pass_log"].records(t0, t1)``
+        out["pass_log"] = PassLog(self._ring, seq)
         cache = self._prefix_cache
         out["prefix_cache"] = None if cache is None else cache.stats()
         return out
@@ -1432,17 +1556,31 @@ class GenerationScheduler:
         ``perf_counter()`` read; returns it so that callers stamp with
         the same instant."""
         now = time.perf_counter()
-        self._acc[self._phase_key] += now - self._phase_t
+        self._life[self._phase_key] += now - self._phase_t
         self._phase_key = key
         self._phase_t = now
         return now
 
+    def _note_gc(self, phase: str, info: Dict[str, int]) -> None:
+        """``gc.callbacks`` hook: a collector pass stops every thread,
+        the engine's among them."""
+        if phase == "start":
+            self._gc_t0 = time.perf_counter()
+        else:
+            self._gc_seconds += time.perf_counter() - self._gc_t0
+
+    def _sent_alone(self, width: int, index: int, slot: int) -> None:
+        """A chunk program went out by itself."""
+        self._acc["chunks_alone"] += 1
+        self._alone_since += 1
+        self._chunk_alone = (width, index, slot)
+
     def _fold(self) -> None:
         """Publish what the engine thread gathered, where no decode step
         will do it soon: before it blocks, and when it ends."""
-        acc = self._acc
+        acc, life = self._acc, self._life
         with self._lock:
-            _fold_counts(acc, self._eng)
+            _fold_counts(acc, life, self._eng)
 
     # -- the engine loop ----------------------------------------------------
 
@@ -1454,6 +1592,7 @@ class GenerationScheduler:
         finally:
             self._mark("other")
             self._fold()
+            gc.callbacks.remove(self._note_gc)
             with self._lock:
                 killed = self._die_exc is not None
             if killed:
@@ -1912,7 +2051,7 @@ class GenerationScheduler:
             self._sweep_followers(tel)  # a parked follower re-claims
             return
         dt = t1 - t0
-        self._prefill_since_dispatch += 1
+        self._bucketed_since += 1
         if bucket > 1:
             # every lane of the fixed-width batch is computed, dead
             # lanes and bucket padding included
@@ -1993,7 +2132,7 @@ class GenerationScheduler:
                     self._held_chunk = (toks, st.slot, s)
                 else:
                     pool.chunk_prefill_into(toks, st.slot, s)
-                    self._acc["chunks_alone"] += 1
+                    self._sent_alone(w, s, st.slot)
                 t1 = self._mark("other")
         except Exception as e:  # noqa: BLE001 - fail this request only
             self._mark("other")
@@ -2013,7 +2152,6 @@ class GenerationScheduler:
                 "request/prefill", t0, t1, ctx=st.req.trace,
                 chunk=w, index=s)
         new_pos = end if s + w >= end else s + w
-        self._prefill_since_dispatch += 1
         self._acc["prefill_positions"] += w
         self._acc["prefill_prompt_tokens"] += new_pos - st.next_pos
         self._acc["ssm_layer_calls"] += pool.state_layers
@@ -2085,7 +2223,8 @@ class GenerationScheduler:
             self._mark("prefill_dispatch")
             self.pool.chunk_prefill_into(*chunk)
             self._mark("other")
-            self._acc["chunks_alone"] += 1
+            toks, slot, index = chunk
+            self._sent_alone(len(toks), index, slot)
 
     def _dispatch_decode(self) -> None:
         pool = self.pool
@@ -2106,11 +2245,18 @@ class GenerationScheduler:
         n_active = pool.n_active()
         chunk, self._held_chunk = self._held_chunk, None
         # prefill programs dispatched since the previous decode dispatch
-        # run on the device between that step and this one: the flag
-        # rides this step's handle to the gap its read-back closes
-        after_prefill = self._prefill_since_dispatch
+        # run on the device between that step and this one: their count,
+        # and what the pass record says of them, ride this step's handle
+        # to the gap its read-back closes
+        joint = chunk is not None
+        alone, bucketed = self._alone_since, self._bucketed_since
+        of_chunk = (len(chunk[0]), chunk[2], chunk[1]) if joint \
+            else self._chunk_alone
+        after_prefill = joint + alone + bucketed
+        seq = self._seq + 1
         try:
-            with tracing.span("serving/decode_dispatch", n_active=n_active,
+            with tracing.span("serving/decode_dispatch", seq=seq,
+                              n_active=n_active,
                               after_prefill=after_prefill, drained=drained):
                 self._mark("decode_dispatch")
                 emit = pool.decode_dispatch(chunk)
@@ -2121,14 +2267,17 @@ class GenerationScheduler:
             logger.exception("pooled decode step failed")
             self._fail_in_flight(e)
             return
-        self._prefill_since_dispatch = 0
+        self._seq = seq
+        self._alone_since = self._bucketed_since = 0
+        self._chunk_alone = _NO_CHUNK
         self._acc["decode_dispatches"] += 1
         self._acc["decode_positions_live"] += emit.positions[0]
         self._acc["decode_positions_read"] += emit.positions[1]
-        self._acc["cache_write_programs"] += pool.cache_write_programs
         self._acc["ssm_layer_calls"] += pool.state_layers
-        self._acc["chunks_joint"] += chunk is not None
-        self._pending = (emit, n_active, after_prefill)
+        self._acc["chunks_joint"] += joint
+        self._pending = (emit, n_active, after_prefill, (
+            seq, joint, alone, bucketed, *of_chunk, drained, n_active,
+            *emit.positions))
         if prev is not None:
             # THE async-readback overlap: step N's host-side emit work
             # (int conversion, callbacks, EOS checks) runs while step
@@ -2137,11 +2286,15 @@ class GenerationScheduler:
 
     def _emit_step(self, pending: Tuple) -> None:
         pool = self.pool
-        emit, n_active, after_prefill = pending
-        with tracing.span("serving/readback"):
+        emit, n_active, after_prefill, sent = pending
+        with tracing.span("serving/readback", seq=sent[0]):
             self._mark("readback_wait")
             out, credit = pool.read_emit_masked(emit)
             now = self._mark("emit")
+        # the pass record's share of this instant: the running totals of
+        # the phases (both ends of a record's share are ``_mark()``'s own
+        # ``now``), of the collector's seconds and of the traces
+        running = (*self._life.values(), self._gc_seconds, pool.traces)
         # the step gap: from the previous step's read-back return to this
         # one's.  Consecutive gaps tile the time the pool spent decoding,
         # whatever is in flight; dispatch-to-read-back intervals overlap
@@ -2173,13 +2326,15 @@ class GenerationScheduler:
                 or len(st.emitted) + 1 >= st.req.max_new_tokens
             n_finished += done
             plan.append((slot, st, tok, done))
+        self._ring.append(sent + (
+            now, np.nan if dt is None else dt, len(plan)) + running)
         with tracing.span("serving/emit", emitted=len(plan),
                           finished=n_finished):
-            self._emit_tokens(plan, n_active, dt, now)
+            self._emit_tokens(plan, n_active, dt, now, sent[0])
         self._mark("other")
 
     def _emit_tokens(self, plan: List[tuple], n_active: int,
-                     dt: Optional[float], now: float) -> None:
+                     dt: Optional[float], now: float, seq: int) -> None:
         """Host work of one read-back step: callbacks, bookkeeping, the
         requests that end with this token."""
         pool = self.pool
@@ -2200,7 +2355,7 @@ class GenerationScheduler:
                 except Exception:   # noqa: BLE001 - user callback
                     logger.exception("on_token callback failed")
         tel = telemetry.enabled()
-        acc = self._acc
+        acc, life = self._acc, self._life
         # counters BEFORE any future resolves: a waiter whose result()
         # just returned may immediately read stats(), which must
         # already include the iteration that finished it
@@ -2210,7 +2365,8 @@ class GenerationScheduler:
             self._occupancy_sum += n_active
             for g, _ in gaps:
                 self._itl_res.add(g)
-            _fold_counts(acc, self._eng)
+            _fold_counts(acc, life, self._eng)
+            self._seq_folded = seq
         for slot, st, _tok, done in plan:
             if done:
                 self._finish(st, now, tel)
@@ -2226,7 +2382,7 @@ class GenerationScheduler:
         row[len(req.prompt):len(req.prompt) + len(st.emitted)] = st.emitted
         ttft = ((st.t_first if st.t_first is not None else now)
                 - req.t_enqueue)
-        acc = self._acc
+        acc, life = self._acc, self._life
         with self._lock:
             # before set_result, same reason as the step counters (a
             # prefill-role engine never emits: this is its only fold
@@ -2235,7 +2391,7 @@ class GenerationScheduler:
             self._ttft_sum += ttft
             self._ttft_n += 1
             self._ttft_res.add(ttft)
-            _fold_counts(acc, self._eng)
+            _fold_counts(acc, life, self._eng)
         if req.trace is not None:
             # BEFORE set_result: the router's terminal callback files
             # the trace the moment the future resolves, and these

@@ -197,11 +197,10 @@ def test_decode_step_flags_equal_the_loops():
 @pytest.mark.parametrize("kernel", [False, True], ids=["loop", "kernel"])
 def test_the_pool_counts_the_programs_that_write_its_cache(
         kernel, monkeypatch):
-    """``stats()["cache_write_programs"]`` adds, at each decode dispatch,
-    the device programs of that step that write the cache: on the loop a
-    ``dynamic_update_slice`` a slot and leaf and the flags' one select, on
-    the kernel one program a layer and that select.  The tokens are the
-    same either way."""
+    """``SlotPool.cache_write_programs`` is the device programs of a decode
+    step that write the cache: on the loop a ``dynamic_update_slice`` a
+    slot and leaf and the flags' one select, on the kernel one program a
+    layer and that select.  The tokens are the same either way."""
     if kernel:
         _force_kernel(monkeypatch)
     slots = 2
@@ -209,7 +208,7 @@ def test_the_pool_counts_the_programs_that_write_its_cache(
     prompt = np.arange(1, 71, dtype=np.int32) % VOCAB + 1
     eng = GenerationScheduler(m, slots=slots, prefill_chunk=CHUNK)
     try:
-        assert eng.stats()["cache_write_programs"] == 0
+        assert "cache_write_programs" not in eng.stats()
         row = eng.submit_async(prompt, 10).result(timeout=300)
         st = eng.stats()
         a_step = eng.pool.cache_write_programs
@@ -217,7 +216,6 @@ def test_the_pool_counts_the_programs_that_write_its_cache(
         eng.shutdown()
     assert a_step == (LAYERS + 1 if kernel else slots * 2 * LAYERS + 1)
     assert st["decode_dispatches"] >= 10
-    assert st["cache_write_programs"] == st["decode_dispatches"] * a_step
     want = np.asarray(m.generate(jnp.asarray(prompt)[None], 10, chunk=CHUNK))
     assert np.array_equal(row, want[0])
 

@@ -696,8 +696,8 @@ def test_engine_pass_spans_nest_under_the_iteration(lm):
         elif s.name == "serving/submit":
             assert s.thread == caller
     disp = [s for s in spans if s.name == "serving/decode_dispatch"]
-    assert all(set(s.args) == {"n_active", "after_prefill", "drained"}
-               for s in disp)
+    assert all(set(s.args) == {"seq", "n_active", "after_prefill",
+                               "drained"} for s in disp)
     # the 20-token prompt was chunked while the other request decoded
     assert any(s.args["after_prefill"] for s in disp)
     emits = [s for s in spans if s.name == "serving/emit"]
