@@ -27,7 +27,8 @@ import jax.numpy as jnp
 
 from bigdl_tpu.core.module import Module, ModuleList, Parameter
 from bigdl_tpu.nn.attention import (SequenceBeamSearch,
-                                    TransformerDecoderLayer, causal_bias,
+                                    TransformerDecoderLayer,
+                                    _residual_dropout, causal_bias,
                                     chunk_incremental_bias,
                                     incremental_bias, padding_bias,
                                     position_encoding)
@@ -279,13 +280,7 @@ class TransformerLM(Module):
         clobber a co-scheduled chunked prefill's freshly written
         positions (test_decode_does_not_disturb_inactive_rows)."""
         if jnp.ndim(index) == 1:
-            # what a row's query may attend: its positions up to its own,
-            # and nothing for a row that only rides along
-            lengths = index + 1
-            if active is not None:
-                lengths = jnp.where(active, lengths, 0)
-                index = jnp.where(active, index,
-                                  jnp.int32(self.max_len - 1))
+            index, lengths = self._row_places(index, active)
             return self._decode_step_rows(tokens, index, lengths, caches,
                                           with_logits)
         pad = jax.lax.dynamic_update_slice(
@@ -305,9 +300,7 @@ class TransformerLM(Module):
         new_caches = {"layers": new_layers, "pad": pad}
         if not with_logits:
             return None, new_caches
-        x = self.final_norm(x)
-        logits = jnp.einsum("bth,vh->btv", x, self.embedding.weight)
-        return logits[:, 0], new_caches
+        return self._head(x), new_caches
 
     def _decode_step_rows(self, tokens, index, lengths, caches,
                           with_logits):
@@ -341,23 +334,9 @@ class TransformerLM(Module):
         of each row, the blocks that hold live positions; elsewhere the
         XLA product over the whole row under ``incremental_bias``'s
         mask."""
-        from bigdl_tpu.nn.attention import _residual_dropout
         from bigdl_tpu.ops import attention_kernels
-        rows = range(tokens.shape[0])
-
-        def write(leaf, new):
-            # new holds one position per row: [B, h, 1, d] for a K/V
-            # leaf [B, h, T, d], [B, 1] for the padding flags [B, T]
-            new = new.astype(leaf.dtype)
-            for b in rows:
-                at = ((b, index[b]) if leaf.ndim == 2
-                      else (b, 0, index[b], 0))
-                leaf = jax.lax.dynamic_update_slice(leaf, new[b:b + 1], at)
-            return leaf
-
-        pad = write(caches["pad"], tokens == 0)
-        x = self.embedding.forward(jnp.maximum(tokens, 1))
-        x = x * (self.hidden_size ** 0.5)
+        pad = self._write_rows(caches["pad"], tokens == 0, index)
+        x = self._embed(tokens)
         pos = jnp.take(position_encoding(self.max_len, self.hidden_size,
                                          dtype=x.dtype), index, axis=0)
         x = x + pos[:, None]
@@ -366,21 +345,68 @@ class TransformerLM(Module):
             attn = blk.self_attn
             xn = blk.self_norm(x)
             old = cache["self"]
-            k = write(old["k"], attn._split_heads(attn.k_layer(xn)))
-            v = write(old["v"], attn._split_heads(attn.v_layer(xn)))
+            # one position per row: [B, h, 1, d] into a leaf [B, h, T, d]
+            k = self._write_rows(
+                old["k"], attn._split_heads(attn.k_layer(xn)), index)
+            v = self._write_rows(
+                old["v"], attn._split_heads(attn.v_layer(xn)), index)
             new_layers.append({"self": {"k": k, "v": v}})
             q = attn._split_heads(attn.q_layer(xn))
             ctxt = attention_kernels.decode_attention(q, k, v, lengths, pad)
-            y = attn.output_layer(attn._combine_heads(ctxt))
-            x = x + _residual_dropout(y, blk.ffn_dropout, blk.training)
-            y = blk.ffn(blk.ffn_norm(x))
-            x = x + _residual_dropout(y, blk.ffn_dropout, blk.training)
+            x = self._block_tail(blk, x, attn._combine_heads(ctxt))
         new_caches = {"layers": new_layers, "pad": pad}
         if not with_logits:
             return None, new_caches
+        return self._head(x), new_caches
+
+    # what the pooled walks share (eval mode: the pool runs an eval clone)
+
+    def _row_places(self, index, active):
+        """``(index, lengths)`` of a per-row step: what a row's query may
+        attend is its positions up to its own; a row that only rides
+        along (``active`` false) attends nothing and writes at ``max_len
+        - 1`` (:meth:`decode_step` says why there)."""
+        lengths = index + 1
+        if active is not None:
+            lengths = jnp.where(active, lengths, 0)
+            index = jnp.where(active, index, jnp.int32(self.max_len - 1))
+        return index, lengths
+
+    def _embed(self, tokens):
+        # 0 is padding; clamp for the gather, the flags mask it
+        x = self.embedding.forward(jnp.maximum(tokens, 1))
+        return x * (self.hidden_size ** 0.5)
+
+    @staticmethod
+    def _write_rows(leaf, new, index):
+        """``new``'s row ``b`` (one position: ``[B, h, 1, d]`` for a K/V
+        leaf ``[B, h, T, d]``, ``[B, 1]`` for the padding flags ``[B,
+        T]``) into ``leaf`` at position ``index[b]``, a
+        ``dynamic_update_slice`` a row (:meth:`_decode_step_rows` says
+        why)."""
+        new = new.astype(leaf.dtype)
+        for b in range(new.shape[0]):
+            at = ((b, index[b]) if leaf.ndim == 2
+                  else (b, 0, index[b], 0))
+            leaf = jax.lax.dynamic_update_slice(leaf, new[b:b + 1], at)
+        return leaf
+
+    @staticmethod
+    def _block_tail(blk, x, ctxt):
+        """A block from its attention's context on (``ctxt [B, T, H]``,
+        the heads joined): the output projection and the feed-forward,
+        each on its residual."""
+        y = blk.self_attn.output_layer(ctxt)
+        x = x + _residual_dropout(y, blk.ffn_dropout, blk.training)
+        y = blk.ffn(blk.ffn_norm(x))
+        return x + _residual_dropout(y, blk.ffn_dropout, blk.training)
+
+    def _head(self, x):
+        """``x [B, 1, H]`` -> logits ``[B, vocab+1]`` against the tied
+        embedding."""
         x = self.final_norm(x)
         logits = jnp.einsum("bth,vh->btv", x, self.embedding.weight)
-        return logits[:, 0], new_caches
+        return logits[:, 0]
 
     def prefill_kv(self, ptoks):
         """Per-layer K/V for every position of ``ptoks`` (a prompt minus
@@ -398,7 +424,6 @@ class TransformerLM(Module):
         x = x + position_encoding(T, self.hidden_size, dtype=x.dtype)
         bias = causal_bias(T, dtype=x.dtype) \
             + padding_bias(ptoks).astype(x.dtype)
-        from bigdl_tpu.nn.attention import _residual_dropout
         from bigdl_tpu.ops import dot_product_attention
         layers = []
         for blk in self.blocks:
@@ -451,7 +476,6 @@ class TransformerLM(Module):
         Attention is inlined like :meth:`prefill_kv` (the K/V written
         to the cache are the K/V attended), expecting eval mode — the
         serving slot pool always runs an eval clone."""
-        from bigdl_tpu.nn.attention import _residual_dropout
         from bigdl_tpu.ops import dot_product_attention
         _B, W = toks.shape
         if slot is None:
@@ -463,8 +487,7 @@ class TransformerLM(Module):
                                                (slot, index))
             pad_read = jax.lax.dynamic_slice(pad, (slot, 0),
                                              (1, self.max_len))
-        x = self.embedding.forward(jnp.maximum(toks, 1))
-        x = x * (self.hidden_size ** 0.5)
+        x = self._embed(toks)
         pos = jax.lax.dynamic_slice_in_dim(
             position_encoding(self.max_len, self.hidden_size,
                               dtype=x.dtype), index, W, axis=0)
@@ -499,11 +522,89 @@ class TransformerLM(Module):
             new_layers.append({"self": {"k": k, "v": v}})
             q = attn._split_heads(attn.q_layer(xn))
             ctxt = dot_product_attention(q, k_read, v_read, bias)
-            y = attn.output_layer(attn._combine_heads(ctxt))
-            x = x + _residual_dropout(y, blk.ffn_dropout, blk.training)
-            y = blk.ffn(blk.ffn_norm(x))
-            x = x + _residual_dropout(y, blk.ffn_dropout, blk.training)
+            x = self._block_tail(blk, x, attn._combine_heads(ctxt))
         return {"layers": new_layers, "pad": pad}
+
+    def decode_step_with_chunk(self, tokens, index, caches, active, toks,
+                               chunk_index, slot):
+        """A pool's pass that carries a prefill chunk, as one walk of the
+        blocks: what ``prefill_chunk(toks, chunk_index, caches,
+        slot=slot)`` followed by ``decode_step(tokens, index, .,
+        active=active)`` gives, ``(logits [B, vocab+1], caches)``, in one
+        program (the serving pool takes this entry for a chunk due while
+        slots decode).  ``tokens [B, 1]``, ``index [B]``, ``active [B]``;
+        ``toks [1, W]`` into row ``slot`` at ``chunk_index``.
+
+        The ``B`` rows and the chunk's ``W`` tokens are one residual
+        stream ``[1, B + W, H]``: a block's norms, its output projection
+        and its feed-forward run once over both, so the pass reads those
+        weights once.  In between each half projects its own queries,
+        keys and values and keeps its own attention: the chunk's window
+        is written and it attends its slot's row
+        (:meth:`prefill_chunk`), then the rows' positions are written
+        and they attend what is live (:meth:`_decode_step_rows`).  Chunk
+        first, then rows, layer by layer: row ``slot`` may decode in the
+        same pass (its prompt's last chunk) and then attends what the
+        chunk wrote.
+
+        Why q, k and v are not shared too: on a v5e at OPT-1.3B's serving
+        shapes (6 rows beside 64 tokens) the walk as it stands took 8.78
+        ms where the two programs took 11.31, and with those three
+        products also made over the 70 rows together 9.31: the weights
+        stream in behind the cache writes and the rows' attention either
+        way, and the joint product's parting into heads cost more than
+        the second, small product (PERF.md, PR 43)."""
+        from bigdl_tpu.ops import attention_kernels, dot_product_attention
+        B, W = tokens.shape[0], toks.shape[1]
+        chunk_pad = jax.lax.dynamic_update_slice(
+            caches["pad"], toks == 0, (slot, chunk_index))
+        chunk_pad_read = jax.lax.dynamic_slice(chunk_pad, (slot, 0),
+                                               (1, self.max_len))
+        index, lengths = self._row_places(index, active)
+        pad = self._write_rows(chunk_pad, tokens == 0, index)
+        x, xc = self._embed(tokens), self._embed(toks)
+        table = position_encoding(self.max_len, self.hidden_size,
+                                  dtype=x.dtype)
+        x = x + jnp.take(table, index, axis=0)[:, None]
+        xc = xc + jax.lax.dynamic_slice_in_dim(table, chunk_index, W,
+                                               axis=0)[None]
+        bias = chunk_incremental_bias(self.max_len, chunk_index, W,
+                                      chunk_pad_read, x.dtype)
+        # the rows, one position each, then the chunk: [1, B + W, H]
+        h = jnp.concatenate([x.reshape(1, B, -1), xc], axis=1)
+        new_layers = []
+        for blk, cache in zip(self.blocks, caches["layers"]):
+            attn = blk.self_attn
+            qkv = (attn.q_layer, attn.k_layer, attn.v_layer)
+            hn = blk.self_norm(h)
+            # each half projects its own queries, keys and values, in the
+            # shape its own walk has (a row's one position [B, heads, 1,
+            # d], the chunk [1, heads, W, d])
+            q, k, v = (attn._split_heads(layer(hn[0, :B, None]))
+                       for layer in qkv)
+            qc, kc, vc = (attn._split_heads(layer(hn[:, B:]))
+                          for layer in qkv)
+            old = cache["self"]
+            row = (1,) + old["k"].shape[1:]
+            k_leaf = jax.lax.dynamic_update_slice(
+                old["k"], kc.astype(old["k"].dtype),
+                (slot, 0, chunk_index, 0))
+            v_leaf = jax.lax.dynamic_update_slice(
+                old["v"], vc.astype(old["v"].dtype),
+                (slot, 0, chunk_index, 0))
+            ctxt_chunk = dot_product_attention(
+                qc, jax.lax.dynamic_slice(k_leaf, (slot, 0, 0, 0), row),
+                jax.lax.dynamic_slice(v_leaf, (slot, 0, 0, 0), row), bias)
+            k_leaf = self._write_rows(k_leaf, k, index)
+            v_leaf = self._write_rows(v_leaf, v, index)
+            new_layers.append({"self": {"k": k_leaf, "v": v_leaf}})
+            ctxt = attention_kernels.decode_attention(q, k_leaf, v_leaf,
+                                                      lengths, pad)
+            h = self._block_tail(blk, h, jnp.concatenate(
+                [attn._combine_heads(ctxt).reshape(1, B, -1),
+                 attn._combine_heads(ctxt_chunk)], axis=1))
+        return self._head(h[0, :B, None]), \
+            {"layers": new_layers, "pad": pad}
 
     def _prefill(self, prompt, caches):
         """Write prompt[:, :-1]'s per-layer K/V into the caches with ONE

@@ -59,14 +59,16 @@ copy/extract programs once per granularity (``trace_counts`` exposes
 the evidence; tests assert it).
 
 Where the model can walk its blocks once for a decode step and a chunk
-together (``decode_step_with_chunk``: ``HybridDecoder``), a chunk due in
-a pass in which slots decode **rides the pass's decode step**: one joint
-program in place of the chunk program followed by the step, in which
-each layer's feed-forward runs once over the decode rows and the chunk's
-rows, so the pass reads those weights once.  The joint program takes the
-lone chunk program's place at a width (the lone one is kept at the full
-width, for an idle pool's long prompt), so the budget is one program
-more.  A model without the entry runs the two programs in turn.
+together (``decode_step_with_chunk``: ``HybridDecoder``,
+``TransformerLM``), a chunk due in a pass in which slots decode **rides
+the pass's decode step**: one joint program in place of the chunk
+program followed by the step, in which each layer's feed-forward
+(``TransformerLM``: its output projection too) runs once over the decode
+rows and the chunk's rows, so the pass reads those weights once.  The
+joint program takes the lone chunk program's place at a width (the lone
+one is kept at the full width, for an idle pool's long prompt), so the
+budget is one program more.  A model without the entry runs the two
+programs in turn.
 
 Correctness bar (unchanged from the original engine, property-tested
 over randomized arrival schedules, cache hit or miss): greedy tokens

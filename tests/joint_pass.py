@@ -1,24 +1,61 @@
-"""What the tests of the three ``HybridDecoder`` block kinds share about
-the joint pass (``HybridDecoder.decode_step_with_chunk``: a pool's decode
-step that carries a prefill chunk, each layer's feed-forward run once
-over both): the same pass made of the two entries it stands for, on the
-same caches, and the comparison.  A helper, not a test file: the models
-and their sizes are the callers'."""
+"""What the tests of the three ``HybridDecoder`` block kinds and of
+``TransformerLM`` share about the joint pass (``decode_step_with_chunk``:
+a pool's decode step that carries a prefill chunk, each layer's
+feed-forward run once over both): the same pass made of the two entries
+it stands for, on the same caches, and the comparison; and a
+``TransformerLM`` whose class hides the entry, for the path a model
+without it takes.  A helper, not a test file: the models and their sizes
+are the callers'."""
+
+import inspect
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bigdl_tpu.models.transformer_lm import TransformerLM
 
 SCENARIOS = ["idle-row", "padded-last-chunk", "fresh-occupant",
              "own-slot-decodes"]
 SLOTS = 3
 
 
+class TwoProgramLM(TransformerLM):
+    """A ``TransformerLM`` whose class hides the joint entry: to a pool,
+    a model that has none (``hasattr`` is what it asks)."""
+
+    @property
+    def decode_step_with_chunk(self):
+        raise AttributeError("decode_step_with_chunk")
+
+
+def without_the_joint_entry(model):
+    """``model`` (a ``TransformerLM``) as a :class:`TwoProgramLM` on the
+    same leaves: its pool sends a chunk and a step, two programs."""
+    children, aux = model._tree_flatten()
+    return TwoProgramLM._tree_unflatten(aux, children)
+
+
+def _init_cache(model, slots, chunk):
+    """A pool's caches; rings get room for a chunk where the model has
+    rings to size (``TransformerLM`` keeps full rows only)."""
+    if "ring_margin" in inspect.signature(model.init_cache).parameters:
+        return model.init_cache(slots, ring_margin=chunk)
+    return model.init_cache(slots)
+
+
+def _prefill_chunk(model, toks, index, caches, slot):
+    """``(caches, routing)``: no routing (None) from a model whose chunk
+    entry returns the caches alone."""
+    out = model.prefill_chunk(toks, index, caches, slot=slot)
+    return out if isinstance(out, tuple) else (out, None)
+
+
 def _fill(model, caches, rng, vocab, slot, n, chunk):
     """``n`` positions of ``slot`` through whole chunks."""
     for s in range(0, n, chunk):
         toks = jnp.asarray(rng.integers(1, vocab + 1, (1, chunk)), jnp.int32)
-        caches, _ = model.prefill_chunk(toks, s, caches, slot=slot)
+        caches, _ = _prefill_chunk(model, toks, s, caches, slot)
     return caches
 
 
@@ -36,7 +73,7 @@ def joint_pass_case(model, chunk, vocab, scenario):
       decodes in the same pass, from the position after it."""
     assert scenario in SCENARIOS, scenario
     rng = np.random.default_rng(SCENARIOS.index(scenario))
-    caches = model.init_cache(SLOTS, ring_margin=chunk)
+    caches = _init_cache(model, SLOTS, chunk)
     caches = _fill(model, caches, rng, vocab, 0, 3 * chunk, chunk)
     caches = _fill(model, caches, rng, vocab, 2, chunk, chunk)
     caches = _fill(model, caches, rng, vocab, 1,
@@ -63,13 +100,14 @@ def assert_joint_pass_equals_chunk_then_step(model, chunk, vocab, scenario,
     ``decode_step``: logits and every cache leaf to ``tol`` (float32 at
     ``highest``: only the feed-forward's row count differs), the flags
     equal, the experts' pairs (routed, and on a held expert) exactly; an
-    expert layer counts one call where the two programs count two."""
+    expert layer counts one call where the two programs count two (a
+    model that counts no routing returns none from any entry)."""
     caches, tokens, index, active, toks, at, slot = joint_pass_case(
         model, chunk, vocab, scenario)
-    after_chunk, did_chunk = model.prefill_chunk(toks, at, caches, slot=slot)
-    want, want_caches, did_step = model.decode_step(
+    after_chunk, did_chunk = _prefill_chunk(model, toks, at, caches, slot)
+    want, want_caches, *did_step = model.decode_step(
         tokens, index, after_chunk, active=active)
-    got, got_caches, did = model.decode_step_with_chunk(
+    got, got_caches, *did = model.decode_step_with_chunk(
         tokens, index, caches, active, toks, at, slot)
     live = np.asarray(active)
     assert float(jnp.max(jnp.abs(got - want)[live])) <= tol
@@ -82,9 +120,12 @@ def assert_joint_pass_equals_chunk_then_step(model, chunk, vocab, scenario,
             assert bool(jnp.all(a == b))
         else:
             assert float(jnp.max(jnp.abs(a - b))) <= tol
-    both = np.asarray(did_chunk) + np.asarray(did_step)
+    assert len(did) == len(did_step) == (did_chunk is not None)
+    if not did:
+        return
+    both = np.asarray(did_chunk) + np.asarray(did_step[0])
     layers = model.expert_layers()
-    assert list(np.asarray(did)[:3]) == [layers, both[1], both[2]]
+    assert list(np.asarray(did[0])[:3]) == [layers, both[1], both[2]]
     assert both[0] == 2 * layers
     if layers:
         assert both[1] > 0

@@ -542,8 +542,10 @@ def test_decode_seconds_is_the_sum_of_step_gaps_within_wall_time(lm):
 
 def test_step_gap_is_flagged_prefill_when_a_chunk_preceded_its_step(lm):
     """Scripted arrivals: B's long prompt arrives at A's fifth token and
-    is prefilled in chunks between A's decode steps.  The expectation
-    comes from the pool's own call log, not from a chunking rule."""
+    is prefilled in chunks between A's decode steps (on this pool, which
+    has the joint program, by them: a dispatch that carries a chunk is a
+    prefill and a dispatch).  The expectation comes from the pool's own
+    call log, not from a chunking rule."""
     rng = np.random.default_rng(23)
     eng = GenerationScheduler(lm, slots=2, prefill_chunk=8, start=False)
     pool = eng.pool
@@ -555,9 +557,15 @@ def test_step_gap_is_flagged_prefill_when_a_chunk_preceded_its_step(lm):
             return fn(*a, **k)
         return call
 
+    dispatch = pool.decode_dispatch
+
+    def decode_dispatch(chunk=None):
+        log.extend(["dispatch"] if chunk is None else ["prefill", "dispatch"])
+        return dispatch(chunk)
+
     pool.chunk_prefill_into = logged("prefill", pool.chunk_prefill_into)
     pool.prefill_into = logged("prefill", pool.prefill_into)
-    pool.decode_dispatch = logged("dispatch", pool.decode_dispatch)
+    pool.decode_dispatch = decode_dispatch
     pool.read_emit_masked = logged("read", pool.read_emit_masked)
     eng.start()
     b_prompt = rng.integers(1, 51, 20).astype(np.int32)
@@ -597,13 +605,48 @@ def test_step_gap_is_flagged_prefill_when_a_chunk_preceded_its_step(lm):
     assert all(v > 0.0 for v in stats["step_gap_seconds"].values())
 
 
-def test_a_pool_without_the_joint_entry_sends_a_chunk_and_a_step(lm):
-    """``TransformerLM`` has no ``decode_step_with_chunk``: a chunk due
-    while a slot decodes goes out as the chunk program, and the decode
-    step follows it, two programs a pass as ever; no joint program is
-    built and the counters say so."""
+def test_a_pool_with_the_joint_entry_carries_a_chunk_on_its_step(lm):
+    """``TransformerLM`` has ``decode_step_with_chunk``, so its pool is a
+    joint one: B's prompt arrives while A decodes and each of its chunks
+    rides a decode step as one program; the tokens are ``generate()``'s
+    and the counters say what went where."""
     rng = np.random.default_rng(29)
     eng = GenerationScheduler(lm, slots=2, prefill_chunk=8, start=False)
+    pool = eng.pool
+    assert pool.joint and pool.chunk_widths == (1, 2, 4, 8)
+    log = joint_pass.logged_pool_calls(pool)
+    eng.start()
+    a_prompt = rng.integers(1, 51, 4).astype(np.int32)
+    b_prompt = rng.integers(1, 51, 20).astype(np.int32)
+    try:
+        a, b = joint_pass.serve_beside_a_decoding_slot(
+            eng, a_prompt, [b_prompt], timeout=120)
+        eng.shutdown()
+        stats = eng.stats()
+    finally:
+        eng.shutdown()
+    np.testing.assert_array_equal(a, solo(lm, a_prompt, 30))
+    np.testing.assert_array_equal(b, solo(lm, b_prompt, 6))
+    # B's 19 positions: two chunks of 8 and a suffix-aligned one of 4
+    assert "alone" not in log and log.count("step+chunk") == 3
+    assert (stats["chunks_joint"], stats["chunks_alone"]) == (3, 0)
+    assert stats["step_gaps"]["prefill"] == 3
+    # the pool's first chunk compiled every chunk program, the lone one
+    # at the full width among them, though nothing ran it
+    counts = pool.trace_counts
+    assert counts["decode_with_chunk"] == {1: 1, 2: 1, 4: 1, 8: 1}
+    assert counts["chunk_prefill"] == {8: 1}
+    assert counts["decode"] == 1
+
+
+def test_a_pool_without_the_joint_entry_sends_a_chunk_and_a_step(lm):
+    """A model without ``decode_step_with_chunk`` (here a ``TransformerLM``
+    whose class hides it): a chunk due while a slot decodes goes out as
+    the chunk program, and the decode step follows it, two programs a
+    pass; no joint program is built and the counters say so."""
+    rng = np.random.default_rng(29)
+    eng = GenerationScheduler(joint_pass.without_the_joint_entry(lm),
+                              slots=2, prefill_chunk=8, start=False)
     pool = eng.pool
     assert not pool.joint
     log = joint_pass.logged_pool_calls(pool)

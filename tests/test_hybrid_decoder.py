@@ -698,7 +698,10 @@ def test_a_joint_pool_keeps_the_upper_four_chunk_widths(model):
     power of two.  The rows are still ``generate()``'s."""
     m, _ = model
     assert SlotPool(m, slots=2, ring_margin=32).chunk_widths == (4, 8, 16, 32)
-    assert SlotPool(_tiny_lm().eval_mode(), slots=2,
+    lm = _tiny_lm().eval_mode()
+    assert SlotPool(lm, slots=2, ring_margin=32).chunk_widths \
+        == (4, 8, 16, 32)
+    assert SlotPool(joint_pass.without_the_joint_entry(lm), slots=2,
                     ring_margin=32).chunk_widths == (1, 2, 4, 8, 16, 32)
     engine = GenerationScheduler(m, slots=2, prefill_chunk=16)
     rng = np.random.default_rng(8)

@@ -3,8 +3,9 @@ record a decode step's read-back, in which the engine thread's seven phases
 tile the step gap, the prefill programs that the device ran in the gap are
 named, and the old sums (``step_gaps``, ``step_gap_seconds``,
 ``chunks_joint``, ``chunks_alone``, ``tokens_emitted``) are the records
-added up.  On the CPU backend, with a tiny ``TransformerLM`` (two programs a
-chunk pass) and a tiny ``HybridDecoder`` (the joint program)."""
+added up.  On the CPU backend, with a tiny ``TransformerLM`` and a tiny
+``HybridDecoder`` (the joint program) and a ``TransformerLM`` whose class
+hides the joint entry (two programs a chunk pass)."""
 
 import gc
 import http.client
@@ -79,15 +80,18 @@ def _logged_chunks(pool):
     return sent
 
 
-@pytest.fixture(scope="module", params=["lm", "hybrid"])
+@pytest.fixture(scope="module", params=["lm", "two-program-lm", "hybrid"])
 def served(request):
     """A short request decodes; two longer prompts arrive at its fifth token
     and prefill in chunks beside it.  ``(stats, records, chunks sent)`` after
-    a drained shutdown."""
-    model = _lm() if request.param == "lm" else _hybrid()
+    a drained shutdown.  ``two-program-lm`` hides the joint entry: its pool
+    sends the chunks alone."""
+    model = _hybrid() if request.param == "hybrid" else _lm()
+    if request.param == "two-program-lm":
+        model = joint_pass.without_the_joint_entry(model)
     engine = GenerationScheduler(model, slots=3, prefill_chunk=CHUNK,
                                  start=False)
-    assert engine.pool.joint == (request.param == "hybrid")
+    assert engine.pool.joint == (request.param != "two-program-lm")
     sent = _logged_chunks(engine.pool)
     engine.start()
     try:

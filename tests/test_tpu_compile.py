@@ -19,6 +19,7 @@ so two processes that compile for a described chip cannot overlap; the
 CPU-platform workers of test_distributed_multiprocess.py never load it.
 """
 
+import collections
 import functools
 import os
 import re
@@ -384,6 +385,72 @@ def test_opt_pool_leaf_keeps_the_vector_unit_body_and_its_block_on_v5e(v5e):
     # K and V blocks of 256 places twice over: 8 MiB; the body's scratch
     # (scores, weights and a lane-wise context a query head): 1.1 MiB
     assert 9.0e6 < int(used) < 9.8e6, used
+
+
+def test_opt_pool_joint_program_reads_each_layers_weights_once_on_v5e(
+        v5e, monkeypatch):
+    """OPT-1.3B's pool as the benchmark serves it (6 slots of 2,048
+    places, float32 rows, bfloat16 weights; model and rows as shapes),
+    its decode step that carries a 64-token chunk
+    (``TransformerLM.decode_step_with_chunk``) as a TPU process traces it,
+    compiled for the described v5e: no copy or transpose of a whole pool
+    leaf and no ``while``; the rows attend through the ragged decode
+    kernel, one call a layer; and **a block's output projection and both
+    feed-forward weights (five sixths of its bytes) are each the operand
+    of one product** over the 6 rows and the 64 chunk tokens together,
+    where the chunk program followed by the step made two.  Queries, keys
+    and values are projected a half: sharing those products too measured
+    0.5 ms a pass slower on the chip (PERF.md section 6, PR 43)."""
+    from bigdl_tpu.models import transformer_lm
+    from bigdl_tpu.ops import attention_kernels
+    from bigdl_tpu.serving.generation import SlotPool
+    monkeypatch.setattr(attention_kernels, "_on_tpu", lambda: True)
+    slots, max_len, chunk, layers, hidden, ffn = 6, 2048, 64, 24, 2048, 8192
+    sds = functools.partial(jax.ShapeDtypeStruct,
+                            sharding=SingleDeviceSharding(v5e.devices[0]))
+    abstract = jax.eval_shape(lambda: transformer_lm(
+        vocab_size=50272, hidden_size=hidden, num_layers=layers,
+        num_heads=32, filter_size=ffn, max_len=max_len).eval_mode())
+    assert abstract.blocks[0].ffn.filter_layer.weight.shape == (ffn, hidden)
+    model = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, jnp.bfloat16), abstract)
+    caches = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: abstract.init_cache(slots, jnp.float32)))
+    pool = object.__new__(SlotPool)
+    pool.slots = slots
+    pool.cache_layers = tuple(abstract.cache_layers())
+    pool.expert_layers = 0
+    pool.trace_counts = dict(TRACE_COUNTS, decode_with_chunk={})
+    pool._build_programs()
+    text = _lower(pool, "decode_with_chunk", model, caches,
+                  sds((0,), jnp.int32), sds, slots, chunk).compile().as_text()
+    assert not re.findall(
+        r"= f32\[6,32,(?:64,2048|2048,64)\]\S* "
+        r"(?:copy|copy-start|transpose|scatter)\(", text)
+    assert " while(" not in text
+    assert text.count('custom_call_target="tpu_custom_call"') == layers
+    # the program's products: q, k and v a half, onto the heads; the
+    # output projection and both feed-forward layers over the 70 rows and
+    # chunk tokens together; the chunk's scores and context; the head over
+    # the 6 rows alone
+    both = slots + chunk
+    by_result = collections.Counter(re.findall(
+        r"= (\w+\[[\d,]*\])\S* convolution\(", text))
+    assert by_result == {
+        "bf16[%d,32,64]" % slots: 3 * layers,
+        "bf16[%d,32,64]" % chunk: 3 * layers,
+        "bf16[%d,%d]" % (both, hidden): 2 * layers,
+        "bf16[%d,%d]" % (both, ffn): layers,
+        "f32[32,%d,%d]" % (chunk, max_len): layers,
+        "bf16[32,%d,64]" % chunk: layers,
+        "bf16[%d,50273]" % slots: 1}, by_result
+    # and by operand: the feed-forward's first weight, as the leaf lies
+    shape_of = dict(re.findall(r"(%[\w.-]+) = (\w+\[[\d,]*\])", text))
+    reads = [args for args in re.findall(r" convolution\(([^)]*)\)", text)
+             if "bf16[%d,%d]" % (ffn, hidden) in [
+                 shape_of[name] for name in re.findall(r"%[\w.-]+", args)]]
+    assert len(reads) == layers
 
 
 def test_pool_decode_step_lowers_to_no_scatter_over_the_pool(pool):

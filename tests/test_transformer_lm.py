@@ -13,6 +13,8 @@ from bigdl_tpu.utils import set_seed
 
 import bigdl_tpu.nn as nn
 
+import joint_pass
+
 
 def _model(**kw):
     set_seed(0)
@@ -136,6 +138,20 @@ def test_incremental_decode_matches_full_forward():
         logits, caches = m.decode_step(toks[:, t:t + 1], t, caches)
         np.testing.assert_allclose(np.asarray(logits), full[:, t],
                                    rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("scenario", joint_pass.SCENARIOS)
+def test_the_joint_pass_equals_the_chunk_program_then_the_step(scenario):
+    """``decode_step_with_chunk`` (one walk of the blocks, the decode rows
+    and the chunk's tokens one residual stream through each block's norms,
+    output projection and feed-forward) against the pooled
+    ``prefill_chunk`` followed by ``decode_step`` on the same caches, at
+    float32 ``highest``: logits of the live rows, every cache leaf and the
+    flags.  ``joint_pass.py`` has the four passes and the comparison."""
+    m = _model(max_len=24).eval_mode()
+    with jax.default_matmul_precision("highest"):
+        joint_pass.assert_joint_pass_equals_chunk_then_step(
+            m, 4, 50, scenario)
 
 
 @pytest.mark.slow
