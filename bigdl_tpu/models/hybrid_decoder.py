@@ -1,8 +1,11 @@
 """Decoder-only language model built from a per-layer list of kinds:
 window and full grouped-query attention layers, layers whose attention
-runs in parallel with a state-space mixer, latent-attention layers, a
-leading dense gated feed-forward layer, and a held share of
-sigmoid-routed gated experts (with or without a shared expert).
+runs in parallel with a state-space mixer, latent-attention layers,
+layers that are a state-space mixer alone, layers that keep nothing and
+read what an earlier layer made in the same pass (its row of keys and
+values, or its mixer's scan output), a leading dense gated feed-forward
+layer, and a held share of sigmoid-routed gated experts (with or without
+a shared expert).
 
 The block of ``mimo_v2`` (MiMo-V2.5), pre-norm and sequential:
 ``h = x + Attn_i(RMSNorm(x))``, ``y = h + FFN_i(RMSNorm(h))``.
@@ -32,6 +35,20 @@ row a position shared by all heads, YaRN rotary frequencies), the first
 a dense gated layer and the rest expert layers whose routed sum is
 scaled by ``routed_scaling_factor`` and stands beside a shared expert.
 
+The walk of ``phi4_flash`` (Phi-4-mini-flash-reasoning; SambaY's
+decoder-hybrid-decoder) is sequential too, with LayerNorms (gain and
+bias), differential attention that encodes no position
+(:class:`bigdl_tpu.nn.differential_attention.DifferentialAttention`) and
+a head tied to the embedding: Mamba-1 mixers
+(:class:`bigdl_tpu.nn.ssm.Mamba1Mixer`) alternate with window layers in
+the first half; then one more mixer, which hands on its scan output
+``m``, and the one full layer; after them gated memory units on ``m``
+alternate with cross-attention layers that have a query and an output
+projection only and attend the full layer's keys and values.  **Not
+every layer has ``cache["self"]`` any more**: a mixer-only layer keeps a
+state and no row, a unit and a cross layer keep nothing, and a chunk's
+rows stop where the caches stop (:class:`HybridDecoder`).
+
 It keeps the repo's conventions (``TransformerLM``): token ids are
 1-based with 0 as padding, and generation emits ``argmax + 1`` (the
 untied head has exactly ``vocab_size`` rows: none is untrained).  It has
@@ -59,13 +76,15 @@ import jax.numpy as jnp
 
 from bigdl_tpu.core.module import Module, ModuleList, Parameter
 from bigdl_tpu.nn.attention import GroupedQueryAttention
+from bigdl_tpu.nn.differential_attention import DifferentialAttention
 from bigdl_tpu.nn.latent_attention import LatentAttention
 from bigdl_tpu.nn.linear import Linear, LookupTable
 from bigdl_tpu.nn.moe import HeldExperts
-from bigdl_tpu.nn.ssm import Mamba2Mixer
+from bigdl_tpu.nn.ssm import Mamba1Mixer, Mamba2Mixer
 from bigdl_tpu.ops import cache_kernels
 
-__all__ = ["HybridDecoder", "mimo_v2", "falcon_h1", "sarvam_mla"]
+__all__ = ["HybridDecoder", "mimo_v2", "falcon_h1", "sarvam_mla",
+           "phi4_flash"]
 
 ROUTING = 4     # what an expert layer counts: HeldExperts.forward
 
@@ -89,6 +108,23 @@ class RMSNorm(Module):
         ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
         return x * jax.lax.rsqrt(ms + self.eps) \
             * self.weight.astype(jnp.float32)
+
+
+class LayerNorm(Module):
+    """``(x - mean) / sqrt(var + eps) * gain + bias`` in float32."""
+
+    def __init__(self, hidden_size: int, eps: float = 1e-5):
+        super().__init__()
+        self.eps = eps
+        self.weight = Parameter(jnp.ones(hidden_size))
+        self.bias = Parameter(jnp.zeros(hidden_size))
+
+    def forward(self, x):
+        x = x.astype(jnp.float32)
+        x = x - jnp.mean(x, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+        return x * jax.lax.rsqrt(var + self.eps) \
+            * self.weight.astype(jnp.float32) + self.bias.astype(jnp.float32)
 
 
 class GatedFFN(Module):
@@ -121,15 +157,60 @@ def _fresh_state(state, fresh):
         lambda leaf: jnp.where(fresh, jnp.zeros_like(leaf), leaf), state)
 
 
+def _run_mixer(mixer, u, index, pooled, slot, active, valid):
+    """A state-space mixer on ``u`` over a layer's pooled state (None: the
+    whole sequence from zeros) -> what the mixer returns, its state put
+    back into the pool's.  The state has no positions: a chunk (scalar
+    ``index``) reads row ``slot``'s state, or starts from zeros when
+    ``index`` is 0 (whoever held the row before is forgotten), and writes
+    back the state after its last valid token; a per-row step leaves the
+    state of a row that is not ``active`` as it was, and starts a row at
+    position 0 from zeros."""
+    if pooled is None:
+        return mixer.forward(u, None, valid)
+    if jnp.ndim(index) == 1:
+        return mixer.step(u, pooled, active, index == 0)
+    rows = pooled if slot is None else jax.tree_util.tree_map(
+        lambda leaf: jax.lax.dynamic_slice_in_dim(leaf, slot, 1), pooled)
+    s, state, *more = mixer.forward(
+        u, _fresh_state(rows, jnp.asarray(index) == 0), valid)
+    if slot is not None:
+        state = jax.tree_util.tree_map(
+            lambda leaf, row: jax.lax.dynamic_update_slice_in_dim(
+                leaf, row.astype(leaf.dtype), slot, 0),
+            pooled, state)
+    return (s, state, *more)
+
+
 class HybridBlock(Module):
-    def __init__(self, hidden_size: int, attn: GroupedQueryAttention,
-                 ffn: Module, eps: float):
+    """A mixer and a feed-forward, each behind its norm and on the
+    residual stream.  ``norm`` makes the two norms (``RMSNorm`` or
+    ``LayerNorm``).  The mixer here is an attention layer that keeps its
+    own keys and values (``cache["self"]``); the subclasses keep a state
+    beside them, a state alone, or nothing.
+
+    ``walk`` is what one pass hands from block to block beside the
+    residual stream (a dict the decoder makes anew for every pass):
+    ``"memory"``, the scan output of the mixer that hands it on, and
+    ``"row"``, the keys and values of the layer whose row later layers
+    read, as that layer left them in this pass."""
+
+    def __init__(self, hidden_size: int, attn: Optional[Module],
+                 ffn: Module, eps: float, norm=RMSNorm,
+                 shares_row: bool = False):
         super().__init__()
-        self.attn_norm = RMSNorm(hidden_size, eps)
-        self.attn = attn
-        self.ffn_norm = RMSNorm(hidden_size, eps)
+        self.attn_norm = norm(hidden_size, eps)
+        if attn is not None:
+            self.attn = attn
+        self.ffn_norm = norm(hidden_size, eps)
         self.ffn = ffn
         self.sparse = isinstance(ffn, HeldExperts)
+        self.shares_row = bool(shares_row)
+
+    @property
+    def window(self) -> Optional[int]:
+        """The window of the ring this layer keeps, or None."""
+        return getattr(getattr(self, "attn", None), "window", None)
 
     def init_cache(self, batch: int, max_len: int, dtype, ring_margin: int):
         return {"self": self.attn.init_cache(batch, max_len, dtype,
@@ -142,7 +223,7 @@ class HybridBlock(Module):
             else ("full", max_len)
 
     def forward(self, x, index=0, cache=None, pad=None, slot=None,
-                active=None, valid=None):
+                active=None, valid=None, walk=None):
         """``x [B, T, H]`` float32 -> ``(y, caches, counts)``: see
         :meth:`GroupedQueryAttention.forward` for ``index``, ``pad``,
         ``slot`` and ``active``; ``cache`` is the layer's caches
@@ -150,16 +231,19 @@ class HybridBlock(Module):
         (with None: the compact keys and values); ``valid [B, T]`` false
         keeps a token from the experts; ``counts`` is what the expert
         layer did (zeros for a dense layer)."""
-        h, caches = self._mix(x, index, cache, pad, slot, active, valid)
+        h, caches = self._mix(x, index, cache, pad, slot, active, valid,
+                              {} if walk is None else walk)
         y, counts = self._feed_forward(h, valid)
         return y, caches, counts
 
-    def forward_step_and_chunk(self, cache, step, chunk):
+    def forward_step_and_chunk(self, cache, step, chunk, walk=None):
         """A pool's decode rows and a prefill chunk of one of its rows in
         one pass -> ``(y, y_chunk, caches, counts)``.  ``step`` is ``(x
         [S, 1, H], index [S], pad, active [S], valid [S, 1])`` and
         ``chunk`` ``(x [1, W, H], index, pad, slot, valid [1, W])``, each
-        as :meth:`forward` takes them.  The chunk's mixer, then the rows'
+        as :meth:`forward` takes them; ``walk`` is the rows' (what the
+        chunk's mixer would hand on is read by nobody: a chunk's rows
+        stop where the caches stop).  The chunk's mixer, then the rows'
         on the caches it left (what :meth:`forward` on the chunk and then
         on the rows does: ``slot`` itself may be among the rows that
         decode), then **one** feed-forward over both residual streams
@@ -168,21 +252,34 @@ class HybridBlock(Module):
         x, index, pad, active, valid = step
         xc, chunk_index, chunk_pad, slot, chunk_valid = chunk
         hc, cache = self._mix(xc, chunk_index, cache, chunk_pad, slot, None,
-                              chunk_valid)
-        h, cache = self._mix(x, index, cache, pad, None, active, valid)
+                              chunk_valid, {})
+        h, cache = self._mix(x, index, cache, pad, None, active, valid,
+                             {} if walk is None else walk)
         rows = x.shape[0]
         y, counts = self._feed_forward(
             jnp.concatenate([h.reshape(1, rows, -1), hc], axis=1),
             jnp.concatenate([valid.reshape(1, rows), chunk_valid], axis=1))
         return y[0, :rows, None], y[:, rows:], cache, counts
 
-    def _mix(self, x, index, cache, pad, slot, active, valid):
+    def write(self, x, index, cache, slot):
+        """The keys and values of a chunk's rows into the layer's cache
+        (compact, with ``cache`` None) and nothing else: no query, no
+        attention, no feed-forward.  Of a layer whose attention can
+        (``DifferentialAttention.write``)."""
+        n = self.attn_norm(x).astype(self.attn.q_layer.weight.dtype)
+        kv = self.attn.write(n, index,
+                             None if cache is None else cache["self"], slot)
+        return kv if cache is None else {"self": kv}
+
+    def _mix(self, x, index, cache, pad, slot, active, valid, walk):
         """The mixer on the normed input and its residual: ``(h,
         caches)``."""
         n = self.attn_norm(x).astype(self.attn.q_layer.weight.dtype)
         a, kv = self.attn.forward(
             n, index, None if cache is None else cache["self"], pad, slot,
             active)
+        if self.shares_row:
+            walk["row"] = kv
         return x + a, (kv if cache is None else {"self": kv})
 
     def _feed_forward(self, h, valid):
@@ -216,39 +313,108 @@ class ParallelBlock(HybridBlock):
     def cache_kinds(self, max_len: int):
         return {"self": ("full", max_len), "ssm": ("state", None)}
 
-    def _mix(self, x, index, cache, pad, slot, active, valid):
-        """As :meth:`HybridBlock._mix`.  The state has no positions:
-        a chunk (scalar ``index``) reads row ``slot``'s state, or starts
-        from zeros when ``index`` is 0 (whoever held the row before is
-        forgotten), and writes back the state after its last valid
-        token; a per-row step leaves the state of a row that is not
-        ``active`` as it was, and starts a row at position 0 from
-        zeros."""
+    def _mix(self, x, index, cache, pad, slot, active, valid, walk):
+        """As :meth:`HybridBlock._mix`; the state as :func:`_run_mixer`
+        keeps it."""
         m = self.multipliers
         n = self.attn_norm(x)
         dtype = self.attn.q_layer.weight.dtype
         a, kv = self.attn.forward(
             (n * m["attention_in"]).astype(dtype), index,
             None if cache is None else cache["self"], pad, slot, active)
-        u = (n * m["ssm_in"]).astype(dtype)
-        if cache is None:
-            s, state = self.ssm.forward(u, None, valid)
-        elif jnp.ndim(index) == 1:
-            s, state = self.ssm.step(u, cache["ssm"], active, index == 0)
-        else:
-            pooled = cache["ssm"]
-            rows = pooled if slot is None else jax.tree_util.tree_map(
-                lambda leaf: jax.lax.dynamic_slice_in_dim(leaf, slot, 1),
-                pooled)
-            s, state = self.ssm.forward(
-                u, _fresh_state(rows, jnp.asarray(index) == 0), valid)
-            if slot is not None:
-                state = jax.tree_util.tree_map(
-                    lambda leaf, row: jax.lax.dynamic_update_slice_in_dim(
-                        leaf, row.astype(leaf.dtype), slot, 0),
-                    pooled, state)
+        s, state = _run_mixer(
+            self.ssm, (n * m["ssm_in"]).astype(dtype), index,
+            None if cache is None else cache["ssm"], slot, active, valid)
         return x + s * m["ssm_out"] + a * m["attention_out"], \
             {"self": kv, "ssm": state}
+
+
+class MixerBlock(HybridBlock):
+    """A state-space mixer alone (:class:`Mamba1Mixer`): the layer keeps
+    a **state and no row**.  With ``hands_on`` its scan output of this
+    pass goes into ``walk["memory"]``, for the gated memory units
+    after it."""
+
+    def __init__(self, hidden_size: int, ssm: Mamba1Mixer, ffn: Module,
+                 eps: float, norm=RMSNorm, hands_on: bool = False):
+        super().__init__(hidden_size, None, ffn, eps, norm)
+        self.ssm = ssm
+        self.hands_on = bool(hands_on)
+
+    def init_cache(self, batch: int, max_len: int, dtype, ring_margin: int):
+        return {"ssm": self.ssm.init_state(batch, dtype)}
+
+    def cache_kinds(self, max_len: int):
+        return {"ssm": ("state", None)}
+
+    def _mix(self, x, index, cache, pad, slot, active, valid, walk):
+        u = self.attn_norm(x).astype(self.ssm.in_proj.weight.dtype)
+        s, state, y = _run_mixer(
+            self.ssm, u, index, None if cache is None else cache["ssm"],
+            slot, active, valid)
+        if self.hands_on:
+            walk["memory"] = y
+        return x + s, {"ssm": state}
+
+
+class CrossBlock(HybridBlock):
+    """An attention layer with a query and an output projection only,
+    over **another layer's row** (``reads``: that layer's index): it
+    keeps nothing, and attends ``walk["row"]``, the row as that layer
+    wrote it in this pass."""
+
+    def __init__(self, hidden_size: int, attn: DifferentialAttention,
+                 ffn: Module, eps: float, norm=RMSNorm, reads: int = 0):
+        super().__init__(hidden_size, attn, ffn, eps, norm)
+        self.reads = int(reads)
+
+    def init_cache(self, batch: int, max_len: int, dtype, ring_margin: int):
+        return {}
+
+    def cache_kinds(self, max_len: int):
+        return {"reads": ("shared", self.reads)}
+
+    def _mix(self, x, index, cache, pad, slot, active, valid, walk):
+        n = self.attn_norm(x).astype(self.attn.q_layer.weight.dtype)
+        a, _ = self.attn.forward(n, index, cache, pad, slot, active,
+                                 shared=walk["row"])
+        return x + a, {}
+
+
+class GatedMemoryUnit(Module):
+    """``W_2( silu(W_1 n) * m )``, no bias: the layer's input gates a
+    memory ``m`` that an earlier layer's mixer handed on (the same
+    position's, of the same pass)."""
+
+    def __init__(self, hidden_size: int, inner: int):
+        super().__init__()
+        self.in_proj = Linear(hidden_size, inner, with_bias=False)
+        self.out_proj = Linear(inner, hidden_size, with_bias=False)
+
+    def forward(self, n, memory):
+        with jax.named_scope("gmu"):
+            g = jax.nn.silu(_product(n, self.in_proj)) * memory
+            return _product(g.astype(n.dtype), self.out_proj)
+
+
+class MemoryBlock(HybridBlock):
+    """A gated memory unit on ``walk["memory"]``: the layer keeps
+    nothing at all."""
+
+    def __init__(self, hidden_size: int, unit: GatedMemoryUnit, ffn: Module,
+                 eps: float, norm=RMSNorm):
+        super().__init__(hidden_size, None, ffn, eps, norm)
+        self.unit = unit
+
+    def init_cache(self, batch: int, max_len: int, dtype, ring_margin: int):
+        return {}
+
+    def cache_kinds(self, max_len: int):
+        return {}
+
+    def _mix(self, x, index, cache, pad, slot, active, valid, walk):
+        n = self.attn_norm(x).astype(self.unit.in_proj.weight.dtype)
+        return x + self.unit.forward(n, walk["memory"]), {}
 
 
 class HybridDecoder(Module):
@@ -257,13 +423,34 @@ class HybridDecoder(Module):
 
     ``layer_kinds[i]`` is ``"full"``, ``"window"``, ``"parallel"`` (a
     full layer beside a state-space mixer built from ``ssm``, the
-    arguments of :class:`Mamba2Mixer`) or ``"latent"`` (built from
-    ``latent``, the arguments of :class:`LatentAttention`); ``sparse[i]``
+    arguments of :class:`Mamba2Mixer`), ``"latent"`` (built from
+    ``latent``, the arguments of :class:`LatentAttention`), or, with
+    ``shared``, one of the kinds below; ``sparse[i]``
     says whether layer ``i``'s feed-forward is the expert layer, whose
     routed sum is scaled by ``expert_scale`` and which has a shared
     expert of ``shared_size`` where that is not 0.  ``multipliers`` are
     constants by name (absent: 1): ``embedding``, ``lm_head``, ``key``,
-    ``mlp_gate``, ``mlp_down``, and a parallel block's four."""
+    ``mlp_gate``, ``mlp_down``, and a parallel block's four.
+
+    ``shared`` (a dict) builds the decoder-hybrid-decoder walk of
+    ``phi4_flash``: every attention layer differential with biases and no
+    positions encoded (:class:`DifferentialAttention`), LayerNorms with a
+    bias, the head tied to the embedding, and three more kinds:
+    ``"selective"``, a :class:`Mamba1Mixer` alone, built from
+    ``shared["mixer"]`` (a **state and no row**); ``"memory"``, a gated
+    memory unit on the scan output that layer ``shared["memory_from"]``
+    hands on (**nothing kept**); ``"cross"``, a query and an output
+    projection over the row of the full layer ``shared["row_from"]``
+    (**nothing kept**).  **Not every layer has ``cache["self"]``, and a
+    layer may have no cache at all**: the walk carries ``walk`` beside
+    the residual stream (:class:`HybridBlock`).  **A chunk's rows leave
+    the walk where the caches stop**: :meth:`prefill_chunk`,
+    :meth:`prefill_kv` and the chunk half of
+    :meth:`decode_step_with_chunk` write caches and give no logits, so
+    where the layers after the last one that keeps a cache keep none, a
+    chunk's rows walk the layers before it whole, write that layer's keys
+    and values (:meth:`HybridBlock.write`: no query, no attention, no
+    feed-forward) and stop (``chunk_layers``)."""
 
     def __init__(self, vocab_size: int, hidden_size: int,
                  layer_kinds: Sequence[str], sparse: Sequence[bool],
@@ -278,7 +465,8 @@ class HybridDecoder(Module):
                  ssm: Optional[Dict[str, Any]] = None,
                  multipliers: Optional[Dict[str, float]] = None,
                  latent: Optional[Dict[str, Any]] = None,
-                 shared_size: int = 0, expert_scale: float = 1.0):
+                 shared_size: int = 0, expert_scale: float = 1.0,
+                 shared: Optional[Dict[str, Any]] = None):
         super().__init__()
         if len(layer_kinds) != len(sparse):
             raise ValueError("one kind and one sparse flag a layer")
@@ -291,13 +479,26 @@ class HybridDecoder(Module):
         self.embedding.weight = Parameter(
             self.embedding.weight * hidden_size ** -0.5)
         blocks = []
-        for kind, is_sparse in zip(layer_kinds, sparse):
-            if kind not in ("full", "window", "parallel", "latent"):
-                raise ValueError(f"layer kind {kind!r}: 'full', 'window', "
-                                 f"'parallel' or 'latent'")
+        kinds = ("full", "window", "parallel", "latent") + (
+            ("selective", "memory", "cross") if shared else ())
+        norm = LayerNorm if shared else RMSNorm
+        for depth, (kind, is_sparse) in enumerate(zip(layer_kinds, sparse)):
+            if kind not in kinds:
+                raise ValueError(f"layer kind {kind!r}: one of {kinds} "
+                                 f"(the last three with shared=)")
             win = kind == "window"
             attends = "window" if win else "full"   # a parallel layer: full
-            if kind == "latent":
+            if shared:
+                if is_sparse or kind in ("parallel", "latent"):
+                    raise ValueError("shared=: dense feed-forwards, and "
+                                     "'selective', 'window', 'full', "
+                                     "'memory' and 'cross' layers")
+                attn = None if kind in ("selective", "memory") else \
+                    DifferentialAttention(
+                        hidden_size, num_heads, kv_heads["full"], head_dim,
+                        depth, window=window if win else None,
+                        cross=kind == "cross", eps=eps)
+            elif kind == "latent":
                 if latent is None:
                     raise ValueError("a latent layer needs latent=")
                 attn = LatentAttention(hidden_size, num_heads, eps=eps,
@@ -323,11 +524,46 @@ class HybridDecoder(Module):
                 blocks.append(ParallelBlock(
                     hidden_size, attn, Mamba2Mixer(hidden_size, eps=eps,
                                                    **ssm), ffn, eps, mult))
+            elif kind == "selective":
+                blocks.append(MixerBlock(
+                    hidden_size, Mamba1Mixer(hidden_size, **shared["mixer"]),
+                    ffn, eps, norm, hands_on=depth == shared["memory_from"]))
+            elif kind == "memory":
+                blocks.append(MemoryBlock(
+                    hidden_size, GatedMemoryUnit(
+                        hidden_size, shared["mixer"]["inner"]), ffn, eps,
+                    norm))
+            elif kind == "cross":
+                blocks.append(CrossBlock(hidden_size, attn, ffn, eps, norm,
+                                         reads=shared["row_from"]))
             else:
-                blocks.append(HybridBlock(hidden_size, attn, ffn, eps))
+                blocks.append(HybridBlock(
+                    hidden_size, attn, ffn, eps, norm,
+                    shares_row=bool(shared) and depth == shared["row_from"]))
+        if shared:
+            source = {"memory": shared["memory_from"],
+                      "cross": shared["row_from"]}
+            wants = {"memory": "selective", "cross": "full"}
+            for depth, kind in enumerate(layer_kinds):
+                if kind in source and not (
+                        source[kind] < depth
+                        and layer_kinds[source[kind]] == wants[kind]):
+                    raise ValueError(
+                        f"layer {depth} ({kind!r}) reads layer "
+                        f"{source[kind]}: an earlier {wants[kind]!r} layer")
         self.blocks = ModuleList(blocks)
-        self.final_norm = RMSNorm(hidden_size, eps)
-        self.lm_head = Linear(hidden_size, vocab_size, with_bias=False)
+        # where a chunk's rows stop (the class docstring): the layers they
+        # walk whole, and whether they then write one layer's keys and
+        # values more
+        last = max(i for i, blk in enumerate(blocks) if blk.cache_kinds(
+            max_len) and not isinstance(blk, CrossBlock))
+        self.chunk_writes = last < len(blocks) - 1 and hasattr(
+            getattr(blocks[last], "attn", None), "write")
+        self.chunk_layers = last + (not self.chunk_writes)
+        self.final_norm = norm(hidden_size, eps)
+        self.tied = bool(shared)
+        if not self.tied:
+            self.lm_head = Linear(hidden_size, vocab_size, with_bias=False)
 
     # ---- what the slot pool asks ------------------------------------------
 
@@ -340,7 +576,11 @@ class HybridDecoder(Module):
         leaf ``"k"`` is the rotary key and ``"v"`` the compressed row.  A
         parallel layer declares two by name: ``{"self": ("full",
         max_len), "ssm": ("state", None)}``, a state having no
-        positions."""
+        positions.  A layer with a state alone declares ``{"ssm":
+        ("state", None)}``; a layer that keeps nothing declares ``{}``,
+        and one that keeps nothing and attends another layer's row names
+        that layer: ``{"reads": ("shared", 17)}`` (no storage: the pool
+        counts it among that row's readers)."""
         return tuple(blk.cache_kinds(self.max_len) for blk in self.blocks)
 
     def expert_layers(self) -> int:
@@ -366,7 +606,7 @@ class HybridDecoder(Module):
         reads of its full rows by this."""
         blocks = {blk.attn.decode_key_block(layer["self"])
                   for blk, layer in zip(self.blocks, caches["layers"])
-                  if blk.attn.window is None}
+                  if "self" in layer and blk.window is None}
         return blocks.pop() if len(blocks) == 1 else None
 
     def cache_write_programs(self, caches) -> int:
@@ -380,9 +620,10 @@ class HybridDecoder(Module):
         rows = caches["pad"].shape[0]
         programs = 1
         for layer in caches["layers"]:
-            k, v = layer["self"]["k"], layer["self"]["v"]
-            programs += 1 if cache_kernels.cache_row_writer(
-                k.shape, v.shape, k.dtype) is not None else 2 * rows
+            if "self" in layer:
+                k, v = layer["self"]["k"], layer["self"]["v"]
+                programs += 1 if cache_kernels.cache_row_writer(
+                    k.shape, v.shape, k.dtype) is not None else 2 * rows
             programs += 2 * ("ssm" in layer)
         return programs
 
@@ -400,8 +641,10 @@ class HybridDecoder(Module):
             else x * self.embedding_multiplier
 
     def _logits(self, x):
-        w = self.lm_head.weight
-        y = _product(self.final_norm(x).astype(w.dtype), self.lm_head)
+        # a tied head scores with the embedding table itself
+        w = (self.embedding if self.tied else self.lm_head).weight
+        y = jnp.einsum("...i,oi->...o", self.final_norm(x).astype(w.dtype),
+                       w, preferred_element_type=jnp.float32)
         return y if self.lm_head_multiplier == 1.0 \
             else y * self.lm_head_multiplier
 
@@ -411,24 +654,43 @@ class HybridDecoder(Module):
             raise ValueError(
                 f"sequence length {T} exceeds max_len={self.max_len}")
         pad = tokens == 0
-        x = self._embed(tokens)
+        x, walk = self._embed(tokens), {}
         for blk in self.blocks:
-            x, _, _ = blk.forward(x, pad=pad, valid=~pad)
+            x, _, _ = blk.forward(x, pad=pad, valid=~pad, walk=walk)
         return self._logits(x)
+
+    def _chunk_walk(self, x, caches, index, pad, slot, valid):
+        """A chunk's rows ``x`` through the layers that keep a cache:
+        ``chunk_layers`` blocks whole (on each layer's cache; compact
+        where ``caches`` is None), then, where the walk stops short of
+        the model's end, the next block's keys and values alone
+        (:meth:`HybridBlock.write`), and ``{}`` for every layer after it
+        -> ``(layers, routing)``."""
+        layers, routing, walk = [], jnp.zeros((ROUTING,), jnp.int32), {}
+        for i, blk in enumerate(self.blocks):
+            cache = None if caches is None else caches["layers"][i]
+            if i < self.chunk_layers:
+                x, kv, counts = blk.forward(x, index, cache, pad, slot,
+                                            valid=valid, walk=walk)
+                routing = routing + counts
+            elif i == self.chunk_layers and self.chunk_writes:
+                kv = blk.write(x, index, cache, slot)
+            else:
+                kv = {}
+            layers.append(kv)
+        return layers, routing
 
     def prefill_kv(self, ptoks):
         """Compact per-layer keys and values ``[B, Hkv, T, d]`` of every
-        position of ``ptoks`` (a parallel layer: ``{"self": those, "ssm":
-        the state after each row's last real token}``), the ``[B, T]``
-        padding flags, and the expert layers' ``routing``: what a
-        bucketed prefill scatters into slots."""
+        position of ``ptoks`` (a layer with a state: ``{"self": those,
+        "ssm": the state after each row's last real token}``, or the
+        state alone; ``{}`` for a layer that keeps nothing), the ``[B,
+        T]`` padding flags, and the expert layers' ``routing``: what a
+        bucketed prefill scatters into slots.  The rows walk what a
+        chunk's rows walk (:meth:`_chunk_walk`)."""
         pad = ptoks == 0
-        x = self._embed(ptoks)
-        layers, routing = [], jnp.zeros((ROUTING,), jnp.int32)
-        for blk in self.blocks:
-            x, kv, counts = blk.forward(x, pad=pad, valid=~pad)
-            layers.append(kv)
-            routing = routing + counts
+        layers, routing = self._chunk_walk(self._embed(ptoks), None, 0, pad,
+                                           None, ~pad)
         return layers, pad, routing
 
     def _chunk_flags(self, toks, index, caches, slot):
@@ -436,7 +698,8 @@ class HybridDecoder(Module):
         wider than a ring has room for)."""
         _B, W = toks.shape
         for blk, cache in zip(self.blocks, caches["layers"]):
-            win, R = blk.attn.window, cache["self"]["k"].shape[2] - 1
+            win = blk.window
+            R = 0 if win is None else cache["self"]["k"].shape[2] - 1
             if win is not None and R < min(self.max_len, win + W - 1):
                 raise ValueError(
                     f"a chunk of {W} positions needs a ring of "
@@ -471,13 +734,8 @@ class HybridDecoder(Module):
         have ``W - 1`` places beside its window: the chunk is written
         before it is attended."""
         pad = self._chunk_flags(toks, index, caches, slot)
-        x = self._embed(toks)
-        new_layers, routing = [], jnp.zeros((ROUTING,), jnp.int32)
-        for blk, cache in zip(self.blocks, caches["layers"]):
-            x, kv, counts = blk.forward(x, index, cache, pad, slot,
-                                        valid=toks != 0)
-            new_layers.append(kv)
-            routing = routing + counts
+        new_layers, routing = self._chunk_walk(self._embed(toks), caches,
+                                               index, pad, slot, toks != 0)
         return dict(caches, layers=new_layers, pad=pad), routing
 
     def decode_step(self, tokens, index, caches, with_logits=True,
@@ -495,11 +753,12 @@ class HybridDecoder(Module):
             pad = jax.lax.dynamic_update_slice(caches["pad"], flag,
                                                (0, index))
             valid = ~flag if active is None else ~flag & active[:, None]
-        x = self._embed(tokens)
+        x, walk = self._embed(tokens), {}
         new_layers, routing = [], jnp.zeros((ROUTING,), jnp.int32)
         for blk, cache in zip(self.blocks, caches["layers"]):
             x, kv, counts = blk.forward(x, index, cache, pad,
-                                        active=active, valid=valid)
+                                        active=active, valid=valid,
+                                        walk=walk)
             new_layers.append(kv)
             routing = routing + counts
         new_caches = dict(caches, layers=new_layers, pad=pad)
@@ -520,16 +779,26 @@ class HybridDecoder(Module):
         ``index [B]``, ``active [B]``; ``toks [1, W]`` into row ``slot``
         at ``chunk_index``.  Chunk first, then step, layer by layer: row
         ``slot`` may decode in the same pass (its last chunk), and then
-        attends what the chunk wrote.  An expert layer counts one call."""
+        attends what the chunk wrote.  An expert layer counts one call.
+        Where the chunk's rows stop before the model's end
+        (``chunk_layers``), the layers after that run over the decode
+        rows alone."""
         chunk_pad = self._chunk_flags(toks, chunk_index, caches, slot)
         index, pad, valid = self._row_flags(tokens, index, chunk_pad, active)
         chunk_valid = toks != 0
-        x, xc = self._embed(tokens), self._embed(toks)
+        x, xc, walk = self._embed(tokens), self._embed(toks), {}
         new_layers, routing = [], jnp.zeros((ROUTING,), jnp.int32)
-        for blk, cache in zip(self.blocks, caches["layers"]):
-            x, xc, kv, counts = blk.forward_step_and_chunk(
-                cache, (x, index, pad, active, valid),
-                (xc, chunk_index, chunk_pad, slot, chunk_valid))
+        for i, (blk, cache) in enumerate(zip(self.blocks, caches["layers"])):
+            if i < self.chunk_layers:
+                x, xc, kv, counts = blk.forward_step_and_chunk(
+                    cache, (x, index, pad, active, valid),
+                    (xc, chunk_index, chunk_pad, slot, chunk_valid), walk)
+            else:
+                if i == self.chunk_layers and self.chunk_writes:
+                    cache = blk.write(xc, chunk_index, cache, slot)
+                x, kv, counts = blk.forward(x, index, cache, pad,
+                                            active=active, valid=valid,
+                                            walk=walk)
             new_layers.append(kv)
             routing = routing + counts
         return self._logits(x)[:, 0], \
@@ -749,3 +1018,72 @@ def sarvam_mla(config: Dict[str, Any], max_len: int) -> HybridDecoder:
         shared_size=c.get("num_shared_experts", 0)
         * c["moe_intermediate_size"],
         expert_scale=float(c.get("routed_scaling_factor") or 1.0))
+
+
+_PHI4_FLASH_REFUSED = ("mlp_bias", "lm_head_bias", "embd_pdrop",
+                       "resid_pdrop", "attention_dropout", "rope_scaling",
+                       "mamba_proj_bias", "use_positional_embedding")
+
+
+def phi4_flash(config: Dict[str, Any], max_len: int) -> HybridDecoder:
+    """The model from the keys of a public ``phi4flash`` ``config.json``
+    (Phi-4-mini-flash-reasoning; the SambaY decoder-hybrid-decoder,
+    arXiv:2507.06607), ``L = num_hidden_layers`` layers: in the first
+    half a Mamba-1 mixer on the even layers (one in ``mb_per_layer`` = 2)
+    and differential attention over the last ``sliding_window`` positions
+    on the odd ones; layer ``L/2`` a Mamba-1 mixer that also hands on its
+    scan output; layer ``L/2 + 1`` differential attention over
+    everything, whose keys and values are the one full row; after it a
+    gated memory unit on the even layers and differential **cross**
+    attention to that row on the odd ones.  LayerNorms with a bias, a
+    gated feed-forward of ``intermediate_size`` in every layer, a head
+    tied to the embedding, no position encoded anywhere.  The mixer's
+    sizes are ``mamba_d_state`` (16), ``mamba_d_conv`` (4),
+    ``mamba_expand`` (2) and ``mamba_dt_rank`` (``"auto"``: ``ceil(hidden
+    / 16)``), the public configuration class's defaults where the
+    ``config.json`` has none.  What is not built is refused by name:
+    biases on the feed-forward or the head, dropout, a scaled or any
+    rotary embedding, an untied head, another activation than silu,
+    another ``mb_per_layer`` than 2, a depth that is not whole periods
+    (a multiple of 4, at least 8)."""
+    c = config
+    for key in _PHI4_FLASH_REFUSED:
+        if c.get(key):
+            raise ValueError(f"phi4_flash: {key}={c[key]!r} is not built")
+    if not c.get("tie_word_embeddings", True):
+        raise ValueError("phi4_flash: tie_word_embeddings=False is not "
+                         "built (the head is the embedding)")
+    if c.get("hidden_act", "silu") != "silu":
+        raise ValueError(f"phi4_flash: hidden_act={c['hidden_act']!r} is "
+                         f"not built (silu)")
+    if c.get("mb_per_layer", 2) != 2:
+        raise ValueError(f"phi4_flash: mb_per_layer={c['mb_per_layer']!r} "
+                         f"is not built (2)")
+    n = c["num_hidden_layers"]
+    if n % 4 or n < 8:
+        raise ValueError(f"phi4_flash: num_hidden_layers={n} is not built "
+                         f"(whole periods: a multiple of 4, at least 8)")
+    hidden, heads = c["hidden_size"], c["num_attention_heads"]
+    if hidden % heads:
+        raise ValueError("phi4_flash: hidden_size is num_attention_heads "
+                         "heads")
+    half = n // 2
+    kinds = [("selective" if i % 2 == 0 else "window") if i < half else
+             "selective" if i == half else "full" if i == half + 1 else
+             ("memory" if i % 2 == 0 else "cross") for i in range(n)]
+    rank = c.get("mamba_dt_rank", "auto")
+    return HybridDecoder(
+        vocab_size=c["vocab_size"], hidden_size=hidden, layer_kinds=kinds,
+        sparse=[False] * n, num_heads=heads, head_dim=hidden // heads,
+        v_head_dim=hidden // heads,
+        kv_heads={"full": c["num_key_value_heads"]}, rope_theta={},
+        rotary_dim=0, window=c["sliding_window"], window_sink=False,
+        value_scale=1.0, dense_size=c["intermediate_size"], expert_size=0,
+        num_experts=0, top_k=0, eps=c.get("layer_norm_eps", 1e-5),
+        max_len=max_len,
+        shared=dict(
+            memory_from=half, row_from=half + 1,
+            mixer=dict(inner=c.get("mamba_expand", 2) * hidden,
+                       state_size=c.get("mamba_d_state", 16),
+                       dt_rank=None if rank == "auto" else int(rank),
+                       conv_width=c.get("mamba_d_conv", 4))))

@@ -18,6 +18,7 @@ from bigdl_tpu.nn.criterion import *       # noqa: F401,F403
 from bigdl_tpu.nn.rnn import *             # noqa: F401,F403
 from bigdl_tpu.nn.attention import *       # noqa: F401,F403
 from bigdl_tpu.nn.latent_attention import *  # noqa: F401,F403
+from bigdl_tpu.nn.differential_attention import *  # noqa: F401,F403
 from bigdl_tpu.nn.moe import *             # noqa: F401,F403
 from bigdl_tpu.nn.ssm import *             # noqa: F401,F403
 from bigdl_tpu.nn.quantized import *       # noqa: F401,F403

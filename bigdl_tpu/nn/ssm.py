@@ -32,6 +32,24 @@ give float32; everything between them is float32.  The recurrence's state
 lies ``[heads, N, P]`` (``ops.ssm_kernels`` says why) and the
 convolution's inputs ``[conv_width - 1, channels]``, channels along the
 lanes.
+
+:class:`Mamba1Mixer` is the older selective state-space layer (Mamba-1):
+no heads, a decay for every channel and state, a step size through a
+low-rank projection, ``B`` and ``C`` shared by all channels::
+
+    [x | z] = W_in u
+    x = silu(conv(x))              depthwise, causal, ``conv_width`` taps, a bias
+    [d | B | C] = W_x x            dt_rank | N | N
+    dt = softplus(W_dt d + b_dt),  A = -exp(A_log)             [inner, N]
+    S_t[c, n] = exp(dt_t[c] A[c, n]) S_{t-1}[c, n] + dt_t[c] x_t[c] B_t[n]
+    y_t[c] = sum_n S_t[c, n] C_t[n] + D[c] x_t[c]
+    out = W_out( y * silu(z) )
+
+It has the same three uses with the same meaning, the same ``valid``,
+``active`` and ``fresh`` rules, and also returns ``y`` (before the gate,
+``D x`` in it): what a later layer's gated memory unit reads.  Its state
+lies ``{"ssm": [B, N, inner] float32, "conv": [B, conv_width - 1,
+inner]}`` (``ops.ssm_kernels`` says why).
 """
 
 from __future__ import annotations
@@ -45,7 +63,7 @@ from bigdl_tpu.core.module import Module, Parameter
 from bigdl_tpu.nn.linear import Linear
 from bigdl_tpu.ops import ssm_kernels
 
-__all__ = ["Mamba2Mixer"]
+__all__ = ["Mamba2Mixer", "Mamba1Mixer"]
 
 
 def _product(x, layer: Linear):
@@ -71,6 +89,15 @@ class DepthwiseCausalConv(Module):
         window = window.astype(jnp.float32)
         return sum(w[:, k] * window[:, k:k + t] for k in range(width)) \
             + self.bias.astype(jnp.float32)
+
+
+def _last_real_inputs(window, valid, keep: int):
+    """Of ``window [B, keep + T, channels]`` (what came before, then the
+    ``T`` positions) the ``keep`` inputs before each row's first padded
+    position: what the convolution of the next call starts from."""
+    real = jnp.sum(valid, axis=1).astype(jnp.int32)
+    return jax.vmap(lambda w, n: jax.lax.dynamic_slice_in_dim(
+        w, n, keep, axis=0))(window, real)
 
 
 class GatedGroupNorm(Module):
@@ -178,10 +205,7 @@ class Mamba2Mixer(Module):
         with jax.named_scope("ssm/conv"):
             window = jnp.concatenate(
                 [state["conv"].astype(jnp.float32), xbc], axis=1)
-            real = jnp.sum(valid, axis=1).astype(jnp.int32)
-            # the last inputs before the first padded position
-            conv = jax.vmap(lambda w, n: jax.lax.dynamic_slice_in_dim(
-                w, n, keep, axis=0))(window, real)
+            conv = _last_real_inputs(window, valid, keep)
             x, b, c = self._split(jax.nn.silu(self.conv.forward(window)))
         with jax.named_scope("ssm/scan"):
             dt = jnp.where(valid[..., None], self._steps(dt), 0.0)
@@ -217,3 +241,112 @@ class Mamba2Mixer(Module):
                 b = jnp.where(active[:, None, None], b, 0.0)
             ssm, y = ssm_kernels.ssm_state_step(state["ssm"], decay, dx, b, c)
         return self._gate_out(y, x, z)[:, None], {"ssm": ssm, "conv": conv}
+
+
+class Mamba1Mixer(Module):
+    def __init__(self, hidden_size: int, inner: int, state_size: int = 16,
+                 dt_rank: Optional[int] = None, conv_width: int = 4):
+        super().__init__()
+        self.inner, self.state_size = inner, state_size
+        self.dt_rank = -(-hidden_size // 16) if dt_rank is None else dt_rank
+        self.conv_width = conv_width
+        self.in_proj = Linear(hidden_size, 2 * inner, with_bias=False)
+        self.conv = DepthwiseCausalConv(inner, conv_width)
+        self.x_proj = Linear(inner, self.dt_rank + 2 * state_size,
+                             with_bias=False)
+        self.dt_proj = Linear(self.dt_rank, inner, with_bias=True)
+        self.A_log = Parameter(jnp.log(jnp.broadcast_to(
+            jnp.arange(1, state_size + 1, dtype=jnp.float32),
+            (inner, state_size))))
+        self.D = Parameter(jnp.ones(inner))
+        self.out_proj = Linear(inner, hidden_size, with_bias=False)
+
+    def init_state(self, batch: int, dtype=jnp.float32) -> Dict[str, Any]:
+        """Zeros: the recurrence's state float32 whatever ``dtype`` is,
+        the convolution's last inputs in ``dtype``."""
+        return {"ssm": jnp.zeros((batch, self.state_size, self.inner),
+                                 jnp.float32),
+                "conv": jnp.zeros((batch, self.conv_width - 1, self.inner),
+                                  dtype)}
+
+    # ---- the pieces ----------------------------------------------------------
+
+    def _project(self, u):
+        with jax.named_scope("mamba1/project"):
+            p = _product(u, self.in_proj)
+            return p[..., :self.inner], p[..., self.inner:]
+
+    def _selective(self, x):
+        """``x [..., inner]`` after the convolution -> ``(dt [..., inner],
+        B [..., N], C [..., N])``."""
+        n, r = self.state_size, self.dt_rank
+        p = _product(x, self.x_proj)
+        dt = _product(p[..., :r], self.dt_proj) \
+            + self.dt_proj.bias.astype(jnp.float32)
+        return jax.nn.softplus(dt), p[..., r:r + n], p[..., r + n:]
+
+    def _a(self):
+        """``A [N, inner]``: as the state lies."""
+        return -jnp.exp(self.A_log.astype(jnp.float32)).T
+
+    def _gate_out(self, y, z):
+        with jax.named_scope("mamba1/gate_out"):
+            return _product(y * jax.nn.silu(z), self.out_proj)
+
+    # ---- the passes ----------------------------------------------------------
+
+    def forward(self, u, state: Optional[Dict[str, Any]] = None, valid=None):
+        """``u [B, T, hidden]`` from ``state`` (zeros when None) ->
+        ``(out [B, T, hidden] float32, state after the row's last real
+        position, y [B, T, inner])``; ``valid [B, T]`` false marks
+        trailing padding."""
+        bsz, t, _ = u.shape
+        keep = self.conv_width - 1
+        if state is None:
+            state = self.init_state(bsz, jnp.float32)
+        if valid is None:
+            valid = jnp.ones((bsz, t), bool)
+        x, z = self._project(u)
+        with jax.named_scope("mamba1/conv"):
+            window = jnp.concatenate(
+                [state["conv"].astype(jnp.float32), x], axis=1)
+            conv = _last_real_inputs(window, valid, keep)
+            x = jax.nn.silu(self.conv.forward(window))
+            dt, b, c = self._selective(x)
+        with jax.named_scope("mamba1/scan"):
+            dt = jnp.where(valid[..., None], dt, 0.0)
+            y, ssm = ssm_kernels.selective_chunk_scan(
+                x, dt, self._a(), b, c, state["ssm"])
+            y = y + self.D.astype(jnp.float32) * x
+        new = {"ssm": ssm.astype(state["ssm"].dtype),
+               "conv": conv.astype(state["conv"].dtype)}
+        return self._gate_out(y, z), new, y
+
+    def step(self, u, state: Dict[str, Any], active=None, fresh=None):
+        """One token a row: ``u [B, 1, hidden]`` -> ``(out [B, 1, hidden],
+        state, y [B, 1, inner])``.  A row whose ``active [B]`` is false
+        only rides along and keeps its state as it was; a row whose
+        ``fresh [B]`` is true starts from zeros (its sequence's first
+        token)."""
+        x, z = self._project(u[:, 0])
+        with jax.named_scope("mamba1/conv"):
+            before = state["conv"].astype(jnp.float32)
+            if fresh is not None:
+                before = jnp.where(fresh[:, None, None], 0.0, before)
+            window = jnp.concatenate([before, x[:, None]], axis=1)
+            x = jax.nn.silu(self.conv.forward(window)[:, 0])
+            conv = window[:, 1:].astype(state["conv"].dtype)
+            dt, b, c = self._selective(x)
+        with jax.named_scope("mamba1/step"):
+            dx, ssm = dt * x, state["ssm"]
+            if fresh is not None:
+                ssm = jnp.where(fresh[:, None, None], 0.0, ssm)
+            if active is not None:
+                conv = jnp.where(active[:, None, None], conv, state["conv"])
+                dt = jnp.where(active[:, None], dt, 0.0)
+                dx = jnp.where(active[:, None], dx, 0.0)
+            ssm, y = ssm_kernels.selective_state_step(
+                ssm, dt, self._a(), dx, b, c)
+            y = y + self.D.astype(jnp.float32) * x
+        return self._gate_out(y, z)[:, None], {"ssm": ssm, "conv": conv}, \
+            y[:, None]

@@ -29,6 +29,23 @@ was not kept).
 ``chunk``, inside a sub-chunk three matrix products (what every position
 adds to every later one), the state passed from one sub-chunk to the
 next.  Float32 throughout; plain ``jax.numpy``.
+
+The **Mamba-1** recurrence (:func:`selective_state_step`,
+:func:`selective_chunk_scan`) has no heads: every channel ``c`` of
+``inner`` decays each of its ``N`` states on its own,
+
+    S_t[n, c] = exp(dt_t[c] * A[n, c]) * S_{t-1}[n, c] + dt_t[c] x_t[c] B_t[n]
+    y_t[c] = sum_n S_t[n, c] C_t[n]
+
+with ``B_t``, ``C_t [N]`` shared by all channels.  A decay a channel and
+state leaves nothing to turn into matrix products between positions, so
+the chunk is a scan over its positions, each an element-wise update of
+the carried state.  **The state lies ``[rows, N, inner]``, float32**:
+the channels along the lanes and the ``N`` (16) states down the
+sublanes (``[inner, N]``, as the public description writes it, would pad
+16 to 128 lanes and cost eight times the bytes); the sum over ``n`` is
+then a sum down the sublanes, and ``dt``, ``dt * x`` and ``y`` are rows
+over the lanes as they come out of their projections.
 """
 
 from __future__ import annotations
@@ -36,7 +53,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["ssm_state_step", "ssm_chunk_scan"]
+__all__ = ["ssm_state_step", "ssm_chunk_scan", "selective_state_step",
+           "selective_chunk_scan"]
 
 _HI = jax.lax.Precision.HIGHEST
 
@@ -114,3 +132,39 @@ def ssm_chunk_scan(x, dt, a, b, c, state, chunk: int = 128):
                             (chunks(x), chunks(dt), chunks(b), chunks(c)))
     y = jnp.moveaxis(y, 0, 1).reshape(bsz, padded, heads, p)[:, :t]
     return y, state
+
+
+def selective_state_step(state, dt, a, dx, b, c):
+    """One position a row of the Mamba-1 recurrence: ``state [rows, N,
+    inner]`` float32, ``dt [rows, inner]`` (the step sizes), ``a [N,
+    inner]`` (negative), ``dx [rows, inner]`` (``dt * x``), ``b`` and ``c
+    [rows, N]`` -> ``(new state, y [rows, inner])`` with ``new = exp(dt *
+    a) * state + b (x) dx`` and ``y = sum_n new * c``.  A row with ``dt``
+    0 and ``dx`` 0 keeps its state bit for bit: that is how the caller
+    leaves an idle row alone.  One element-wise pass over the state, which
+    XLA makes one fusion over the donated leaf."""
+    dt, a, dx, b, c = (v.astype(jnp.float32) for v in (dt, a, dx, b, c))
+    new = jnp.exp(dt[:, None, :] * a[None]) * state \
+        + b[:, :, None] * dx[:, None, :]
+    return new.astype(state.dtype), jnp.sum(new * c[:, :, None], axis=1)
+
+
+def selective_chunk_scan(x, dt, a, b, c, state, unroll: int = 8):
+    """``T`` positions of the Mamba-1 recurrence from a carried state: ``x
+    [B, T, inner]``, ``dt [B, T, inner]`` (0 at a position that must
+    advance nothing: padding), ``a [N, inner]`` (negative), ``b`` and ``c
+    [B, T, N]``, ``state [B, N, inner]`` -> ``(y [B, T, inner], state
+    after position T - 1)``, all float32.  A scan over the positions, one
+    :func:`selective_state_step` each (``unroll`` of them a turn of the
+    loop)."""
+    x, dt, b, c = (jnp.moveaxis(v.astype(jnp.float32), 1, 0)
+                   for v in (x, dt, b, c))
+    a = a.astype(jnp.float32)
+
+    def one(s, at):
+        x_t, dt_t, b_t, c_t = at
+        return selective_state_step(s, dt_t, a, dt_t * x_t, b_t, c_t)
+
+    state, y = jax.lax.scan(one, state.astype(jnp.float32), (x, dt, b, c),
+                            unroll=max(1, min(int(unroll), x.shape[0])))
+    return jnp.moveaxis(y, 0, 1), state

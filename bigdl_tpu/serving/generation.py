@@ -191,7 +191,16 @@ class SlotPool:
     position scanned twice would be in the state twice).  A state cannot
     be copied or extracted by position, so ``kv_copy`` and ``kv_extract``
     are not offered on such a model (the scheduler refuses it a prefix
-    cache).  All six programs below take the list.
+    cache; the two programs are written for layers that each keep a row).
+    The other four programs take the list.  **Not every layer has
+    keys and values, and a layer may keep nothing**: a layer with a state
+    alone declares ``{"ssm": ("state", None)}``, a layer with no cache
+    ``{}``, and a layer that keeps nothing and attends **another layer's
+    row** names it, ``{"reads": ("shared", 17)}``: no storage, one more
+    reader of that row in every decode step (``full_row_readers``).  A
+    model whose later layers keep nothing also says how many layers a
+    chunk's rows walk (``chunk_layers``: they stop where the caches
+    stop), which ``chunk_layer_positions`` counts by.
 
     **How the pool lies on the chip.**  A K or V leaf is
     ``[S, heads, max_len, head_dim]``.  With a head size under the 128
@@ -260,6 +269,19 @@ class SlotPool:
         self.has_ring = any("ring" in of_layer for of_layer in kinds)
         self.state_layers = sum("state" in of_layer for of_layer in kinds)
         self.has_state = self.state_layers > 0
+        # layers whose decode attention reads a full row, for each row
+        # written: its own layer and every layer that names it
+        named = [places for layer in self.cache_layers
+                 for kind, places in _caches_of(layer).values()
+                 if kind == "shared"]
+        self.full_row_readers = max(
+            (1 + named.count(i) for i, layer in enumerate(self.cache_layers)
+             if _caches_of(layer).get("self", ("",))[0] in ("full", "latent")),
+            default=0)
+        # layers that a prefill's positions walk (a model whose later
+        # layers keep no cache stops a chunk's rows before them)
+        self.chunk_layers = int(getattr(model, "chunk_layers",
+                                        len(self.cache_layers)))
         self.ring_margin = int(ring_margin)
         self.caches = self.model.init_cache(
             self.slots, self.dtype,
@@ -469,7 +491,8 @@ class SlotPool:
                 kv = kv if isinstance(decl, dict) else {"self": kv}
                 new_layers.append({
                     name: rows(kind, cache[name], kv[name])
-                    for name, (kind, _) in _caches_of(decl).items()})
+                    for name, (kind, _) in _caches_of(decl).items()
+                    if kind != "shared"})
             pad = caches["pad"].at[slot_ids, :t].set(pads, mode="drop")
             return {"layers": new_layers, "pad": pad}
 
@@ -563,6 +586,8 @@ class SlotPool:
         out = {"full": 0, "ring": 0, "state": 0, "latent": 0}
         for decl, layer in zip(self.cache_layers, self.caches["layers"]):
             for name, (kind, _) in _caches_of(decl).items():
+                if kind == "shared":
+                    continue
                 out[kind] += sum(
                     int(leaf.size) * leaf.dtype.itemsize
                     for leaf in jax.tree_util.tree_leaves(layer[name]))
@@ -953,7 +978,7 @@ _ENGINE_COUNTERS = (
     "gap_seconds_prefill", "prefill_positions", "prefill_prompt_tokens",
     "admitted", "queue_wait_seconds",
     "decode_positions_live", "decode_positions_read",
-    "chunks_joint", "chunks_alone",
+    "chunks_joint", "chunks_alone", "chunk_layer_positions",
     "moe_layer_calls", "moe_pairs_total", "moe_pairs_held",
     "moe_active_experts",
     "ssm_layer_calls", "ssm_scan_positions", "ssm_scan_positions_real",
@@ -1518,6 +1543,14 @@ class GenerationScheduler:
                 # alone (a pool without the joint program: all of them)
                 "chunks_joint": eng["chunks_joint"],
                 "chunks_alone": eng["chunks_alone"],
+                # layers whose decode attention reads a full row, for
+                # each row written (1, unless layers that keep nothing
+                # attend another layer's row), and positions x layers
+                # that chunks and bucketed prefills walked
+                # (``prefill_positions`` x the model's depth, unless a
+                # chunk's rows stop where the caches stop)
+                "full_row_readers": self.pool.full_row_readers,
+                "chunk_layer_positions": eng["chunk_layer_positions"],
                 # what the expert layers did, in decode and prefill
                 # programs alike: calls of an expert layer, the
                 # token-to-expert pairs they routed, those that landed
@@ -2059,6 +2092,8 @@ class GenerationScheduler:
             # lanes and bucket padding included
             self._acc["prefill_positions"] += \
                 pool.prefill_batch * (bucket - 1)
+            self._acc["chunk_layer_positions"] += \
+                pool.chunk_layers * pool.prefill_batch * (bucket - 1)
             self._acc["ssm_layer_calls"] += pool.state_layers
             self._acc["ssm_scan_positions"] += \
                 pool.state_layers * pool.prefill_batch * (bucket - 1)
@@ -2155,6 +2190,7 @@ class GenerationScheduler:
                 chunk=w, index=s)
         new_pos = end if s + w >= end else s + w
         self._acc["prefill_positions"] += w
+        self._acc["chunk_layer_positions"] += pool.chunk_layers * w
         self._acc["prefill_prompt_tokens"] += new_pos - st.next_pos
         self._acc["ssm_layer_calls"] += pool.state_layers
         self._acc["ssm_scan_positions"] += pool.state_layers * w

@@ -5,7 +5,9 @@ feed-forward run once over both): the same pass made of the two entries
 it stands for, on the same caches, and the comparison; and a
 ``TransformerLM`` whose class hides the entry, for the path a model
 without it takes.  A helper, not a test file: the models and their sizes
-are the callers'."""
+are the callers'.  Also how a prompt goes into a pool's slot as the
+scheduler sends it, and teacher-forced pooled decode steps against a
+reference's logits, for the models whose pools keep a state."""
 
 import inspect
 
@@ -167,3 +169,40 @@ def logged_pool_calls(pool):
     pool.chunk_prefill_into = chunk_prefill_into
     pool.decode_dispatch = decode_dispatch
     return log
+
+
+def pool_prefill(pool, prompt, slot, chunk, chunks_only=False):
+    """A prompt into ``slot`` as the scheduler sends it to a pool that
+    keeps a state: no longer than ``chunk`` through ``prefill_kv`` and the
+    scatter, longer through the pooled chunk program, the last chunk
+    padded at its end (``chunks_only``: the chunk program whatever the
+    length, as a first-and-only chunk that is short)."""
+    n_prompt, end = len(prompt), len(prompt) - 1
+    if n_prompt == 1:
+        return
+    if n_prompt <= chunk and not chunks_only:
+        pool.prefill_into([prompt], [slot], 1 << (n_prompt - 1).bit_length())
+        return
+    pos = 0
+    while pos < end:
+        w = chunk if end - pos >= chunk else 1 << (end - pos - 1).bit_length()
+        toks = np.zeros(w, np.int32)
+        toks[:min(w, end - pos)] = prompt[pos:min(pos + w, end)]
+        pool.chunk_prefill_into(toks, slot, pos)
+        pos += w
+
+
+def decode_check(pool, slot, row, n_prompt, want, close, steps=None):
+    """Pooled decode steps of ``slot`` alone, teacher-forced, each step's
+    logits against the reference's column (``close(got, want)``);
+    returns the caches they left."""
+    active = jnp.arange(pool.slots) == slot
+    caches = pool.caches
+    stop = len(row) if steps is None else min(len(row), n_prompt - 1 + steps)
+    for t in range(n_prompt - 1, stop):
+        tok = jnp.where(active, int(row[t]), 0)[:, None].astype(jnp.int32)
+        index = jnp.where(active, t, 0).astype(jnp.int32)
+        logits, caches, _ = pool.model.decode_step(tok, index, caches,
+                                                   active=active)
+        assert close(logits[slot], want[t]), t
+    return caches

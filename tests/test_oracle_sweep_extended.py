@@ -516,6 +516,8 @@ ELSEWHERE = {
     "GroupedQueryAttention": "test_hybrid_decoder.py",
     "Mamba2Mixer": "test_state_space.py",
     "LatentAttention": "test_latent_attention.py",
+    "Mamba1Mixer": "test_selective_ssm.py",
+    "DifferentialAttention": "test_differential_attention.py",
     # containers & recurrent variants exercised with numerics elsewhere
     "Sequential": "test_optim.py",
     "ConvLSTMPeephole3D": "test_sparse_tree_misc.py",

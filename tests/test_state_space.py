@@ -321,39 +321,13 @@ def test_the_joint_pass_equals_the_chunk_program_then_the_step(
 # ---- the slot pool ------------------------------------------------------------
 
 def _pool_prefill(pool, prompt, slot, chunks_only=False):
-    """A prompt into ``slot`` as the scheduler sends it: no longer than
-    the chunk through ``prefill_kv`` and the scatter, longer through the
-    pooled chunk program, the last chunk padded at its end
-    (``chunks_only``: the chunk program whatever the length, as a
-    first-and-only chunk that is short)."""
-    n_prompt, end = len(prompt), len(prompt) - 1
-    if n_prompt == 1:
-        return
-    if n_prompt <= CHUNK and not chunks_only:
-        pool.prefill_into([prompt], [slot], 1 << (n_prompt - 1).bit_length())
-        return
-    pos = 0
-    while pos < end:
-        w = CHUNK if end - pos >= CHUNK else 1 << (end - pos - 1).bit_length()
-        toks = np.zeros(w, np.int32)
-        toks[:min(w, end - pos)] = prompt[pos:min(pos + w, end)]
-        pool.chunk_prefill_into(toks, slot, pos)
-        pos += w
+    joint_pass.pool_prefill(pool, prompt, slot, CHUNK, chunks_only)
 
 
 def _decode_check(pool, slot, row, n_prompt, want, steps=None, tol=TOL):
-    """Pooled decode steps of ``slot`` alone, teacher-forced, each step's
-    logits against the reference's column."""
-    active = jnp.arange(pool.slots) == slot
-    caches = pool.caches
-    stop = len(row) if steps is None else min(len(row), n_prompt - 1 + steps)
-    for t in range(n_prompt - 1, stop):
-        tok = jnp.where(active, int(row[t]), 0)[:, None].astype(jnp.int32)
-        index = jnp.where(active, t, 0).astype(jnp.int32)
-        logits, caches, _ = pool.model.decode_step(tok, index, caches,
-                                                   active=active)
-        assert close(logits[slot], want[t], tol), t
-    return caches
+    return joint_pass.decode_check(
+        pool, slot, row, n_prompt, want,
+        lambda got, ref_row: close(got, ref_row, tol), steps)
 
 
 @pytest.mark.parametrize("n_prompt", list(PROMPTS.values()), ids=list(PROMPTS))

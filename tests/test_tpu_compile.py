@@ -861,3 +861,101 @@ def test_latent_pool_program_copies_no_latent_leaf_on_v5e(
     assert 10.5e9 < held < 15.5 * 2 ** 30, held
     if program != "chunk_prefill":
         assert held > 11e9
+
+
+# ---- the pool of one shared row, rings and states, at the published sizes ----
+# (benchmark/configs/phi-4-mini-flash-reasoning.json: all 32 layers of
+# Phi-4-mini-flash-reasoning over 96 slots of 5,120 positions: nine Mamba-1
+# layers that keep a float32 state [16, 5120] a slot and no row, eight
+# differential-attention layers over rings of 512 + 255 + 1 places, one over
+# the full row that seven cross layers read too, seven gated memory units
+# that keep nothing; 3.85 B parameters in bfloat16.)  Shapes only, as above.
+
+def _shared_row_config():
+    return {
+        "vocab_size": 200064, "hidden_size": 2560, "num_hidden_layers": 32,
+        "num_attention_heads": 40, "num_key_value_heads": 20,
+        "intermediate_size": 10240, "sliding_window": 512, "mb_per_layer": 2,
+        "layer_norm_eps": 1e-5, "tie_word_embeddings": True,
+        "serving": {"slots": 96, "max_len": 5120, "prefill_chunk": 256}}
+
+
+def _lower_shared_row_program(program, sharding):
+    from bigdl_tpu.models import phi4_flash
+    from bigdl_tpu.serving.generation import SlotPool
+    cfg = _shared_row_config()
+    s = cfg["serving"]
+    slots, chunk = s["slots"], s["prefill_chunk"]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    abstract = jax.eval_shape(lambda: phi4_flash(cfg, s["max_len"]))
+    model = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, jnp.bfloat16), abstract)
+    caches = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+            lambda: abstract.init_cache(slots, jnp.bfloat16,
+                                        ring_margin=chunk)))
+    pool = object.__new__(SlotPool)
+    pool.slots = slots
+    pool.cache_layers = tuple(abstract.cache_layers())
+    pool.expert_layers = abstract.expert_layers()
+    pool.trace_counts = dict(TRACE_COUNTS, decode_with_chunk={})
+    pool._build_programs()
+    routing = sds((0,), jnp.int32)
+    return _lower(pool, program, model, caches, routing, sds, slots,
+                  chunk), caches, abstract
+
+
+@pytest.mark.parametrize("program", POOL_MODEL_PROGRAMS)
+def test_shared_row_pool_program_copies_no_leaf_on_v5e(
+        v5e, program, monkeypatch):
+    """The decode step, the chunk program and the joint program of
+    Phi-4-mini-flash-reasoning whole, as a TPU process traces them,
+    compiled for the described v5e.  No ``copy``, ``transpose`` or
+    ``scatter`` of a pooled state, of the shared row or of a ring; the
+    state's leaf lies channels-minor (``[slots, 16, 5120]``, the 5,120
+    channels along the lanes: ``[5120, 16]`` would pad 16 to 128).  The
+    decode step writes nine layers' rows through ``ops.write_cache_rows``
+    (eight rings and the full row) and attends the **one full row eight
+    times** through the ragged decode kernel (the full layer and seven
+    cross layers; the rings go through the XLA product), with no
+    ``while``; a chunk's rows scan nine states in nine loops and hold no
+    kernel call (they never reach a cross layer, and leave after layer
+    17's keys and values are written).  Weights, pool and temporaries fit
+    the chip and fill 13.5 GB of it."""
+    from bigdl_tpu.ops import attention_kernels
+    monkeypatch.setattr(attention_kernels, "_on_tpu", lambda: True)
+    lowered, caches, abstract = _lower_shared_row_program(
+        program, SingleDeviceSharding(v5e.devices[0]))
+    layers = caches["layers"]
+    assert layers[16]["ssm"]["ssm"].shape == (96, 16, 5120)
+    assert layers[16]["ssm"]["ssm"].dtype == jnp.float32
+    assert layers[17]["self"]["k"].shape == (96, 10, 5120, 128)
+    assert layers[15]["self"]["v"].shape == (96, 10, 768, 128)
+    assert layers[18] == {} and layers[31] == {}
+    assert abstract.decode_key_block(caches) in (256, 512)
+    # nine row writers and the flags' select; two writers a state
+    assert abstract.cache_write_programs(caches) == 1 + 9 + 2 * 9
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    leaf = (r"(?:f32\[96,16,5120\]|bf16\[96,10,(?:5120,128|128,5120|768,128"
+            r"|128,768)\])")
+    assert not re.findall(
+        r"= %s\S* (?:copy|copy-start|transpose|scatter)\(" % leaf, text)
+    layouts = set(re.findall(r"f32\[96,16,5120\]\{([\d,]+)", text))
+    assert layouts and all(lay.startswith("2,1,0") for lay in layouts), layouts
+    calls = _kernel_calls(text)
+    whiles = len(re.findall(r" while\(", text))
+    if program == "decode":
+        assert (calls, whiles) == ((9, 8), 0)
+    elif program == "chunk_prefill":
+        assert (calls, whiles) == ((0, 0), 9)
+    else:
+        assert (calls, whiles) == ((9, 8), 9)
+    mem = compiled.memory_analysis()
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert held < 15.5 * 2 ** 30, held
+    if program != "chunk_prefill":
+        assert held > 13.5e9, held
