@@ -908,10 +908,9 @@ _LANES = 128
 _DECODE_VMEM = 8 * 2 ** 20   # the streamed K and V blocks, double-buffered
 
 
-def _ragged_decode_kernel(lens_ref, src_ref, lo_ref, hi_ref, q_ref, k_ref,
-                          v_ref, bias_ref, o_ref, s_ref, m_ref, l_ref,
-                          a_ref, acc_ref, *, scale: float, block: int,
-                          group: int):
+def _ragged_decode_kernel(lens_ref, row_ref, blk_ref, q_ref, k_ref, v_ref,
+                          bias_ref, o_ref, s_ref, m_ref, l_ref, a_ref,
+                          acc_ref, *, scale: float, block: int, group: int):
     """One (slot, key block) program.  ``k_ref [Hkv, d, block]`` and
     ``v_ref [Hkv, dv, block]`` hold positions on the lanes, as the pool
     stores them, so a head's scores are a sum over sublanes of
@@ -921,7 +920,7 @@ def _ragged_decode_kernel(lens_ref, src_ref, lo_ref, hi_ref, q_ref, k_ref,
     and ``o_ref [dv, Hq]`` keep the width on the sublanes for the same
     reason.  The context gathers lane-wise in ``acc_ref [Hq, dv, 128]``
     and is summed over the lanes once, at the slot's last block."""
-    del src_ref, lo_ref, hi_ref          # the index maps read them
+    del row_ref, blk_ref                 # the index maps read them
     b, j = pl.program_id(0), pl.program_id(1)
     length = lens_ref[b]
     hkv, hq = k_ref.shape[0], q_ref.shape[1]
@@ -977,8 +976,8 @@ def _ragged_decode_kernel(lens_ref, src_ref, lo_ref, hi_ref, q_ref, k_ref,
         o_ref[...] = jnp.zeros_like(o_ref)
 
 
-def _ragged_decode_mxu_kernel(lens_ref, src_ref, lo_ref, hi_ref, q_ref,
-                              *refs, scale: float, block: int, tiles,
+def _ragged_decode_mxu_kernel(lens_ref, row_ref, blk_ref, q_ref, *refs,
+                              scale: float, block: int, tiles,
                               value_scores: bool = False):
     """One (slot, key block) program where a key head serves a group of
     query heads, or a leaf lies width-minor.  ``q_ref [Hkv, G', d]`` holds
@@ -1000,7 +999,7 @@ def _ragged_decode_mxu_kernel(lens_ref, src_ref, lo_ref, hi_ref, q_ref,
     being the part that reads the keys (the rotary part); a place's score
     is the sum of the two products, and the context is the weights
     against the same value block, which is fetched once."""
-    del src_ref, lo_ref, hi_ref          # the index maps read them
+    del row_ref, blk_ref                 # the index maps read them
     qv_ref = refs[0] if value_scores else None
     k_ref, v_ref, bias_ref, o_ref, m_ref, l_ref, acc_ref = refs[value_scores:]
     b, j = pl.program_id(0), pl.program_id(1)
@@ -1066,10 +1065,22 @@ def ragged_decode_attention(q, k, v, lengths, pad=None, *,
     ``[B, T]`` bool or None.  Returns ``[B, Hq, 1, dv]`` in q's dtype.
 
     The grid is (row, key block) and ``lengths`` goes ahead as scalar
-    prefetch: the block index of a step past a row's last live block is
-    that block's again, so no DMA is issued for it, and its arithmetic
-    is skipped; a row with nothing live stays on its predecessor's block.
-    What is read is each live length rounded up to ``block_k``.
+    prefetch.  A step with nothing to read (past its row's last live
+    block, or of a row with nothing live) skips its arithmetic and
+    fetches nothing of its own: it names a block that a live step reads
+    (:func:`_step_block`), so every live block is fetched once and
+    nothing else is, and what is read is each live length rounded up to
+    ``block_k``.  **Which block a dead step names decides behind which
+    step the next row's first block is fetched** (the pipeline asks for a
+    step's block one step ahead): where a group's queries run on the MXU
+    it is block 0 of the next live row, so the fetch goes out behind the
+    row's last live step and the next row finds its block in VMEM; the
+    vector-unit body keeps a row's dead steps on its own last block, so
+    the fetch goes out behind the row's finish, there the longer step
+    (:func:`_dead_steps`).  The steps after the last live row stay on its
+    last block.  (The row axis is ``parallel``: on a chip with two cores
+    a core's last row may name a row the other core owns, one wasted
+    fetch a call.)
 
     **Each leaf is handed over as it lies on the chip**
     (:func:`cache_kernels.cache_row_tiles`): positions-minor,
@@ -1126,11 +1137,13 @@ def latent_decode_attention(q_latent, q_rotary, rotary, latent, lengths,
     ``[B, H, 1, r]`` is ``softmax(score) latent``, in ``q_latent``'s dtype.
 
     :func:`ragged_decode_attention`'s grid, scalar prefetch and index
-    maps, so only live blocks are fetched and each once: the body
+    maps, so only live blocks are fetched, each once, and a row's first
+    one behind the last live step of the row before it: the body
     (:func:`_ragged_decode_mxu_kernel` with ``value_scores``) scores
-    against the value block it then weighs.  Both leaves are handed over as they lie (the latent
-    width-minor, the rotary part positions-minor).  The weights are
-    rounded to the row's dtype, sums are float32."""
+    against the value block it then weighs.  Both leaves are handed over
+    as they lie (the latent width-minor, the rotary part
+    positions-minor).  The weights are rounded to the row's dtype, sums
+    are float32."""
     b, h, tq, r = q_latent.shape
     t, dr = rotary.shape[2], rotary.shape[3]
     if tq != 1 or latent.shape != (b, 1, t, r) \
@@ -1150,6 +1163,54 @@ def latent_decode_attention(q_latent, q_rotary, rotary, latent, lengths,
                           interpret=bool(interpret))
 
 
+def _dead_steps(lengths, block: int, *, ahead: bool):
+    """Where the grid steps of a call that have nothing to read point,
+    from ``lengths [B]`` alone: two int32 tables a row, ``row[b]`` and
+    ``blk[b]``, the (row, key block) that every step past row ``b``'s
+    last live block names, and every step of a row with nothing live
+    (:func:`_step_block` reads them).  The pipeline asks for a step's
+    block one step ahead, so the tables say behind which step of a row
+    the next row's first block is fetched; a dead step itself (~0.2 us)
+    hides nothing.
+
+    ``ahead``: **block 0 of the next live row**, so that its fetch goes
+    out behind row ``b``'s last live step; with no live row ahead, the
+    last live row's last block (nothing is left to fetch); with no row
+    live at all, block 0 of row 0 throughout.  Not ``ahead``: row
+    ``b``'s own last live block, and for a row with nothing live the
+    block where the live row before it ended (or where the first live
+    row will start), so that the fetch goes out behind the row's last
+    grid step, its finish.  The kernel streams blocks as fast as HBM
+    gives them, so either way one of the two steps runs with nothing in
+    flight: the caller picks the rule that leaves the shorter one bare
+    (:func:`_ragged_decode`)."""
+    b = lengths.shape[0]
+    rows = jnp.arange(b, dtype=jnp.int32)
+    live = lengths > 0
+    last = jnp.maximum(lengths - 1, 0) // block
+    before = jax.lax.cummax(jnp.where(live, rows, -1))
+    if not ahead:
+        src = jnp.where(before >= 0, before,
+                        jnp.argmax(live).astype(jnp.int32))
+        return src, jnp.where(before >= 0, last[src], 0)
+    # the first live row from each row on, then from the row after it on
+    after = jax.lax.cummin(jnp.where(live, rows, b), reverse=True)
+    after = jnp.concatenate([after[1:], jnp.full((1,), b, jnp.int32)])
+    final = jnp.maximum(before[-1], 0)
+    more = after < b
+    return jnp.where(more, after, final), jnp.where(more, 0, last[final])
+
+
+def _step_block(bi, j, lens, row, blk, *, block: int):
+    """The (row, key block) that grid step ``(bi, j)`` names: its own
+    while ``j`` is a live block of row ``bi``, else what
+    :func:`_dead_steps` gives the row.  A block is fetched where the
+    named pair changes from one step to the next, so walking the grid in
+    order every live block is fetched at or before its own step, once."""
+    own = j * block < lens[bi]
+    return jnp.where(own, bi, row[bi]), jnp.where(own, j, blk[bi])
+
+
 @functools.partial(jax.jit, static_argnames=("scale", "block", "interpret"))
 def _ragged_decode(q, k, v, lengths, pad, qv=None, *, scale, block,
                    interpret):
@@ -1165,23 +1226,23 @@ def _ragged_decode(q, k, v, lengths, pad, qv=None, *, scale, block,
     _, hkv, t, dv = v.shape
     group = hq // hkv
     lengths = lengths.astype(jnp.int32)
-    # the blocks a row's steps read: its own, first to last live; a row
-    # with nothing live stays where the live row before it ended (or
-    # where the first live row will start), so that it moves nothing
-    rows = jnp.arange(b, dtype=jnp.int32)
-    live = lengths > 0
-    last = jnp.maximum(lengths - 1, 0) // block
-    before = jax.lax.cummax(jnp.where(live, rows, -1))
-    src = jnp.where(before >= 0, before, jnp.argmax(live).astype(jnp.int32))
-    hi = jnp.where(before >= 0, last[src], 0)
-    lo = jnp.where(live, 0, hi)
+    tiles = tuple(cache_row_tiles(a.shape, a.dtype) for a in (k, v))
+    vector_unit = qv is None and group == 1 and tiles == ("lanes", "lanes")
+    # the next row's first block is fetched behind the longer of a row's
+    # last live step and its finish.  The vector-unit body's finish is a
+    # lane sum a query head (3.7 us a row at OPT's 32 heads, over ~2 us of
+    # a live block's arithmetic: looking ahead cost 1.2-2.2 us a row
+    # there, v5e); the MXU body's is one divide, under a live step of
+    # 0.85-3.1 us (what looking ahead saved a row, by the block's bytes)
+    tables = _dead_steps(lengths, block, ahead=not vector_unit)
     if pad is None:
         bias = jnp.zeros((b, 1, t), jnp.float32)
     else:
         bias = jnp.where(pad, _NEG_INF, 0.0).astype(jnp.float32)[:, None]
 
-    def lanes_map(bi, j, lens, src, lo, hi):
-        return src[bi], 0, 0, jnp.minimum(jnp.maximum(j, lo[bi]), hi[bi])
+    def lanes_map(bi, j, *refs):
+        row, blk = _step_block(bi, j, *refs, block=block)
+        return row, 0, 0, blk
 
     def bias_map(bi, j, *refs):
         row, _, _, blk = lanes_map(bi, j, *refs)
@@ -1199,12 +1260,10 @@ def _ragged_decode(q, k, v, lengths, pad, qv=None, *, scale, block,
                 (None, hkv, width, block), lanes_map)
         return a, pl.BlockSpec((None, hkv, block, width), sublanes_map)
 
-    tiles = tuple(cache_row_tiles(a.shape, a.dtype) for a in (k, v))
     (k, k_spec), (v, v_spec) = leaf(k, tiles[0]), leaf(v, tiles[1])
     bias_spec = pl.BlockSpec((None, 1, block), bias_map)
     dtype = q.dtype
     q = q[:, :, 0, :]
-    vector_unit = qv is None and group == 1 and tiles == ("lanes", "lanes")
     if vector_unit:
         kernel = functools.partial(_ragged_decode_kernel, group=group)
         # the width on the sublanes: [B, d, Hq] in and [B, dv, Hq] out
@@ -1234,7 +1293,7 @@ def _ragged_decode(q, k, v, lengths, pad, qv=None, *, scale, block,
     res = pl.pallas_call(
         functools.partial(kernel, scale=scale, block=block),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=3,
             grid=(b, t // block),
             in_specs=[pl.BlockSpec((None,) + a.shape[1:], row)
                       for a in queries] + [k_spec, v_spec, bias_spec],
@@ -1243,7 +1302,7 @@ def _ragged_decode(q, k, v, lengths, pad, qv=None, *, scale, block,
         out_shape=jax.ShapeDtypeStruct(out, dtype),
         interpret=interpret,
         **_dimsem("parallel", "arbitrary"),
-    )(lengths, src, lo, hi, *queries, k, v, bias)
+    )(lengths, *tables, *queries, k, v, bias)
     if vector_unit:
         return jnp.swapaxes(res, 1, 2)[:, :, None, :]
     return res[:, :, :group].reshape(b, hq, 1, dv)
@@ -1258,10 +1317,13 @@ def _decode_block(k_shape, v_shape, dtype) -> Optional[int]:
     under its fetch (~1.3 us), and a live length is read rounded up to
     the block: OPT's 16 KB a place fill the VMEM share at 256; leaves of
     a few key heads (2 KB a place) take 512, which one layer alone read
-    a quarter faster than 256 on a v5e.  Not more: a row's first block
-    is fetched in the open (the step before it is too short to hide it),
-    and at 1,024 that cost what the fewer steps saved while the rounding
-    grew by a tenth.  None where the row does not tile."""
+    a quarter faster than 256 on a v5e.  Not more: when 1,024 was tried
+    a row's first block was still fetched in the open (behind a dead
+    step, too short to hide it), a cost that grows with the block and
+    took back what the fewer steps saved, while the rounding grew by a
+    tenth; that fetch now goes out behind the row before
+    (:func:`_dead_steps`), the rounding stays, and 1,024 has not been
+    measured against 512 since.  None where the row does not tile."""
     _, hkv, t, d = k_shape
     dv = v_shape[-1]
     size = jnp.dtype(dtype).itemsize

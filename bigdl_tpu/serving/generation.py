@@ -876,6 +876,11 @@ class SlotPool:
         positions = (int(lengths.sum()),
                      int((-(-lengths // block) * block).sum()) if block
                      else self.slots * self.max_len)
+        # the rows the ragged kernel starts in a full-layer call, and
+        # those of them whose first key block it fetches behind a step of
+        # the live row before (all but the call's first); none where the
+        # step reads whole rows
+        rows = (len(lengths), max(len(lengths) - 1, 0)) if block else (0, 0)
         tok_d, idx_d, act_d = self._dev
         if chunk is None:
             out = self._decode_jit(self.model, self.caches, tok_d, idx_d,
@@ -891,7 +896,7 @@ class SlotPool:
         self._dev = (new_tok, new_idx, act_d)
         self._emit_active = self.active.copy()
         self._touched[:] = False
-        handle = _StepHandle(emit, positions)
+        handle = _StepHandle(emit, positions, rows)
         self._open_handle = handle
         return handle
 
@@ -932,13 +937,16 @@ class _StepHandle:
     epoch (finalized at the NEXT dispatch — until then the pool's live
     epoch applies)."""
 
-    __slots__ = ("emit", "mask", "routing", "positions")
+    __slots__ = ("emit", "mask", "routing", "positions", "rows")
 
-    def __init__(self, emit, positions=(0, 0)):
+    def __init__(self, emit, positions=(0, 0), rows=(0, 0)):
         self.emit = emit
         # (live, read): the cache positions this step's attention may
         # attend, and those its program reads to do so, a full layer
         self.positions = positions
+        # (live, prefetched): the active rows of a full layer's call of
+        # the ragged decode kernel, and those with a live row before them
+        self.rows = rows
         self.mask: Optional[np.ndarray] = None
         # read back with the tokens: what the expert layers did since
         # the previous step (empty for a model without them)
@@ -978,6 +986,7 @@ _ENGINE_COUNTERS = (
     "gap_seconds_prefill", "prefill_positions", "prefill_prompt_tokens",
     "admitted", "queue_wait_seconds",
     "decode_positions_live", "decode_positions_read",
+    "decode_rows_live", "decode_rows_prefetched",
     "chunks_joint", "chunks_alone", "chunk_layer_positions",
     "moe_layer_calls", "moe_pairs_total", "moe_pairs_held",
     "moe_active_experts",
@@ -1538,6 +1547,13 @@ class GenerationScheduler:
                 # where it does not)
                 "decode_positions_live": eng["decode_positions_live"],
                 "decode_positions_read": eng["decode_positions_read"],
+                # rows, a full layer's call of the ragged decode kernel,
+                # summed likewise: the active ones, and those of them
+                # with an active row before them in the call, whose
+                # first key block is fetched behind a step of that row
+                # (both zero where the step reads whole rows)
+                "decode_rows_live": eng["decode_rows_live"],
+                "decode_rows_prefetched": eng["decode_rows_prefetched"],
                 # chunk programs (not bucketed prefills) that rode a
                 # decode step as one joint program, and that went out
                 # alone (a pool without the joint program: all of them)
@@ -2311,6 +2327,8 @@ class GenerationScheduler:
         self._acc["decode_dispatches"] += 1
         self._acc["decode_positions_live"] += emit.positions[0]
         self._acc["decode_positions_read"] += emit.positions[1]
+        self._acc["decode_rows_live"] += emit.rows[0]
+        self._acc["decode_rows_prefetched"] += emit.rows[1]
         self._acc["ssm_layer_calls"] += pool.state_layers
         self._acc["chunks_joint"] += joint
         self._pending = (emit, n_active, after_prefill, (

@@ -8,6 +8,8 @@ to bfloat16 before the normaliser is applied and not after, which is a
 bfloat16 rounding of a weight.  ``tests/test_tpu_compile.py`` compiles the
 kernel for a described v5e; what it computes there is a chip run's to say.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -40,6 +42,49 @@ def _oracle(q, k, v, lengths, pad):
                             jnp.repeat(v, group, axis=1), bias)
 
 
+def parent_dead_steps(lengths, block):
+    """The oracle of the index maps: the rule the kernel had until PR 45,
+    in ``_dead_steps``' form.  A step past a row's last live block stays
+    on that block; a row with nothing live stays where the live row
+    before it ended, or where the first live row will start."""
+    rows = jnp.arange(lengths.shape[0], dtype=jnp.int32)
+    live = lengths > 0
+    last = jnp.maximum(lengths - 1, 0) // block
+    before = jax.lax.cummax(jnp.where(live, rows, -1))
+    src = jnp.where(before >= 0, before, jnp.argmax(live).astype(jnp.int32))
+    return src, jnp.where(before >= 0, last[src], 0)
+
+
+ahead_dead_steps = functools.partial(ak._dead_steps, ahead=True)
+
+
+@pytest.fixture
+def under_rule(monkeypatch):
+    """``under_rule(rule, asked)``: entered, every body's steps name their
+    blocks by ``rule(lengths, block)`` (traced anew: ``_ragged_decode``
+    keeps its traces), and ``asked`` gathers what each call would have
+    chosen.  The
+    same blocks are read in the same order under either rule, so a result
+    is the other's bit for bit."""
+    import contextlib
+
+    @contextlib.contextmanager
+    def enter(rule, asked):
+        def forced(lengths, block, *, ahead):
+            asked.append(ahead)
+            return rule(lengths, block)
+
+        def anew(*args, **kw):      # a function of its own: a trace of its own
+            return traced(*args, **kw)
+        traced = ak._ragged_decode.__wrapped__
+        with monkeypatch.context() as m:
+            m.setattr(ak, "_dead_steps", forced)
+            m.setattr(ak, "_ragged_decode", jax.jit(
+                anew, static_argnames=("scale", "block", "interpret")))
+            yield
+    return enter
+
+
 @pytest.mark.parametrize("padded", [False, True], ids=["nopad", "pad"])
 @pytest.mark.parametrize("block", [256, 128])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
@@ -70,9 +115,13 @@ def test_kernel_matches_the_masked_xla_product(heads, dtype, block, padded):
     np.testing.assert_allclose(out[1:], want[1:], rtol=tol, atol=tol)
 
 
-def test_rows_between_and_before_the_live_ones_move_nothing_and_read_zero():
+def test_rows_between_and_before_the_live_ones_move_nothing_and_read_zero(
+        under_rule):
     """Idle rows first, between and last: each returns zeros, and the live
-    rows read what they read alone."""
+    rows read what they read alone, bit for bit what the parent's maps
+    gave and what the other rule gives (the vector-unit body, which keeps
+    the parent's rule; with pad flags across a block's edge, three blocks
+    of four live, too)."""
     q, k, v = _operands("mha", jnp.float32, seed=7)
     lengths = jnp.asarray([0, 0, 300, 0, 17, 0], jnp.int32)
     out = np.asarray(ak.ragged_decode_attention(q, k, v, lengths,
@@ -85,6 +134,126 @@ def test_rows_between_and_before_the_live_ones_move_nothing_and_read_zero():
     none = ak.ragged_decode_attention(q, k, v, jnp.zeros((6,), jnp.int32),
                                       interpret=True)
     assert (np.asarray(none) == 0).all()
+    pad = jnp.zeros((6, T), bool).at[2, 120:130].set(True)
+    padded = ak.ragged_decode_attention(q, k, v, lengths, pad, block_k=128,
+                                        interpret=True)
+    for rule in (parent_dead_steps, ahead_dead_steps):
+        asked = []
+        with under_rule(rule, asked):
+            np.testing.assert_array_equal(out, np.asarray(
+                ak.ragged_decode_attention(q, k, v, lengths,
+                                           interpret=True)))
+            np.testing.assert_array_equal(np.asarray(padded), np.asarray(
+                ak.ragged_decode_attention(q, k, v, lengths, pad,
+                                           block_k=128, interpret=True)))
+        assert asked == [False, False]      # the finish is the longer step
+
+
+# ---- what a grid step names: pure functions of the lengths, no kernel run ----
+# (rows, places a row, key block) of the five served pools
+POOLS = {"opt": (6, 2048, 256), "mimo": (32, 6144, 512),
+         "falcon": (48, 3584, 512), "sarvam": (112, 7168, 512),
+         "chains": (96, 5120, 512)}
+
+
+def _all_live(b, t, block, r):
+    return r.randint(1, t + 1, size=b)
+
+
+def _idle_first_between_last(b, t, block, r):
+    lengths = r.randint(1, t + 1, size=b)
+    lengths[[0, 1, b // 2, b - 1]] = 0
+    return lengths
+
+
+def _one_live(b, t, block, r):
+    lengths = np.zeros(b, int)
+    lengths[b // 3] = block + 5
+    return lengths
+
+
+def _none_live(b, t, block, r):
+    return np.zeros(b, int)
+
+
+def _to_the_last_block(b, t, block, r):
+    lengths = r.randint(0, t + 1, size=b)
+    lengths[[0, b // 2, b - 1]] = t, t - block + 1, t
+    return lengths
+
+
+def _on_a_blocks_edge(b, t, block, r):
+    edges = [block - 1, block, block + 1, 2 * block, 2 * block + 1, 0]
+    return np.resize(edges, b)
+
+
+PATTERNS = [_all_live, _idle_first_between_last, _one_live, _none_live,
+            _to_the_last_block, _on_a_blocks_edge]
+
+
+def _named(rule, lengths, block, steps):
+    """The (row, block) that each step of the ``[B, steps]`` grid names
+    under ``rule`` (``_dead_steps`` looking ahead, or the parent's), as
+    two arrays."""
+    lens = jnp.asarray(lengths, jnp.int32)
+    bi = np.arange(len(lengths))[:, None]
+    j = np.arange(steps)[None, :]
+    row, blk = ak._step_block(bi, j, np.asarray(lens),
+                              *map(np.asarray, rule(lens, block)),
+                              block=block)
+    return np.asarray(row), np.asarray(blk)
+
+
+@pytest.mark.parametrize("pattern", PATTERNS,
+                         ids=[f.__name__[1:] for f in PATTERNS])
+@pytest.mark.parametrize("pool", sorted(POOLS))
+def test_what_a_step_names(pool, pattern):
+    """Looking ahead (the rule of the MXU bodies): every live block is
+    named at its own step; walking the grid in order the named block
+    changes once a live block and never else, so each is fetched once; a
+    step with nothing to read names block 0 of the next live row, the
+    fetch the row's last live step then covers; behind the last live row
+    the steps stay on its last block; with no row live nothing moves."""
+    b, t, block = POOLS[pool]
+    steps = t // block
+    lengths = pattern(b, t, block, np.random.RandomState(b + block))
+    blocks = -(-lengths // block)                 # live blocks a row
+    row, blk = _named(ahead_dead_steps, lengths, block, steps)
+    own = np.arange(steps)[None, :] < blocks[:, None]
+    rows = np.broadcast_to(np.arange(b)[:, None], own.shape)
+    cols = np.broadcast_to(np.arange(steps)[None, :], own.shape)
+    np.testing.assert_array_equal(row[own], rows[own])
+    np.testing.assert_array_equal(blk[own], cols[own])
+    walk = np.stack([row.ravel(), blk.ravel()], axis=1)
+    moves = (walk[1:] != walk[:-1]).any(axis=1).sum()
+    assert moves == max(blocks.sum() - 1, 0)
+    live = np.flatnonzero(lengths)
+    if not live.size:
+        assert (walk == walk[0]).all()
+        return
+    # the steps that read nothing: the next live row's block 0, or the
+    # last live row's last block
+    for r in range(b):
+        ahead = live[live > r]
+        want = (ahead[0], 0) if ahead.size \
+            else (live[-1], blocks[live[-1]] - 1)
+        assert (row[r][~own[r]] == want[0]).all()
+        assert (blk[r][~own[r]] == want[1]).all()
+    # the parent's rule, which ``_dead_steps`` keeps for the body whose
+    # finish is the longer step, named the same block at every step that
+    # reads, and moved as often: only where the dead steps wait differs
+    lens = jnp.asarray(lengths, jnp.int32)
+    for kept, was in zip(ak._dead_steps(lens, block, ahead=False),
+                         parent_dead_steps(lens, block)):
+        np.testing.assert_array_equal(np.asarray(kept), np.asarray(was))
+    was_row, was_blk = _named(parent_dead_steps, lengths, block, steps)
+    np.testing.assert_array_equal(was_row[own], row[own])
+    np.testing.assert_array_equal(was_blk[own], blk[own])
+    was = np.stack([was_row.ravel(), was_blk.ravel()], axis=1)
+    assert (was[1:] != was[:-1]).any(axis=1).sum() == moves
+    if blocks[live[0]] < steps and live.size > 1:
+        assert (was_row[live[0]] == live[0]).all()
+        assert row[live[0], -1] == live[1]
 
 
 def test_entry_chooses_by_backend_and_shape(monkeypatch):
@@ -200,9 +369,10 @@ def test_mxu_body_matches_grouped_attention(model, block, padded):
 
 
 @pytest.mark.parametrize("model", sorted(GROUPED))
-def test_mxu_body_in_a_pool_with_idle_rows(model):
+def test_mxu_body_in_a_pool_with_idle_rows(model, under_rule):
     """Only some rows active: the idle ones, first, between and last,
-    read zeros and the live rows what they read alone."""
+    read zeros and the live rows what they read alone, bit for bit what
+    the parent's maps gave (one block a row, and three of four)."""
     q, k, v = _grouped_operands(model, 6, seed=11)
     lengths = jnp.asarray([0, 0, 300, 0, 17, 0], jnp.int32)
     out = np.asarray(ak.ragged_decode_attention(
@@ -212,6 +382,17 @@ def test_mxu_body_in_a_pool_with_idle_rows(model):
         assert (out[row] == 0).all()
     np.testing.assert_allclose(out[[2, 4]], want[[2, 4]], rtol=6e-3,
                                atol=6e-3)
+    for block in (None, 128):
+        got = ak.ragged_decode_attention(q.astype(jnp.float32), k, v,
+                                         lengths, block_k=block,
+                                         interpret=True)
+        asked = []
+        with under_rule(parent_dead_steps, asked):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(
+                ak.ragged_decode_attention(
+                    q.astype(jnp.float32), k, v, lengths, block_k=block,
+                    interpret=True)))
+        assert asked == [True]          # a live step is the longer one
 
 
 def kernel_call(q, k, v):
@@ -234,7 +415,7 @@ def kernel_call(q, k, v):
     call, = find(jax.make_jaxpr(ak.ragged_decode_attention)(*args).jaxpr,
                  "pallas_call")
     return (call.params["grid_mapping"].grid,
-            [var.aval.shape for var in call.invars[4:]],
+            [var.aval.shape for var in call.invars[3:]],
             len(list(find(call.params["jaxpr"], "dot_general"))))
 
 
@@ -287,7 +468,6 @@ def test_grouped_query_attention_step_takes_the_kernel_on_full_rows_only(
     takes here: a full layer goes through ``ragged_decode_attention`` and
     gives the same rows where a row is active, an idle row included in
     the pool; a window layer never asks for the kernel."""
-    import functools
     from bigdl_tpu.nn.attention import GroupedQueryAttention
     rows, max_len = 5, 256
     layer = GroupedQueryAttention(
@@ -396,7 +576,7 @@ def test_latent_body_matches_the_masked_product(dtype, block, padded):
     assert np.abs(blind[3:] - out[3:]).max() > 0.05
 
 
-def test_latent_body_in_a_pool_with_idle_rows():
+def test_latent_body_in_a_pool_with_idle_rows(under_rule):
     ql, qr, rotary, latent = _latent_operands(6, jnp.bfloat16, seed=12)
     lengths = jnp.asarray([0, 0, 300, 0, 17, 0], jnp.int32)
     out = np.asarray(ak.latent_decode_attention(
@@ -406,6 +586,19 @@ def test_latent_body_in_a_pool_with_idle_rows():
         assert (out[row] == 0).all()
     np.testing.assert_allclose(out[[2, 4]], want[[2, 4]], rtol=6e-3,
                                atol=6e-3)
+    # bit for bit what the parent's maps gave, at one block a row and at
+    # three of four
+    for block in (None, 128):
+        got = ak.latent_decode_attention(
+            ql, qr, rotary, latent, lengths, scale=LATENT_SCALE,
+            block_k=block, interpret=True)
+        asked = []
+        with under_rule(parent_dead_steps, asked):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(
+                ak.latent_decode_attention(
+                    ql, qr, rotary, latent, lengths, scale=LATENT_SCALE,
+                    block_k=block, interpret=True)))
+        assert asked == [True]
 
 
 def test_latent_call_fetches_each_block_once_and_refuses_other_shapes():
@@ -433,7 +626,10 @@ def test_latent_call_fetches_each_block_once_and_refuses_other_shapes():
         lambda *a: ak.latent_decode_attention(*a, scale=0.135))(*args).jaxpr,
         "pallas_call")
     assert call.params["grid_mapping"].grid == (112, 14)
-    assert [v.aval.shape for v in call.invars[4:]] == [
+    # three prefetched scalars a row go ahead: the lengths, and the (row,
+    # block) its dead steps name
+    assert [v.aval.shape for v in call.invars[:3]] == [(112,)] * 3
+    assert [v.aval.shape for v in call.invars[3:]] == [
         (112, 1, 64, 64), (112, 1, 64, 512), (112, 1, 64, 7168),
         (112, 1, 7168, 512), (112, 1, 7168)]
     assert len(list(find(call.params["jaxpr"], "dot_general"))) == 3

@@ -874,3 +874,40 @@ def test_decode_position_counters_follow_a_known_schedule(
     else:
         assert st["decode_positions_read"] == n * 2 * 384
     assert st["decode_positions_live"] <= st["decode_positions_read"]
+
+
+@pytest.mark.parametrize("ragged", [False, True], ids=["xla", "ragged"])
+def test_decode_row_counters_count_the_rows_a_call_starts(
+        lm384, monkeypatch, ragged):
+    """Two of a pool's four slots decode: a full layer's call of the
+    ragged kernel starts two rows and fetches the second's first key block
+    behind a step of the first (``decode_rows_live`` 2,
+    ``decode_rows_prefetched`` 1 a dispatch); a row alone has no live row
+    before it; a step that reads whole rows counts none.  First the
+    pool's handle, then the engine's sums against its own pass records."""
+    if ragged:
+        _force_ragged(monkeypatch)
+    pool = SlotPool(lm384, slots=4)
+    pool.activate(1, 7, 130)
+    assert pool.decode_dispatch().rows == ((1, 0) if ragged else (0, 0))
+    pool.activate(3, 9, 5)
+    assert pool.decode_dispatch().rows == ((2, 1) if ragged else (0, 0))
+    rng = np.random.default_rng(9)
+    eng = GenerationScheduler(lm384, slots=4)
+    try:
+        assert eng.stats()["decode_rows_live"] == 0
+        futs = [eng.submit_async(rng.integers(1, 51, n).astype(np.int32), 16)
+                for n in (40, 130)]
+        for f in futs:
+            f.result(timeout=300)
+    finally:
+        eng.shutdown()
+    st = eng.stats()
+    active = st["pass_log"].records()["n_active"]
+    assert active.max() == 2 and len(active) == st["decode_dispatches"]
+    if ragged:
+        assert st["decode_rows_live"] == active.sum()
+        assert st["decode_rows_prefetched"] \
+            == np.maximum(active - 1, 0).sum() > 0
+    else:
+        assert st["decode_rows_live"] == st["decode_rows_prefetched"] == 0

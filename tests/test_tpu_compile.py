@@ -375,8 +375,11 @@ def test_opt_pool_leaf_keeps_the_vector_unit_body_and_its_block_on_v5e(v5e):
     call, = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert re.search(r"= bf16\[6,64,32\]\S* custom-call\(", call)
-    assert ("f32[6,64,32]{2,1,0}, f32[6,32,64,2048]{3,2,1,0}, "
-            "f32[6,32,64,2048]{3,2,1,0}, f32[6,1,2048]{2,1,0}") in call
+    # three prefetched scalars a row (the lengths and what a dead step
+    # names), then the body's four operands
+    assert ("{s32[6]{0}, s32[6]{0}, s32[6]{0}, f32[6,64,32]{2,1,0}, "
+            "f32[6,32,64,2048]{3,2,1,0}, f32[6,32,64,2048]{3,2,1,0}, "
+            "f32[6,1,2048]{2,1,0}}") in call
     assert len(re.findall(r"= f32\[6,32,64,2048\]\S* bitcast\(", text)) == 2
     assert not re.findall(
         r"= f32\[6,32,(?:64,2048|2048,64)\]\S* (?:copy|transpose)\(", text)
