@@ -1,11 +1,11 @@
 """Decoder-only language model built from a per-layer list of kinds:
 window and full grouped-query attention layers, layers whose attention
 runs in parallel with a state-space mixer, latent-attention layers,
-layers that are a state-space mixer alone, layers that keep nothing and
-read what an earlier layer made in the same pass (its row of keys and
-values, or its mixer's scan output), a leading dense gated feed-forward
-layer, and a held share of sigmoid-routed gated experts (with or without
-a shared expert).
+layers that are a state-space mixer or a gated short convolution alone,
+layers that keep nothing and read what an earlier layer made in the same
+pass (its row of keys and values, or its mixer's scan output), leading
+dense gated feed-forward layers, and a held share of sigmoid-routed gated
+experts (with or without a shared expert).
 
 The block of ``mimo_v2`` (MiMo-V2.5), pre-norm and sequential:
 ``h = x + Attn_i(RMSNorm(x))``, ``y = h + FFN_i(RMSNorm(h))``.
@@ -49,6 +49,15 @@ every layer has ``cache["self"]`` any more**: a mixer-only layer keeps a
 state and no row, a unit and a cross layer keep nothing, and a chunk's
 rows stop where the caches stop (:class:`HybridDecoder`).
 
+The walk of ``lfm2_moe`` (LFM2-24B-A2B) is sequential with RMS norms
+and a head tied to the embedding: three layers in four are a **gated
+short convolution** (:class:`bigdl_tpu.nn.short_conv.GatedShortConv`: a
+:class:`MixerBlock` whose state is the convolution's two-row tail and
+nothing else), the fourth full grouped-query attention whose query and
+key heads are normed before they are rotated; the first
+``num_dense_layers`` (two) feed-forwards are dense gated layers, the rest
+all 64 sigmoid-routed experts with a selection bias.
+
 It keeps the repo's conventions (``TransformerLM``): token ids are
 1-based with 0 as padding, and generation emits ``argmax + 1`` (the
 untied head has exactly ``vocab_size`` rows: none is untrained).  It has
@@ -61,7 +70,7 @@ positions of one head: the rotary key and the compressed row), and
 beside the row of a parallel layer a ``state`` (no positions: the
 mixer's recurrence and the last inputs of its convolution).  A model
 with expert layers also returns what they did (``routing``, int32
-``[4]``) from every pass the pool runs.
+``[ROUTING]``: ``HeldExperts.forward``) from every pass the pool runs.
 
 The residual stream, the norms, the scores and the router are float32;
 the matrix products take their operands in the weights' dtype.
@@ -79,14 +88,13 @@ from bigdl_tpu.nn.attention import GroupedQueryAttention
 from bigdl_tpu.nn.differential_attention import DifferentialAttention
 from bigdl_tpu.nn.latent_attention import LatentAttention
 from bigdl_tpu.nn.linear import Linear, LookupTable
-from bigdl_tpu.nn.moe import HeldExperts
+from bigdl_tpu.nn.moe import ROUTING, HeldExperts
+from bigdl_tpu.nn.short_conv import GatedShortConv
 from bigdl_tpu.nn.ssm import Mamba1Mixer, Mamba2Mixer
 from bigdl_tpu.ops import cache_kernels
 
 __all__ = ["HybridDecoder", "mimo_v2", "falcon_h1", "sarvam_mla",
-           "phi4_flash"]
-
-ROUTING = 4     # what an expert layer counts: HeldExperts.forward
+           "phi4_flash", "lfm2_moe"]
 
 
 def _product(x, layer: Linear):
@@ -330,12 +338,16 @@ class ParallelBlock(HybridBlock):
 
 
 class MixerBlock(HybridBlock):
-    """A state-space mixer alone (:class:`Mamba1Mixer`): the layer keeps
-    a **state and no row**.  With ``hands_on`` its scan output of this
-    pass goes into ``walk["memory"]``, for the gated memory units
-    after it."""
+    """A mixer with a state alone: the layer keeps a **state and no
+    row**.  The mixer is a :class:`Mamba1Mixer` (a recurrence and the
+    last inputs of its convolution) or a :class:`GatedShortConv`, whose
+    state is **the convolution's tail and nothing else**: the block asks
+    the mixer for its state (``init_state``) and takes whatever leaves it
+    has, and a mixer returns ``(out, state)`` and, where it has one, its
+    scan output.  With ``hands_on`` that scan output of this pass goes
+    into ``walk["memory"]``, for the gated memory units after it."""
 
-    def __init__(self, hidden_size: int, ssm: Mamba1Mixer, ffn: Module,
+    def __init__(self, hidden_size: int, ssm: Module, ffn: Module,
                  eps: float, norm=RMSNorm, hands_on: bool = False):
         super().__init__(hidden_size, None, ffn, eps, norm)
         self.ssm = ssm
@@ -349,11 +361,11 @@ class MixerBlock(HybridBlock):
 
     def _mix(self, x, index, cache, pad, slot, active, valid, walk):
         u = self.attn_norm(x).astype(self.ssm.in_proj.weight.dtype)
-        s, state, y = _run_mixer(
+        s, state, *scan = _run_mixer(
             self.ssm, u, index, None if cache is None else cache["ssm"],
             slot, active, valid)
         if self.hands_on:
-            walk["memory"] = y
+            walk["memory"] = scan[0]
         return x + s, {"ssm": state}
 
 
@@ -432,6 +444,14 @@ class HybridDecoder(Module):
     constants by name (absent: 1): ``embedding``, ``lm_head``, ``key``,
     ``mlp_gate``, ``mlp_down``, and a parallel block's four.
 
+    ``conv`` (the arguments of :class:`GatedShortConv`) adds the kind
+    ``"conv"``: that mixer alone, a **state that is a tail and no row**.
+    ``sparse`` may name any layers dense, several leading ones among them;
+    ``qk_norm`` norms every query and key head of the ``"full"`` and
+    ``"window"`` layers before rotation, ``tie_head`` scores with the
+    embedding table, and ``normalize_eps`` goes under the routing
+    weights' normalising sum.
+
     ``shared`` (a dict) builds the decoder-hybrid-decoder walk of
     ``phi4_flash``: every attention layer differential with biases and no
     positions encoded (:class:`DifferentialAttention`), LayerNorms with a
@@ -466,7 +486,10 @@ class HybridDecoder(Module):
                  multipliers: Optional[Dict[str, float]] = None,
                  latent: Optional[Dict[str, Any]] = None,
                  shared_size: int = 0, expert_scale: float = 1.0,
-                 shared: Optional[Dict[str, Any]] = None):
+                 shared: Optional[Dict[str, Any]] = None,
+                 conv: Optional[Dict[str, Any]] = None,
+                 qk_norm: bool = False, tie_head: bool = False,
+                 normalize_eps: float = 0.0):
         super().__init__()
         if len(layer_kinds) != len(sparse):
             raise ValueError("one kind and one sparse flag a layer")
@@ -480,12 +503,14 @@ class HybridDecoder(Module):
             self.embedding.weight * hidden_size ** -0.5)
         blocks = []
         kinds = ("full", "window", "parallel", "latent") + (
+            ("conv",) if conv else ()) + (
             ("selective", "memory", "cross") if shared else ())
         norm = LayerNorm if shared else RMSNorm
         for depth, (kind, is_sparse) in enumerate(zip(layer_kinds, sparse)):
             if kind not in kinds:
                 raise ValueError(f"layer kind {kind!r}: one of {kinds} "
-                                 f"(the last three with shared=)")
+                                 f"('conv' with conv=, 'selective', "
+                                 f"'memory' and 'cross' with shared=)")
             win = kind == "window"
             attends = "window" if win else "full"   # a parallel layer: full
             if shared:
@@ -503,18 +528,22 @@ class HybridDecoder(Module):
                     raise ValueError("a latent layer needs latent=")
                 attn = LatentAttention(hidden_size, num_heads, eps=eps,
                                        **latent)
+            elif kind == "conv":
+                attn = None
             else:
                 attn = GroupedQueryAttention(
                     hidden_size, num_heads, kv_heads[attends], head_dim,
                     v_head_dim, window=window if win else None,
                     rope_theta=rope_theta[attends], rotary_dim=rotary_dim,
                     sink=win and window_sink, value_scale=value_scale,
-                    key_scale=mult.get("key", 1.0))
+                    key_scale=mult.get("key", 1.0), qk_norm=qk_norm,
+                    norm_eps=eps)
             ffn = HeldExperts(
                 hidden_size, expert_size, num_experts, top_k, held,
                 normalize_top_k,
                 shared=GatedFFN(hidden_size, shared_size) if shared_size
-                else None, scale=expert_scale) if is_sparse \
+                else None, scale=expert_scale,
+                normalize_eps=normalize_eps) if is_sparse \
                 else GatedFFN(hidden_size, dense_size,
                               mult.get("mlp_gate", 1.0),
                               mult.get("mlp_down", 1.0))
@@ -524,6 +553,10 @@ class HybridDecoder(Module):
                 blocks.append(ParallelBlock(
                     hidden_size, attn, Mamba2Mixer(hidden_size, eps=eps,
                                                    **ssm), ffn, eps, mult))
+            elif kind == "conv":
+                blocks.append(MixerBlock(
+                    hidden_size, GatedShortConv(hidden_size, **conv), ffn,
+                    eps, norm))
             elif kind == "selective":
                 blocks.append(MixerBlock(
                     hidden_size, Mamba1Mixer(hidden_size, **shared["mixer"]),
@@ -561,7 +594,7 @@ class HybridDecoder(Module):
             getattr(blocks[last], "attn", None), "write")
         self.chunk_layers = last + (not self.chunk_writes)
         self.final_norm = norm(hidden_size, eps)
-        self.tied = bool(shared)
+        self.tied = bool(shared) or bool(tie_head)
         if not self.tied:
             self.lm_head = Linear(hidden_size, vocab_size, with_bias=False)
 
@@ -614,8 +647,8 @@ class HybridDecoder(Module):
         step (:meth:`decode_step` with ``index [B]``): a layer's keys and
         values in one where ``ops.cache_row_writer`` takes its leaves, a
         ``dynamic_update_slice`` a row and leaf where not, one select
-        over the padding flags, and for a state two whatever the rows
-        (the recurrence's update and the select that shifts the
+        over the padding flags, and for a state one a leaf whatever the
+        rows (a recurrence's update, and the select that shifts a
         convolution's inputs).  The serving pool counts by this."""
         rows = caches["pad"].shape[0]
         programs = 1
@@ -624,7 +657,7 @@ class HybridDecoder(Module):
                 k, v = layer["self"]["k"], layer["self"]["v"]
                 programs += 1 if cache_kernels.cache_row_writer(
                     k.shape, v.shape, k.dtype) is not None else 2 * rows
-            programs += 2 * ("ssm" in layer)
+            programs += len(layer.get("ssm", ()))
         return programs
 
     @staticmethod
@@ -1087,3 +1120,68 @@ def phi4_flash(config: Dict[str, Any], max_len: int) -> HybridDecoder:
                        state_size=c.get("mamba_d_state", 16),
                        dt_rank=None if rank == "auto" else int(rank),
                        conv_width=c.get("mamba_d_conv", 4))))
+
+
+_LFM2_REFUSED = ("conv_bias", "attention_bias", "mlp_bias", "rope_scaling",
+                 "num_shared_experts", "n_shared_experts")
+
+
+def lfm2_moe(config: Dict[str, Any], max_len: int) -> HybridDecoder:
+    """The model from the keys of a public ``lfm2_moe`` ``config.json``
+    (LFM2-24B-A2B) plus the chip's share: ``experts_held`` (how many of
+    ``num_experts`` live here, from ``experts_offset``, default 0: all of
+    them).  ``layer_types[i]`` names layer ``i``'s mixer for the first
+    ``num_hidden_layers`` layers: ``"conv"`` a gated short convolution of
+    ``conv_L_cache`` taps, ``"full_attention"`` grouped-query attention
+    (``num_attention_heads`` heads of ``hidden_size / num_attention_heads``
+    over ``num_key_value_heads``) whose query and key heads go through an
+    RMS norm before the half-split rotation over the whole head (base
+    ``rope_parameters.rope_theta``).  The first ``num_dense_layers``
+    feed-forwards are dense gated layers of ``intermediate_size``, the
+    others ``num_experts`` sigmoid-routed experts of
+    ``moe_intermediate_size`` with a selection bias (``use_expert_bias``),
+    ``num_experts_per_tok`` a token, weights over the sum of the chosen
+    plus ``1e-6``, the routed sum times ``routed_scaling_factor``.  RMS
+    norms at ``norm_eps``, no bias anywhere, the head tied to the
+    embedding.  What is not built is refused by name: a bias on the
+    convolution or a projection, a scaled rotary embedding, shared
+    experts, a router without its selection bias, weights not normalised
+    over the chosen, an untied head, another layer type."""
+    c = config
+    for key in _LFM2_REFUSED:
+        if c.get(key):
+            raise ValueError(f"lfm2_moe: {key}={c[key]!r} is not built")
+    for key in ("use_expert_bias", "norm_topk_prob", "tie_word_embeddings"):
+        if not c.get(key, True):
+            raise ValueError(f"lfm2_moe: {key}={c[key]!r} is not built")
+    rope = c.get("rope_parameters") or {}
+    if rope.get("rope_type", "default") != "default":
+        raise ValueError(f"lfm2_moe: rope_parameters={rope!r} is not built "
+                         f"(rope_type default)")
+    n = c["num_hidden_layers"]
+    names = {"conv": "conv", "full_attention": "full"}
+    types = list(c["layer_types"])[:n]
+    for kind in types:
+        if kind not in names:
+            raise ValueError(f"lfm2_moe: layer_types has {kind!r}: 'conv' "
+                             f"and 'full_attention' are what is built")
+    hidden, heads = c["hidden_size"], c["num_attention_heads"]
+    head_dim = c.get("head_dim") or hidden // heads
+    return HybridDecoder(
+        vocab_size=c["vocab_size"], hidden_size=hidden,
+        layer_kinds=[names[kind] for kind in types],
+        sparse=[i >= c.get("num_dense_layers", 0) for i in range(n)],
+        num_heads=heads, head_dim=head_dim, v_head_dim=head_dim,
+        kv_heads={"full": c["num_key_value_heads"]},
+        rope_theta={"full": float(rope.get("rope_theta",
+                                           c.get("rope_theta", 1e6)))},
+        rotary_dim=head_dim, window=0, window_sink=False, value_scale=1.0,
+        dense_size=c["intermediate_size"],
+        expert_size=c["moe_intermediate_size"],
+        num_experts=c["num_experts"], top_k=c["num_experts_per_tok"],
+        held=(c.get("experts_offset", 0),
+              c.get("experts_held", c["num_experts"])),
+        eps=c.get("norm_eps", 1e-5), max_len=max_len,
+        expert_scale=float(c.get("routed_scaling_factor") or 1.0),
+        conv=dict(taps=c.get("conv_L_cache", 3)), qk_norm=True,
+        tie_head=True, normalize_eps=1e-6)

@@ -21,6 +21,7 @@ from bigdl_tpu.nn.latent_attention import *  # noqa: F401,F403
 from bigdl_tpu.nn.differential_attention import *  # noqa: F401,F403
 from bigdl_tpu.nn.moe import *             # noqa: F401,F403
 from bigdl_tpu.nn.ssm import *             # noqa: F401,F403
+from bigdl_tpu.nn.short_conv import *      # noqa: F401,F403
 from bigdl_tpu.nn.quantized import *       # noqa: F401,F403
 from bigdl_tpu.nn.detection import *       # noqa: F401,F403
 from bigdl_tpu.nn.sparse import *          # noqa: F401,F403

@@ -479,6 +479,22 @@ class AttentionSink(Module):
         self.bias = Parameter(jnp.zeros(num_heads))
 
 
+class HeadNorm(Module):
+    """``x / rms(x) * gain`` over a head's width, in float32: one learned
+    gain a dim, shared by the heads it is applied to."""
+
+    def __init__(self, head_dim: int, eps: float = 1e-5):
+        super().__init__()
+        self.eps = eps
+        self.weight = Parameter(jnp.ones(head_dim))
+
+    def forward(self, x):
+        x = x.astype(jnp.float32)
+        ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+        return x * jax.lax.rsqrt(ms + self.eps) \
+            * self.weight.astype(jnp.float32)
+
+
 class GroupedQueryAttention(Module):
     """Causal self-attention with fewer key/value heads than query
     heads, keys ``head_dim`` wide and values ``v_head_dim``, for the two
@@ -493,17 +509,24 @@ class GroupedQueryAttention(Module):
     key head are rotated by position (:func:`rotary_half`, base
     ``rope_theta``: a layer kind has its own).  The keys are scaled by
     ``key_scale`` as they leave their projection and the context by
-    ``value_scale`` before the output projection.  No bias, no q/k norm,
-    scores over ``sqrt(head_dim)``.  :meth:`forward` is the one entry: a
-    full forward, a prefill that returns compact keys and values, a
-    prefill chunk and a decode step differ only in whether a cache is
-    passed and what ``index`` is."""
+    ``value_scale`` before the output projection.  With ``qk_norm`` every
+    query head and every key head goes through an RMS norm over its
+    ``head_dim`` (:class:`HeadNorm`: ``q_norm`` and ``k_norm``, a learned
+    gain each, eps ``norm_eps``) as it leaves its projection, **before**
+    it is rotated (named scope ``attn/qk_norm``); the cache then holds
+    normed, rotated keys.  No bias, scores over ``sqrt(head_dim)``.
+    :meth:`forward` is the one entry, so the norm is on every path: a full
+    forward, a prefill that returns compact keys and values, a prefill
+    chunk and a decode step differ only in whether a cache is passed and
+    what ``index`` is (the class has no ``write``: a chunk's rows walk
+    such a layer whole)."""
 
     def __init__(self, hidden_size: int, num_heads: int, num_kv_heads: int,
                  head_dim: int, v_head_dim: Optional[int] = None,
                  window: Optional[int] = None, rope_theta: float = 10000.0,
                  rotary_dim: int = 0, sink: bool = False,
-                 value_scale: float = 1.0, key_scale: float = 1.0):
+                 value_scale: float = 1.0, key_scale: float = 1.0,
+                 qk_norm: bool = False, norm_eps: float = 1e-5):
         super().__init__()
         if num_heads % num_kv_heads:
             raise ValueError("num_heads must be a multiple of num_kv_heads")
@@ -528,6 +551,10 @@ class GroupedQueryAttention(Module):
         if sink:
             self.sink = AttentionSink(num_heads)
         self.has_sink = bool(sink)
+        self.has_qk_norm = bool(qk_norm)
+        if qk_norm:
+            self.q_norm = HeadNorm(head_dim, norm_eps)
+            self.k_norm = HeadNorm(head_dim, norm_eps)
 
     def cache_length(self, max_len: int, ring_margin: int = 1) -> int:
         """Places of one cache row.  A ``full`` row holds ``max_len``
@@ -597,6 +624,9 @@ class GroupedQueryAttention(Module):
                         self.head_dim)
         v = self._heads(x, self.v_layer, self.num_kv_heads,
                         self.v_head_dim)
+        if self.has_qk_norm:
+            with jax.named_scope("attn/qk_norm"):
+                q, k = self.q_norm.forward(q), self.k_norm.forward(k)
         if self.key_scale != 1.0:
             k = k * self.key_scale
         index = jnp.asarray(index, jnp.int32)

@@ -30,9 +30,10 @@ stacked once when the layer is built: no path re-stacks a Python list of
 modules on every call.
 
 :class:`HeldExperts` is the serving-side layer: one chip's share of a
-sigmoid-routed layer of gated experts, no capacity and no drop; a batched
-product over every held stack for a pool's step or chunk, a grouped
-product for a longer call.
+sigmoid-routed layer of gated experts (or all of it), no capacity and no
+drop; a batched product over every held stack for a share's step or
+chunk, and the pairs laid out by expert in tiles (``ops.expert_kernels``)
+for a longer call and for a layer that holds every expert.
 """
 
 from __future__ import annotations
@@ -47,29 +48,35 @@ from jax.sharding import Mesh, PartitionSpec as P
 from bigdl_tpu.core.module import Module, Parameter
 from bigdl_tpu.telemetry import collectives as _coll
 from bigdl_tpu.nn.linear import Linear
+from bigdl_tpu.ops import attention_kernels, expert_kernels
 from bigdl_tpu.utils.rng import next_key
 from bigdl_tpu.parallel.mesh import pin_replicated, shard_map_compat
 
 __all__ = ["MoE", "HeldExperts", "route_top_k"]
+
+ROUTING = 5     # what a call of HeldExperts counts (``forward``)
 
 # Per-device (inside-shard_map) buffer shapes of the most recent a2a
 # trace — a debug/test hook (module attrs would pollute the pytree).
 LAST_A2A_SHAPES = {}
 
 
-def route_top_k(scores, k: int, normalize: bool = True, bias=None):
+def route_top_k(scores, k: int, normalize: bool = True, bias=None,
+                eps: float = 0.0):
     """Routing from scores to weights, the one place it is decided:
     ``scores [..., E]`` (softmax probabilities, sigmoids: any
     non-negative score) -> ``(experts [..., k] int32, weights [..., k])``.
     The ``k`` largest of ``scores + bias`` are chosen (``bias [E]``: a
     selection bias that balances load and never reaches the weights);
     each weight is the chosen expert's own score and, with
-    ``normalize``, over the sum of the chosen (``norm_topk_prob``)."""
+    ``normalize``, over the sum of the chosen plus ``eps``
+    (``norm_topk_prob``; some families publish ``+ 1e-6`` there)."""
     ranked = scores if bias is None else scores + bias
     _, idx = jax.lax.top_k(ranked, k)
     vals = jnp.take_along_axis(scores, idx, axis=-1)
     if normalize:
-        vals = vals / jnp.sum(vals, axis=-1, keepdims=True)
+        total = jnp.sum(vals, axis=-1, keepdims=True)
+        vals = vals / (total + eps if eps else total)
     return idx.astype(jnp.int32), vals
 
 
@@ -306,15 +313,6 @@ class MoE(Module):
         return fn(stacked, x, weights)
 
 
-def grouped_product(rows, stack, sizes):
-    """``rows [M, in]`` sorted by group, ``stack [E, in, out]``, ``sizes
-    [E]`` rows a group -> float32 ``[M, out]``: rows of group ``e`` times
-    ``stack[e]``.  Rows past ``sum(sizes)`` are not computed, and what
-    they hold is undefined."""
-    return jax.lax.ragged_dot(rows, stack, sizes,
-                              preferred_element_type=jnp.float32)
-
-
 class Router(Module):
     """The router of :class:`HeldExperts`: ``weight [E, H]`` scores every
     expert, ``bias [E]`` moves which are chosen and never the weights."""
@@ -349,36 +347,78 @@ class HeldExperts(Module):
     alike and it is counted once: it is no pair, and ``counts`` does not
     see it.
 
-    **Two products over the stack, chosen by the call's token count**
-    (static under ``jit``).  Up to ``DENSE_TOKENS`` tokens (a slot pool's
-    decode step and its prefill chunks) every token goes through every
-    held expert in one batched product and the routing weights, zero for
-    an expert a token did not choose, pick the result: each held stack is
-    read once a call whatever the routing, as it is in the deployment the
-    share stands for, whose pooled sequences leave no held expert idle; a
-    call's cost then does not follow which experts its few tokens chose.
-    Above it (a whole sequence at once) the token-to-expert pairs are
-    grouped by held expert and go through one grouped product
-    (``jax.lax.ragged_dot``), so an expert costs the tokens that chose
-    it.  The count is where the two cross on a v5e at 16 held of 256
-    experts of 4,096 x 2,048 (PERF.md section 6, PR 30): one layer took
-    1.12 against 1.16 ms at 32 tokens, 1.40 against 2.83 at 256, 2.49
-    against 3.22 at 512 and 5.00 against 4.59 at 1,024.
+    **Two products over the stacks; which one a call takes follows from
+    static shapes** (:meth:`product_of`: the held share and the call's
+    tokens; no option).
+
+    *Every stack* (``_every_stack``): every token goes through every held
+    expert in one batched product and the routing weights, zero for an
+    expert a token did not choose, pick the result.  It is what **a held
+    share** takes up to ``DENSE_TOKENS`` tokens a call (a slot pool's
+    decode step and its prefill chunks): each held stack is read once a
+    call whatever the routing, as it is in the deployment the share stands
+    for, whose other chips' sequences leave no held expert idle; a call's
+    cost then does not follow which experts its few tokens chose.
+
+    *Tiled* (``_tiled``): the token-to-expert pairs are laid out by held
+    expert, each expert's rows padded to whole tiles of
+    ``ops.expert_kernels.ROW_TILE``, and two Pallas programs do the three
+    products, a tile's expert a prefetched scalar: each chosen expert's
+    stack is read once a call, an expert nobody chose is never read, and
+    the cost follows the tiles.  It is what a share's longer calls take,
+    and **every call of a layer that holds every expert**: such a layer
+    has all the pairs there are (no other chip's tokens would come), and
+    through every held expert it would multiply ``num_experts / top_k``
+    times the rows the routing asks for.
+
+    One layer alone on a v5e, bfloat16, ms a call (PERF.md section 6, PR
+    46; *XLA's* ``ragged_dot`` *over sorted pairs, the longer calls'
+    product until then, is gone: the tiles beat it at every size*):
+
+    ====================  ======  ===========  ==========  ===========
+    held / experts, k     tokens  every stack  ragged_dot  tiled (16)
+    ====================  ======  ===========  ==========  ===========
+    64 / 64, 4            16      1.749        1.342       1.249
+    (2,048 x 1,536)       128     1.770        4.001       1.823
+    ..                    256     2.210        4.103       2.185
+    ..                    384     3.035        4.219       2.477
+    16 / 256, 8           32      1.227        1.116       0.859
+    (4,096 x 2,048)       288     1.568        2.045       1.677
+    ..                    1,024   4.990        4.697       3.364
+    16 / 128, 8           32      1.227        1.574       1.179
+    ..                    288     1.552        2.127       1.811
+    ====================  ======  ===========  ==========  ===========
+
+    With tiles of 32, which is what stands (``ops.expert_kernels`` says
+    why), the 64-held layer read 1.848, 1.978 and 2.064 ms at 128, 256 and
+    384 tokens: within 5 % of every stack at a step's 128 rows, 10 and 32 %
+    under it at a chunk's and a joint pass's sizes.
+
+    The standing cells' shares (16 held; 32 to 112 rows a step, 288 to 368
+    tokens a joint pass) would win a plain step and lose a joint pass,
+    where their tail sits: they keep every stack, as the rule above says
+    they should.  ``jax.experimental.pallas.ops.tpu.megablox.gmm`` under
+    the same layout read within 4 % of the repo's kernels at 64 held and 4
+    to 9 % under them at 16 (its blocks are fetched a stack at a time), and
+    brings ``while`` loops of group metadata into every program; it is not
+    used.
 
     ``forward(x [..., H], valid=None) -> (y [..., H] float32, counts)``:
     ``x`` is routed as it comes (float32 from a float32 norm) and cast to
     the experts' dtype for their products; ``valid [...]`` false keeps a
     token away from every expert (a slot pool's idle lanes, padding);
-    ``counts`` is int32 ``[4]``: 1 (this call), the token-to-expert pairs
-    routed, those that landed on a held expert, and the held experts that
-    had at least one."""
+    ``counts`` is int32 ``[ROUTING]``: 1 (this call), the token-to-expert
+    pairs routed, those that landed on a held expert, the held experts
+    that had at least one, and ``rows_computed``: the token-expert rows
+    the product multiplied (held experts x tokens through every stack;
+    the rows the tiles covered, padding and all, on the tiled product)."""
 
     DENSE_TOKENS = 512
 
     def __init__(self, hidden_size: int, expert_size: int, num_experts: int,
                  top_k: int, held: Optional[tuple] = None,
                  normalize: bool = True, shared: Optional[Module] = None,
-                 scale: float = 1.0):
+                 scale: float = 1.0, normalize_eps: float = 0.0):
         super().__init__()
         first, count = (0, num_experts) if held is None else held
         if not 0 <= first < first + count <= num_experts:
@@ -386,6 +426,7 @@ class HeldExperts(Module):
         self.num_experts, self.top_k = num_experts, top_k
         self.first, self.count = int(first), int(count)
         self.normalize = normalize
+        self.normalize_eps = float(normalize_eps)
         self.scale = float(scale)
         self.has_shared = shared is not None
         if shared is not None:
@@ -411,11 +452,13 @@ class HeldExperts(Module):
                 precision=jax.lax.Precision.HIGHEST)
             return route_top_k(jax.nn.sigmoid(logits), self.top_k,
                                self.normalize,
-                               self.router.bias.astype(jnp.float32))
+                               self.router.bias.astype(jnp.float32),
+                               self.normalize_eps)
 
     def _every_stack(self, x, local, weights, held):
         """Every token through every held expert: ``x [T, H]`` in the
-        experts' dtype -> ``(y [T, H] float32, held experts chosen)``."""
+        experts' dtype -> ``(y [T, H] float32, held experts chosen,
+        token-expert rows multiplied)``."""
         with jax.named_scope("moe/experts"):
             dot = functools.partial(jnp.einsum,
                                     preferred_element_type=jnp.float32)
@@ -428,29 +471,65 @@ class HeldExperts(Module):
                 local[..., None] == jnp.arange(self.count))  # [T, k, count]
             w = jnp.sum(jnp.where(chose, weights[..., None], 0.0), axis=1)
             return (jnp.einsum("eth,te->th", out, w),
-                    jnp.sum(jnp.any(chose, axis=(0, 1))))
+                    jnp.sum(jnp.any(chose, axis=(0, 1))),
+                    jnp.int32(self.count * x.shape[0]))
 
-    def _grouped(self, x, local, weights, held):
-        """The pairs grouped by held expert: same arguments and result."""
+    def _tiled(self, x, local, weights, held):
+        """The pairs laid out by held expert, each expert's rows padded to
+        whole tiles (``ops.expert_kernels``): same arguments and result.
+        No sort: a pair's place is its expert's first row plus how many
+        pairs of that expert come before it (a running count), and the
+        token of each place is read off by comparing places (pairs x
+        places booleans: a few million at a pool's pass)."""
         T, k = local.shape
+        tile, pairs = expert_kernels.ROW_TILE, T * k
+        interpret = not attention_kernels._on_tpu()
         with jax.named_scope("moe/experts"):
-            # every pair gets a group: its held expert, or the one past
-            # the last (never computed); sorted, each expert's rows lie
-            # together and the rows of no held expert come last
-            group = jnp.where(held, local, self.count).reshape(-1)
-            order = jnp.argsort(group, stable=True)
-            sizes = jnp.zeros((self.count + 1,), jnp.int32).at[group].add(1)
-            rows = x[order // k]                              # [T*k, H]
-            dot = functools.partial(grouped_product,
-                                    sizes=sizes[:self.count])
-            act = jax.nn.silu(dot(rows, self.w_gate)) * dot(rows, self.w_up)
-            out = dot(act.astype(x.dtype), self.w_down)
+            on = held.reshape(-1)
+            hot = on[:, None] & (
+                local.reshape(-1, 1) == jnp.arange(self.count))
+            sizes = jnp.sum(hot, axis=0, dtype=jnp.int32)
+            padded = (sizes + tile - 1) // tile * tile
+            ends = jnp.cumsum(padded)
+            before = jnp.cumsum(hot, axis=0, dtype=jnp.int32) - hot
+            # a token chooses an expert once, and every expert's last tile
+            # may be all but empty
+            places = T * min(k, self.count) + self.count * (tile - 1)
+            places = (places + tile - 1) // tile * tile
+            place = jnp.where(on, jnp.sum(
+                jnp.where(hot, ends - padded + before, 0), axis=1), places)
+            token = jnp.sum(jnp.where(
+                place[None, :] == jnp.arange(places)[:, None],
+                jnp.arange(pairs) // k, 0), axis=1)         # padding: token 0
+            group = jnp.minimum(jnp.sum(
+                ends[None, :] <= tile * jnp.arange(places // tile)[:, None],
+                axis=1, dtype=jnp.int32), self.count - 1)
+            used = ends[-1:] // tile
+            act = expert_kernels.gate_up(x[token], self.w_gate, self.w_up,
+                                         group, used, interpret=interpret)
+            out = expert_kernels.down(act, self.w_down, group, used,
+                                      interpret=interpret)
+            mine = out[jnp.minimum(place, places - 1)]        # [pairs, H]
         with jax.named_scope("moe/combine"):
-            w = (weights * held).reshape(-1)[order]
-            # rows past the held groups are whatever the product left
-            out = jnp.where(w[:, None] > 0, out * w[:, None], 0.0)
-            return (out[jnp.argsort(order)].reshape(T, k, -1).sum(axis=1),
-                    jnp.sum(sizes[:self.count] > 0))
+            w = (weights * held).reshape(-1, 1)
+            # a pair of no held expert reads a row nobody computed
+            mine = jnp.where(w > 0, mine * w, 0.0)
+            return (mine.reshape(T, k, -1).sum(axis=1),
+                    jnp.sum(sizes > 0), ends[-1])
+
+    @classmethod
+    def product_of(cls, held: int, experts: int, tokens: int) -> str:
+        """Which product a call of ``tokens`` tokens takes in a layer that
+        holds ``held`` of ``experts`` experts, ``"every_stack"`` or
+        ``"tiled"``: from static shapes alone (the class docstring says
+        why, and has the table)."""
+        share = held < experts
+        return "every_stack" if share and tokens <= cls.DENSE_TOKENS \
+            else "tiled"
+
+    def _product(self, tokens: int):
+        return getattr(self, "_" + self.product_of(
+            self.count, self.num_experts, tokens))
 
     def forward(self, x, valid=None):
         lead, H = x.shape[:-1], x.shape[-1]
@@ -461,14 +540,13 @@ class HeldExperts(Module):
         routed = jnp.ones((T, 1), bool) if valid is None \
             else valid.reshape(-1, 1)
         held = (local >= 0) & (local < self.count) & routed
-        product = self._every_stack if T <= self.DENSE_TOKENS \
-            else self._grouped
-        y, chosen = product(x.astype(self.w_gate.dtype), local, weights, held)
+        y, chosen, rows = self._product(T)(
+            x.astype(self.w_gate.dtype), local, weights, held)
         if self.scale != 1.0:
             y = y * self.scale
         if self.has_shared:
             with jax.named_scope("moe/shared"):
                 y = y + jnp.where(routed, self.shared.forward(x), 0.0)
         counts = jnp.stack([jnp.int32(1), jnp.sum(routed) * k, jnp.sum(held),
-                            chosen]).astype(jnp.int32)
+                            chosen, rows]).astype(jnp.int32)
         return y.reshape(lead + (H,)), counts

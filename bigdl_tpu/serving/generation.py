@@ -287,14 +287,15 @@ class SlotPool:
             self.slots, self.dtype,
             **({"ring_margin": self.ring_margin} if self.has_ring else {}))
         # a model with expert layers returns what they did from every
-        # pass (int32 [4]: calls, pairs routed, pairs on held experts,
-        # held experts with a token).  The counts gather on the device
+        # pass (int32 [ROUTING]: calls, pairs routed, pairs on held
+        # experts, held experts with a token, token-expert rows the
+        # product multiplied).  The counts gather on the device
         # (prefill programs add theirs) and ride the next decode step's
         # read-back behind the tokens: no transfer of their own.
         self.expert_layers = int(model.expert_layers()) \
             if hasattr(model, "expert_layers") else 0
-        self._routing = jnp.zeros((4 if self.expert_layers else 0,),
-                                  jnp.int32)
+        self._routing = jnp.zeros(
+            (len(MOE_COUNTERS) if self.expert_layers else 0,), jnp.int32)
         # places of a full row that the decode program's attention reads
         # at a time, where the model's step reads live blocks only; None
         # where it reads every row whole (decode_dispatch counts by it)
@@ -980,6 +981,10 @@ class _ActiveSlot:
 # queue or in the follower poll), so the seven sum to the thread's life.
 _ENGINE_PHASES = ("admit", "prefill_dispatch", "decode_dispatch",
                   "readback_wait", "emit", "other", "idle")
+# what an expert layer's call counts, in the order it returns them
+# (``nn.moe.HeldExperts.forward``, ``nn.moe.ROUTING``)
+MOE_COUNTERS = ("moe_layer_calls", "moe_pairs_total", "moe_pairs_held",
+                "moe_active_experts", "moe_rows_computed")
 _ENGINE_COUNTERS = (
     "iterations", "decode_dispatches", "pipeline_drains",
     "gaps_plain", "gaps_prefill", "gap_seconds_plain",
@@ -988,8 +993,7 @@ _ENGINE_COUNTERS = (
     "decode_positions_live", "decode_positions_read",
     "decode_rows_live", "decode_rows_prefetched",
     "chunks_joint", "chunks_alone", "chunk_layer_positions",
-    "moe_layer_calls", "moe_pairs_total", "moe_pairs_held",
-    "moe_active_experts",
+    *MOE_COUNTERS,
     "ssm_layer_calls", "ssm_scan_positions", "ssm_scan_positions_real",
     "state_resets")
 
@@ -1570,13 +1574,17 @@ class GenerationScheduler:
                 # what the expert layers did, in decode and prefill
                 # programs alike: calls of an expert layer, the
                 # token-to-expert pairs they routed, those that landed
-                # on an expert held here, and the held experts that had
-                # a token, summed over the calls (all zero for a model
+                # on an expert held here, the held experts that had a
+                # token, and the token-expert rows the experts' product
+                # multiplied (held x tokens where every token goes through
+                # every held expert; a grouped product's tiles, padding
+                # and all), summed over the calls (all zero for a model
                 # without expert layers)
                 "moe_layer_calls": eng["moe_layer_calls"],
                 "moe_pairs_total": eng["moe_pairs_total"],
                 "moe_pairs_held": eng["moe_pairs_held"],
                 "moe_active_experts": eng["moe_active_experts"],
+                "moe_rows_computed": eng["moe_rows_computed"],
                 # what the state layers did (all zero for a model without
                 # them): calls of a state layer, by decode steps and
                 # prefill programs alike; the positions the prefill scans
@@ -2358,9 +2366,7 @@ class GenerationScheduler:
         dt = None if self._t_readback is None else now - self._t_readback
         self._t_readback = now
         if len(emit.routing):
-            for key, n in zip(("moe_layer_calls", "moe_pairs_total",
-                               "moe_pairs_held", "moe_active_experts"),
-                              emit.routing):
+            for key, n in zip(MOE_COUNTERS, emit.routing):
                 self._acc[key] += int(n)
         if dt is not None:
             kind = "prefill" if after_prefill else "plain"

@@ -256,8 +256,10 @@ def test_prefill_kv_returns_the_keys_the_chunks_write(model, tokens):
 
 # ---- the expert layer -------------------------------------------------------
 
-# tokens a call, for each of the two products over the held stacks: every
-# token through every held expert, or (a longer call) the pairs grouped
+# tokens a call, on either side of where a held share changes its product
+# over the held stacks: every token through every held expert, or (a longer
+# call, and every call of a layer that holds all its experts) the pairs laid
+# out by expert in tiles (tests/test_expert_products.py forces each)
 PRODUCTS = {"every-stack": 11, "grouped": HeldExperts.DENSE_TOKENS + 9}
 
 
@@ -303,9 +305,14 @@ def test_the_counts_follow_the_routing_and_idle_rows_get_nothing(tokens):
     experts, _ = layer.route(x)
     held = (experts >= 2) & (experts < 5)
     y, counts = layer.forward(x)
-    assert counts.tolist() == [
+    assert counts.tolist()[:4] == [
         1, 2 * tokens, int(held.sum()),
         len(set(np.asarray(experts)[np.asarray(held)]))]
+    name = HeldExperts.product_of(3, 8, tokens)
+    assert name == ("every_stack" if tokens <= HeldExperts.DENSE_TOKENS
+                    else "tiled")
+    assert counts.tolist()[4] == 3 * tokens if name == "every_stack" \
+        else counts.tolist()[4] >= int(held.sum())
     valid = jnp.arange(tokens) < 4
     y2, counts2 = layer.forward(x, valid)
     assert counts2.tolist()[1:3] == [8, int(held[:4].sum())]

@@ -518,6 +518,7 @@ ELSEWHERE = {
     "LatentAttention": "test_latent_attention.py",
     "Mamba1Mixer": "test_selective_ssm.py",
     "DifferentialAttention": "test_differential_attention.py",
+    "GatedShortConv": "test_short_conv.py",
     # containers & recurrent variants exercised with numerics elsewhere
     "Sequential": "test_optim.py",
     "ConvLSTMPeephole3D": "test_sparse_tree_misc.py",

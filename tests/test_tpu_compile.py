@@ -32,6 +32,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+from bigdl_tpu.nn.moe import ROUTING
 from bigdl_tpu.ops import cache_kernels
 from bigdl_tpu.ops import conv_bn_kernels as ck
 from bigdl_tpu.ops.attention_kernels import (flash_attention,
@@ -558,7 +559,7 @@ def _lower_cut_program(program, sharding):
     pool.expert_layers = abstract.expert_layers()
     pool.trace_counts = dict(TRACE_COUNTS, decode_with_chunk={})
     pool._build_programs()
-    routing = sds((4,), jnp.int32)
+    routing = sds((ROUTING,), jnp.int32)
     return _lower(pool, program, model, caches, routing, sds, slots,
                   chunk), cfg, caches
 
@@ -802,7 +803,7 @@ def _lower_latent_cut_program(program, sharding):
     pool.expert_layers = abstract.expert_layers()
     pool.trace_counts = dict(TRACE_COUNTS, decode_with_chunk={})
     pool._build_programs()
-    routing = sds((4,), jnp.int32)
+    routing = sds((ROUTING,), jnp.int32)
     return _lower(pool, program, model, caches, routing, sds, slots,
                   chunk), cfg, caches, abstract
 
@@ -962,3 +963,118 @@ def test_shared_row_pool_program_copies_no_leaf_on_v5e(
     assert held < 15.5 * 2 ** 30, held
     if program != "chunk_prefill":
         assert held > 13.5e9, held
+
+
+# ---- the pool whose states are tails, all of a layer's experts held --------------
+# (benchmark/configs/lfm2-24b-a2b.json: LFM2-24B-A2B's first pipeline stage,
+# layers 0-9: eight gated short convolutions that keep [slots, 2 x 2048] and
+# no row, two attention layers of 8 key heads of 64 with the head norm, two
+# leading dense layers, then 64 experts of 1,536 a layer, all held)
+
+def _conv_moe_config():
+    return {
+        "vocab_size": 65536, "hidden_size": 2048, "num_hidden_layers": 10,
+        "layer_types": ["conv", "conv", "full_attention", "conv", "conv",
+                        "conv", "full_attention", "conv", "conv", "conv"],
+        "num_attention_heads": 32, "num_key_value_heads": 8,
+        "intermediate_size": 11776, "num_dense_layers": 2,
+        "moe_intermediate_size": 1536, "num_experts": 64,
+        "num_experts_per_tok": 4, "conv_L_cache": 3, "conv_bias": False,
+        "norm_eps": 1e-5, "norm_topk_prob": True, "use_expert_bias": True,
+        "routed_scaling_factor": 1,
+        "rope_parameters": {"rope_theta": 1000000, "rope_type": "default"},
+        "serving": {"slots": 128, "max_len": 5632, "prefill_chunk": 256}}
+
+
+def _lower_conv_moe_program(program, sharding):
+    from bigdl_tpu.models import lfm2_moe
+    from bigdl_tpu.serving.generation import SlotPool
+    cfg = _conv_moe_config()
+    s = cfg["serving"]
+    slots, chunk = s["slots"], s["prefill_chunk"]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    abstract = jax.eval_shape(lambda: lfm2_moe(cfg, s["max_len"]))
+    model = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, jnp.bfloat16), abstract)
+    caches = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+            lambda: abstract.init_cache(slots, jnp.bfloat16,
+                                        ring_margin=chunk)))
+    pool = object.__new__(SlotPool)
+    pool.slots = slots
+    pool.cache_layers = tuple(abstract.cache_layers())
+    pool.expert_layers = abstract.expert_layers()
+    pool.trace_counts = dict(TRACE_COUNTS, decode_with_chunk={})
+    pool._build_programs()
+    routing = sds((ROUTING,), jnp.int32)
+    return _lower(pool, program, model, caches, routing, sds, slots,
+                  chunk), caches, abstract
+
+
+@pytest.mark.parametrize("program", POOL_MODEL_PROGRAMS)
+def test_conv_moe_pool_program_copies_no_leaf_and_no_stack_on_v5e(
+        v5e, program, monkeypatch):
+    """The decode step, the chunk program and the joint program of
+    LFM2-24B-A2B's first stage, as a TPU process traces them, compiled for
+    the described v5e.  No ``copy``, ``transpose`` or ``scatter`` of a
+    pooled tail or row, and none of a whole expert stack (64 experts of
+    2,048 x 1,536: 403 MB a stack).  A tail's two rows lie side by side
+    along the lanes (``[128, 4096]``; as ``[128, 2, 2048]`` the leaf was
+    copied into a layout of its own and back in every step).  The decode
+    step writes its two attention
+    layers' rows through ``ops.write_cache_rows`` and attends them
+    through the ragged decode kernel at a **block of 512** (heads of 64
+    in bfloat16, four query heads a key head: the kernel's MXU body),
+    with no ``while``.  Which product the experts take follows the call's
+    held share (``HeldExperts.product_of``): all 64 held, every call goes
+    through the tiled product, two kernel calls a layer
+    (``ops.expert_kernels``) on the stacks as they lie; no loop and no
+    ``ragged-dot``.  Weights, pool and
+    temporaries fit the chip and fill over 13 GB of it."""
+    from bigdl_tpu.nn.moe import HeldExperts
+    from bigdl_tpu.ops import attention_kernels
+    monkeypatch.setattr(attention_kernels, "_on_tpu", lambda: True)
+    lowered, caches, abstract = _lower_conv_moe_program(
+        program, SingleDeviceSharding(v5e.devices[0]))
+    layers = caches["layers"]
+    assert list(layers[0]["ssm"]) == ["conv"]
+    assert layers[0]["ssm"]["conv"].shape == (128, 2 * 2048)
+    assert layers[0]["ssm"]["conv"].dtype == jnp.bfloat16
+    assert layers[2]["self"]["k"].shape == (128, 8, 5632, 64)
+    assert abstract.decode_key_block(caches) == 512
+    # two row writers, the flags' select, one writer a tail
+    assert abstract.cache_write_programs(caches) == 1 + 2 + 8
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    leaf = r"bf16\[128,(?:4096|8,5632,64|8,64,5632)\]"
+    stack = r"bf16\[64,(?:2048,1536|1536,2048)\]"
+    assert not re.findall(
+        r"= (?:%s|%s)\S* (?:copy|copy-start|transpose|scatter)\(" % (
+            leaf, stack), text)
+    layouts = set(re.findall(r"bf16\[128,4096\]\{([\d,]+)", text))
+    assert layouts and all(lay.startswith("1,0") for lay in layouts), layouts
+    calls = collections.Counter(re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="[^"]*jit\((\w+)\)'
+        r'/pallas_call"', text))
+    assert sum(calls.values()) == text.count(
+        'custom_call_target="tpu_custom_call"')
+    tokens = {"decode": 128, "chunk_prefill": 256,
+              "decode_with_chunk": 128 + 256}[program]
+    tiled = HeldExperts.product_of(64, 64, tokens) == "tiled"
+    # a lone chunk's rows give no logits: the last layer's products feed
+    # nothing and the compiler drops them (its counts stay)
+    n = 7 if program == "chunk_prefill" else 8
+    assert tiled and (calls["gate_up"], calls["down"]) == (n, n)
+    if program == "chunk_prefill":
+        assert (calls["_write_cache_rows"], calls["_ragged_decode"]) == (0, 0)
+    else:
+        assert (calls["_write_cache_rows"], calls["_ragged_decode"]) == (2, 2)
+    assert " while(" not in text and "ragged-dot" not in text
+    mem = compiled.memory_analysis()
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert held < 15.5 * 2 ** 30, held
+    if program != "chunk_prefill":
+        assert held > 13e9, held
