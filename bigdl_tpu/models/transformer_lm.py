@@ -29,7 +29,6 @@ from bigdl_tpu.core.module import Module, ModuleList, Parameter
 from bigdl_tpu.nn.attention import (SequenceBeamSearch,
                                     TransformerDecoderLayer,
                                     _residual_dropout, causal_bias,
-                                    chunk_incremental_bias,
                                     incremental_bias, padding_bias,
                                     position_encoding)
 from bigdl_tpu.nn.linear import LookupTable
@@ -243,6 +242,15 @@ class TransformerLM(Module):
         leaf = caches["layers"][0]["self"]
         return attention_kernels.decode_key_block(
             leaf["k"].shape, leaf["v"].shape, leaf["k"].dtype)
+
+    def chunk_key_block(self, caches):
+        """Places of its slot's row that a prefill chunk's attention reads
+        at a time (``ops.chunk_attention``: live blocks only, on every
+        backend): the serving pool counts what its chunk programs read
+        by this."""
+        from bigdl_tpu.ops import attention_kernels
+        return attention_kernels.chunk_key_block(
+            caches["layers"][0]["self"]["k"].shape)
 
     def init_cache(self, batch: int, dtype=jnp.float32):
         """Per-block KV caches sized to ``max_len``, plus the per-slot
@@ -470,58 +478,44 @@ class TransformerLM(Module):
           The cache write covers exactly the chunk window (so a DONATED
           pool updates in place at O(chunk) write cost — writing a
           whole gathered row back was measured to cost the full row's
-          traffic per chunk) and the attention keys are read by slicing
-          the slot's row after the write.
+          traffic per chunk).
+
+        **What a chunk reads.**  Its queries attend their rows where they
+        lie after the write, **the key blocks up to the chunk's last
+        position and no place beyond** (``ops.chunk_attention``; blocks
+        of :meth:`chunk_key_block` places): a chunk at position 0 reads
+        one block of its row, one at 1,920 of 2,048 all eight.  The
+        count of blocks is traced, a loop's length or a kernel's
+        prefetched scalar, and not a shape: a program a length would be a
+        program a block count at every width, and the pool keeps its
+        chunk programs by width alone.
 
         Attention is inlined like :meth:`prefill_kv` (the K/V written
         to the cache are the K/V attended), expecting eval mode — the
         serving slot pool always runs an eval clone."""
-        from bigdl_tpu.ops import dot_product_attention
+        from bigdl_tpu.ops import attention_kernels
         _B, W = toks.shape
-        if slot is None:
-            pad = jax.lax.dynamic_update_slice(caches["pad"], toks == 0,
-                                               (0, index))
-            pad_read = pad
-        else:
-            pad = jax.lax.dynamic_update_slice(caches["pad"], toks == 0,
-                                               (slot, index))
-            pad_read = jax.lax.dynamic_slice(pad, (slot, 0),
-                                             (1, self.max_len))
+        row = 0 if slot is None else slot
+        pad = jax.lax.dynamic_update_slice(caches["pad"], toks == 0,
+                                           (row, index))
         x = self._embed(toks)
         pos = jax.lax.dynamic_slice_in_dim(
             position_encoding(self.max_len, self.hidden_size,
                               dtype=x.dtype), index, W, axis=0)
         x = x + pos[None]
-        bias = chunk_incremental_bias(self.max_len, index, W, pad_read,
-                                      x.dtype)
         new_layers = []
         for blk, cache in zip(self.blocks, caches["layers"]):
             attn = blk.self_attn
             xn = blk.self_norm(x)
-            k_new = attn._split_heads(attn.k_layer(xn))
-            v_new = attn._split_heads(attn.v_layer(xn))
             old = cache["self"]
-            if slot is None:
-                k = jax.lax.dynamic_update_slice(
-                    old["k"], k_new.astype(old["k"].dtype),
-                    (0, 0, index, 0))
-                v = jax.lax.dynamic_update_slice(
-                    old["v"], v_new.astype(old["v"].dtype),
-                    (0, 0, index, 0))
-                k_read, v_read = k, v
-            else:
-                k = jax.lax.dynamic_update_slice(
-                    old["k"], k_new.astype(old["k"].dtype),
-                    (slot, 0, index, 0))
-                v = jax.lax.dynamic_update_slice(
-                    old["v"], v_new.astype(old["v"].dtype),
-                    (slot, 0, index, 0))
-                row = (1,) + old["k"].shape[1:]
-                k_read = jax.lax.dynamic_slice(k, (slot, 0, 0, 0), row)
-                v_read = jax.lax.dynamic_slice(v, (slot, 0, 0, 0), row)
+            k, v = (jax.lax.dynamic_update_slice(
+                old[n], attn._split_heads(layer(xn)).astype(old[n].dtype),
+                (row, 0, index, 0))
+                for n, layer in (("k", attn.k_layer), ("v", attn.v_layer)))
             new_layers.append({"self": {"k": k, "v": v}})
             q = attn._split_heads(attn.q_layer(xn))
-            ctxt = dot_product_attention(q, k_read, v_read, bias)
+            ctxt = attention_kernels.chunk_attention(q, k, v, row, index,
+                                                     pad)
             x = self._block_tail(blk, x, attn._combine_heads(ctxt))
         return {"layers": new_layers, "pad": pad}
 
@@ -539,13 +533,21 @@ class TransformerLM(Module):
         stream ``[1, B + W, H]``: a block's norms, its output projection
         and its feed-forward run once over both, so the pass reads those
         weights once.  In between each half projects its own queries,
-        keys and values and keeps its own attention: the chunk's window
-        is written and it attends its slot's row
-        (:meth:`prefill_chunk`), then the rows' positions are written
-        and they attend what is live (:meth:`_decode_step_rows`).  Chunk
-        first, then rows, layer by layer: row ``slot`` may decode in the
-        same pass (its prompt's last chunk) and then attends what the
-        chunk wrote.
+        keys and values and keeps its own attention.  **Both halves write
+        first** (the chunk's window, then the rows' positions: one chain
+        of in-place updates a leaf) **and then both attend the leaf as it
+        lies**: the chunk the key blocks up to its last position
+        (:meth:`prefill_chunk`, "What a chunk reads"), the rows what is
+        live (:meth:`_decode_step_rows`).  That is the data flow of
+        chunk first, then rows: a place a row writes is one the chunk's
+        queries cannot attend (another row; past the chunk's last
+        position; or, where row ``slot`` decodes in the same pass from a
+        place inside a padded last chunk, a place the chunk's own flags
+        mask), and row ``slot``, decoding its first token in the pass of
+        its prompt's last chunk, attends what the chunk wrote.  With a
+        read between the two writes the compiler copied every key leaf
+        twice a layer once the program had 24 layers
+        (``tests/test_tpu_compile.py`` holds it to none).
 
         Why q, k and v are not shared too: on a v5e at OPT-1.3B's serving
         shapes (6 rows beside 64 tokens) the walk as it stands took 8.78
@@ -554,12 +556,10 @@ class TransformerLM(Module):
         stream in behind the cache writes and the rows' attention either
         way, and the joint product's parting into heads cost more than
         the second, small product (PERF.md, PR 43)."""
-        from bigdl_tpu.ops import attention_kernels, dot_product_attention
+        from bigdl_tpu.ops import attention_kernels
         B, W = tokens.shape[0], toks.shape[1]
         chunk_pad = jax.lax.dynamic_update_slice(
             caches["pad"], toks == 0, (slot, chunk_index))
-        chunk_pad_read = jax.lax.dynamic_slice(chunk_pad, (slot, 0),
-                                               (1, self.max_len))
         index, lengths = self._row_places(index, active)
         pad = self._write_rows(chunk_pad, tokens == 0, index)
         x, xc = self._embed(tokens), self._embed(toks)
@@ -568,8 +568,6 @@ class TransformerLM(Module):
         x = x + jnp.take(table, index, axis=0)[:, None]
         xc = xc + jax.lax.dynamic_slice_in_dim(table, chunk_index, W,
                                                axis=0)[None]
-        bias = chunk_incremental_bias(self.max_len, chunk_index, W,
-                                      chunk_pad_read, x.dtype)
         # the rows, one position each, then the chunk: [1, B + W, H]
         h = jnp.concatenate([x.reshape(1, B, -1), xc], axis=1)
         new_layers = []
@@ -585,18 +583,16 @@ class TransformerLM(Module):
             qc, kc, vc = (attn._split_heads(layer(hn[:, B:]))
                           for layer in qkv)
             old = cache["self"]
-            row = (1,) + old["k"].shape[1:]
             k_leaf = jax.lax.dynamic_update_slice(
                 old["k"], kc.astype(old["k"].dtype),
                 (slot, 0, chunk_index, 0))
             v_leaf = jax.lax.dynamic_update_slice(
                 old["v"], vc.astype(old["v"].dtype),
                 (slot, 0, chunk_index, 0))
-            ctxt_chunk = dot_product_attention(
-                qc, jax.lax.dynamic_slice(k_leaf, (slot, 0, 0, 0), row),
-                jax.lax.dynamic_slice(v_leaf, (slot, 0, 0, 0), row), bias)
             k_leaf = self._write_rows(k_leaf, k, index)
             v_leaf = self._write_rows(v_leaf, v, index)
+            ctxt_chunk = attention_kernels.chunk_attention(
+                qc, k_leaf, v_leaf, slot, chunk_index, chunk_pad)
             new_layers.append({"self": {"k": k_leaf, "v": v_leaf}})
             ctxt = attention_kernels.decode_attention(q, k_leaf, v_leaf,
                                                       lengths, pad)

@@ -1338,6 +1338,125 @@ def _decode_block(k_shape, v_shape, dtype) -> Optional[int]:
 
 
 # ---------------------------------------------------------------------------
+# A prefill chunk over the live key blocks of its rows
+# ---------------------------------------------------------------------------
+# (Listed here and not at the top: the kernels above carry their source
+# lines into the programs that hold them, so a line more ahead of them
+# would change the lowered text of every pool's decode program.)
+
+__all__ += ["chunk_attention", "chunk_key_block", "ragged_chunk_attention"]
+
+# places of a row that a prefill chunk's attention reads at a time
+# (:func:`chunk_key_block`)
+CHUNK_KEY_BLOCK = 256
+# the chunk kernel's VMEM: the streamed K and V blocks, double-buffered, up
+# to half of it; the queries, the output and the softmax state in the rest
+_CHUNK_VMEM = 32 * 2 ** 20
+
+
+def _ragged_chunk_kernel(at_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
+                         m_ref, l_ref, acc_ref, *, scale: float, block: int):
+    """One (row, key block) program of a chunk's ``W`` queries a head,
+    ``q_ref [H, W, d]``, against ``k_ref [H, d, block]`` and ``v_ref [H,
+    dv, block]``, positions on the lanes as the pool stores them: a
+    head's scores are the product ``[W, d] x [d, block]`` on the MXU and
+    its context ``[W, block] x [dv, block]^T``, operands in the queries'
+    dtype, sums in float32.  Query ``i`` stands at position ``at_ref[1] +
+    i`` and attends the places up to its own.  ``o_ref [H, W, dv]``; the
+    softmax state ``m_ref``, ``l_ref [H, W, 1]`` and ``acc_ref [H, W,
+    dv]`` are float32."""
+    j = pl.program_id(1)
+    heads, width, _ = q_ref.shape
+    index = at_ref[1]
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(j * block < index + width)
+    def _body():
+        shape = (width, block)
+        live = j * block + jax.lax.broadcasted_iota(jnp.int32, shape, 1) \
+            <= index + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        bias = bias_ref[...]                                   # [1, block]
+        for h in range(heads):
+            q = q_ref[h]
+            s = jax.lax.dot_general(
+                q, k_ref[h].astype(q.dtype), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)            # [W, block]
+            s = jnp.where(live, s * scale + bias, _NEG_INF)
+            m_prev = m_ref[h]                                  # [W, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
+            m_ref[h] = m_new
+            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                p.astype(q.dtype), v_ref[h].astype(q.dtype),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)            # [W, dv]
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finish():
+        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def ragged_chunk_attention(q, k, v, row, index, pad, *, block: int,
+                           interpret: bool = False):
+    """:func:`chunk_attention` as a Pallas TPU kernel, for leaves that lie
+    positions-minor (a width under the 128 lanes:
+    :func:`cache_kernels.cache_row_tiles`), handed over as they lie (the
+    ``swapaxes`` below changes a name and no byte, as in
+    :func:`ragged_decode_attention`).
+
+    The grid is (row, key block); the rows' first row and the chunk's
+    position go ahead as scalar prefetch.  A step past the chunk's last
+    block skips its arithmetic and names that last block again, so
+    nothing is fetched for it: what is read is ``index + W`` rounded up
+    to ``block``.  A function of its own under ``jit`` so that a model's
+    layers share one trace of the kernel, whose body is unrolled over
+    the heads (:func:`_ragged_decode`)."""
+    b, h, w, d = q.shape
+    t, dv = v.shape[2], v.shape[3]
+    at = jnp.stack([jnp.asarray(row, jnp.int32),
+                    jnp.asarray(index, jnp.int32)])
+    bias = jnp.where(pad, _NEG_INF, 0.0).astype(jnp.float32)[:, None]
+
+    def own(bi, j, at):
+        return bi, 0, 0, 0
+
+    def flags(bi, j, at):
+        return at[0] + bi, 0, jnp.minimum(j, (at[1] + w - 1) // block)
+
+    def leaf(bi, j, at):
+        r, _, blk = flags(bi, j, at)
+        return r, 0, 0, blk
+
+    return pl.pallas_call(
+        functools.partial(_ragged_chunk_kernel, scale=1.0 / (d ** 0.5),
+                          block=block),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, t // block),
+            in_specs=[pl.BlockSpec((None, h, w, d), own),
+                      pl.BlockSpec((None, h, d, block), leaf),
+                      pl.BlockSpec((None, h, dv, block), leaf),
+                      pl.BlockSpec((None, 1, block), flags)],
+            out_specs=pl.BlockSpec((None, h, w, dv), own),
+            scratch_shapes=[_scratch(s) for s in
+                            ((h, w, 1), (h, w, 1), (h, w, dv))]),
+        out_shape=jax.ShapeDtypeStruct((b, h, w, dv), q.dtype),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_CHUNK_VMEM),
+    )(at, q, jnp.swapaxes(k, 2, 3), jnp.swapaxes(v, 2, 3), bias)
+
+
+# ---------------------------------------------------------------------------
 # Dispatch
 # ---------------------------------------------------------------------------
 
@@ -1418,6 +1537,95 @@ def decode_attention(q, k, v, lengths, pad=None, *,
         invalid = invalid | pad
     bias = jnp.where(invalid, _NEG_INF, 0.0)[:, None, None, :]
     return xla_attention(q, k, v, bias, scale=scale)
+
+
+def chunk_key_block(k_shape) -> int:
+    """Places of a row ``k_shape [S, H, T, d]`` that
+    :func:`chunk_attention` reads at a time: :data:`CHUNK_KEY_BLOCK`, or
+    what it shares with a row it does not divide.  The serving pool asks
+    this too, to count what its chunk programs read."""
+    return math.gcd(k_shape[2], CHUNK_KEY_BLOCK)
+
+
+def _chunk_kernel_takes(k_shape, v_shape, dtype, block: int) -> bool:
+    """Whether :func:`ragged_chunk_attention` takes leaves of these
+    shapes: both positions-minor, blocks of whole lane tiles that fit the
+    kernel's share of VMEM twice over."""
+    from bigdl_tpu.ops.cache_kernels import cache_row_tiles
+    _, h, _, d = k_shape
+    need = 2 * h * (d + v_shape[3]) * block * jnp.dtype(dtype).itemsize
+    return (block % _LANES == 0 and 2 * need <= _CHUNK_VMEM
+            and cache_row_tiles(k_shape, dtype) == "lanes"
+            and cache_row_tiles(v_shape, dtype) == "lanes")
+
+
+def chunk_attention(q, k, v, row, index, pad, *,
+                    force: Optional[str] = None):
+    """A prefill chunk's attention **over the live part of its rows**:
+    queries ``q [B, H, W, d]`` at positions ``index .. index+W-1`` over
+    rows ``row .. row+B`` of the cache leaves ``k [S, H, T, d]``, ``v [S,
+    H, T, dv]`` as they lie after the chunk's window was written; ``pad
+    [S, T]`` flags padding by row and position.  Returns the context
+    ``[B, H, W, dv]`` in ``q``'s dtype.
+
+    Key blocks ``0 .. (index + W - 1) // block`` are read and no place
+    beyond (``block``: :func:`chunk_key_block`), by a count that is
+    traced: **one compiled program whatever the chunk's position** (a
+    program a length would be a program a block count, eight at 2,048
+    places, times a pool's four widths and two entries).  The
+    mathematics is :func:`xla_attention` under
+    ``nn.attention.chunk_incremental_bias``: scores, sums and an online
+    softmax in float32, the weights rounded to the values' dtype before
+    the second product; only the order of summation differs.  (A query
+    none of whose places is valid, a padding token before its row's first
+    real one, averages the blocks read where the full product averaged
+    the row: nobody reads either.)
+
+    On a TPU, leaves that lie positions-minor go through
+    :func:`ragged_chunk_attention` (``force`` ∈ {"ragged", "xla", None}
+    overrides, as in :func:`decode_attention`); everything else takes a
+    ``fori_loop`` over blocks sliced out of the leaves, the form
+    ``nn.latent_attention.latent_rows_attention`` has for a latent row."""
+    block = chunk_key_block(k.shape)
+    takes = _chunk_kernel_takes(k.shape, v.shape, k.dtype, block)
+    if force == "ragged" and not takes:
+        raise ValueError(f"rows {tuple(k.shape)} / {tuple(v.shape)} do "
+                         f"not tile for the chunk kernel")
+    if force == "ragged" or (force is None and _on_tpu() and takes):
+        return ragged_chunk_attention(q, k, v, row, index, pad, block=block,
+                                      interpret=not _on_tpu())
+    b, h, w, d = q.shape
+    dv = v.shape[3]
+    scale = jnp.float32(1.0 / (d ** 0.5))
+    q_pos = index + jnp.arange(w, dtype=jnp.int32)
+
+    def step(j, carry):
+        m, den, acc = carry
+        start = j * block
+        k_j = jax.lax.dynamic_slice(k, (row, 0, start, 0), (b, h, block, d))
+        v_j = jax.lax.dynamic_slice(v, (row, 0, start, 0), (b, h, block, dv))
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k_j,
+                       preferred_element_type=jnp.float32) * scale
+        k_pos = start + jnp.arange(block, dtype=jnp.int32)
+        ok = (k_pos[None, None, :] <= q_pos[None, :, None]) \
+            & ~jax.lax.dynamic_slice(pad, (row, start),
+                                     (b, block))[:, None, :]   # [B, W, block]
+        s = jnp.where(ok[:, None], s, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        p = jnp.exp(s - m_new[..., None])
+        alpha = jnp.exp(m - m_new)
+        den = alpha * den + jnp.sum(p, axis=-1)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "bhqk,bhkd->bhqd", p.astype(v.dtype), v_j,
+            preferred_element_type=jnp.float32)
+        return m_new, den, acc
+
+    init = (jnp.full((b, h, w), _NEG_INF, jnp.float32),
+            jnp.zeros((b, h, w), jnp.float32),
+            jnp.zeros((b, h, w, dv), jnp.float32))
+    _, den, acc = jax.lax.fori_loop(0, (index + w - 1) // block + 1, step,
+                                    init)
+    return (acc / den[..., None]).astype(q.dtype)
 
 
 def _per_shard(kernel, mesh, q, k, v, bias):

@@ -301,6 +301,12 @@ class SlotPool:
         # where it reads every row whole (decode_dispatch counts by it)
         self.key_block = model.decode_key_block(self.caches) \
             if hasattr(model, "decode_key_block") else None
+        # the same of a prefill chunk's attention: places of its slot's
+        # full row that it reads at a time where the model's chunk path
+        # reads live blocks only; None where it reads the row whole
+        # (the scheduler counts a chunk's reading by it)
+        self.chunk_key_block = model.chunk_key_block(self.caches) \
+            if hasattr(model, "chunk_key_block") else None
         # device programs that write the cache in one decode step: one
         # dynamic_update_slice a slot and leaf (keys, values, flags),
         # unless the model says that its step writes with fewer
@@ -992,6 +998,7 @@ _ENGINE_COUNTERS = (
     "admitted", "queue_wait_seconds",
     "decode_positions_live", "decode_positions_read",
     "decode_rows_live", "decode_rows_prefetched",
+    "chunk_positions_live", "chunk_positions_read",
     "chunks_joint", "chunks_alone", "chunk_layer_positions",
     *MOE_COUNTERS,
     "ssm_layer_calls", "ssm_scan_positions", "ssm_scan_positions_real",
@@ -1558,6 +1565,15 @@ class GenerationScheduler:
                 # (both zero where the step reads whole rows)
                 "decode_rows_live": eng["decode_rows_live"],
                 "decode_rows_prefetched": eng["decode_rows_prefetched"],
+                # the same of the prefill chunks, joint and lone alike,
+                # summed over the chunks dispatched: the places of its
+                # slot's row that a chunk's last query could attend (its
+                # start and its width), and those the chunk's attention
+                # read (that rounded up to the chunk's key block where
+                # the model's chunk path reads live blocks only, the
+                # whole row where it does not)
+                "chunk_positions_live": eng["chunk_positions_live"],
+                "chunk_positions_read": eng["chunk_positions_read"],
                 # chunk programs (not bucketed prefills) that rode a
                 # decode step as one joint program, and that went out
                 # alone (a pool without the joint program: all of them)
@@ -2215,6 +2231,10 @@ class GenerationScheduler:
         new_pos = end if s + w >= end else s + w
         self._acc["prefill_positions"] += w
         self._acc["chunk_layer_positions"] += pool.chunk_layers * w
+        block = pool.chunk_key_block
+        self._acc["chunk_positions_live"] += s + w
+        self._acc["chunk_positions_read"] += \
+            -(-(s + w) // block) * block if block else pool.max_len
         self._acc["prefill_prompt_tokens"] += new_pos - st.next_pos
         self._acc["ssm_layer_calls"] += pool.state_layers
         self._acc["ssm_scan_positions"] += pool.state_layers * w

@@ -19,6 +19,9 @@ from bigdl_tpu.models.transformer_lm import TransformerLM
 
 SCENARIOS = ["idle-row", "padded-last-chunk", "fresh-occupant",
              "own-slot-decodes"]
+# one more for a model whose rows keep no state (a state is not written
+# at a place): the slot decodes from a place inside its padded chunk
+ROW_SCENARIOS = SCENARIOS + ["own-slot-decodes-in-the-padding"]
 SLOTS = 3
 
 
@@ -72,9 +75,12 @@ def joint_pass_case(model, chunk, vocab, scenario):
     * ``fresh-occupant``: the chunk is a new request's first, at position
       0 of a slot whose rows and state another request left behind;
     * ``own-slot-decodes``: the chunk is its prompt's last and slot 1
-      decodes in the same pass, from the position after it."""
-    assert scenario in SCENARIOS, scenario
-    rng = np.random.default_rng(SCENARIOS.index(scenario))
+      decodes in the same pass, from the position after it;
+    * ``own-slot-decodes-in-the-padding``: the same with the chunk's
+      tail padding, so that slot 1 decodes from the first padded place:
+      the row's write lands inside the chunk's window."""
+    assert scenario in ROW_SCENARIOS, scenario
+    rng = np.random.default_rng(ROW_SCENARIOS.index(scenario))
     caches = _init_cache(model, SLOTS, chunk)
     caches = _fill(model, caches, rng, vocab, 0, 3 * chunk, chunk)
     caches = _fill(model, caches, rng, vocab, 2, chunk, chunk)
@@ -86,10 +92,12 @@ def joint_pass_case(model, chunk, vocab, scenario):
     tokens = rng.integers(1, vocab + 1, (SLOTS, 1)).astype(np.int32)
     index = np.asarray([3 * chunk, 0, chunk], np.int32)
     active = np.asarray([True, False, True])
-    if scenario == "padded-last-chunk":
-        toks[0, chunk - chunk // 2:] = 0
-    if scenario == "own-slot-decodes":
-        active[1], index[1] = True, chunk_index + chunk
+    real = chunk
+    if scenario in ("padded-last-chunk", "own-slot-decodes-in-the-padding"):
+        real = chunk - chunk // 2
+        toks[0, real:] = 0
+    if scenario.startswith("own-slot-decodes"):
+        active[1], index[1] = True, chunk_index + real
     else:
         tokens[1] = 0
     return (caches, jnp.asarray(tokens), jnp.asarray(index),

@@ -876,6 +876,80 @@ def test_decode_position_counters_follow_a_known_schedule(
     assert st["decode_positions_live"] <= st["decode_positions_read"]
 
 
+def test_chunk_kernel_through_the_pool_emits_generates_tokens(
+        lm384, monkeypatch):
+    """Prompts of several chunks, prefilled beside decoding slots and
+    into an idle pool, whose chunks attend through the chunk kernel (and
+    the rows through the ragged decode kernel), both interpreted: each
+    request emits what solo ``generate()`` emits.  What a TPU process
+    chooses by itself is asked for here, at the one place the model's
+    two chunk entries ask."""
+    from bigdl_tpu.ops import attention_kernels
+    _force_ragged(monkeypatch)
+    calls = []
+
+    def chunk_attention(*args, _kernel=attention_kernels.chunk_attention):
+        calls.append(args[0].shape)
+        return _kernel(*args, force="ragged")
+
+    monkeypatch.setattr(attention_kernels, "chunk_attention",
+                        chunk_attention)
+    rng = np.random.default_rng(11)
+    lengths, max_news = [150, 300, 70, 201], [5, 4, 6, 4]
+    prompts = [rng.integers(1, 51, n).astype(np.int32) for n in lengths]
+    eng = GenerationScheduler(lm384, slots=2, prefill_chunk=64)
+    try:
+        futs = [eng.submit_async(p, m) for p, m in zip(prompts, max_news)]
+        rows = [f.result(timeout=600) for f in futs]
+        st = eng.stats()
+    finally:
+        eng.shutdown()
+    for p, m, row in zip(prompts, max_news, rows):
+        np.testing.assert_array_equal(row, solo(lm384, p, m))
+    assert st["chunks_joint"] > 0 and st["chunks_alone"] > 0
+    assert (1, 4, 64, 8) in calls
+    assert st["chunk_positions_read"] < \
+        (st["chunks_joint"] + st["chunks_alone"]) * 384
+
+
+@pytest.mark.parametrize("how", ["alone", "beside-a-step", "whole-rows"])
+def test_chunk_position_counters_follow_a_known_schedule(lm384, how):
+    """A prompt of 300 tokens in chunks of 64: its 299 prefill positions
+    go out as chunks at 0, 64, 128 and 192 and a suffix-aligned one at
+    235.  ``chunk_positions_live`` is what each chunk's last query may
+    attend (its start and its width), ``chunk_positions_read`` that
+    rounded up to the model's chunk key block (128: what 256 and a row of
+    384 share), lone chunks and chunks that ride a decode step alike; a
+    pool whose model does not say reads every chunk's whole row."""
+    prompt = np.arange(300, dtype=np.int32) % 50 + 1
+    eng = GenerationScheduler(lm384, slots=2, prefill_chunk=64, start=False)
+    assert eng.pool.chunk_key_block == 128
+    if how == "whole-rows":
+        eng.pool.chunk_key_block = None
+    eng.start()
+    try:
+        before = eng.stats()
+        if how == "beside-a-step":
+            _, row = joint_pass.serve_beside_a_decoding_slot(
+                eng, prompt[:4], [prompt], new_later=3, timeout=120)
+        else:
+            row = eng.submit(prompt, 3)
+        eng.shutdown()
+        st = eng.stats()
+    finally:
+        eng.shutdown()
+    np.testing.assert_array_equal(row, solo(lm384, prompt, 3))
+    assert before["chunk_positions_live"] == 0 \
+        and before["chunk_positions_read"] == 0
+    chunks = [(0, 64), (64, 64), (128, 64), (192, 64), (235, 64)]
+    assert st["chunks_joint"] + st["chunks_alone"] == len(chunks)
+    assert (st["chunks_joint"] > 0) == (how == "beside-a-step")
+    assert st["chunk_positions_live"] == sum(s + w for s, w in chunks)
+    assert st["chunk_positions_read"] == (
+        len(chunks) * 384 if how == "whole-rows"
+        else sum(128 * -(-(s + w) // 128) for s, w in chunks))
+
+
 @pytest.mark.parametrize("ragged", [False, True], ids=["xla", "ragged"])
 def test_decode_row_counters_count_the_rows_a_call_starts(
         lm384, monkeypatch, ragged):

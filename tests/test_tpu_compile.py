@@ -265,10 +265,10 @@ def pool():
     return _fixture_pool()
 
 
-def _fixture_pool():
+def _fixture_pool(layers=1):
     from bigdl_tpu.models import transformer_lm
     from bigdl_tpu.serving.generation import SlotPool
-    lm = transformer_lm(vocab_size=30, num_layers=1,
+    lm = transformer_lm(vocab_size=30, num_layers=layers,
                         hidden_size=POOL_HEADS * POOL_HEAD_DIM,
                         num_heads=POOL_HEADS, filter_size=256,
                         max_len=POOL_MAX_LEN)
@@ -330,28 +330,33 @@ def test_pool_program_holds_no_pool_sized_copy_on_v5e(v5e, pool, program):
         assert " while(" not in text
 
 
-def test_pool_decode_with_the_ragged_kernel_relayouts_no_leaf_on_v5e(
-        v5e, monkeypatch):
-    """The decode step as a TPU process traces it — attention through the
-    ragged kernel, which wants its operands row-major — still holds no
-    copy, transpose or ``while`` of a pool leaf's size: the kernel is
-    handed keys and values positions-minor, which is how the leaf lies, so
-    the change of axes is a ``bitcast``.  A fresh pool: the fixture's
-    decode step may already be traced the other way.  Which path a
-    process takes it asks ``_on_tpu()``; here the test answers."""
+@pytest.mark.parametrize("program", ["decode", "chunk_prefill"])
+def test_pool_program_with_its_kernel_relayouts_no_leaf_on_v5e(
+        v5e, monkeypatch, program):
+    """The decode step and the chunk program as a TPU process traces them
+    — attention through the ragged decode kernel and through the chunk
+    kernel, which want their operands row-major — still hold no copy,
+    transpose or ``while`` of a pool leaf's size: a kernel is handed keys
+    and values positions-minor, which is how the leaf lies, so the change
+    of axes is a ``bitcast``.  A fresh pool: the fixture's programs may
+    already be traced the other way; of two layers, since the chunk
+    program returns no logits and what the last layer attends is nobody's
+    to read.  Which path a process takes it asks ``_on_tpu()``; here the
+    test answers."""
     from bigdl_tpu.ops import attention_kernels
     monkeypatch.setattr(attention_kernels, "_on_tpu", lambda: True)
-    pool = _fixture_pool()
+    pool = _fixture_pool(layers=2)
     assert pool.key_block == 512            # two heads of 64: 1 KB a place
+    assert pool.chunk_key_block == 256
     text = _lower_pool_program(
-        pool, "decode", SingleDeviceSharding(v5e.devices[0])
+        pool, program, SingleDeviceSharding(v5e.devices[0])
     ).compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') \
-        == len(pool.caches["layers"])
+    calls = 2 if program == "decode" else 1
+    assert text.count('custom_call_target="tpu_custom_call"') == calls
     assert "dynamic-update-slice" in text
-    assert not _pool_leaf_ops(text, ["copy", "copy-start", "transpose"])
-    assert len(_pool_leaf_ops(text, ["bitcast"])) \
-        == 2 * len(pool.caches["layers"])
+    assert not _pool_leaf_ops(text, ["copy", "copy-start", "transpose",
+                                     "convert"])
+    assert len(_pool_leaf_ops(text, ["bitcast"])) == 2 * calls
     assert " while(" not in text
 
 
@@ -397,9 +402,12 @@ def test_opt_pool_joint_program_reads_each_layers_weights_once_on_v5e(
     places, float32 rows, bfloat16 weights; model and rows as shapes),
     its decode step that carries a 64-token chunk
     (``TransformerLM.decode_step_with_chunk``) as a TPU process traces it,
-    compiled for the described v5e: no copy or transpose of a whole pool
-    leaf and no ``while``; the rows attend through the ragged decode
-    kernel, one call a layer; and **a block's output projection and both
+    compiled for the described v5e: no copy, transpose or conversion of a
+    whole pool leaf and no ``while``; the rows attend through the ragged
+    decode kernel and the chunk through the chunk kernel, which reads the
+    key blocks up to the chunk's last position (``ops.chunk_attention``:
+    no product over the row's 2,048 places is left), one call of each a
+    layer; and **a block's output projection and both
     feed-forward weights (five sixths of its bytes) are each the operand
     of one product** over the 6 rows and the 64 chunk tokens together,
     where the chunk program followed by the step made two.  Queries, keys
@@ -430,14 +438,24 @@ def test_opt_pool_joint_program_reads_each_layers_weights_once_on_v5e(
     text = _lower(pool, "decode_with_chunk", model, caches,
                   sds((0,), jnp.int32), sds, slots, chunk).compile().as_text()
     assert not re.findall(
-        r"= f32\[6,32,(?:64,2048|2048,64)\]\S* "
-        r"(?:copy|copy-start|transpose|scatter)\(", text)
+        r"= \w+\[6,32,(?:64,2048|2048,64)\]\S* "
+        r"(?:copy|copy-start|transpose|scatter|convert)\(", text)
     assert " while(" not in text
-    assert text.count('custom_call_target="tpu_custom_call"') == layers
+    # the rows' kernel goes out behind three prefetched scalars a row, the
+    # chunk's behind its first row and its position
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    rows = "operand_layout_constraints={s32[6]{0}, s32[6]{0}, s32[6]{0}, "
+    a_chunk = ("operand_layout_constraints={s32[2]{0}, "
+               "bf16[1,32,%d,64]{3,2,1,0}, f32[6,32,64,2048]{3,2,1,0}, "
+               "f32[6,32,64,2048]{3,2,1,0}, f32[6,1,2048]{2,1,0}}" % chunk)
+    assert len([c for c in calls if rows in c]) == layers
+    assert len([c for c in calls if a_chunk in c]) == layers
+    assert len(calls) == 2 * layers
     # the program's products: q, k and v a half, onto the heads; the
     # output projection and both feed-forward layers over the 70 rows and
-    # chunk tokens together; the chunk's scores and context; the head over
-    # the 6 rows alone
+    # chunk tokens together; the head over the 6 rows alone (the chunk's
+    # scores and context are inside its kernel)
     both = slots + chunk
     by_result = collections.Counter(re.findall(
         r"= (\w+\[[\d,]*\])\S* convolution\(", text))
@@ -446,8 +464,6 @@ def test_opt_pool_joint_program_reads_each_layers_weights_once_on_v5e(
         "bf16[%d,32,64]" % chunk: 3 * layers,
         "bf16[%d,%d]" % (both, hidden): 2 * layers,
         "bf16[%d,%d]" % (both, ffn): layers,
-        "f32[32,%d,%d]" % (chunk, max_len): layers,
-        "bf16[32,%d,64]" % chunk: layers,
         "bf16[%d,50273]" % slots: 1}, by_result
     # and by operand: the feed-forward's first weight, as the leaf lies
     shape_of = dict(re.findall(r"(%[\w.-]+) = (\w+\[[\d,]*\])", text))

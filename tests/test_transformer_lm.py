@@ -9,6 +9,9 @@ import pytest
 from bigdl_tpu.core.module import combine, partition
 from bigdl_tpu.models import transformer_lm
 from bigdl_tpu.models.transformer_lm import TransformerLM
+from bigdl_tpu.nn.attention import chunk_incremental_bias
+from bigdl_tpu.ops.attention_kernels import (CHUNK_KEY_BLOCK, chunk_attention,
+                                             xla_attention)
 from bigdl_tpu.utils import set_seed
 
 import bigdl_tpu.nn as nn
@@ -140,18 +143,90 @@ def test_incremental_decode_matches_full_forward():
                                    rtol=2e-4, atol=2e-5)
 
 
-@pytest.mark.parametrize("scenario", joint_pass.SCENARIOS)
+@pytest.mark.parametrize("scenario", joint_pass.ROW_SCENARIOS)
 def test_the_joint_pass_equals_the_chunk_program_then_the_step(scenario):
     """``decode_step_with_chunk`` (one walk of the blocks, the decode rows
     and the chunk's tokens one residual stream through each block's norms,
     output projection and feed-forward) against the pooled
     ``prefill_chunk`` followed by ``decode_step`` on the same caches, at
     float32 ``highest``: logits of the live rows, every cache leaf and the
-    flags.  ``joint_pass.py`` has the four passes and the comparison."""
+    flags.  ``joint_pass.py`` has the passes and the comparison.  (The
+    joint walk writes the rows' places before the chunk attends: the
+    fifth pass puts such a place inside the chunk's window.)"""
     m = _model(max_len=24).eval_mode()
     with jax.default_matmul_precision("highest"):
         joint_pass.assert_joint_pass_equals_chunk_then_step(
             m, 4, 50, scenario)
+
+
+# ---- a chunk attends the live part of its rows -------------------------------
+# Rows of four key blocks, sixteen heads of eight (two of the kernel's head
+# groups): the helper alone, against the
+# masked product over the whole row that it took the place of.
+
+_ROW = 4 * CHUNK_KEY_BLOCK
+_PLACES = {"first": lambda w: 0,
+           "mid-block": lambda w: CHUNK_KEY_BLOCK + 100,
+           # the chunk's first token on a block's last place, and on the
+           # next block's first
+           "block-end": lambda w: 2 * CHUNK_KEY_BLOCK - 1,
+           "block-start": lambda w: 2 * CHUNK_KEY_BLOCK,
+           "row-end": lambda w: _ROW - w}
+# how the rows lie: a pool's slot, with and without padding flags, and a
+# request's own rows (``slot=None``: rows 0 and 1, the second one flagged)
+_ROWS = {"slot-2": (1, 2, (True,)), "slot-2-unflagged": (1, 2, (False,)),
+         "two-rows": (2, 0, (False, True))}
+
+
+@pytest.mark.parametrize("how", ["xla", "ragged"])
+@pytest.mark.parametrize("rows", _ROWS)
+@pytest.mark.parametrize("width", [64, 32, 16, 8])
+@pytest.mark.parametrize("place", _PLACES)
+def test_a_chunk_attends_the_live_blocks_of_its_rows_only(place, width,
+                                                          rows, how):
+    """``ops.chunk_attention`` against ``xla_attention`` under
+    ``chunk_incremental_bias`` over the whole row, at the chunk positions
+    where a count of blocks could go wrong, with padding flagged before
+    the chunk (the row's first places, a place mid-row) and inside it;
+    the loop every backend has and the kernel a TPU takes (interpreted).
+    **Every place past the chunk's last block holds NaN** in the leaves
+    the helper is handed, and zero in the reference's: a block read in
+    vain would show in the result (``0 * NaN``), and none does."""
+    b, row, flagged = _ROWS[rows]
+    index = _PLACES[place](width)
+    rng = np.random.default_rng(width + index)
+    q = jnp.asarray(rng.normal(size=(b, 16, width, 8)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(4, 16, _ROW, 8)), jnp.float32)
+            for _ in range(2))
+    pad = np.zeros((4, _ROW), bool)
+    for r, flag in zip(range(row, row + b), flagged):
+        if flag:
+            pad[r, :3] = pad[r, index // 2] = True
+            pad[r, index + 1] = pad[r, index + width - 2] = True
+    pad[:, 0] &= index > 0      # the first query keeps a place to attend
+    pad = jnp.asarray(pad)
+    read = (index + width - 1) // CHUNK_KEY_BLOCK * CHUNK_KEY_BLOCK \
+        + CHUNK_KEY_BLOCK
+    unread = jnp.arange(_ROW)[None, None, :, None] >= read
+    got = chunk_attention(q, jnp.where(unread, jnp.nan, k),
+                          jnp.where(unread, jnp.nan, v), row, index, pad,
+                          force=how)
+    bias = chunk_incremental_bias(_ROW, index, width, pad[row:row + b])
+    want = xla_attention(q, jnp.where(unread, 0, k)[row:row + b],
+                         jnp.where(unread, 0, v)[row:row + b], bias)
+    assert got.shape == want.shape and got.dtype == q.dtype
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-6)
+
+
+def test_chunk_key_block_divides_a_short_row():
+    """A row shorter than the block, or not a multiple of it, is read in
+    blocks of their common divisor (the tests' models have rows of 24 and
+    32 places); the pool counts a chunk's reading by the same number."""
+    for max_len, block in ((24, 8), (32, 32), (2048, CHUNK_KEY_BLOCK),
+                           (384, 128)):
+        m = _model(max_len=max_len, num_layers=1)
+        assert m.chunk_key_block(m.init_cache(1)) == block
 
 
 @pytest.mark.slow
