@@ -642,6 +642,20 @@ class HybridDecoder(Module):
                   if "self" in layer and blk.window is None}
         return blocks.pop() if len(blocks) == 1 else None
 
+    def chunk_key_block(self, caches) -> Optional[int]:
+        """Places of its slot's full row that a prefill chunk's attention
+        reads at a time, where every full row is a latent one
+        (``LatentAttention.chunk_key_block``: live blocks only, on every
+        backend); None where a chunk reads its row whole
+        (``GroupedQueryAttention`` and ``DifferentialAttention``'s full
+        layers): the serving pool counts what its chunk programs read by
+        this."""
+        blocks = {blk.attn.chunk_key_block(layer["self"])
+                  if isinstance(blk.attn, LatentAttention) else None
+                  for blk, layer in zip(self.blocks, caches["layers"])
+                  if "self" in layer and blk.window is None}
+        return blocks.pop() if len(blocks) == 1 else None
+
     def cache_write_programs(self, caches) -> int:
         """Device programs that write ``caches`` in one per-row decode
         step (:meth:`decode_step` with ``index [B]``): a layer's keys and

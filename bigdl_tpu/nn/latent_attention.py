@@ -24,6 +24,13 @@ row of one head with two leaves, ``"v"`` the latent (it is the value and
 most of the key) and ``"k"`` the rotary part of the key.  They are
 written and read by position like any full row, so every program of the
 serving pool takes them as it takes keys and values.
+
+**A prefill chunk** attends the live key blocks of its slot's row,
+absorbed: on a TPU, where the leaves tile, through one kernel a layer
+(``ops.attention_kernels.latent_chunk_attention``; the predicate is
+``latent_chunk_takes``), on every other backend and shape through
+:func:`latent_rows_attention`, a loop over slices.  One meaning, and the
+choice follows the backend and the shapes.
 """
 
 from __future__ import annotations
@@ -129,7 +136,9 @@ class LatentAttention(Module):
     :meth:`forward` is the one entry, as
     :meth:`GroupedQueryAttention.forward`: a whole sequence (expanded), a
     prefill chunk against the slot's row (absorbed, live key blocks
-    only), and one token a row (absorbed; on a TPU through
+    only; on a TPU through ``ops.latent_chunk_attention``, elsewhere
+    :func:`latent_rows_attention`), and one token a row (absorbed; on a
+    TPU through
     ``ops.latent_decode_attention``, which reads live blocks only, each
     once) share their projections."""
 
@@ -190,6 +199,13 @@ class LatentAttention(Module):
         a TPU."""
         return attention_kernels.decode_key_block(
             cache["k"].shape, cache["v"].shape, cache["k"].dtype)
+
+    def chunk_key_block(self, cache) -> int:
+        """Places of its slot's row that a prefill chunk attends at a
+        time, on every backend (live blocks only, up to the chunk's last
+        position): :data:`CHUNK_KEY_BLOCK`, or what it shares with a row
+        it does not divide."""
+        return math.gcd(cache["v"].shape[2], CHUNK_KEY_BLOCK)
 
     # ---- the three entries -------------------------------------------------
 
@@ -273,12 +289,20 @@ class LatentAttention(Module):
                 if pad is not None and slot is not None:
                     pad = jax.lax.dynamic_slice(
                         pad, (slot, 0), (1, pad.shape[1]))
-                L = kv["v"].shape[2]
-                block = math.gcd(L, CHUNK_KEY_BLOCK)
+                block = self.chunk_key_block(kv)
+                q_c = q_c.astype(x.dtype)
                 with jax.named_scope("mla/attend"):
-                    ctx = latent_rows_attention(
-                        q_c.astype(x.dtype), q_r, kv["v"], kv["k"], row,
-                        q_pos, pad, self.scale, block)
+                    if attention_kernels.latent_chunk_takes(
+                            q_c.shape, kv["v"].shape, kv["k"].shape,
+                            kv["v"].dtype, block):
+                        ctx = attention_kernels.latent_chunk_attention(
+                            q_c, q_r, kv["v"], kv["k"], row, index, pad,
+                            scale=self.scale, block=block,
+                            interpret=not attention_kernels._on_tpu())
+                    else:
+                        ctx = latent_rows_attention(
+                            q_c, q_r, kv["v"], kv["k"], row, q_pos, pad,
+                            self.scale, block)
             with jax.named_scope("mla/absorb"):
                 o = jnp.einsum("bhtr,hvr->bhtv", ctx.astype(x.dtype), w_v,
                                preferred_element_type=jnp.float32)

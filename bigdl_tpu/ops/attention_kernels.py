@@ -1457,6 +1457,173 @@ def ragged_chunk_attention(q, k, v, row, index, pad, *, block: int,
 
 
 # ---------------------------------------------------------------------------
+# A prefill chunk over the live key blocks of its latent rows
+# ---------------------------------------------------------------------------
+
+__all__ += ["latent_chunk_attention", "latent_chunk_takes"]
+
+# query rows (a head's chunk positions, the heads one after another) that a
+# grid step holds, and that one pair of products inside the step takes
+_LATENT_CHUNK_TILE = 2048
+_LATENT_CHUNK_ROWS = 256
+
+
+def _latent_chunk_kernel(at_ref, ql_ref, qr_ref, kr_ref, c_ref, bias_ref,
+                         o_ref, m_ref, l_ref, acc_ref, *, scale: float,
+                         block: int, width: int, rows: int):
+    """One (row, query tile, key block) program of a chunk over a latent
+    row, whose one head every query head shares: the tile's queries are
+    rows of ``ql_ref [tile, r]`` (absorbed into the latent space) and
+    ``qr_ref [tile, dr]`` (the rotary part), row ``i`` of the ``H x W``
+    being chunk position ``i % width`` of head ``i // width``, against the
+    block ``c_ref [block, r]`` of the latent leaf and ``kr_ref [dr,
+    block]`` of the rotary leaf, each as its leaf lies.  ``rows`` queries
+    at a time: scores ``[rows, r] x [block, r]^T + [rows, dr] x [dr,
+    block]`` on the MXU, **each exponential taken once** and used twice,
+    summed in float32 for the denominator and rounded to the leaf's dtype
+    for the context ``[rows, block] x [block, r]``; unrolled over the
+    tile, so that one group's exponentials are scheduled under another's
+    products.  ``o_ref [tile, r]``; the softmax state ``m_ref``, ``l_ref
+    [tile, 1]`` and ``acc_ref [tile, r]`` are float32 and stay on the
+    chip from a tile's first block to its last."""
+    j = pl.program_id(2)
+    tile = ql_ref.shape[0]
+    index = at_ref[1]
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(j * block < index + width)
+    def _body():
+        c = c_ref[...]
+        kr = kr_ref[...]
+        bias = bias_ref[...]                                   # [1, block]
+        shape = (rows, block)
+        k_pos = j * block + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        masks = {}
+        for g in range(tile // rows):
+            # a tile starts on a head's first position, so a group's
+            # place in its chunk is static
+            first = g * rows % width
+            if first not in masks:
+                masks[first] = k_pos <= index + first + (
+                    row if rows <= width else jax.lax.rem(row, width))
+            at = slice(g * rows, (g + 1) * rows)
+            s = jax.lax.dot_general(
+                ql_ref[at], c, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) + jax.lax.dot_general(
+                qr_ref[at], kr, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)            # [rows, block]
+            s = jnp.where(masks[first], s * scale + bias, _NEG_INF)
+            m_prev = m_ref[at]                                 # [rows, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[at] = alpha * l_ref[at] + jnp.sum(p, axis=1, keepdims=True)
+            m_ref[at] = m_new
+            acc_ref[at] = acc_ref[at] * alpha + jax.lax.dot_general(
+                p.astype(c.dtype), c, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)            # [rows, r]
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finish():
+        o_ref[...] = (acc_ref[...] * (1.0 / l_ref[...])).astype(o_ref.dtype)
+
+
+def _latent_chunk_tiles(heads: int, width: int):
+    """``(tile, rows)`` of :func:`_latent_chunk_kernel` for a chunk of
+    ``width`` queries a head: at most :data:`_LATENT_CHUNK_TILE` of the
+    ``heads x width`` rows a grid step, :data:`_LATENT_CHUNK_ROWS` a
+    product, or None where they do not divide into whole heads or whole
+    chunks."""
+    total = heads * width
+    tile, rows = min(total, _LATENT_CHUNK_TILE), \
+        min(total, _LATENT_CHUNK_ROWS)
+    if total % tile or tile % rows or tile % width \
+            or (rows % width and width % rows):
+        return None
+    return tile, rows
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "block", "interpret"))
+def latent_chunk_attention(q_latent, q_rotary, latent, rotary, row, index,
+                           pad, *, scale: float, block: int,
+                           interpret: bool = False):
+    """A prefill chunk's attention over the live key blocks of its
+    **latent rows**, as a Pallas TPU kernel: the mathematics of
+    ``nn.latent_attention.latent_rows_attention`` (absorbed form, scores
+    and sums in float32, the weights rounded to the row's dtype before
+    the context product), only the order of summation differs.
+
+    ``q_latent [B, H, W, r]`` / ``q_rotary [B, H, W, dr]`` at positions
+    ``index .. index+W-1`` (rounded to the row's dtype here) over rows
+    ``row .. row+B`` of ``latent [S, 1, T, r]`` and ``rotary [S, 1, T,
+    dr]`` as they lie after the chunk's window was written (the latent
+    width-minor, the rotary part positions-minor: the ``swapaxes`` below
+    changes a name and no byte); ``pad [B, T]`` flags padding by position,
+    or None.  Returns the context in the latent space ``[B, H, W, r]`` in
+    ``q_latent``'s dtype.
+
+    The grid is (row, query tile, key block), key blocks innermost; the
+    first row and the chunk's position go ahead as scalar prefetch.  A
+    query tile is a group of whole heads (:func:`_latent_chunk_tiles`) and
+    reads every live block again (0.59 MB at 512 places of 512 + 64: a
+    sixteenth of the tile's two products at the chip's peaks).  A step
+    past the chunk's last block skips its arithmetic and names that last
+    block again, so nothing is fetched for it.  A function of its own
+    under ``jit`` so that a model's layers and a pool's programs share one
+    trace of the kernel, whose body is unrolled over the tile
+    (:func:`_ragged_decode`)."""
+    b, h, w, r = q_latent.shape
+    t, dr = rotary.shape[2], rotary.shape[3]
+    tile, rows = _latent_chunk_tiles(h, w)
+    at = jnp.stack([jnp.asarray(row, jnp.int32),
+                    jnp.asarray(index, jnp.int32)])
+    bias = jnp.zeros((b, 1, t), jnp.float32) if pad is None else \
+        jnp.where(pad, _NEG_INF, 0.0).astype(jnp.float32)[:, None]
+
+    def own(bi, ti, j, at):
+        return bi, ti, 0
+
+    def flags(bi, ti, j, at):
+        return bi, 0, jnp.minimum(j, (at[1] + w - 1) // block)
+
+    def lanes(bi, ti, j, at):
+        return at[0] + bi, 0, 0, flags(bi, ti, j, at)[2]
+
+    def sublanes(bi, ti, j, at):
+        return at[0] + bi, 0, flags(bi, ti, j, at)[2], 0
+
+    out = pl.pallas_call(
+        functools.partial(_latent_chunk_kernel, scale=scale, block=block,
+                          width=w, rows=rows),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, h * w // tile, t // block),
+            in_specs=[pl.BlockSpec((None, tile, r), own),
+                      pl.BlockSpec((None, tile, dr), own),
+                      pl.BlockSpec((None, None, dr, block), lanes),
+                      pl.BlockSpec((None, None, block, r), sublanes),
+                      pl.BlockSpec((None, 1, block), flags)],
+            out_specs=pl.BlockSpec((None, tile, r), own),
+            scratch_shapes=[_scratch(s) for s in
+                            ((tile, 1), (tile, 1), (tile, r))]),
+        out_shape=jax.ShapeDtypeStruct((b, h * w, r), q_latent.dtype),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_CHUNK_VMEM),
+    )(at, q_latent.astype(latent.dtype).reshape(b, h * w, r),
+      q_rotary.astype(rotary.dtype).reshape(b, h * w, dr),
+      jnp.swapaxes(rotary, 2, 3), latent, bias)
+    return out.reshape(b, h, w, r)
+
+
+# ---------------------------------------------------------------------------
 # Dispatch
 # ---------------------------------------------------------------------------
 
@@ -1559,6 +1726,47 @@ def _chunk_kernel_takes(k_shape, v_shape, dtype, block: int) -> bool:
             and cache_row_tiles(v_shape, dtype) == "lanes")
 
 
+def latent_chunk_takes(q_shape, latent_shape, rotary_shape, dtype,
+                       block: int, *, force: Optional[str] = None) -> bool:
+    """Whether a chunk of queries ``q_shape [B, H, W, r]`` over latent
+    rows of these shapes goes through :func:`latent_chunk_attention`: on a
+    TPU, where the leaves tile — the latent width-minor and the rotary
+    part positions-minor (:func:`cache_kernels.cache_row_tiles`), blocks
+    of whole lane tiles that divide the row, and ``H x W`` query rows that
+    divide into tiles of whole heads and sublanes
+    (:func:`_latent_chunk_tiles`).  Everything else takes the loop over
+    slices, ``nn.latent_attention.latent_rows_attention`` (``force`` ∈
+    {"kernel", "loop", None} overrides, as in
+    :func:`cache_kernels.cache_row_writer`)."""
+    from bigdl_tpu.ops.cache_kernels import _sublanes, cache_row_tiles
+    if force == "loop" or (force is None and not _on_tpu()):
+        return False
+    _, h, w, r = q_shape
+    tiles = _latent_chunk_tiles(h, w)
+    takes = (block % _LANES == 0 and latent_shape[2] % block == 0
+             and tiles is not None and tiles[1] % _sublanes(dtype) == 0
+             and cache_row_tiles(latent_shape, dtype) == "sublanes"
+             and cache_row_tiles(rotary_shape, dtype) == "lanes")
+    if takes:
+        # what a step holds: the tile's queries and context (both
+        # double-buffered; a rotary part fills whole lanes), the float32
+        # state, two blocks of each leaf, a group's scores and weights:
+        # inside three quarters of the kernel's VMEM (19 MB of 32 at the
+        # sarvam cut's bfloat16 rows; float32 rows of 512 would take 30
+        # and are refused by the compiler)
+        size = jnp.dtype(dtype).itemsize
+        dr = max(rotary_shape[3], _LANES)
+        tile, rows = tiles
+        need = (2 * tile * (2 * r + dr) * size + 4 * tile * (r + 2 * _LANES)
+                + 2 * block * (r + dr) * size + 4 * rows * (3 * block + r))
+        takes = 4 * need <= 3 * _CHUNK_VMEM
+    if force == "kernel" and not takes:
+        raise ValueError(
+            f"queries {tuple(q_shape)} over rows {tuple(latent_shape)} / "
+            f"{tuple(rotary_shape)} do not tile for the latent chunk kernel")
+    return takes
+
+
 def chunk_attention(q, k, v, row, index, pad, *,
                     force: Optional[str] = None):
     """A prefill chunk's attention **over the live part of its rows**:
@@ -1585,7 +1793,10 @@ def chunk_attention(q, k, v, row, index, pad, *,
     :func:`ragged_chunk_attention` (``force`` ∈ {"ragged", "xla", None}
     overrides, as in :func:`decode_attention`); everything else takes a
     ``fori_loop`` over blocks sliced out of the leaves, the form
-    ``nn.latent_attention.latent_rows_attention`` has for a latent row."""
+    ``nn.latent_attention.latent_rows_attention`` has for a latent row
+    off a TPU (on one a latent row's chunk has a kernel of its own,
+    :func:`latent_chunk_attention`: one head that every query head
+    shares, so a tile of heads against one block)."""
     block = chunk_key_block(k.shape)
     takes = _chunk_kernel_takes(k.shape, v.shape, k.dtype, block)
     if force == "ragged" and not takes:

@@ -8,7 +8,10 @@ the program): the whole forward, the absorbed step against the expanded
 form, chunks then steps through both of the pool's prefill routes, YaRN's
 frequencies against hand-worked values, a slot's second occupant, the
 pool's declaration and bytes, what the builder refuses, the share test,
-and the engine end to end."""
+and the engine end to end; and the chunk's kernel
+(``ops.latent_chunk_attention``, what a TPU process chooses for a chunk:
+interpreted here) against the loop over slices on the same leaves, and
+through the model."""
 
 import functools
 import math
@@ -32,8 +35,10 @@ from reference import latent_moe_lm as ref                    # noqa: E402
 from bigdl_tpu.models import mimo_v2, sarvam_mla              # noqa: E402
 from bigdl_tpu.models.hybrid_decoder import GatedFFN          # noqa: E402
 from bigdl_tpu.nn import attention as att                     # noqa: E402
-from bigdl_tpu.nn.latent_attention import LatentAttention     # noqa: E402
+from bigdl_tpu.nn.latent_attention import (                    # noqa: E402
+    LatentAttention, latent_rows_attention)
 from bigdl_tpu.nn.moe import HeldExperts                      # noqa: E402
+from bigdl_tpu.ops import attention_kernels                   # noqa: E402
 from bigdl_tpu.serving.generation import (                    # noqa: E402
     GenerationScheduler, SlotPool)
 
@@ -100,6 +105,35 @@ def ref_logits(model, tokens):
     m, cfg = model
     with jax.default_matmul_precision("highest"):
         return ref.forward(params_of(m), cfg, tokens)
+
+
+@pytest.fixture
+def chunk_kernel(monkeypatch):
+    """What a TPU process chooses for a chunk whose leaves tile, asked
+    for here at the one place ``LatentAttention.forward`` asks, so the
+    kernel runs interpreted (a single token at a scalar position tiles
+    nothing and keeps the loop); the list gathers the queries' shapes the
+    kernel took."""
+    asked = []
+
+    def takes(q_shape, *args, _takes=attention_kernels.latent_chunk_takes):
+        try:
+            _takes(q_shape, *args, force="kernel")
+        except ValueError:
+            return False
+        asked.append(tuple(q_shape))
+        return True
+
+    monkeypatch.setattr(attention_kernels, "latent_chunk_takes", takes)
+    return asked
+
+
+@pytest.fixture(scope="module")
+def tiling_model():
+    """Rows the chunk kernel takes: a latent of 128 (whole lane tiles),
+    a rotary key of 8, 384 places."""
+    with jax.default_matmul_precision("highest"):
+        return build(384, kv_lora_rank=128, head_dim=136)
 
 
 def close(a, b, tol=1e-4):
@@ -195,6 +229,28 @@ def test_a_chunk_attends_key_blocks_past_the_first(monkeypatch):
         assert close(logits, want[:, t]), t
 
 
+def test_a_chunk_attends_key_blocks_past_the_first_through_the_kernel(
+        monkeypatch, tiling_model, chunk_kernel):
+    """The same through the chunk kernel: blocks of 128 places, chunks of
+    32 tokens up to position 288, the last of which crosses three blocks
+    (and skips none of the row's three); the steps that follow read the
+    rows the chunks wrote."""
+    from bigdl_tpu.nn import latent_attention
+    monkeypatch.setattr(latent_attention, "CHUNK_KEY_BLOCK", 128)
+    m, cfg = tiling_model
+    toks = jnp.asarray(np.random.default_rng(2).integers(
+        1, VOCAB + 1, (1, 292)), jnp.int32)
+    want = ref.forward(params_of(m), cfg, toks)
+    caches = m.init_cache(1)
+    for s in range(0, 288, 32):
+        caches, _ = m.prefill_chunk(toks[:, s:s + 32], s, caches)
+    assert chunk_kernel == [(1, 4, 32, 128)] * (9 * LAYERS)
+    for t in range(288, 292):
+        logits, caches, _ = m.decode_step(toks[:, t:t + 1], jnp.int32(t),
+                                          caches)
+        assert close(logits, want[:, t]), t
+
+
 @pytest.mark.parametrize("scenario", joint_pass.SCENARIOS)
 def test_the_joint_pass_equals_the_chunk_program_then_the_step(
         model, scenario):
@@ -205,6 +261,20 @@ def test_the_joint_pass_equals_the_chunk_program_then_the_step(
     m, _ = model
     joint_pass.assert_joint_pass_equals_chunk_then_step(
         m, CHUNK, VOCAB, scenario)
+
+
+@pytest.mark.parametrize("scenario", joint_pass.SCENARIOS)
+def test_the_joint_pass_equals_the_chunk_program_then_the_step_through_the_kernel(
+        monkeypatch, tiling_model, chunk_kernel, scenario):
+    """The same where the chunk attends through the kernel, in the joint
+    walk and in the chunk program alike (blocks of 128: the chunk's
+    places lie in the first and the steps past it skip their work)."""
+    from bigdl_tpu.nn import latent_attention
+    monkeypatch.setattr(latent_attention, "CHUNK_KEY_BLOCK", 128)
+    m, _ = tiling_model
+    joint_pass.assert_joint_pass_equals_chunk_then_step(
+        m, CHUNK, VOCAB, scenario)
+    assert chunk_kernel and set(chunk_kernel) == {(1, 4, CHUNK, 128)}
 
 
 def _pool_prefill(pool, prompt, slot):
@@ -259,6 +329,89 @@ def test_a_slots_second_occupant_reads_nothing_of_the_first(
     for n_prompt in PROMPTS.values():
         _pool_prefill(pool, second[:n_prompt], 0)
         _teacher_forced(pool, second, 0, n_prompt - 1, 28, ref_logits[1])
+
+
+# ---- the chunk's kernel against the loop --------------------------------------
+
+# (heads, width, places, row, index, places flagged from 0, dtype): a block
+# is 128 places; the row is one of three
+CHUNK_CASES = {
+    "position-0": (8, 256, 1024, 1, 0, 0, jnp.float32),
+    "inside-the-first-block": (8, 256, 1024, 2, 37, 0, jnp.float32),
+    "on-a-blocks-boundary-in-slot-0": (8, 256, 1024, 0, 256, 0, jnp.float32),
+    "ending-on-the-rows-last-place": (8, 256, 1024, 2, 768, 0, jnp.float32),
+    "padding-before-the-first-token": (8, 256, 1024, 1, 128, 140,
+                                       jnp.float32),
+    "width-32-ending-on-the-last-place": (64, 32, 512, 1, 480, 0,
+                                          jnp.float32),
+    "width-32-padded-in-the-first-block": (64, 32, 512, 2, 100, 107,
+                                           jnp.float32),
+    "bfloat16-rows": (8, 256, 1024, 1, 700, 0, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", list(CHUNK_CASES))
+def test_the_chunk_kernel_equals_the_loop_over_slices(case):
+    """``ops.latent_chunk_attention`` (interpreted) against
+    ``latent_rows_attention`` on the same leaves: the same mathematics in
+    another order of summation, so float32 to a few roundings (and
+    bfloat16 rows to half a bfloat16 step of the result, which the kernel
+    rounds to the queries' dtype).  Every place past the chunk's last
+    block holds NaN: neither form reads it.  A query all of whose places
+    are flagged (padding before its row's first token) averages the
+    blocks read, in both."""
+    h, w, t, row, index, flagged, dtype = CHUNK_CASES[case]
+    r, dr, block = 128, 8 if dtype == jnp.float32 else 16, 128
+    keys = jax.random.split(jax.random.key(len(case)), 4)
+    ql = jax.random.normal(keys[0], (1, h, w, r), dtype)
+    qr = jax.random.normal(keys[1], (1, h, w, dr), dtype)
+    live = -(-(index + w) // block) * block
+    dead = (jnp.arange(t) >= live)[None, None, :, None]
+    latent = jnp.where(dead, jnp.nan, jax.random.normal(
+        keys[2], (3, 1, t, r))).astype(dtype)
+    rotary = jnp.where(dead, jnp.nan, jax.random.normal(
+        keys[3], (3, 1, t, dr))).astype(dtype)
+    pad = (jnp.arange(t) < flagged)[None] if flagged else None
+    assert attention_kernels.latent_chunk_takes(
+        ql.shape, latent.shape, rotary.shape, dtype, block, force="kernel")
+    assert not attention_kernels.latent_chunk_takes(
+        ql.shape, latent.shape, rotary.shape, dtype, block)   # not a TPU
+    got = attention_kernels.latent_chunk_attention(
+        ql, qr, latent, rotary, row, jnp.int32(index), pad, scale=0.11,
+        block=block, interpret=True)
+    want = latent_rows_attention(
+        ql, qr, latent, rotary, row,
+        (index + jnp.arange(w, dtype=jnp.int32))[None], pad, 0.11, block)
+    assert got.shape == (1, h, w, r) and got.dtype == dtype
+    assert bool(jnp.all(jnp.isfinite(want)))
+    assert close(got.astype(jnp.float32), want,
+                 2e-6 if dtype == jnp.float32 else 8e-3)
+
+
+@pytest.mark.parametrize("why,q,latent,rotary,dtype,block", [
+    ("a latent that is no whole lane tile", (1, 4, 32, 32), (3, 1, 384, 32),
+     (3, 1, 384, 8), jnp.float32, 128),
+    ("a block that is no whole lane tile", (1, 4, 32, 128), (3, 1, 384, 128),
+     (3, 1, 384, 8), jnp.float32, 64),
+    ("a row the block does not divide", (1, 4, 32, 128), (3, 1, 320, 128),
+     (3, 1, 320, 8), jnp.float32, 128),
+    ("query rows that fill no sublane tile", (1, 2, 2, 128), (3, 1, 384, 128),
+     (3, 1, 384, 8), jnp.float32, 128),
+    ("a width that does not divide the rows of a product", (1, 8, 96, 128),
+     (3, 1, 384, 128), (3, 1, 384, 8), jnp.float32, 128),
+    ("a rotary part that lies width-minor", (1, 4, 32, 128),
+     (3, 1, 384, 128), (3, 1, 384, 128), jnp.float32, 128),
+    ("a tile past the kernel's VMEM (the compiler refuses it on a v5e)",
+     (1, 64, 256, 512), (3, 1, 1024, 512), (3, 1, 1024, 64), jnp.float32,
+     512),
+])
+def test_the_chunk_kernel_is_refused_where_the_leaves_do_not_tile(
+        why, q, latent, rotary, dtype, block):
+    with pytest.raises(ValueError, match="do not tile"):
+        attention_kernels.latent_chunk_takes(q, latent, rotary, dtype, block,
+                                             force="kernel")
+    assert not attention_kernels.latent_chunk_takes(
+        q, latent, rotary, dtype, block, force="loop")
 
 
 # ---- rotary ------------------------------------------------------------------
@@ -585,3 +738,41 @@ def test_the_pool_counts_what_the_kernels_step_reads_and_writes(monkeypatch):
     assert engine.pool.cache_write_programs == 1 + LAYERS
     want = np.asarray(m.generate(jnp.asarray(prompt)[None], new, chunk=24))
     assert np.array_equal(row, want[0])
+
+
+@pytest.mark.parametrize("rows", ["latent", "grouped-query"])
+def test_chunk_position_counters_follow_a_known_schedule(rows):
+    """A prompt of 300 tokens in chunks of 64 goes out as chunks at 0,
+    64, 128 and 192 and a suffix-aligned one at 235 (as
+    ``tests/test_generation.py`` has it for ``TransformerLM``).  A latent
+    pool's chunk reads the live key blocks of its slot's row, 128 places
+    here (what 512 and a row of 384 share), on every backend, and the
+    pool counts so; a pool of grouped-query rows reads every chunk's row
+    whole (``HybridDecoder.chunk_key_block`` answers None for it)."""
+    if rows == "latent":
+        m, _ = build(384)
+        chunk, prompt = 64, np.arange(300, dtype=np.int32) % VOCAB + 1
+        chunks = [(0, 64), (64, 64), (128, 64), (192, 64), (235, 64)]
+        block = 128
+    else:
+        from tests.test_hybrid_decoder import CFG as MIMO
+        m = mimo_v2(MIMO, MAX_LEN).eval_mode()
+        chunk, prompt = CHUNK, np.arange(14, dtype=np.int32) % VOCAB + 1
+        chunks = [(0, 4), (4, 4), (8, 4), (12, 1)]
+        block = None
+    engine = GenerationScheduler(m, slots=2, prefill_chunk=chunk)
+    try:
+        assert engine.pool.chunk_key_block == block \
+            == m.chunk_key_block(engine.pool.caches)
+        row = engine.submit(prompt, 3)
+        engine.shutdown()
+        st = engine.stats()
+    finally:
+        engine.shutdown()
+    want = np.asarray(m.generate(jnp.asarray(prompt)[None], 3, chunk=chunk))
+    assert np.array_equal(row, want[0])
+    assert st["chunks_joint"] + st["chunks_alone"] == len(chunks)
+    assert st["chunk_positions_live"] == sum(s + w for s, w in chunks)
+    assert st["chunk_positions_read"] == (
+        len(chunks) * MAX_LEN if block is None
+        else sum(block * -(-(s + w) // block) for s, w in chunks))

@@ -36,6 +36,8 @@ from bigdl_tpu.nn.moe import ROUTING
 from bigdl_tpu.ops import cache_kernels
 from bigdl_tpu.ops import conv_bn_kernels as ck
 from bigdl_tpu.ops.attention_kernels import (flash_attention,
+                                             latent_chunk_attention,
+                                             latent_chunk_takes,
                                              ragged_decode_attention)
 
 
@@ -158,6 +160,33 @@ DECODE_CASES = [_decode_case(6, 32, 32, 2048, 64, 64, jnp.float32),
                 _decode_case(48, 20, 4, 3584, 128, 128, jnp.bfloat16),
                 _decode_case(32, 64, 4, 6144, 192, 128, jnp.bfloat16)]
 
+def _latent_chunk_case(width, heads, slots, t, r, dr, dtype):
+    """The latent chunk kernel: ``width`` queries a head over the live key
+    blocks of a row of the pooled latent leaves ``[slots, 1, t, r]`` and
+    ``[slots, 1, t, dr]``, handed over as the pool holds them."""
+    def build(sds):
+        args = [sds((1, heads, width, r), dtype),
+                sds((1, heads, width, dr), dtype),
+                sds((slots, 1, t, r), dtype), sds((slots, 1, t, dr), dtype),
+                sds((), jnp.int32), sds((), jnp.int32),
+                sds((1, t), jnp.bool_)]
+        assert latent_chunk_takes(args[0].shape, args[2].shape,
+                                  args[3].shape, dtype, 512, force="kernel")
+        return functools.partial(latent_chunk_attention, scale=0.135,
+                                 block=512), args
+    name = "latent_chunk-%dx%d-%dx%d+%d-%s" % (
+        width, heads, t, r, dr, jnp.dtype(dtype).name)
+    return pytest.param(build, id=name)
+
+
+# the sarvam-105b cut's chunk at the pool's full width and at the narrowest
+# of its joint widths: 64 heads over one 512-wide latent and one 64-wide
+# rotary key a place
+LATENT_CHUNK_CASES = [
+    _latent_chunk_case(256, 64, 112, 7168, 512, 64, jnp.bfloat16),
+    _latent_chunk_case(32, 64, 112, 7168, 512, 64, jnp.bfloat16)]
+
+
 def _row_write_case(slots, heads, t, d, dv, dtype):
     """The row-write kernel over a layer's two leaves, each handed over as
     the chip stores it (``cache_row_writer`` says how)."""
@@ -183,7 +212,7 @@ ROW_WRITE_CASES = [_row_write_case(32, 4, 6144, 192, 128, jnp.bfloat16),
 CASES = (
     [_flash_case(s, bias, bwd) for s in FLASH_SHAPES
      for bias in (False, True) for bwd in (False, True)]
-    + DECODE_CASES + ROW_WRITE_CASES
+    + DECODE_CASES + LATENT_CHUNK_CASES + ROW_WRITE_CASES
     + [_matmul_case(s, bwd) for s in MATMUL_SHAPES for bwd in (False, True)]
     + [_conv3_case(s, bwd) for s in CONV3_SHAPES for bwd in (False, True)]
 )
@@ -539,16 +568,17 @@ def _cut():
         "serving": {"slots": 32, "max_len": 6144, "prefill_chunk": 256}}
 
 
-def _kernel_calls(text):
+def _kernel_calls(text, also=()):
     """The program's Pallas calls by kernel: ``(row writers, ragged
-    decode attentions)``, told apart by the ``jit`` each was traced
-    under."""
+    decode attentions)`` and then those of each ``jit`` in ``also``, told
+    apart by the ``jit`` each was traced under."""
+    names = ("_write_cache_rows", "_ragged_decode") + tuple(also)
     calls = re.findall(
         r'custom_call_target="tpu_custom_call".*?op_name="[^"]*jit\((\w+)\)'
         r'/pallas_call"', text)
-    assert set(calls) <= {"_write_cache_rows", "_ragged_decode"}, calls
+    assert set(calls) <= set(names), calls
     assert len(calls) == text.count('custom_call_target="tpu_custom_call"')
-    return (calls.count("_write_cache_rows"), calls.count("_ragged_decode"))
+    return tuple(calls.count(name) for name in names)
 
 
 def _lower_cut_program(program, sharding):
@@ -838,7 +868,9 @@ def test_latent_pool_program_copies_no_latent_leaf_on_v5e(
     ``dynamic-update-slice`` into a leaf or the flags, no ``while``; the
     model answers the pool ``cache_write_programs`` = one a layer and the
     flags' select, and a key block of 512.  The chunk program writes a
-    window and walks the row's live key blocks in one loop a layer.
+    window and attends the row's live key blocks in one kernel call a
+    layer (``ops.latent_chunk_attention``): no ``while``, and the running
+    context nowhere outside the kernel.
     Weights, rows and temporaries fit the chip, and fill 11 GB of it."""
     from bigdl_tpu.ops import attention_kernels
     monkeypatch.setattr(attention_kernels, "_on_tpu", lambda: True)
@@ -858,19 +890,23 @@ def test_latent_pool_program_copies_no_latent_leaf_on_v5e(
         r"= %s\S* (?:copy|copy-start|transpose|scatter)\(" % leaf, text)
     # keys or values at the 64 heads over a whole row
     assert not re.findall(r"bf16\[\d+,64,7168,(?:128|192|256)\]", text)
-    calls = _kernel_calls(text)
+    calls = _kernel_calls(text, also=("latent_chunk_attention",))
     whiles = len(re.findall(r" while\(", text))
     updates = len(re.findall(
         r"= (?:%s|pred\[112,7168\])\S* dynamic-update-slice\(" % leaf, text))
     if program == "decode":
-        assert (calls, whiles, updates) == ((layers, layers), 0, 0)
+        assert (calls, whiles, updates) == ((layers, layers, 0), 0, 0)
     elif program == "chunk_prefill":
-        assert (calls, whiles, updates) == ((0, 0), layers, 2 * layers + 1)
+        assert (calls, whiles, updates) == ((0, 0, layers), 0,
+                                            2 * layers + 1)
     else:
         # the joint program: the step's two kernels a layer beside the
-        # chunk's windows and its loop over the slot's live key blocks
-        assert (calls, whiles, updates) == ((layers, layers), layers,
+        # chunk's windows and its kernel over the slot's live key blocks
+        assert (calls, whiles, updates) == ((layers, layers, layers), 0,
                                             2 * layers + 1)
+    # a chunk's running context (64 heads x 256 places x 512, float32)
+    # lives in the chunk kernel's scratch and nowhere in the program
+    assert "f32[1,64,256,512]" not in text
     # the experts' batched product on the held stacks as they lie
     stack = r"bf16\[16,(?:4096,2048|2048,4096)\]"
     assert not re.findall(r"= %s\S* (?:copy|copy-start|transpose)\(" % stack,
