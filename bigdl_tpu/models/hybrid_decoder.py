@@ -1,62 +1,21 @@
-"""Decoder-only language model built from a per-layer list of kinds:
-window and full grouped-query attention layers, layers whose attention
-runs in parallel with a state-space mixer, latent-attention layers,
-layers that are a state-space mixer or a gated short convolution alone,
-layers that keep nothing and read what an earlier layer made in the same
-pass (its row of keys and values, or its mixer's scan output), leading
-dense gated feed-forward layers, and a held share of sigmoid-routed gated
-experts (with or without a shared expert).
+"""Decoder-only language model over a list of blocks that a model
+family builds for itself: window and full grouped-query attention layers,
+layers whose attention runs in parallel with a state-space mixer,
+latent-attention layers, layers that are a state-space mixer or a gated
+short convolution alone, layers that keep nothing and read what an
+earlier layer made in the same pass (its row of keys and values, or its
+mixer's scan output), dense gated feed-forward layers, and a held share
+of sigmoid-routed gated experts (with or without a shared expert).
 
-The block of ``mimo_v2`` (MiMo-V2.5), pre-norm and sequential:
-``h = x + Attn_i(RMSNorm(x))``, ``y = h + FFN_i(RMSNorm(h))``.
-``hybrid_layer_pattern[i]`` names layer ``i``'s attention: 0 a **full**
-layer (causal over everything), 1 a **window** layer (the last
-``sliding_window`` positions, a learned sink logit per head, key/value
-heads and rotary base of its own).  Keys are ``head_dim`` wide, values
-``v_head_dim``; the first ``partial_rotary_factor * head_dim`` dims of
-every query and key head are rotated by position.  ``moe_layer_freq[i]``
-names the feed-forward: 0 a dense gated layer
-``W_d(silu(W_g n) * W_u n)``, 1 :class:`bigdl_tpu.nn.HeldExperts`.  Final
-RMSNorm, an untied head, the embedding not scaled.
-
-The block of ``falcon_h1`` (Falcon-H1) is **parallel**: both mixers read
-the same normed input and their results are summed,
-``h = x + SSM(n * m_ssm_in) * m_ssm_out + Attn(n * m_attn_in) * m_attn_out``
-with ``n = RMSNorm(x)``, then ``y = h + FFN(RMSNorm(h))``; ``SSM`` is
-:class:`bigdl_tpu.nn.ssm.Mamba2Mixer`, ``Attn`` a full layer whose keys
-are scaled, ``FFN`` the dense gated layer with a multiplier inside the
-gate and one on the result; the embedding and the logits are scaled too
-(the architecture's µP multipliers, constants of its ``config.json``).
-
-The block of ``sarvam_mla`` (sarvam-105b) is sequential like
-``mimo_v2``'s, every layer a **latent** layer
-(:class:`bigdl_tpu.nn.latent_attention.LatentAttention`: one compressed
-row a position shared by all heads, YaRN rotary frequencies), the first
-a dense gated layer and the rest expert layers whose routed sum is
-scaled by ``routed_scaling_factor`` and stands beside a shared expert.
-
-The walk of ``phi4_flash`` (Phi-4-mini-flash-reasoning; SambaY's
-decoder-hybrid-decoder) is sequential too, with LayerNorms (gain and
-bias), differential attention that encodes no position
-(:class:`bigdl_tpu.nn.differential_attention.DifferentialAttention`) and
-a head tied to the embedding: Mamba-1 mixers
-(:class:`bigdl_tpu.nn.ssm.Mamba1Mixer`) alternate with window layers in
-the first half; then one more mixer, which hands on its scan output
-``m``, and the one full layer; after them gated memory units on ``m``
-alternate with cross-attention layers that have a query and an output
-projection only and attend the full layer's keys and values.  **Not
-every layer has ``cache["self"]`` any more**: a mixer-only layer keeps a
-state and no row, a unit and a cross layer keep nothing, and a chunk's
-rows stop where the caches stop (:class:`HybridDecoder`).
-
-The walk of ``lfm2_moe`` (LFM2-24B-A2B) is sequential with RMS norms
-and a head tied to the embedding: three layers in four are a **gated
-short convolution** (:class:`bigdl_tpu.nn.short_conv.GatedShortConv`: a
-:class:`MixerBlock` whose state is the convolution's two-row tail and
-nothing else), the fourth full grouped-query attention whose query and
-key heads are normed before they are rotated; the first
-``num_dense_layers`` (two) feed-forwards are dense gated layers, the rest
-all 64 sigmoid-routed experts with a selection bias.
+A family is a factory (``mimo_v2``, ``falcon_h1``, ``sarvam_mla``,
+``phi4_flash``, ``lfm2_moe``; each one's docstring has its walk): it
+reads its ``config.json``, refuses what is not built, makes each layer's
+block (:class:`HybridBlock` over an attention layer, :class:`ParallelBlock`,
+:class:`MixerBlock`, :class:`CrossBlock`, :class:`MemoryBlock`) and hands
+the list to :class:`HybridDecoder`, which asks a block what it keeps and
+never what it is.  **Not every layer has ``cache["self"]``**: a
+mixer-only layer keeps a state and no row, a unit and a cross layer keep
+nothing, and a chunk's rows stop where the caches stop.
 
 It keeps the repo's conventions (``TransformerLM``): token ids are
 1-based with 0 as padding, and generation emits ``argmax + 1`` (the
@@ -201,7 +160,17 @@ class HybridBlock(Module):
     residual stream (a dict the decoder makes anew for every pass):
     ``"memory"``, the scan output of the mixer that hands it on, and
     ``"row"``, the keys and values of the layer whose row later layers
-    read, as that layer left them in this pass."""
+    read, as that layer left them in this pass.
+
+    What the decoder asks of a block and no more: ``keeps`` (whether the
+    layer keeps anything in a pool's cache), ``writes_alone``,
+    ``shares_row``, ``hands_on``, ``reads`` (the layer whose row it
+    attends, or None) and ``reads_memory``."""
+
+    keeps = True
+    hands_on = False
+    reads = None
+    reads_memory = False
 
     def __init__(self, hidden_size: int, attn: Optional[Module],
                  ffn: Module, eps: float, norm=RMSNorm,
@@ -220,15 +189,32 @@ class HybridBlock(Module):
         """The window of the ring this layer keeps, or None."""
         return getattr(getattr(self, "attn", None), "window", None)
 
+    @property
+    def writes_alone(self) -> bool:
+        """Whether :meth:`write` can put a chunk's keys and values into
+        the layer's cache with no attention and no feed-forward."""
+        return hasattr(self.attn, "write")
+
     def init_cache(self, batch: int, max_len: int, dtype, ring_margin: int):
         return {"self": self.attn.init_cache(batch, max_len, dtype,
                                              ring_margin)}
 
     def cache_kinds(self, max_len: int):
-        if isinstance(self.attn, LatentAttention):
-            return ("latent", max_len)
-        return ("ring", self.attn.window) if self.attn.window is not None \
-            else ("full", max_len)
+        return self.attn.cache_kind(max_len)
+
+    def cache_write_programs(self, cache, rows: int) -> int:
+        """Device programs that write ``cache`` (this layer's, of ``rows``
+        rows) in one per-row decode step: its keys and values in one
+        where ``ops.cache_row_writer`` takes its leaves, a
+        ``dynamic_update_slice`` a row and leaf where not; for a state
+        one a leaf whatever the rows (a recurrence's update, and the
+        select that shifts a convolution's inputs)."""
+        programs = len(cache.get("ssm", ()))
+        if "self" in cache:
+            k, v = cache["self"]["k"], cache["self"]["v"]
+            programs += 1 if cache_kernels.cache_row_writer(
+                k.shape, v.shape, k.dtype) is not None else 2 * rows
+        return programs
 
     def forward(self, x, index=0, cache=None, pad=None, slot=None,
                 active=None, valid=None, walk=None):
@@ -347,6 +333,8 @@ class MixerBlock(HybridBlock):
     scan output.  With ``hands_on`` that scan output of this pass goes
     into ``walk["memory"]``, for the gated memory units after it."""
 
+    writes_alone = False
+
     def __init__(self, hidden_size: int, ssm: Module, ffn: Module,
                  eps: float, norm=RMSNorm, hands_on: bool = False):
         super().__init__(hidden_size, None, ffn, eps, norm)
@@ -374,6 +362,8 @@ class CrossBlock(HybridBlock):
     over **another layer's row** (``reads``: that layer's index): it
     keeps nothing, and attends ``walk["row"]``, the row as that layer
     wrote it in this pass."""
+
+    keeps = writes_alone = False
 
     def __init__(self, hidden_size: int, attn: DifferentialAttention,
                  ffn: Module, eps: float, norm=RMSNorm, reads: int = 0):
@@ -413,6 +403,9 @@ class MemoryBlock(HybridBlock):
     """A gated memory unit on ``walk["memory"]``: the layer keeps
     nothing at all."""
 
+    keeps = writes_alone = False
+    reads_memory = True
+
     def __init__(self, hidden_size: int, unit: GatedMemoryUnit, ffn: Module,
                  eps: float, norm=RMSNorm):
         super().__init__(hidden_size, None, ffn, eps, norm)
@@ -433,168 +426,60 @@ class HybridDecoder(Module):
     """``forward(tokens [B, T] int, 1-based; 0 = padding) -> logits
     [B, T, vocab]`` float32 (column ``j`` scores token ``j + 1``).
 
-    ``layer_kinds[i]`` is ``"full"``, ``"window"``, ``"parallel"`` (a
-    full layer beside a state-space mixer built from ``ssm``, the
-    arguments of :class:`Mamba2Mixer`), ``"latent"`` (built from
-    ``latent``, the arguments of :class:`LatentAttention`), or, with
-    ``shared``, one of the kinds below; ``sparse[i]``
-    says whether layer ``i``'s feed-forward is the expert layer, whose
-    routed sum is scaled by ``expert_scale`` and which has a shared
-    expert of ``shared_size`` where that is not 0.  ``multipliers`` are
-    constants by name (absent: 1): ``embedding``, ``lm_head``, ``key``,
-    ``mlp_gate``, ``mlp_down``, and a parallel block's four.
+    ``blocks`` are the layers as a family's factory built them
+    (:class:`HybridBlock` and its subclasses), between an embedding and a
+    final ``norm`` (``RMSNorm`` or ``LayerNorm``, at ``eps``) before the
+    head, which with ``tie_head`` scores with the embedding table.  The
+    embedding and the logits are scaled by their multipliers (constants;
+    1: not scaled).  What only the combination of blocks can get wrong is
+    refused here: a block that attends another layer's row names an
+    earlier layer that shares it, and one that reads a scan output comes
+    after a mixer that hands its own on.
 
-    ``conv`` (the arguments of :class:`GatedShortConv`) adds the kind
-    ``"conv"``: that mixer alone, a **state that is a tail and no row**.
-    ``sparse`` may name any layers dense, several leading ones among them;
-    ``qk_norm`` norms every query and key head of the ``"full"`` and
-    ``"window"`` layers before rotation, ``tie_head`` scores with the
-    embedding table, and ``normalize_eps`` goes under the routing
-    weights' normalising sum.
-
-    ``shared`` (a dict) builds the decoder-hybrid-decoder walk of
-    ``phi4_flash``: every attention layer differential with biases and no
-    positions encoded (:class:`DifferentialAttention`), LayerNorms with a
-    bias, the head tied to the embedding, and three more kinds:
-    ``"selective"``, a :class:`Mamba1Mixer` alone, built from
-    ``shared["mixer"]`` (a **state and no row**); ``"memory"``, a gated
-    memory unit on the scan output that layer ``shared["memory_from"]``
-    hands on (**nothing kept**); ``"cross"``, a query and an output
-    projection over the row of the full layer ``shared["row_from"]``
-    (**nothing kept**).  **Not every layer has ``cache["self"]``, and a
-    layer may have no cache at all**: the walk carries ``walk`` beside
-    the residual stream (:class:`HybridBlock`).  **A chunk's rows leave
-    the walk where the caches stop**: :meth:`prefill_chunk`,
-    :meth:`prefill_kv` and the chunk half of
-    :meth:`decode_step_with_chunk` write caches and give no logits, so
-    where the layers after the last one that keeps a cache keep none, a
-    chunk's rows walk the layers before it whole, write that layer's keys
-    and values (:meth:`HybridBlock.write`: no query, no attention, no
-    feed-forward) and stop (``chunk_layers``)."""
+    **Not every layer has ``cache["self"]``, and a layer may have no cache
+    at all**: the walk carries ``walk`` beside the residual stream
+    (:class:`HybridBlock`).  **A chunk's rows leave the walk where the
+    caches stop**: :meth:`prefill_chunk`, :meth:`prefill_kv` and the chunk
+    half of :meth:`decode_step_with_chunk` write caches and give no
+    logits, so where the layers after the last one that keeps a cache
+    keep none, a chunk's rows walk the layers before it whole, write that
+    layer's keys and values (:meth:`HybridBlock.write`: no query, no
+    attention, no feed-forward) and stop (``chunk_layers``)."""
 
     def __init__(self, vocab_size: int, hidden_size: int,
-                 layer_kinds: Sequence[str], sparse: Sequence[bool],
-                 num_heads: int, head_dim: int, v_head_dim: int,
-                 kv_heads: Dict[str, int], rope_theta: Dict[str, float],
-                 rotary_dim: int, window: int, window_sink: bool,
-                 value_scale: float, dense_size: int, expert_size: int,
-                 num_experts: int, top_k: int,
-                 held: Optional[Tuple[int, int]] = None,
-                 eps: float = 1e-5, max_len: int = 512,
-                 normalize_top_k: bool = True,
-                 ssm: Optional[Dict[str, Any]] = None,
-                 multipliers: Optional[Dict[str, float]] = None,
-                 latent: Optional[Dict[str, Any]] = None,
-                 shared_size: int = 0, expert_scale: float = 1.0,
-                 shared: Optional[Dict[str, Any]] = None,
-                 conv: Optional[Dict[str, Any]] = None,
-                 qk_norm: bool = False, tie_head: bool = False,
-                 normalize_eps: float = 0.0):
+                 blocks: Sequence[HybridBlock], eps: float = 1e-5,
+                 max_len: int = 512, norm=RMSNorm, tie_head: bool = False,
+                 embedding_multiplier: float = 1.0,
+                 lm_head_multiplier: float = 1.0):
         super().__init__()
-        if len(layer_kinds) != len(sparse):
-            raise ValueError("one kind and one sparse flag a layer")
-        mult = dict(multipliers or {})
+        for depth, blk in enumerate(blocks):
+            if blk.reads is not None and not (
+                    blk.reads < depth and blocks[blk.reads].shares_row):
+                raise ValueError(
+                    f"layer {depth} reads layer {blk.reads}: an earlier "
+                    f"layer that shares its row")
+            if blk.reads_memory and not any(
+                    before.hands_on for before in blocks[:depth]):
+                raise ValueError(
+                    f"layer {depth} reads a scan output: a mixer before it "
+                    f"that hands its own on")
         self.hidden_size = hidden_size
         self.max_len = max_len
-        self.embedding_multiplier = float(mult.get("embedding", 1.0))
-        self.lm_head_multiplier = float(mult.get("lm_head", 1.0))
+        self.embedding_multiplier = float(embedding_multiplier)
+        self.lm_head_multiplier = float(lm_head_multiplier)
         self.embedding = LookupTable(vocab_size, hidden_size)
         self.embedding.weight = Parameter(
             self.embedding.weight * hidden_size ** -0.5)
-        blocks = []
-        kinds = ("full", "window", "parallel", "latent") + (
-            ("conv",) if conv else ()) + (
-            ("selective", "memory", "cross") if shared else ())
-        norm = LayerNorm if shared else RMSNorm
-        for depth, (kind, is_sparse) in enumerate(zip(layer_kinds, sparse)):
-            if kind not in kinds:
-                raise ValueError(f"layer kind {kind!r}: one of {kinds} "
-                                 f"('conv' with conv=, 'selective', "
-                                 f"'memory' and 'cross' with shared=)")
-            win = kind == "window"
-            attends = "window" if win else "full"   # a parallel layer: full
-            if shared:
-                if is_sparse or kind in ("parallel", "latent"):
-                    raise ValueError("shared=: dense feed-forwards, and "
-                                     "'selective', 'window', 'full', "
-                                     "'memory' and 'cross' layers")
-                attn = None if kind in ("selective", "memory") else \
-                    DifferentialAttention(
-                        hidden_size, num_heads, kv_heads["full"], head_dim,
-                        depth, window=window if win else None,
-                        cross=kind == "cross", eps=eps)
-            elif kind == "latent":
-                if latent is None:
-                    raise ValueError("a latent layer needs latent=")
-                attn = LatentAttention(hidden_size, num_heads, eps=eps,
-                                       **latent)
-            elif kind == "conv":
-                attn = None
-            else:
-                attn = GroupedQueryAttention(
-                    hidden_size, num_heads, kv_heads[attends], head_dim,
-                    v_head_dim, window=window if win else None,
-                    rope_theta=rope_theta[attends], rotary_dim=rotary_dim,
-                    sink=win and window_sink, value_scale=value_scale,
-                    key_scale=mult.get("key", 1.0), qk_norm=qk_norm,
-                    norm_eps=eps)
-            ffn = HeldExperts(
-                hidden_size, expert_size, num_experts, top_k, held,
-                normalize_top_k,
-                shared=GatedFFN(hidden_size, shared_size) if shared_size
-                else None, scale=expert_scale,
-                normalize_eps=normalize_eps) if is_sparse \
-                else GatedFFN(hidden_size, dense_size,
-                              mult.get("mlp_gate", 1.0),
-                              mult.get("mlp_down", 1.0))
-            if kind == "parallel":
-                if ssm is None:
-                    raise ValueError("a parallel layer needs ssm=")
-                blocks.append(ParallelBlock(
-                    hidden_size, attn, Mamba2Mixer(hidden_size, eps=eps,
-                                                   **ssm), ffn, eps, mult))
-            elif kind == "conv":
-                blocks.append(MixerBlock(
-                    hidden_size, GatedShortConv(hidden_size, **conv), ffn,
-                    eps, norm))
-            elif kind == "selective":
-                blocks.append(MixerBlock(
-                    hidden_size, Mamba1Mixer(hidden_size, **shared["mixer"]),
-                    ffn, eps, norm, hands_on=depth == shared["memory_from"]))
-            elif kind == "memory":
-                blocks.append(MemoryBlock(
-                    hidden_size, GatedMemoryUnit(
-                        hidden_size, shared["mixer"]["inner"]), ffn, eps,
-                    norm))
-            elif kind == "cross":
-                blocks.append(CrossBlock(hidden_size, attn, ffn, eps, norm,
-                                         reads=shared["row_from"]))
-            else:
-                blocks.append(HybridBlock(
-                    hidden_size, attn, ffn, eps, norm,
-                    shares_row=bool(shared) and depth == shared["row_from"]))
-        if shared:
-            source = {"memory": shared["memory_from"],
-                      "cross": shared["row_from"]}
-            wants = {"memory": "selective", "cross": "full"}
-            for depth, kind in enumerate(layer_kinds):
-                if kind in source and not (
-                        source[kind] < depth
-                        and layer_kinds[source[kind]] == wants[kind]):
-                    raise ValueError(
-                        f"layer {depth} ({kind!r}) reads layer "
-                        f"{source[kind]}: an earlier {wants[kind]!r} layer")
         self.blocks = ModuleList(blocks)
         # where a chunk's rows stop (the class docstring): the layers they
         # walk whole, and whether they then write one layer's keys and
         # values more
-        last = max(i for i, blk in enumerate(blocks) if blk.cache_kinds(
-            max_len) and not isinstance(blk, CrossBlock))
-        self.chunk_writes = last < len(blocks) - 1 and hasattr(
-            getattr(blocks[last], "attn", None), "write")
+        last = max(i for i, blk in enumerate(blocks) if blk.keeps)
+        self.chunk_writes = last < len(blocks) - 1 \
+            and blocks[last].writes_alone
         self.chunk_layers = last + (not self.chunk_writes)
         self.final_norm = norm(hidden_size, eps)
-        self.tied = bool(shared) or bool(tie_head)
+        self.tied = bool(tie_head)
         if not self.tied:
             self.lm_head = Linear(hidden_size, vocab_size, with_bias=False)
 
@@ -630,49 +515,42 @@ class HybridDecoder(Module):
             "pad": jnp.zeros((batch, self.max_len), bool),
         }
 
+    def _full_rows(self, caches):
+        """``(attention, row)`` of every layer that keeps a full row."""
+        return [(blk.attn, layer["self"])
+                for blk, layer in zip(self.blocks, caches["layers"])
+                if "self" in layer and blk.window is None]
+
     def decode_key_block(self, caches) -> Optional[int]:
         """Places of a full row that the per-row decode step's attention
         reads at a time, or None where it reads every row whole whatever
-        is live (``GroupedQueryAttention.decode_key_block``, and
-        ``LatentAttention``'s for a latent row; rings are read whole
-        either way): the serving pool counts what its decode program
-        reads of its full rows by this."""
-        blocks = {blk.attn.decode_key_block(layer["self"])
-                  for blk, layer in zip(self.blocks, caches["layers"])
-                  if "self" in layer and blk.window is None}
+        is live (each full row's attention says: its
+        ``decode_key_block``; rings are read whole either way): the
+        serving pool counts what its decode program reads of its full
+        rows by this."""
+        blocks = {attn.decode_key_block(row)
+                  for attn, row in self._full_rows(caches)}
         return blocks.pop() if len(blocks) == 1 else None
 
     def chunk_key_block(self, caches) -> Optional[int]:
         """Places of its slot's full row that a prefill chunk's attention
-        reads at a time, where every full row is a latent one
-        (``LatentAttention.chunk_key_block``: live blocks only, on every
-        backend); None where a chunk reads its row whole
-        (``GroupedQueryAttention`` and ``DifferentialAttention``'s full
-        layers): the serving pool counts what its chunk programs read by
-        this."""
-        blocks = {blk.attn.chunk_key_block(layer["self"])
-                  if isinstance(blk.attn, LatentAttention) else None
-                  for blk, layer in zip(self.blocks, caches["layers"])
-                  if "self" in layer and blk.window is None}
+        reads at a time, where every full row's attention says one (a
+        latent row's: live blocks only, on every backend); None where a
+        chunk reads its row whole: the serving pool counts what its chunk
+        programs read by this."""
+        blocks = {attn.chunk_key_block(row)
+                  for attn, row in self._full_rows(caches)}
         return blocks.pop() if len(blocks) == 1 else None
 
     def cache_write_programs(self, caches) -> int:
         """Device programs that write ``caches`` in one per-row decode
-        step (:meth:`decode_step` with ``index [B]``): a layer's keys and
-        values in one where ``ops.cache_row_writer`` takes its leaves, a
-        ``dynamic_update_slice`` a row and leaf where not, one select
-        over the padding flags, and for a state one a leaf whatever the
-        rows (a recurrence's update, and the select that shifts a
-        convolution's inputs).  The serving pool counts by this."""
+        step (:meth:`decode_step` with ``index [B]``): one select over
+        the padding flags, and each layer's own
+        (:meth:`HybridBlock.cache_write_programs`).  The serving pool
+        counts by this."""
         rows = caches["pad"].shape[0]
-        programs = 1
-        for layer in caches["layers"]:
-            if "self" in layer:
-                k, v = layer["self"]["k"], layer["self"]["v"]
-                programs += 1 if cache_kernels.cache_row_writer(
-                    k.shape, v.shape, k.dtype) is not None else 2 * rows
-            programs += len(layer.get("ssm", ()))
-        return programs
+        return 1 + sum(blk.cache_write_programs(layer, rows)
+                       for blk, layer in zip(self.blocks, caches["layers"]))
 
     @staticmethod
     def _mask_untrained_logit(logits):
@@ -763,7 +641,7 @@ class HybridDecoder(Module):
             # a full row's last position is beyond every prefill
             # query's mask and rewritten by its occupant's own decode
             # before it is attended; a ring sends the row to its
-            # spare place (GroupedQueryAttention.forward)
+            # spare place (the attention layer's ``forward``)
             index = jnp.where(active, index, jnp.int32(self.max_len - 1))
         # one select over the flags, not a write a row
         with jax.named_scope("cache/write"):
@@ -885,11 +763,21 @@ class HybridDecoder(Module):
 
 def mimo_v2(config: Dict[str, Any], max_len: int) -> HybridDecoder:
     """The model from the keys of a public ``mimo_v2`` ``config.json``
-    plus the chip's share: ``experts_held`` (how many of
+    (MiMo-V2.5) plus the chip's share: ``experts_held`` (how many of
     ``n_routed_experts`` live here, from ``experts_offset``, default 0)
     and ``vocab_size`` as sliced.  ``hybrid_layer_pattern`` and
     ``moe_layer_freq`` are read for the first ``num_hidden_layers``
-    layers."""
+    layers.  Pre-norm and sequential: ``h = x + Attn_i(RMSNorm(x))``,
+    ``y = h + FFN_i(RMSNorm(h))``.  ``hybrid_layer_pattern[i]`` names
+    layer ``i``'s attention: 0 a **full** layer (causal over everything),
+    1 a **window** layer (the last ``sliding_window`` positions, a
+    learned sink logit per head, key/value heads and rotary base of its
+    own).  Keys are ``head_dim`` wide, values ``v_head_dim``; the first
+    ``partial_rotary_factor * head_dim`` dims of every query and key head
+    are rotated by position.  ``moe_layer_freq[i]`` names the
+    feed-forward: 0 a dense gated layer ``W_d(silu(W_g n) * W_u n)``, 1
+    :class:`bigdl_tpu.nn.HeldExperts`.  Final RMSNorm, an untied head,
+    the embedding not scaled."""
     c = config
     if c.get("attention_bias") or c.get("n_shared_experts") \
             or c.get("add_full_attention_sink_bias") \
@@ -906,36 +794,40 @@ def mimo_v2(config: Dict[str, Any], max_len: int) -> HybridDecoder:
                          "bias and one group are what is built, and a "
                          "mimo_v2 config has no routed_scaling_factor "
                          "(HeldExperts has one: sarvam_mla)")
-    n = c["num_hidden_layers"]
-    kinds = ["window" if p else "full"
-             for p in list(c["hybrid_layer_pattern"])[:n]]
-    sparse = [bool(f) for f in list(c["moe_layer_freq"])[:n]]
     if c.get("swa_head_dim", c["head_dim"]) != c["head_dim"] \
             or c.get("swa_v_head_dim", c["v_head_dim"]) != c["v_head_dim"] \
             or c.get("swa_num_attention_heads",
                      c["num_attention_heads"]) != c["num_attention_heads"]:
         raise ValueError("mimo_v2: window and full layers share their "
                          "query heads and head widths here")
-    return HybridDecoder(
-        vocab_size=c["vocab_size"], hidden_size=c["hidden_size"],
-        layer_kinds=kinds, sparse=sparse,
-        num_heads=c["num_attention_heads"], head_dim=c["head_dim"],
-        v_head_dim=c["v_head_dim"],
-        kv_heads={"full": c["num_key_value_heads"],
-                  "window": c["swa_num_key_value_heads"]},
-        rope_theta={"full": float(c["rope_theta"]),
-                    "window": float(c["swa_rope_theta"])},
-        rotary_dim=2 * (int(c["partial_rotary_factor"] * c["head_dim"]) // 2),
-        window=c["sliding_window"],
-        window_sink=bool(c.get("add_swa_attention_sink_bias", False)),
-        value_scale=float(c.get("attention_value_scale") or 1.0),
-        dense_size=c["intermediate_size"],
-        expert_size=c["moe_intermediate_size"],
-        num_experts=c["n_routed_experts"], top_k=c["num_experts_per_tok"],
-        held=(c.get("experts_offset", 0),
-              c.get("experts_held", c["n_routed_experts"])),
-        eps=c.get("layernorm_epsilon", 1e-5), max_len=max_len,
-        normalize_top_k=c.get("norm_topk_prob", True))
+    n, hidden = c["num_hidden_layers"], c["hidden_size"]
+    pattern = list(c["hybrid_layer_pattern"])[:n]
+    freq = list(c["moe_layer_freq"])[:n]
+    if len(pattern) != len(freq):
+        raise ValueError("mimo_v2: one hybrid_layer_pattern and one "
+                         "moe_layer_freq entry a layer")
+    eps = c.get("layernorm_epsilon", 1e-5)
+    held = (c.get("experts_offset", 0),
+            c.get("experts_held", c["n_routed_experts"]))
+    blocks = []
+    for window, sparse in zip(pattern, freq):
+        attn = GroupedQueryAttention(
+            hidden, c["num_attention_heads"],
+            c["swa_num_key_value_heads" if window else "num_key_value_heads"],
+            c["head_dim"], c["v_head_dim"],
+            window=c["sliding_window"] if window else None,
+            rope_theta=float(c["swa_rope_theta" if window else "rope_theta"]),
+            rotary_dim=2 * (int(c["partial_rotary_factor"] * c["head_dim"])
+                            // 2),
+            sink=bool(window and c.get("add_swa_attention_sink_bias")),
+            value_scale=float(c.get("attention_value_scale") or 1.0))
+        ffn = HeldExperts(
+            hidden, c["moe_intermediate_size"], c["n_routed_experts"],
+            c["num_experts_per_tok"], held,
+            c.get("norm_topk_prob", True)) if sparse \
+            else GatedFFN(hidden, c["intermediate_size"])
+        blocks.append(HybridBlock(hidden, attn, ffn, eps))
+    return HybridDecoder(c["vocab_size"], hidden, blocks, eps, max_len)
 
 
 _FALCON_H1_REFUSED = ("attention_bias", "mlp_bias", "projectors_bias",
@@ -945,9 +837,11 @@ _FALCON_H1_REFUSED = ("attention_bias", "mlp_bias", "projectors_bias",
 
 def falcon_h1(config: Dict[str, Any], max_len: int) -> HybridDecoder:
     """The model from the keys of a public ``falcon_h1`` ``config.json``:
-    every layer a parallel block (grouped-query attention over full rows
-    beside a Mamba-2 mixer), a dense gated feed-forward with its two
-    multipliers, an untied head, and the µP multipliers as constants.
+    every layer a parallel block (grouped-query attention over full rows,
+    its keys scaled, beside a Mamba-2 mixer, both on the same normed
+    input and summed: :class:`ParallelBlock`), a dense gated feed-forward
+    with its two multipliers, an untied head, and the µP multipliers
+    (the embedding's and the logits' among them) as constants.
     What is not built is refused: biases on the projections, a scaled
     rotary embedding, a tied head, attention on some layers only, a
     mixer without its gated norm or with the norm before the gate."""
@@ -965,33 +859,28 @@ def falcon_h1(config: Dict[str, Any], max_len: int) -> HybridDecoder:
     if heads * width != c["mamba_d_ssm"]:
         raise ValueError("falcon_h1: mamba_d_ssm is mamba_n_heads heads of "
                          "mamba_d_head")
-    n = c["num_hidden_layers"]
+    hidden, eps = c["hidden_size"], c.get("rms_norm_eps", 1e-5)
     gate, down = c.get("mlp_multipliers") or (1.0, 1.0)
+    multipliers = {side: c.get(f"{side}_multiplier", 1.0) for side in (
+        "attention_in", "attention_out", "ssm_in", "ssm_out")}
+    blocks = []
+    for _ in range(c["num_hidden_layers"]):
+        attn = GroupedQueryAttention(
+            hidden, c["num_attention_heads"], c["num_key_value_heads"],
+            c["head_dim"], rope_theta=float(c["rope_theta"]),
+            rotary_dim=c["head_dim"],
+            key_scale=c.get("key_multiplier", 1.0))
+        ffn = GatedFFN(hidden, c["intermediate_size"], gate, down)
+        ssm = Mamba2Mixer(
+            hidden, heads, width, c["mamba_n_groups"], c["mamba_d_state"],
+            c["mamba_d_conv"], c.get("mamba_chunk_size", 128), eps,
+            c.get("ssm_multipliers") or (1.0,) * 5)
+        blocks.append(ParallelBlock(hidden, attn, ssm, ffn, eps,
+                                    multipliers))
     return HybridDecoder(
-        vocab_size=c["vocab_size"], hidden_size=c["hidden_size"],
-        layer_kinds=["parallel"] * n, sparse=[False] * n,
-        num_heads=c["num_attention_heads"], head_dim=c["head_dim"],
-        v_head_dim=c["head_dim"],
-        kv_heads={"full": c["num_key_value_heads"]},
-        rope_theta={"full": float(c["rope_theta"])},
-        rotary_dim=c["head_dim"], window=0, window_sink=False,
-        value_scale=1.0, dense_size=c["intermediate_size"], expert_size=0,
-        num_experts=0, top_k=0, eps=c.get("rms_norm_eps", 1e-5),
-        max_len=max_len,
-        ssm=dict(heads=heads, head_dim=width, groups=c["mamba_n_groups"],
-                 state_size=c["mamba_d_state"],
-                 conv_width=c["mamba_d_conv"],
-                 chunk=c.get("mamba_chunk_size", 128),
-                 multipliers=c.get("ssm_multipliers") or (1.0,) * 5),
-        multipliers={
-            "embedding": c.get("embedding_multiplier", 1.0),
-            "lm_head": c.get("lm_head_multiplier", 1.0),
-            "key": c.get("key_multiplier", 1.0),
-            "mlp_gate": gate, "mlp_down": down,
-            "attention_in": c.get("attention_in_multiplier", 1.0),
-            "attention_out": c.get("attention_out_multiplier", 1.0),
-            "ssm_in": c.get("ssm_in_multiplier", 1.0),
-            "ssm_out": c.get("ssm_out_multiplier", 1.0)})
+        c["vocab_size"], hidden, blocks, eps, max_len,
+        embedding_multiplier=c.get("embedding_multiplier", 1.0),
+        lm_head_multiplier=c.get("lm_head_multiplier", 1.0))
 
 
 _SARVAM_REFUSED = ("q_lora_rank", "tie_word_embeddings", "attention_bias")
@@ -1001,9 +890,12 @@ def sarvam_mla(config: Dict[str, Any], max_len: int) -> HybridDecoder:
     """The model from the keys of a public ``sarvam_mla`` ``config.json``
     (sarvam-105b) plus the chip's share: ``experts_held`` (how many of
     ``num_experts`` live here, from ``experts_offset``, default 0) and
-    ``vocab_size`` as sliced.  Every layer a latent-attention layer
-    (``kv_lora_rank``, ``qk_nope_head_dim``, ``qk_rope_head_dim``,
-    ``v_head_dim``, YaRN from ``rope_scaling``); the first
+    ``vocab_size`` as sliced.  Sequential like ``mimo_v2``'s, every layer
+    a latent-attention layer
+    (:class:`bigdl_tpu.nn.latent_attention.LatentAttention`: one
+    compressed row a position shared by all heads; ``kv_lora_rank``,
+    ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``, YaRN
+    from ``rope_scaling``); the first
     ``first_k_dense_replace`` (1) a dense gated layer of
     ``intermediate_size``, the others ``num_experts`` sigmoid-routed
     experts of ``moe_intermediate_size`` with a selection bias
@@ -1042,29 +934,24 @@ def sarvam_mla(config: Dict[str, Any], max_len: int) -> HybridDecoder:
             != c["qk_nope_head_dim"] + c["qk_rope_head_dim"]:
         raise ValueError("sarvam_mla: q_head_dim is qk_nope_head_dim + "
                          "qk_rope_head_dim")
-    n = c["num_hidden_layers"]
-    return HybridDecoder(
-        vocab_size=c["vocab_size"], hidden_size=c["hidden_size"],
-        layer_kinds=["latent"] * n, sparse=[i >= 1 for i in range(n)],
-        num_heads=c["num_attention_heads"], head_dim=0, v_head_dim=0,
-        kv_heads={}, rope_theta={}, rotary_dim=0, window=0,
-        window_sink=False, value_scale=1.0,
-        dense_size=c["intermediate_size"],
-        expert_size=c["moe_intermediate_size"],
-        num_experts=c["num_experts"], top_k=c["num_experts_per_tok"],
-        held=(c.get("experts_offset", 0),
-              c.get("experts_held", c["num_experts"])),
-        eps=c.get("rms_norm_eps", 1e-6), max_len=max_len,
-        normalize_top_k=c.get("norm_topk_prob", True),
-        latent=dict(nope_dim=c["qk_nope_head_dim"],
-                    rope_dim=c["qk_rope_head_dim"],
-                    v_head_dim=c["v_head_dim"],
-                    latent_dim=c["kv_lora_rank"],
-                    rope_theta=float(c.get("rope_theta", 10000.0)),
-                    rope_scaling=scaling or None),
-        shared_size=c.get("num_shared_experts", 0)
-        * c["moe_intermediate_size"],
-        expert_scale=float(c.get("routed_scaling_factor") or 1.0))
+    hidden, eps = c["hidden_size"], c.get("rms_norm_eps", 1e-6)
+    width, shared = c["moe_intermediate_size"], c.get("num_shared_experts", 0)
+    held = (c.get("experts_offset", 0),
+            c.get("experts_held", c["num_experts"]))
+    blocks = []
+    for i in range(c["num_hidden_layers"]):
+        attn = LatentAttention(
+            hidden, c["num_attention_heads"], c["qk_nope_head_dim"],
+            c["qk_rope_head_dim"], c["v_head_dim"], c["kv_lora_rank"],
+            float(c.get("rope_theta", 10000.0)), scaling or None, eps)
+        ffn = HeldExperts(
+            hidden, width, c["num_experts"], c["num_experts_per_tok"], held,
+            c.get("norm_topk_prob", True),
+            shared=GatedFFN(hidden, shared * width) if shared else None,
+            scale=float(c.get("routed_scaling_factor") or 1.0)) if i >= 1 \
+            else GatedFFN(hidden, c["intermediate_size"])
+        blocks.append(HybridBlock(hidden, attn, ffn, eps))
+    return HybridDecoder(c["vocab_size"], hidden, blocks, eps, max_len)
 
 
 _PHI4_FLASH_REFUSED = ("mlp_bias", "lm_head_bias", "embd_pdrop",
@@ -1114,26 +1001,37 @@ def phi4_flash(config: Dict[str, Any], max_len: int) -> HybridDecoder:
     if hidden % heads:
         raise ValueError("phi4_flash: hidden_size is num_attention_heads "
                          "heads")
-    half = n // 2
-    kinds = [("selective" if i % 2 == 0 else "window") if i < half else
-             "selective" if i == half else "full" if i == half + 1 else
-             ("memory" if i % 2 == 0 else "cross") for i in range(n)]
-    rank = c.get("mamba_dt_rank", "auto")
-    return HybridDecoder(
-        vocab_size=c["vocab_size"], hidden_size=hidden, layer_kinds=kinds,
-        sparse=[False] * n, num_heads=heads, head_dim=hidden // heads,
-        v_head_dim=hidden // heads,
-        kv_heads={"full": c["num_key_value_heads"]}, rope_theta={},
-        rotary_dim=0, window=c["sliding_window"], window_sink=False,
-        value_scale=1.0, dense_size=c["intermediate_size"], expert_size=0,
-        num_experts=0, top_k=0, eps=c.get("layer_norm_eps", 1e-5),
-        max_len=max_len,
-        shared=dict(
-            memory_from=half, row_from=half + 1,
-            mixer=dict(inner=c.get("mamba_expand", 2) * hidden,
-                       state_size=c.get("mamba_d_state", 16),
-                       dt_rank=None if rank == "auto" else int(rank),
-                       conv_width=c.get("mamba_d_conv", 4))))
+    half, eps = n // 2, c.get("layer_norm_eps", 1e-5)
+    inner, rank = c.get("mamba_expand", 2) * hidden, \
+        c.get("mamba_dt_rank", "auto")
+    blocks = []
+    for i in range(n):
+        # odd layers attend: a window before the full layer ``half + 1``,
+        # whose row the layers after it read
+        attn = DifferentialAttention(
+            hidden, heads, c["num_key_value_heads"], hidden // heads, i,
+            window=c["sliding_window"] if i < half else None,
+            cross=i > half + 1, eps=eps) if i % 2 else None
+        ffn = GatedFFN(hidden, c["intermediate_size"])
+        if i % 2 and i > half + 1:
+            blk = CrossBlock(hidden, attn, ffn, eps, LayerNorm,
+                             reads=half + 1)
+        elif i % 2:
+            blk = HybridBlock(hidden, attn, ffn, eps, LayerNorm,
+                              shares_row=i == half + 1)
+        elif i > half:
+            blk = MemoryBlock(hidden, GatedMemoryUnit(hidden, inner), ffn,
+                              eps, LayerNorm)
+        else:
+            blk = MixerBlock(
+                hidden, Mamba1Mixer(
+                    hidden, inner, c.get("mamba_d_state", 16),
+                    None if rank == "auto" else int(rank),
+                    c.get("mamba_d_conv", 4)),
+                ffn, eps, LayerNorm, hands_on=i == half)
+        blocks.append(blk)
+    return HybridDecoder(c["vocab_size"], hidden, blocks, eps, max_len,
+                         LayerNorm, tie_head=True)
 
 
 _LFM2_REFUSED = ("conv_bias", "attention_bias", "mlp_bias", "rope_scaling",
@@ -1146,7 +1044,9 @@ def lfm2_moe(config: Dict[str, Any], max_len: int) -> HybridDecoder:
     ``num_experts`` live here, from ``experts_offset``, default 0: all of
     them).  ``layer_types[i]`` names layer ``i``'s mixer for the first
     ``num_hidden_layers`` layers: ``"conv"`` a gated short convolution of
-    ``conv_L_cache`` taps, ``"full_attention"`` grouped-query attention
+    ``conv_L_cache`` taps (:class:`bigdl_tpu.nn.short_conv.GatedShortConv`:
+    a :class:`MixerBlock` whose state is the convolution's two-row tail
+    and nothing else), ``"full_attention"`` grouped-query attention
     (``num_attention_heads`` heads of ``hidden_size / num_attention_heads``
     over ``num_key_value_heads``) whose query and key heads go through an
     RMS norm before the half-split rotation over the whole head (base
@@ -1172,30 +1072,34 @@ def lfm2_moe(config: Dict[str, Any], max_len: int) -> HybridDecoder:
     if rope.get("rope_type", "default") != "default":
         raise ValueError(f"lfm2_moe: rope_parameters={rope!r} is not built "
                          f"(rope_type default)")
-    n = c["num_hidden_layers"]
-    names = {"conv": "conv", "full_attention": "full"}
-    types = list(c["layer_types"])[:n]
+    types = list(c["layer_types"])[:c["num_hidden_layers"]]
     for kind in types:
-        if kind not in names:
+        if kind not in ("conv", "full_attention"):
             raise ValueError(f"lfm2_moe: layer_types has {kind!r}: 'conv' "
                              f"and 'full_attention' are what is built")
     hidden, heads = c["hidden_size"], c["num_attention_heads"]
     head_dim = c.get("head_dim") or hidden // heads
-    return HybridDecoder(
-        vocab_size=c["vocab_size"], hidden_size=hidden,
-        layer_kinds=[names[kind] for kind in types],
-        sparse=[i >= c.get("num_dense_layers", 0) for i in range(n)],
-        num_heads=heads, head_dim=head_dim, v_head_dim=head_dim,
-        kv_heads={"full": c["num_key_value_heads"]},
-        rope_theta={"full": float(rope.get("rope_theta",
-                                           c.get("rope_theta", 1e6)))},
-        rotary_dim=head_dim, window=0, window_sink=False, value_scale=1.0,
-        dense_size=c["intermediate_size"],
-        expert_size=c["moe_intermediate_size"],
-        num_experts=c["num_experts"], top_k=c["num_experts_per_tok"],
-        held=(c.get("experts_offset", 0),
-              c.get("experts_held", c["num_experts"])),
-        eps=c.get("norm_eps", 1e-5), max_len=max_len,
-        expert_scale=float(c.get("routed_scaling_factor") or 1.0),
-        conv=dict(taps=c.get("conv_L_cache", 3)), qk_norm=True,
-        tie_head=True, normalize_eps=1e-6)
+    eps = c.get("norm_eps", 1e-5)
+    held = (c.get("experts_offset", 0),
+            c.get("experts_held", c["num_experts"]))
+    blocks = []
+    for i, kind in enumerate(types):
+        attn = GroupedQueryAttention(
+            hidden, heads, c["num_key_value_heads"], head_dim,
+            rope_theta=float(rope.get("rope_theta",
+                                      c.get("rope_theta", 1e6))),
+            rotary_dim=head_dim, qk_norm=True, norm_eps=eps) \
+            if kind == "full_attention" else None
+        ffn = HeldExperts(
+            hidden, c["moe_intermediate_size"], c["num_experts"],
+            c["num_experts_per_tok"], held,
+            scale=float(c.get("routed_scaling_factor") or 1.0),
+            normalize_eps=1e-6) if i >= c.get("num_dense_layers", 0) \
+            else GatedFFN(hidden, c["intermediate_size"])
+        blocks.append(
+            HybridBlock(hidden, attn, ffn, eps) if attn is not None else
+            MixerBlock(hidden, GatedShortConv(hidden,
+                                              c.get("conv_L_cache", 3)),
+                       ffn, eps))
+    return HybridDecoder(c["vocab_size"], hidden, blocks, eps, max_len,
+                         tie_head=True)

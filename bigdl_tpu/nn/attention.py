@@ -576,6 +576,16 @@ class GroupedQueryAttention(Module):
                 "v": jnp.zeros((batch, self.num_kv_heads, length,
                                 self.v_head_dim), dtype)}
 
+    def cache_kind(self, max_len: int):
+        """What the layer declares to a slot pool: a ring of its window,
+        or a full row of ``max_len`` positions."""
+        return ("full", max_len) if self.window is None \
+            else ("ring", self.window)
+
+    def chunk_key_block(self, cache) -> None:
+        """A prefill chunk attends its slot's row whole."""
+        return None
+
     def decode_key_block(self, cache) -> Optional[int]:
         """Places of a row that the per-row decode step (``index [B]``)
         over ``cache`` attends at a time, through

@@ -127,6 +127,15 @@ class DifferentialAttention(Module):
                  self.cache_length(max_len, ring_margin), self.pair_dim)
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
+    def cache_kind(self, max_len: int):
+        """As :meth:`GroupedQueryAttention.cache_kind`."""
+        return ("full", max_len) if self.window is None \
+            else ("ring", self.window)
+
+    def chunk_key_block(self, cache) -> None:
+        """A prefill chunk attends its slot's row whole."""
+        return None
+
     def decode_key_block(self, cache) -> Optional[int]:
         """As :meth:`GroupedQueryAttention.decode_key_block`, of the row
         this layer's step attends (a cross layer: the row it is

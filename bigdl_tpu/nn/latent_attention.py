@@ -192,6 +192,10 @@ class LatentAttention(Module):
         return {"k": jnp.zeros((batch, 1, length, self.rope_dim), dtype),
                 "v": jnp.zeros((batch, 1, length, self.latent_dim), dtype)}
 
+    def cache_kind(self, max_len: int):
+        """What the layer declares to a slot pool: a latent row."""
+        return ("latent", max_len)
+
     def decode_key_block(self, cache) -> Optional[int]:
         """Places of a row that the per-row decode step attends at a time
         (``ops.latent_decode_attention``), or None where it attends every

@@ -58,17 +58,16 @@ prompt bucket, the chunk program once per chunk width, and the prefix
 copy/extract programs once per granularity (``trace_counts`` exposes
 the evidence; tests assert it).
 
-Where the model can walk its blocks once for a decode step and a chunk
-together (``decode_step_with_chunk``: ``HybridDecoder``,
-``TransformerLM``), a chunk due in a pass in which slots decode **rides
-the pass's decode step**: one joint program in place of the chunk
-program followed by the step, in which each layer's feed-forward
-(``TransformerLM``: its output projection too) runs once over the decode
-rows and the chunk's rows, so the pass reads those weights once.  The
-joint program takes the lone chunk program's place at a width (the lone
-one is kept at the full width, for an idle pool's long prompt), so the
-budget is one program more.  A model without the entry runs the two
-programs in turn.
+The model walks its blocks once for a decode step and a chunk together
+(``decode_step_with_chunk``: ``HybridDecoder``, ``TransformerLM``), so a
+chunk due in a pass in which slots decode **rides the pass's decode
+step**: one joint program in place of the chunk program followed by the
+step, in which each layer's feed-forward (``TransformerLM``: its output
+projection too) runs once over the decode rows and the chunk's rows, so
+the pass reads those weights once.  The joint program takes the lone
+chunk program's place at a width (the lone one is kept at the full
+width, for an idle pool's long prompt), so the budget is one program
+more.
 
 Correctness bar (unchanged from the original engine, property-tested
 over randomized arrival schedules, cache hit or miss): greedy tokens
@@ -232,13 +231,15 @@ class SlotPool:
                 "sequence-parallel models cannot serve from a slot pool "
                 "(the ring path has no decode cache); build a dense copy")
         for attr in ("init_cache", "cache_layers", "decode_step",
-                     "prefill_kv", "prefill_chunk", "max_len",
-                     "_mask_untrained_logit"):
+                     "decode_step_with_chunk", "prefill_kv",
+                     "prefill_chunk", "decode_key_block", "chunk_key_block",
+                     "max_len", "_mask_untrained_logit"):
             if not hasattr(model, attr):
                 raise TypeError(
                     f"slot-pool generation needs a model with the "
                     f"incremental-decode API (init_cache/cache_layers/"
-                    f"decode_step/prefill_kv/prefill_chunk): "
+                    f"decode_step/decode_step_with_chunk/prefill_kv/"
+                    f"prefill_chunk): "
                     f"{type(model).__name__} lacks {attr!r}")
         if slots < 1:
             raise ValueError(f"slots must be >= 1, got {slots}")
@@ -299,14 +300,12 @@ class SlotPool:
         # places of a full row that the decode program's attention reads
         # at a time, where the model's step reads live blocks only; None
         # where it reads every row whole (decode_dispatch counts by it)
-        self.key_block = model.decode_key_block(self.caches) \
-            if hasattr(model, "decode_key_block") else None
+        self.key_block = model.decode_key_block(self.caches)
         # the same of a prefill chunk's attention: places of its slot's
         # full row that it reads at a time where the model's chunk path
         # reads live blocks only; None where it reads the row whole
         # (the scheduler counts a chunk's reading by it)
-        self.chunk_key_block = model.chunk_key_block(self.caches) \
-            if hasattr(model, "chunk_key_block") else None
+        self.chunk_key_block = model.chunk_key_block(self.caches)
         # device programs that write the cache in one decode step: one
         # dynamic_update_slice a slot and leaf (keys, values, flags),
         # unless the model says that its step writes with fewer
@@ -314,27 +313,23 @@ class SlotPool:
             model.cache_write_programs(self.caches)
             if hasattr(model, "cache_write_programs") else
             self.slots * len(jax.tree_util.tree_leaves(self.caches)))
-        # a model that can walk its blocks once for a decode step and a
-        # prefill chunk together (``decode_step_with_chunk``: each layer's
-        # feed-forward runs once over both) gets the joint program: a
-        # chunk due in a pass in which slots decode rides the step
-        # (``decode_dispatch(chunk)``).  Such a pool holds its chunk
-        # programs compiled, by width, all of them from its first chunk
-        # on (``_chunk_programs``); a model without the entry keeps the
-        # two programs, run in turn
-        self.joint = hasattr(model, "decode_step_with_chunk")
-        # the widest chunk the pool is handed: what the rings have room
+        # the model walks its blocks once for a decode step and a prefill
+        # chunk together (``decode_step_with_chunk``: each layer's
+        # feed-forward runs once over both): a chunk due in a pass in
+        # which slots decode rides the step (``decode_dispatch(chunk)``).
+        # The pool holds its chunk programs compiled, by width, all of
+        # them from its first chunk on (``_chunk_programs``).  The
+        # widest chunk the pool is handed is what the rings have room
         # for (the scheduler's ``prefill_chunk``); and the widths a
-        # prompt's last chunk draws from, the powers of two up to it.  A
-        # pool with the joint program keeps the upper four of them (a
-        # joint program costs a server's start half as much again as the
-        # lone one it replaces: four and the lone one cost less than the
-        # lone ones of every width did): a shorter remainder rides the
-        # narrowest, as it rides the next power of two elsewhere
+        # prompt's last chunk draws from, the upper four of the powers of
+        # two up to it (a joint program costs a server's start half as
+        # much again as the lone one it replaces: four and the lone one
+        # cost less than the lone ones of every width did): a shorter
+        # remainder rides the narrowest
         self.chunk_width = min(self.ring_margin, self.max_len)
         self.chunk_widths = tuple(
             w for w in bucket_sizes(self.chunk_width)
-            if not self.joint or w >= self.chunk_width // 8)
+            if w >= self.chunk_width // 8)
         self._chunk_compiled: Dict[int, Tuple] = {}
         self.tok = np.zeros((self.slots,), np.int32)
         self.index = np.zeros((self.slots,), np.int32)
@@ -649,13 +644,13 @@ class SlotPool:
         return self._chunk_lowered(width).compile()
 
     def _chunk_programs(self, width: int) -> Tuple:
-        """Of a pool with the joint program: the compiled programs that
-        can carry a chunk of ``width``, ``(joint, alone)``.  The joint
-        program takes the place of the lone one at a width and does not
-        stand beside it (a chunk beside an idle pool rides it with every
-        row idle); the lone program is kept (``alone`` is not None) at
-        the full width only, where an idle server's long prompt would
-        otherwise pay a dead step a chunk.
+        """The compiled programs that can carry a chunk of ``width``,
+        ``(joint, alone)``.  The joint program takes the place of the
+        lone one at a width and does not stand beside it (a chunk beside
+        an idle pool rides it with every row idle); the lone program is
+        kept (``alone`` is not None) at the full width only, where an
+        idle server's long prompt would otherwise pay a dead step a
+        chunk.
 
         **The pool's first chunk compiles the programs of every width**
         (``chunk_widths``), whichever sends it: a width that only idle
@@ -798,11 +793,6 @@ class SlotPool:
         to everything already written below ``index``."""
         import jax.numpy as jnp
         toks = jnp.asarray(np.ascontiguousarray(toks, np.int32))
-        if not self.joint:
-            self.caches, self._routing = self._chunk_jit(
-                self.model, self.caches, np.int32(slot), toks,
-                np.int32(index), self._routing)
-            return
         joint, alone = self._chunk_programs(int(toks.shape[0]))
         if alone is not None:
             self.caches, self._routing = alone(
@@ -854,7 +844,7 @@ class SlotPool:
         can be dispatched before this one's host work.  Finalizes the
         credit epoch of the still-outstanding previous step first.
         ``chunk`` (``(toks, slot, index)`` as :meth:`chunk_prefill_into`
-        takes them; a pool with the joint program only) rides the step:
+        takes them) rides the step:
         one program writes the chunk and then advances the slots, what
         the chunk program followed by the step would have done."""
         import jax.numpy as jnp
@@ -1156,9 +1146,8 @@ class GenerationScheduler:
     most ``prefill_chunk_budget`` prefill program calls run per engine
     iteration, bounding how long a long prompt can stall the token
     cadence of co-resident streams; with nothing decoding, pending
-    prefill drains at full speed.  On a pool with the joint program
-    (``SlotPool.joint``) a chunk prepared while slots decode is not
-    dispatched by itself: the pass's decode dispatch carries it
+    prefill drains at full speed.  A chunk prepared while slots decode is
+    not dispatched by itself: the pass's decode dispatch carries it
     (``chunks_joint`` in ``stats()``; ``chunks_alone`` counts the chunk
     programs that went out by themselves).
 
@@ -1258,10 +1247,10 @@ class GenerationScheduler:
         # the dispatch's fields of the pass record)
         self._pending: Optional[Tuple] = None
         # a prefill chunk, ``(toks, slot, index)``, prepared in this pass
-        # for the pass's decode dispatch to carry (a pool with the joint
-        # program, slots decoding).  It never outlives its pass: the next
-        # prefill work item, or a pass that ends without a dispatch,
-        # sends it alone first (``_send_held_chunk``)
+        # for the pass's decode dispatch to carry (slots decoding).  It
+        # never outlives its pass: the next prefill work item, or a pass
+        # that ends without a dispatch, sends it alone first
+        # (``_send_held_chunk``)
         self._held_chunk: Optional[Tuple] = None
         self._lock = threading.Lock()
         self._outstanding = 0
@@ -1576,7 +1565,7 @@ class GenerationScheduler:
                 "chunk_positions_read": eng["chunk_positions_read"],
                 # chunk programs (not bucketed prefills) that rode a
                 # decode step as one joint program, and that went out
-                # alone (a pool without the joint program: all of them)
+                # alone
                 "chunks_joint": eng["chunks_joint"],
                 "chunks_alone": eng["chunks_alone"],
                 # layers whose decode attention reads a full row, for
@@ -2195,11 +2184,10 @@ class GenerationScheduler:
                 # attended, and padding advances no state
                 toks = np.concatenate(
                     [toks, np.zeros(w - len(toks), np.int32)])
-        # beside decoding slots a pool with the joint program carries the
-        # chunk in the pass's decode step (_dispatch_decode).  With a
-        # prefix cache a prompt's last chunk goes out alone, as every
-        # chunk does elsewhere: its keys are extracted right after it
-        hold = pool.joint and pool.n_active() > 0 and not (
+        # beside decoding slots the pass's decode step carries the chunk
+        # (_dispatch_decode).  With a prefix cache a prompt's last chunk
+        # goes out alone: its keys are extracted right after it
+        hold = pool.n_active() > 0 and not (
             self._prefix_cache is not None and s + w >= end)
         try:
             with tracing.span("serving/prefill", chunk=w, index=s,

@@ -2,10 +2,8 @@
 ``TransformerLM`` share about the joint pass (``decode_step_with_chunk``:
 a pool's decode step that carries a prefill chunk, each layer's
 feed-forward run once over both): the same pass made of the two entries
-it stands for, on the same caches, and the comparison; and a
-``TransformerLM`` whose class hides the entry, for the path a model
-without it takes.  A helper, not a test file: the models and their sizes
-are the callers'.  Also how a prompt goes into a pool's slot as the
+it stands for, on the same caches, and the comparison.  A helper, not a
+test file: the models and their sizes are the callers'.  Also how a prompt goes into a pool's slot as the
 scheduler sends it, and teacher-forced pooled decode steps against a
 reference's logits, for the models whose pools keep a state."""
 
@@ -15,30 +13,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bigdl_tpu.models.transformer_lm import TransformerLM
-
 SCENARIOS = ["idle-row", "padded-last-chunk", "fresh-occupant",
              "own-slot-decodes"]
 # one more for a model whose rows keep no state (a state is not written
 # at a place): the slot decodes from a place inside its padded chunk
 ROW_SCENARIOS = SCENARIOS + ["own-slot-decodes-in-the-padding"]
 SLOTS = 3
-
-
-class TwoProgramLM(TransformerLM):
-    """A ``TransformerLM`` whose class hides the joint entry: to a pool,
-    a model that has none (``hasattr`` is what it asks)."""
-
-    @property
-    def decode_step_with_chunk(self):
-        raise AttributeError("decode_step_with_chunk")
-
-
-def without_the_joint_entry(model):
-    """``model`` (a ``TransformerLM``) as a :class:`TwoProgramLM` on the
-    same leaves: its pool sends a chunk and a step, two programs."""
-    children, aux = model._tree_flatten()
-    return TwoProgramLM._tree_unflatten(aux, children)
 
 
 def _init_cache(model, slots, chunk):

@@ -486,6 +486,13 @@ def test_engine_phase_seconds_sum_to_the_threads_wall_time(lm):
     try:
         futs = [eng.submit_async(p, m) for p, m in zip(prompts, max_news)]
         [f.result(timeout=120) for f in futs]
+        # a future resolves inside the thread's last pass: under load the
+        # thread reaches its queue some milliseconds later, and a sleep
+        # begun before that is not all idle
+        deadline = time.perf_counter() + 60
+        while eng._phase_key != "idle" and time.perf_counter() < deadline:
+            time.sleep(0.001)
+        assert eng._phase_key == "idle"
         time.sleep(0.05)            # blocked on the empty queue: idle
         eng.submit(prompts[0], 3, timeout=120)
     finally:
@@ -499,8 +506,11 @@ def test_engine_phase_seconds_sum_to_the_threads_wall_time(lm):
     for key in ("admit", "prefill_dispatch", "decode_dispatch", "emit",
                 "other"):
         assert phases[key] > 0.0, key
-    life = wall["t1"] - wall["t0"]
-    assert sum(phases.values()) == pytest.approx(life, rel=0.05)
+    # the marks tile the time between the first and the last of them: no
+    # phase holds the thread's start before the first and its end after
+    # the last, a few statements each whatever the thread's life
+    unheld = wall["t1"] - wall["t0"] - sum(phases.values())
+    assert -1e-6 <= unheld <= 0.25
     # a pass per decode step at least, and the lame-duck drains
     assert stats["iterations"] >= stats["decode_steps"]
     assert stats["decode_dispatches"] == stats["decode_steps"]
@@ -606,14 +616,13 @@ def test_step_gap_is_flagged_prefill_when_a_chunk_preceded_its_step(lm):
 
 
 def test_a_pool_with_the_joint_entry_carries_a_chunk_on_its_step(lm):
-    """``TransformerLM`` has ``decode_step_with_chunk``, so its pool is a
-    joint one: B's prompt arrives while A decodes and each of its chunks
-    rides a decode step as one program; the tokens are ``generate()``'s
-    and the counters say what went where."""
+    """B's prompt arrives while A decodes and each of its chunks rides a
+    decode step as one program (``decode_step_with_chunk``); the tokens
+    are ``generate()``'s and the counters say what went where."""
     rng = np.random.default_rng(29)
     eng = GenerationScheduler(lm, slots=2, prefill_chunk=8, start=False)
     pool = eng.pool
-    assert pool.joint and pool.chunk_widths == (1, 2, 4, 8)
+    assert pool.chunk_widths == (1, 2, 4, 8)
     log = joint_pass.logged_pool_calls(pool)
     eng.start()
     a_prompt = rng.integers(1, 51, 4).astype(np.int32)
@@ -639,37 +648,18 @@ def test_a_pool_with_the_joint_entry_carries_a_chunk_on_its_step(lm):
     assert counts["decode"] == 1
 
 
-def test_a_pool_without_the_joint_entry_sends_a_chunk_and_a_step(lm):
-    """A model without ``decode_step_with_chunk`` (here a ``TransformerLM``
-    whose class hides it): a chunk due while a slot decodes goes out as
-    the chunk program, and the decode step follows it, two programs a
-    pass; no joint program is built and the counters say so."""
-    rng = np.random.default_rng(29)
-    eng = GenerationScheduler(joint_pass.without_the_joint_entry(lm),
-                              slots=2, prefill_chunk=8, start=False)
-    pool = eng.pool
-    assert not pool.joint
-    log = joint_pass.logged_pool_calls(pool)
-    eng.start()
-    a_prompt = rng.integers(1, 51, 4).astype(np.int32)
-    b_prompt = rng.integers(1, 51, 20).astype(np.int32)
-    try:
-        a, b = joint_pass.serve_beside_a_decoding_slot(
-            eng, a_prompt, [b_prompt], timeout=120)
-        eng.shutdown()
-        stats = eng.stats()
-    finally:
-        eng.shutdown()
-    np.testing.assert_array_equal(a, solo(lm, a_prompt, 30))
-    np.testing.assert_array_equal(b, solo(lm, b_prompt, 6))
-    # B's 19 positions: three chunks, each followed by the pass's step
-    assert log.count("alone") == 3 and "step+chunk" not in log
-    at = [i for i, entry in enumerate(log) if entry == "alone"]
-    assert all(log[i + 1] == "step" for i in at)
-    assert (stats["chunks_joint"], stats["chunks_alone"]) == (0, 3)
-    assert pool.trace_counts["decode_with_chunk"] == {}
-    assert pool.trace_counts["chunk_prefill"] == {8: 1, 4: 1}
-    assert stats["step_gaps"]["prefill"] == 3
+def test_a_pool_refuses_a_model_that_lacks_the_joint_entry(lm):
+    """``decode_step_with_chunk`` is part of what a pool requires of a
+    model: one without it is refused by name, as one without any other
+    entry of the incremental API is."""
+    class NoJointEntry:
+        def __getattr__(self, name):
+            if name == "decode_step_with_chunk":
+                raise AttributeError(name)
+            return getattr(lm, name)
+
+    with pytest.raises(TypeError, match="lacks 'decode_step_with_chunk'"):
+        SlotPool(NoJointEntry(), slots=2)
 
 
 def test_prefill_counters_cover_every_prompt_once(lm):

@@ -630,7 +630,6 @@ def test_a_chunk_beside_decoding_slots_rides_their_step(model):
                for n in (3, 21, 14))
     engine = GenerationScheduler(m, slots=3, prefill_chunk=CHUNK,
                                  start=False)
-    assert engine.pool.joint
     log = joint_pass.logged_pool_calls(engine.pool)
     engine.start()
     try:
@@ -699,17 +698,14 @@ def test_chunks_beside_an_idle_pool_leave_nothing_to_compile_beside_a_busy_one(
 
 def test_a_joint_pool_keeps_the_upper_four_chunk_widths(model):
     """A joint program costs a start more than the lone program it
-    replaces, so a pool with the joint program keeps four widths (the
-    full one down to its eighth) and a shorter remainder rides the
-    narrowest, moved back over the prompt; a pool without it keeps every
-    power of two.  The rows are still ``generate()``'s."""
+    replaces, so a pool keeps four widths (the full one down to its
+    eighth) and a shorter remainder rides the narrowest, moved back over
+    the prompt.  The rows are still ``generate()``'s."""
     m, _ = model
     assert SlotPool(m, slots=2, ring_margin=32).chunk_widths == (4, 8, 16, 32)
     lm = _tiny_lm().eval_mode()
     assert SlotPool(lm, slots=2, ring_margin=32).chunk_widths \
         == (4, 8, 16, 32)
-    assert SlotPool(joint_pass.without_the_joint_entry(lm), slots=2,
-                    ring_margin=32).chunk_widths == (1, 2, 4, 8, 16, 32)
     engine = GenerationScheduler(m, slots=2, prefill_chunk=16)
     rng = np.random.default_rng(8)
     # 16 + 1, 16 + 3 and 16 + 6 positions: remainders under and over 2

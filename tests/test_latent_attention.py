@@ -504,10 +504,14 @@ def test_mimo_v2_keeps_refusing_both_for_its_own_config():
 
 
 def test_a_latent_layer_needs_its_arguments():
+    """A block over a latent layer declares a latent row to the pool, and
+    the layer refuses a rotary part that has no pairs."""
     from bigdl_tpu.models import HybridDecoder
-    with pytest.raises(ValueError, match="latent="):
-        HybridDecoder(30, 32, ["latent"], [False], 4, 0, 0, {}, {}, 0, 0,
-                      False, 1.0, 64, 0, 0, 0)
+    from bigdl_tpu.models.hybrid_decoder import HybridBlock
+    m = HybridDecoder(30, 32, [HybridBlock(
+        32, LatentAttention(32, 4, 8, 4, 8, 16), GatedFFN(32, 64), 1e-6)],
+        max_len=16)
+    assert m.cache_layers() == (("latent", 16),)
     with pytest.raises(ValueError, match="rope_dim"):
         LatentAttention(32, 4, 8, 3, 8, 16)
 

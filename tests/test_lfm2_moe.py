@@ -191,15 +191,44 @@ def test_what_is_not_built_is_refused_by_name(key, value):
         lfm2_moe(dict(CFG, **{key: value}), MAX_LEN)
 
 
-def test_a_conv_layer_needs_its_arguments():
+def test_a_decoder_refuses_a_block_with_no_earlier_layer_to_read():
+    """What only the combination of blocks can get wrong: a cross block
+    names an earlier layer that shares its row, a memory block comes
+    after a mixer that hands its scan output on."""
     from bigdl_tpu.models import HybridDecoder
-    with pytest.raises(ValueError, match="'conv' with conv="):
-        HybridDecoder(
-            vocab_size=20, hidden_size=16, layer_kinds=["conv"],
-            sparse=[False], num_heads=4, head_dim=4, v_head_dim=4,
-            kv_heads={"full": 2}, rope_theta={"full": 1e4}, rotary_dim=4,
-            window=0, window_sink=False, value_scale=1.0, dense_size=16,
-            expert_size=0, num_experts=0, top_k=0, max_len=16)
+    from bigdl_tpu.models.hybrid_decoder import (
+        CrossBlock, GatedFFN, GatedMemoryUnit, HybridBlock, MemoryBlock,
+        MixerBlock)
+    from bigdl_tpu.nn.differential_attention import DifferentialAttention
+    from bigdl_tpu.nn.short_conv import GatedShortConv
+
+    def ffn():
+        return GatedFFN(16, 16)
+
+    def row(**kw):
+        return HybridBlock(16, DifferentialAttention(16, 4, 2, 4, 0), ffn(),
+                           1e-5, **kw)
+
+    def cross(reads):
+        return CrossBlock(16, DifferentialAttention(16, 4, 2, 4, 1,
+                                                    cross=True),
+                          ffn(), 1e-5, reads=reads)
+
+    def mixer(**kw):
+        return MixerBlock(16, GatedShortConv(16), ffn(), 1e-5, **kw)
+
+    def memory():
+        return MemoryBlock(16, GatedMemoryUnit(16, 16), ffn(), 1e-5)
+
+    for blocks, says in (
+            ([row(), cross(0)], "reads layer 0"),       # row 0 is not shared
+            ([cross(1), row(shares_row=True)], "reads layer 1"),   # later
+            ([mixer(), memory()], "layer 1 reads a scan output"),
+            ([memory(), mixer(hands_on=True)], "layer 0 reads a scan")):
+        with pytest.raises(ValueError, match=says):
+            HybridDecoder(20, 16, blocks, max_len=16)
+    m = HybridDecoder(20, 16, [row(shares_row=True), cross(0)], max_len=16)
+    assert (m.chunk_layers, m.chunk_writes) == (0, True)
 
 
 # ---- the attention layer's head norm ------------------------------------------

@@ -4,8 +4,8 @@ tile the step gap, the prefill programs that the device ran in the gap are
 named, and the old sums (``step_gaps``, ``step_gap_seconds``,
 ``chunks_joint``, ``chunks_alone``, ``tokens_emitted``) are the records
 added up.  On the CPU backend, with a tiny ``TransformerLM`` and a tiny
-``HybridDecoder`` (the joint program) and a ``TransformerLM`` whose class
-hides the joint entry (two programs a chunk pass)."""
+``HybridDecoder`` (every chunk rides a step) and a ``TransformerLM``
+behind a prefix cache (a prompt's last chunk goes out alone)."""
 
 import gc
 import http.client
@@ -80,18 +80,18 @@ def _logged_chunks(pool):
     return sent
 
 
-@pytest.fixture(scope="module", params=["lm", "two-program-lm", "hybrid"])
+@pytest.fixture(scope="module", params=["lm", "prefix-cache-lm", "hybrid"])
 def served(request):
     """A short request decodes; two longer prompts arrive at its fifth token
     and prefill in chunks beside it.  ``(stats, records, chunks sent)`` after
-    a drained shutdown.  ``two-program-lm`` hides the joint entry: its pool
-    sends the chunks alone."""
+    a drained shutdown.  ``prefix-cache-lm`` keeps a prefix cache: each
+    prompt's last chunk goes out alone (its keys are extracted right after
+    it), the others ride a step."""
     model = _hybrid() if request.param == "hybrid" else _lm()
-    if request.param == "two-program-lm":
-        model = joint_pass.without_the_joint_entry(model)
+    cache = dict(prefix_cache_bytes=1 << 20, prefix_granularity=CHUNK) \
+        if request.param == "prefix-cache-lm" else {}
     engine = GenerationScheduler(model, slots=3, prefill_chunk=CHUNK,
-                                 start=False)
-    assert engine.pool.joint == (request.param != "two-program-lm")
+                                 start=False, **cache)
     sent = _logged_chunks(engine.pool)
     engine.start()
     try:
@@ -101,6 +101,7 @@ def served(request):
     finally:
         engine.shutdown()
     stats = engine.stats()
+    assert stats["chunks_alone"] == (2 if cache else 0)
     return stats, stats["pass_log"].records(), sent
 
 
@@ -137,12 +138,14 @@ def test_the_sums_are_the_records_added_up(served):
 
 def test_a_record_names_the_chunk_its_gap_ran(served):
     """The widths and first positions ``_chunk_prefill_step`` chose, on the
-    record of the step dispatched after them: ``joint`` where the pool has
-    the joint program, ``chunks_alone`` where it has two."""
+    record of the step dispatched after them: ``joint`` where the chunk
+    rode the step, ``chunks_alone`` where it went out before it (behind a
+    prefix cache, the two prompts' last chunks)."""
     stats, rec, sent = served
     assert sent and {w for _, w, _, _ in sent} <= {1, 2, CHUNK}
-    kinds = {k for k, *_ in sent}
-    assert kinds == ({"joint"} if stats["chunks_joint"] else {"alone"})
+    alone = [c for c in sent if c[0] == "alone"]
+    assert len(alone) == stats["chunks_alone"]
+    assert len(sent) - len(alone) == stats["chunks_joint"] > 0
     chunked = rec[(rec["joint"] > 0) | (rec["chunks_alone"] > 0)]
     # at most one chunk a pass beside a decoding slot (the budget)
     assert (chunked["joint"] + chunked["chunks_alone"] == 1).all()

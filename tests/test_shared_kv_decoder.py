@@ -172,19 +172,18 @@ def test_what_is_not_built_is_refused_by_name(key, value):
 
 
 def test_a_layer_must_read_an_earlier_layer_of_the_right_kind():
+    """The factory's own blocks, put in an order it never makes: the
+    cross layers before the row they name, the units before the mixer
+    that hands its scan output on."""
     from bigdl_tpu.models import HybridDecoder
-    args = dict(vocab_size=20, hidden_size=16, sparse=[False] * 3,
-                num_heads=4, head_dim=4, v_head_dim=4, kv_heads={"full": 2},
-                rope_theta={}, rotary_dim=0, window=4, window_sink=False,
-                value_scale=1.0, dense_size=16, expert_size=0, num_experts=0,
-                top_k=0, max_len=16)
-    shared = dict(memory_from=0, row_from=1, mixer=dict(inner=32))
-    with pytest.raises(ValueError, match="reads layer 1"):
-        HybridDecoder(layer_kinds=["selective", "window", "cross"],
-                      shared=shared, **args)
-    with pytest.raises(ValueError, match="with shared="):
-        HybridDecoder(layer_kinds=["full", "full", "cross"],
-                      **dict(args, rope_theta={"full": 1e4}))
+    blocks = list(phi4_flash(CFG, MAX_LEN).blocks)
+    args = dict(vocab_size=VOCAB, hidden_size=32, max_len=MAX_LEN)
+    for order, says in (
+            (blocks[:HALF + 1] + blocks[HALF + 2:], f"reads layer {HALF + 1}"),
+            (blocks[HALF + 1:], "reads a scan output")):
+        with pytest.raises(ValueError, match=says):
+            HybridDecoder(blocks=order, **args)
+    HybridDecoder(blocks=blocks, **args)
 
 
 # ---- where a chunk's rows stop ----------------------------------------------------
