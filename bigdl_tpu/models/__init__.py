@@ -12,7 +12,8 @@ from bigdl_tpu.models.maskrcnn import (
 from bigdl_tpu.models.ssd import SSDVGG16, ssd_vgg16_300
 from bigdl_tpu.models.transformer_lm import TransformerLM, transformer_lm
 from bigdl_tpu.models.hybrid_decoder import (
-    HybridDecoder, falcon_h1, lfm2_moe, mimo_v2, phi4_flash, sarvam_mla,
+    HybridDecoder, afmoe, falcon_h1, lfm2_moe, mimo_v2, phi4_flash,
+    sarvam_mla,
 )
 from bigdl_tpu.models.ncf import NeuralCF
 from bigdl_tpu.models.dlrm import WideAndDeep, wide_and_deep

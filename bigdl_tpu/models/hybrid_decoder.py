@@ -8,7 +8,8 @@ mixer's scan output), dense gated feed-forward layers, and a held share
 of sigmoid-routed gated experts (with or without a shared expert).
 
 A family is a factory (``mimo_v2``, ``falcon_h1``, ``sarvam_mla``,
-``phi4_flash``, ``lfm2_moe``; each one's docstring has its walk): it
+``phi4_flash``, ``lfm2_moe``, ``afmoe``; each one's docstring has its
+walk): it
 reads its ``config.json``, refuses what is not built, makes each layer's
 block (:class:`HybridBlock` over an attention layer, :class:`ParallelBlock`,
 :class:`MixerBlock`, :class:`CrossBlock`, :class:`MemoryBlock`) and hands
@@ -53,7 +54,7 @@ from bigdl_tpu.nn.ssm import Mamba1Mixer, Mamba2Mixer
 from bigdl_tpu.ops import cache_kernels
 
 __all__ = ["HybridDecoder", "mimo_v2", "falcon_h1", "sarvam_mla",
-           "phi4_flash", "lfm2_moe"]
+           "phi4_flash", "lfm2_moe", "afmoe"]
 
 
 def _product(x, layer: Linear):
@@ -156,6 +157,13 @@ class HybridBlock(Module):
     own keys and values (``cache["self"]``); the subclasses keep a state
     beside them, a state alone, or nothing.
 
+    A family whose sub-layers are normed **on both sides** hands the
+    block the two further norms as modules (``attn_post_norm``,
+    ``ffn_post_norm``): ``h = x + N2(Mix(N1 x))``, ``y = h + N4(FF(N3
+    h))`` (named scope ``block/post_norm``).  A block has the norms it was
+    handed: without them a sub-layer's output joins the stream as it
+    comes, and the block has no leaf and no operation more.
+
     ``walk`` is what one pass hands from block to block beside the
     residual stream (a dict the decoder makes anew for every pass):
     ``"memory"``, the scan output of the mixer that hands it on, and
@@ -174,13 +182,19 @@ class HybridBlock(Module):
 
     def __init__(self, hidden_size: int, attn: Optional[Module],
                  ffn: Module, eps: float, norm=RMSNorm,
-                 shares_row: bool = False):
+                 shares_row: bool = False,
+                 attn_post_norm: Optional[Module] = None,
+                 ffn_post_norm: Optional[Module] = None):
         super().__init__()
         self.attn_norm = norm(hidden_size, eps)
         if attn is not None:
             self.attn = attn
+        if attn_post_norm is not None:
+            self.attn_post_norm = attn_post_norm
         self.ffn_norm = norm(hidden_size, eps)
         self.ffn = ffn
+        if ffn_post_norm is not None:
+            self.ffn_post_norm = ffn_post_norm
         self.sparse = isinstance(ffn, HeldExperts)
         self.shares_row = bool(shares_row)
 
@@ -274,7 +288,17 @@ class HybridBlock(Module):
             active)
         if self.shares_row:
             walk["row"] = kv
-        return x + a, (kv if cache is None else {"self": kv})
+        return x + self._behind("attn_post_norm", a), \
+            (kv if cache is None else {"self": kv})
+
+    def _behind(self, name: str, out):
+        """A sub-layer's output through the further norm ``name`` where
+        the factory handed the block one; as it comes where not."""
+        norm = getattr(self, name, None)
+        if norm is None:
+            return out
+        with jax.named_scope("block/post_norm"):
+            return norm(out)
 
     def _feed_forward(self, h, valid):
         n = self.ffn_norm(h)
@@ -282,7 +306,7 @@ class HybridBlock(Module):
             f, counts = self.ffn.forward(n, valid)
         else:
             f, counts = self.ffn.forward(n), jnp.zeros((ROUTING,), jnp.int32)
-        return h + f, counts
+        return h + self._behind("ffn_post_norm", f), counts
 
 
 class ParallelBlock(HybridBlock):
@@ -1103,3 +1127,95 @@ def lfm2_moe(config: Dict[str, Any], max_len: int) -> HybridDecoder:
                        ffn, eps))
     return HybridDecoder(c["vocab_size"], hidden, blocks, eps, max_len,
                          tie_head=True)
+
+
+_AFMOE_ONE = ("n_group", "num_expert_groups", "topk_group",
+              "num_limited_groups")
+
+
+def afmoe(config: Dict[str, Any], max_len: int) -> HybridDecoder:
+    """The model from the keys of a public ``afmoe`` ``config.json``
+    (Trinity-Mini) plus the chip's share: ``experts_held`` (how many of
+    ``num_experts`` live here, from ``experts_offset``, default 0: all of
+    them).  ``layer_types[i]`` names layer ``i``'s attention for the first
+    ``num_hidden_layers`` layers: ``"sliding_attention"`` a **window**
+    layer over the last ``sliding_window`` positions (a ring in a pool),
+    whose query and key heads are rotated by position over the whole head
+    (half-split pairs, base ``rope_theta``); ``"full_attention"`` a layer
+    that attends everything before it and **rotates nothing**: it sees no
+    position at all.  Both are grouped-query attention
+    (``num_attention_heads`` heads of ``head_dim`` over
+    ``num_key_value_heads``) whose query and key heads go through an RMS
+    norm before any rotation and whose context is **gated**: ``W_o (ctx *
+    sigmoid(W_a n))`` (:class:`GroupedQueryAttention`, ``gate``).  A block
+    norms its sub-layers **on both sides**: ``h = x + N2(Attn(N1 x))``,
+    ``y = h + N4(FF(N3 h))`` (:class:`HybridBlock`'s further norms).  The
+    first ``num_dense_layers`` feed-forwards are dense gated layers of
+    ``intermediate_size``, the others ``num_experts`` sigmoid-routed
+    experts of ``moe_intermediate_size`` with a selection bias,
+    ``num_experts_per_tok`` a token, weights over the sum of the chosen
+    plus ``1e-20`` (``route_norm``), the routed sum times ``route_scale``,
+    beside ``num_shared_experts`` (1 or 0) shared expert of the same
+    width.  RMS norms at ``rms_norm_eps``, no bias anywhere, an untied
+    head, and with ``mup_enabled`` the embedding times ``sqrt(hidden_size)``
+    (false: a multiplier of 1).  What is not built is refused by name:
+    another ``score_func`` than sigmoid, expert groups, weights not
+    normalised over the chosen, a scaled rotary embedding, more shared
+    experts than one, a tied head, another activation than silu, another
+    layer type."""
+    c = config
+    if c.get("score_func", "sigmoid") != "sigmoid":
+        raise ValueError(f"afmoe: score_func={c['score_func']!r} is not "
+                         f"built (sigmoid)")
+    for key in _AFMOE_ONE:
+        if c.get(key, 1) not in (None, 1):
+            raise ValueError(f"afmoe: {key}={c[key]!r} is not built (one "
+                             f"group of experts)")
+    if not c.get("route_norm", True):
+        raise ValueError("afmoe: route_norm=False is not built (weights "
+                         "over the sum of the chosen)")
+    if c.get("rope_scaling"):
+        raise ValueError(f"afmoe: rope_scaling={c['rope_scaling']!r} is "
+                         f"not built")
+    shared = c.get("num_shared_experts", 0)
+    if shared not in (0, 1):
+        raise ValueError(f"afmoe: num_shared_experts={shared!r} is not "
+                         f"built (1 or 0)")
+    if c.get("tie_word_embeddings"):
+        raise ValueError("afmoe: tie_word_embeddings=True is not built "
+                         "(the head is its own table)")
+    if c.get("hidden_act", "silu") != "silu":
+        raise ValueError(f"afmoe: hidden_act={c['hidden_act']!r} is not "
+                         f"built (silu)")
+    types = list(c["layer_types"])[:c["num_hidden_layers"]]
+    for kind in types:
+        if kind not in ("sliding_attention", "full_attention"):
+            raise ValueError(f"afmoe: layer_types has {kind!r}: "
+                             f"'sliding_attention' and 'full_attention' "
+                             f"are what is built")
+    hidden, heads = c["hidden_size"], c["num_attention_heads"]
+    head_dim = c.get("head_dim") or hidden // heads
+    eps, width = c.get("rms_norm_eps", 1e-5), c["moe_intermediate_size"]
+    held = (c.get("experts_offset", 0),
+            c.get("experts_held", c["num_experts"]))
+    blocks = []
+    for i, kind in enumerate(types):
+        window = kind == "sliding_attention"
+        attn = GroupedQueryAttention(
+            hidden, heads, c["num_key_value_heads"], head_dim,
+            window=c["sliding_window"] if window else None,
+            rope_theta=float(c.get("rope_theta", 10000.0)),
+            rotary_dim=head_dim if window else 0, qk_norm=True,
+            norm_eps=eps, gate=True)
+        ffn = HeldExperts(
+            hidden, width, c["num_experts"], c["num_experts_per_tok"], held,
+            shared=GatedFFN(hidden, width) if shared else None,
+            scale=float(c.get("route_scale") or 1.0),
+            normalize_eps=1e-20) if i >= c.get("num_dense_layers", 0) \
+            else GatedFFN(hidden, c["intermediate_size"])
+        blocks.append(HybridBlock(
+            hidden, attn, ffn, eps, attn_post_norm=RMSNorm(hidden, eps),
+            ffn_post_norm=RMSNorm(hidden, eps)))
+    return HybridDecoder(
+        c["vocab_size"], hidden, blocks, eps, max_len,
+        embedding_multiplier=hidden ** 0.5 if c.get("mup_enabled") else 1.0)

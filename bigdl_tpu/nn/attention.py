@@ -514,19 +514,24 @@ class GroupedQueryAttention(Module):
     ``head_dim`` (:class:`HeadNorm`: ``q_norm`` and ``k_norm``, a learned
     gain each, eps ``norm_eps``) as it leaves its projection, **before**
     it is rotated (named scope ``attn/qk_norm``); the cache then holds
-    normed, rotated keys.  No bias, scores over ``sqrt(head_dim)``.
-    :meth:`forward` is the one entry, so the norm is on every path: a full
-    forward, a prefill that returns compact keys and values, a prefill
-    chunk and a decode step differ only in whether a cache is passed and
-    what ``index`` is (the class has no ``write``: a chunk's rows walk
-    such a layer whole)."""
+    normed, rotated keys.  With ``gate`` the attended context is
+    multiplied by ``sigmoid(W_a x)`` before the output projection
+    (``gate_layer``, as wide as the heads' output, on the very input that
+    made the queries; float32; named scope ``attn/gate``): ``y = W_o (ctx
+    * sigmoid(W_a x))``.  No bias, scores over ``sqrt(head_dim)``.
+    :meth:`forward` is the one entry, so the norm and the gate are on
+    every path: a full forward, a prefill that returns compact keys and
+    values, a prefill chunk and a decode step differ only in whether a
+    cache is passed and what ``index`` is (the class has no ``write``: a
+    chunk's rows walk such a layer whole)."""
 
     def __init__(self, hidden_size: int, num_heads: int, num_kv_heads: int,
                  head_dim: int, v_head_dim: Optional[int] = None,
                  window: Optional[int] = None, rope_theta: float = 10000.0,
                  rotary_dim: int = 0, sink: bool = False,
                  value_scale: float = 1.0, key_scale: float = 1.0,
-                 qk_norm: bool = False, norm_eps: float = 1e-5):
+                 qk_norm: bool = False, norm_eps: float = 1e-5,
+                 gate: bool = False):
         super().__init__()
         if num_heads % num_kv_heads:
             raise ValueError("num_heads must be a multiple of num_kv_heads")
@@ -555,6 +560,11 @@ class GroupedQueryAttention(Module):
         if qk_norm:
             self.q_norm = HeadNorm(head_dim, norm_eps)
             self.k_norm = HeadNorm(head_dim, norm_eps)
+        self.has_gate = bool(gate)
+        if gate:
+            self.gate_layer = Linear(hidden_size,
+                                     num_heads * self.v_head_dim,
+                                     with_bias=False)
 
     def cache_length(self, max_len: int, ring_margin: int = 1) -> int:
         """Places of one cache row.  A ``full`` row holds ``max_len``
@@ -591,7 +601,11 @@ class GroupedQueryAttention(Module):
         over ``cache`` attends at a time, through
         ``ops.ragged_decode_attention``; None where it attends every
         place of every row through :func:`grouped_attention`: a window
-        layer (its ring is short, position-mapped, and may carry a sink),
+        layer (its ring is position-mapped and may carry a sink; it is
+        short where the window is a few hundred positions, and at a
+        window of 2,048 beside a chunk of 256 it is 2,304 places that
+        every step reads of every slot, live or not: the serving pool
+        counts them, ``ring_positions_live`` and ``ring_positions_read``),
         rows that do not tile, and every backend but a TPU
         (``ops.decode_key_block``)."""
         if self.window is not None or self.has_sink:
@@ -702,8 +716,13 @@ class GroupedQueryAttention(Module):
             ctx = grouped_attention(
                 q, keys, vals, q_pos, k_pos, self.window, pad,
                 self.sink.bias if self.has_sink else None)
-        ctx = (ctx * self.value_scale).astype(x.dtype)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(B, T, -1)
+        ctx = ctx * self.value_scale
+        if self.has_gate:
+            with jax.named_scope("attn/gate"):
+                a = self._heads(x, self.gate_layer, self.num_heads,
+                                self.v_head_dim)
+                ctx = ctx * jax.nn.sigmoid(a)
+        ctx = ctx.astype(x.dtype).transpose(0, 2, 1, 3).reshape(B, T, -1)
         y = jnp.einsum("bti,oi->bto", ctx, self.output_layer.weight,
                        preferred_element_type=jnp.float32)
         return y, kv

@@ -306,6 +306,18 @@ class SlotPool:
         # reads live blocks only; None where it reads the row whole
         # (the scheduler counts a chunk's reading by it)
         self.chunk_key_block = model.chunk_key_block(self.caches)
+        # the ring layers by what they keep: ``(window, places, layers)``
+        # for each window among them, ``places`` what a slot's ring is
+        # allocated (the window, the chunk's margin and the spare place).
+        # The decode program reads every slot's ring whole in each of
+        # them (decode_dispatch counts by it)
+        rings: Dict[Tuple[int, int], int] = {}
+        for decl, layer in zip(self.cache_layers, self.caches["layers"]):
+            kind, window = _caches_of(decl).get("self", ("", None))
+            if kind == "ring":
+                key = (int(window), int(layer["self"]["k"].shape[2]))
+                rings[key] = rings.get(key, 0) + 1
+        self.rings = tuple((w, p, n) for (w, p), n in rings.items())
         # device programs that write the cache in one decode step: one
         # dynamic_update_slice a slot and leaf (keys, values, flags),
         # unless the model says that its step writes with fewer
@@ -878,6 +890,12 @@ class SlotPool:
         # the live row before (all but the call's first); none where the
         # step reads whole rows
         rows = (len(lengths), max(len(lengths) - 1, 0)) if block else (0, 0)
+        # the same of the ring layers, summed over them: the places an
+        # active slot's query can attend (its positions, the window at
+        # most), and what the program reads, every slot's ring whole
+        rings = (sum(n * int(np.minimum(lengths, w).sum())
+                     for w, _, n in self.rings),
+                 sum(n * self.slots * p for _, p, n in self.rings))
         tok_d, idx_d, act_d = self._dev
         if chunk is None:
             out = self._decode_jit(self.model, self.caches, tok_d, idx_d,
@@ -893,7 +911,7 @@ class SlotPool:
         self._dev = (new_tok, new_idx, act_d)
         self._emit_active = self.active.copy()
         self._touched[:] = False
-        handle = _StepHandle(emit, positions, rows)
+        handle = _StepHandle(emit, positions, rows, rings)
         self._open_handle = handle
         return handle
 
@@ -934,9 +952,9 @@ class _StepHandle:
     epoch (finalized at the NEXT dispatch — until then the pool's live
     epoch applies)."""
 
-    __slots__ = ("emit", "mask", "routing", "positions", "rows")
+    __slots__ = ("emit", "mask", "routing", "positions", "rows", "rings")
 
-    def __init__(self, emit, positions=(0, 0), rows=(0, 0)):
+    def __init__(self, emit, positions=(0, 0), rows=(0, 0), rings=(0, 0)):
         self.emit = emit
         # (live, read): the cache positions this step's attention may
         # attend, and those its program reads to do so, a full layer
@@ -944,6 +962,9 @@ class _StepHandle:
         # (live, prefetched): the active rows of a full layer's call of
         # the ragged decode kernel, and those with a live row before them
         self.rows = rows
+        # (live, read): the ring places this step's attention may attend
+        # and those its program reads, summed over the ring layers
+        self.rings = rings
         self.mask: Optional[np.ndarray] = None
         # read back with the tokens: what the expert layers did since
         # the previous step (empty for a model without them)
@@ -988,6 +1009,7 @@ _ENGINE_COUNTERS = (
     "admitted", "queue_wait_seconds",
     "decode_positions_live", "decode_positions_read",
     "decode_rows_live", "decode_rows_prefetched",
+    "ring_positions_live", "ring_positions_read",
     "chunk_positions_live", "chunk_positions_read",
     "chunks_joint", "chunks_alone", "chunk_layer_positions",
     *MOE_COUNTERS,
@@ -1554,6 +1576,14 @@ class GenerationScheduler:
                 # (both zero where the step reads whole rows)
                 "decode_rows_live": eng["decode_rows_live"],
                 "decode_rows_prefetched": eng["decode_rows_prefetched"],
+                # ring places, summed over the ring layers and the decode
+                # steps dispatched: those the active slots' queries could
+                # attend (a slot's positions, its window at most), and
+                # those the decode program read (every slot's ring whole:
+                # the window, the chunk's margin and the spare place).
+                # Both zero for a model without window layers
+                "ring_positions_live": eng["ring_positions_live"],
+                "ring_positions_read": eng["ring_positions_read"],
                 # the same of the prefill chunks, joint and lone alike,
                 # summed over the chunks dispatched: the places of its
                 # slot's row that a chunk's last query could attend (its
@@ -2345,6 +2375,8 @@ class GenerationScheduler:
         self._acc["decode_positions_read"] += emit.positions[1]
         self._acc["decode_rows_live"] += emit.rows[0]
         self._acc["decode_rows_prefetched"] += emit.rows[1]
+        self._acc["ring_positions_live"] += emit.rings[0]
+        self._acc["ring_positions_read"] += emit.rings[1]
         self._acc["ssm_layer_calls"] += pool.state_layers
         self._acc["chunks_joint"] += joint
         self._pending = (emit, n_active, after_prefill, (

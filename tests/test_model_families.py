@@ -1,5 +1,5 @@
 """The seam between a model family and the decoder that serves it: each
-of the five factories of ``models.hybrid_decoder`` builds its own blocks
+of the six factories of ``models.hybrid_decoder`` builds its own blocks
 and hands them to ``HybridDecoder``, whose constructor takes nothing that
 is one layer's.  The flattened parameter paths and shapes of every family,
 at its tests' tiny configuration, are the committed list
@@ -15,12 +15,13 @@ import jax
 import pytest
 
 from bigdl_tpu import models
-from tests import (test_hybrid_decoder, test_latent_attention,
+from tests import (test_afmoe, test_hybrid_decoder, test_latent_attention,
                    test_lfm2_moe, test_shared_kv_decoder, test_state_space)
 
 TINY = {"mimo_v2": test_hybrid_decoder, "falcon_h1": test_state_space,
         "sarvam_mla": test_latent_attention,
-        "phi4_flash": test_shared_kv_decoder, "lfm2_moe": test_lfm2_moe}
+        "phi4_flash": test_shared_kv_decoder, "lfm2_moe": test_lfm2_moe,
+        "afmoe": test_afmoe}
 with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
                        "family_params.json")) as f:
     COMMITTED = json.load(f)

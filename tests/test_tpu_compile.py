@@ -1130,3 +1130,116 @@ def test_conv_moe_pool_program_copies_no_leaf_and_no_stack_on_v5e(
     assert held < 15.5 * 2 ** 30, held
     if program != "chunk_prefill":
         assert held > 13e9, held
+
+
+# ---- the pool of gated window layers, all of a layer's experts held --------------
+# (benchmark/configs/trinity-mini.json: one pipeline stage of Trinity-Mini,
+# five layers: four window layers whose rings are 2,304 places of 4 key heads
+# of 128, one full layer of 14,336 places, a gate as wide as the heads'
+# output, four norms a block, one leading dense layer, then 128 experts of
+# 1,024 a layer, all held, beside a shared one)
+
+def _gated_window_config():
+    return {
+        "vocab_size": 200192, "hidden_size": 2048, "num_hidden_layers": 5,
+        "layer_types": ["sliding_attention", "sliding_attention",
+                        "sliding_attention", "full_attention",
+                        "sliding_attention"],
+        "num_attention_heads": 32, "num_key_value_heads": 4, "head_dim": 128,
+        "sliding_window": 2048, "rope_theta": 10000, "rope_scaling": None,
+        "intermediate_size": 6144, "num_dense_layers": 1,
+        "moe_intermediate_size": 1024, "num_experts": 128,
+        "num_experts_per_tok": 8, "num_shared_experts": 1,
+        "route_scale": 2.826, "route_norm": True, "score_func": "sigmoid",
+        "rms_norm_eps": 1e-5, "mup_enabled": True,
+        "tie_word_embeddings": False,
+        "serving": {"slots": 96, "max_len": 14336, "prefill_chunk": 256}}
+
+
+def _lower_gated_window_program(program, sharding):
+    from bigdl_tpu.models import afmoe
+    from bigdl_tpu.serving.generation import SlotPool
+    cfg = _gated_window_config()
+    s = cfg["serving"]
+    slots, chunk = s["slots"], s["prefill_chunk"]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    abstract = jax.eval_shape(lambda: afmoe(cfg, s["max_len"]))
+    model = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, jnp.bfloat16), abstract)
+    caches = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+            lambda: abstract.init_cache(slots, jnp.bfloat16,
+                                        ring_margin=chunk)))
+    pool = object.__new__(SlotPool)
+    pool.slots = slots
+    pool.cache_layers = tuple(abstract.cache_layers())
+    pool.expert_layers = abstract.expert_layers()
+    pool.trace_counts = dict(TRACE_COUNTS, decode_with_chunk={})
+    pool._build_programs()
+    routing = sds((ROUTING,), jnp.int32)
+    return _lower(pool, program, model, caches, routing, sds, slots,
+                  chunk), caches, abstract
+
+
+@pytest.mark.parametrize("program", POOL_MODEL_PROGRAMS)
+def test_gated_window_pool_program_copies_no_ring_row_or_stack_on_v5e(
+        v5e, program, monkeypatch):
+    """The decode step, the chunk program and the joint program of
+    Trinity-Mini's five-layer stage, as a TPU process traces them, compiled
+    for the described v5e.  No ``copy``, ``transpose`` or ``scatter`` of a
+    pooled ring (``[96, 4, 2304, 128]``) or row (``[96, 4, 14336, 128]``),
+    and none of a whole expert stack (128 experts of 2,048 x 1,024: 537 MB
+    a stack).  The row-write kernel takes both leaf shapes
+    (``cache_write_programs``: one a layer and the flags' select, not 2 x
+    96 a layer), so the decode step writes all five layers through
+    ``ops.write_cache_rows``; its one full layer attends through the
+    ragged decode kernel at a **block of 512** (8 query heads to a key
+    head of 128 in bfloat16: the kernel's MXU body) and its four window
+    layers read their rings through the grouped product, with no
+    ``while``.  All 128 experts held: every call goes through the tiled
+    product, two kernel calls an expert layer on the stacks as they lie,
+    and no ``ragged-dot``.  Weights, pool and temporaries fit the chip and
+    fill over 13 GB of it."""
+    from bigdl_tpu.nn.moe import HeldExperts
+    from bigdl_tpu.ops import attention_kernels
+    monkeypatch.setattr(attention_kernels, "_on_tpu", lambda: True)
+    lowered, caches, abstract = _lower_gated_window_program(
+        program, SingleDeviceSharding(v5e.devices[0]))
+    layers = caches["layers"]
+    assert [layer["self"]["k"].shape for layer in layers] == [
+        (96, 4, 14336 if i == 3 else 2304, 128) for i in range(5)]
+    assert abstract.decode_key_block(caches) == 512
+    assert abstract.chunk_key_block(caches) is None
+    assert abstract.cache_write_programs(caches) == 1 + 5
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    leaf = r"bf16\[96,4,(?:2304,128|128,2304|14336,128|128,14336)\]"
+    stack = r"bf16\[128,(?:2048,1024|1024,2048)\]"
+    assert not re.findall(
+        r"= (?:%s|%s)\S* (?:copy|copy-start|transpose|scatter)\(" % (
+            leaf, stack), text)
+    calls = collections.Counter(re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="[^"]*jit\((\w+)\)'
+        r'/pallas_call"', text))
+    assert sum(calls.values()) == text.count(
+        'custom_call_target="tpu_custom_call"')
+    tokens = {"decode": 96, "chunk_prefill": 256,
+              "decode_with_chunk": 96 + 256}[program]
+    assert HeldExperts.product_of(128, 128, tokens) == "tiled"
+    # a lone chunk's rows give no logits: the last layer's products feed
+    # nothing and the compiler drops them (its counts stay)
+    n = 3 if program == "chunk_prefill" else 4
+    assert (calls["gate_up"], calls["down"]) == (n, n)
+    if program == "chunk_prefill":
+        assert (calls["_write_cache_rows"], calls["_ragged_decode"]) == (0, 0)
+    else:
+        assert (calls["_write_cache_rows"], calls["_ragged_decode"]) == (5, 1)
+    assert " while(" not in text and "ragged-dot" not in text
+    mem = compiled.memory_analysis()
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert held < 15.5 * 2 ** 30, held
+    if program != "chunk_prefill":
+        assert held > 13e9, held
