@@ -558,10 +558,10 @@ class HybridDecoder(Module):
 
     def chunk_key_block(self, caches) -> Optional[int]:
         """Places of its slot's full row that a prefill chunk's attention
-        reads at a time, where every full row's attention says one (a
-        latent row's: live blocks only, on every backend); None where a
-        chunk reads its row whole: the serving pool counts what its chunk
-        programs read by this."""
+        reads at a time, where every full row's attention says one (live
+        blocks only, on every backend: grouped-query and latent rows);
+        None where a chunk reads its row whole (differential attention):
+        the serving pool counts what its chunk programs read by this."""
         blocks = {attn.chunk_key_block(row)
                   for attn, row in self._full_rows(caches)}
         return blocks.pop() if len(blocks) == 1 else None

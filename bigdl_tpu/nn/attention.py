@@ -522,8 +522,8 @@ class GroupedQueryAttention(Module):
     :meth:`forward` is the one entry, so the norm and the gate are on
     every path: a full forward, a prefill that returns compact keys and
     values, a prefill chunk and a decode step differ only in whether a
-    cache is passed and what ``index`` is (the class has no ``write``: a
-    chunk's rows walk such a layer whole)."""
+    cache is passed and what ``index`` is (no ``write``: a chunk's rows
+    walk such a layer whole; what they read, :meth:`chunk_key_block`)."""
 
     def __init__(self, hidden_size: int, num_heads: int, num_kv_heads: int,
                  head_dim: int, v_head_dim: Optional[int] = None,
@@ -592,10 +592,6 @@ class GroupedQueryAttention(Module):
         return ("full", max_len) if self.window is None \
             else ("ring", self.window)
 
-    def chunk_key_block(self, cache) -> None:
-        """A prefill chunk attends its slot's row whole."""
-        return None
-
     def decode_key_block(self, cache) -> Optional[int]:
         """Places of a row that the per-row decode step (``index [B]``)
         over ``cache`` attends at a time, through
@@ -632,7 +628,11 @@ class GroupedQueryAttention(Module):
         * ``cache`` given, scalar ``index``: the new keys and values are
           written at ``index ..`` of every row (of row ``slot`` alone
           when given: the pool's chunked prefill, ``B == 1``) and the
-          queries attend the row.  ``pad [B|S, max_len]`` by position.
+          queries attend the row: in a full layer with no sink the key
+          blocks up to the chunk's last position and no place beyond,
+          out of the leaves as they lie (``ops.chunk_attention``,
+          :meth:`chunk_key_block`); a window layer its ring, whole.
+          ``pad [B|S, max_len]`` by position.
         * ``cache`` given, ``index [B]``, ``T == 1``: the pool's decode
           step, row ``b`` written and masked at ``index[b]``.  A row
           whose ``active[b]`` is false only rides along: in a ring it
@@ -662,7 +662,7 @@ class GroupedQueryAttention(Module):
             k = rotary_half(k, q_pos[:, None, :], self.rope_theta,
                             self.rotary_dim)
         q, k, v = (a.astype(x.dtype) for a in (q, k, v))
-        block = None
+        block = chunk = None
         if cache is None:
             kv = {"k": k, "v": v}
             keys, vals, k_pos = k, v, q_pos
@@ -685,6 +685,7 @@ class GroupedQueryAttention(Module):
                     raise ValueError("a cache of other rows than x "
                                      "takes a slot")
                 row = 0 if slot is None else slot
+                chunk = self.chunk_key_block(cache)
                 kv, rows = {}, {}
                 for n, new in (("k", k), ("v", v)):
                     kv[n], rows[n] = _write_window(cache[n], new, row,
@@ -692,7 +693,7 @@ class GroupedQueryAttention(Module):
                 keys, vals = rows["k"], rows["v"]
                 last = index + (T - 1)
             k_pos = cache_positions(L, last, ring)
-            if pad is not None:
+            if pad is not None and chunk is None:
                 # pad is by position; a ring place holds position k_pos
                 if slot is not None:
                     pad = jax.lax.dynamic_slice(
@@ -703,15 +704,24 @@ class GroupedQueryAttention(Module):
                             jnp.maximum(k_pos, 0),
                             (pad.shape[0], L)), axis=1)
         if block is not None:
-            # the pool's step over full rows: live key blocks only.  The
-            # queries go in float32 (they hold x's precision) so that the
-            # context comes back in float32, as grouped_attention's does
+            # the pool's step over full rows: live key blocks only (the
+            # queries go in float32, as do a chunk's: see below)
             lengths = index + 1
             if active is not None:
                 lengths = jnp.where(active, lengths, 0)
             ctx = attention_kernels.ragged_decode_attention(
                 q.astype(jnp.float32), keys, vals, lengths, pad,
                 block_k=block, interpret=not attention_kernels._on_tpu())
+        elif chunk is not None:
+            # a chunk over full rows: the key blocks up to its last
+            # position, out of the leaves as they lie (the rows sliced
+            # back out above are read by nothing, and so not made).  The
+            # queries go in float32 (they hold x's precision) so that the
+            # context comes back in float32, as grouped_attention's does
+            if pad is None:
+                pad = jnp.zeros((cache["k"].shape[0], L), bool)
+            ctx = attention_kernels.chunk_attention(
+                q.astype(jnp.float32), kv["k"], kv["v"], row, index, pad)
         else:
             ctx = grouped_attention(
                 q, keys, vals, q_pos, k_pos, self.window, pad,
@@ -726,6 +736,21 @@ class GroupedQueryAttention(Module):
         y = jnp.einsum("bti,oi->bto", ctx, self.output_layer.weight,
                        preferred_element_type=jnp.float32)
         return y, kv
+
+    # (behind forward: the lines of the calls above are in the lowered
+    # text of every kernel they reach)
+    def chunk_key_block(self, cache) -> Optional[int]:
+        """Places of its rows that a prefill chunk (scalar ``index``)
+        over ``cache`` attends at a time, through ``ops.chunk_attention``:
+        the key blocks up to the chunk's last position and no place
+        beyond, on every backend, so that no array of scores as long as
+        the row exists; None where it attends its rows whole through
+        :func:`grouped_attention`: a window layer (its ring is
+        position-mapped and short) and a layer with a sink, the rule
+        :meth:`decode_key_block` has."""
+        if self.window is not None or self.has_sink:
+            return None
+        return attention_kernels.chunk_key_block(cache["k"].shape)
 
 
 class FeedForwardNetwork(Module):

@@ -1770,57 +1770,66 @@ def latent_chunk_takes(q_shape, latent_shape, rotary_shape, dtype,
 def chunk_attention(q, k, v, row, index, pad, *,
                     force: Optional[str] = None):
     """A prefill chunk's attention **over the live part of its rows**:
-    queries ``q [B, H, W, d]`` at positions ``index .. index+W-1`` over
-    rows ``row .. row+B`` of the cache leaves ``k [S, H, T, d]``, ``v [S,
-    H, T, dv]`` as they lie after the chunk's window was written; ``pad
-    [S, T]`` flags padding by row and position.  Returns the context
-    ``[B, H, W, dv]`` in ``q``'s dtype.
+    queries ``q [B, Hq, W, d]`` at positions ``index .. index+W-1`` over
+    rows ``row .. row+B`` of the cache leaves ``k [S, Hkv, T, d]``, ``v
+    [S, Hkv, T, dv]`` as they lie after the chunk's window was written
+    (query head ``h`` reads key head ``h // (Hq // Hkv)``); ``pad [S,
+    T]`` flags padding by row and position.  Returns the context ``[B, Hq,
+    W, dv]`` in ``q``'s dtype: float32 queries give a float32 context.
 
     Key blocks ``0 .. (index + W - 1) // block`` are read and no place
     beyond (``block``: :func:`chunk_key_block`), by a count that is
     traced: **one compiled program whatever the chunk's position** (a
     program a length would be a program a block count, eight at 2,048
     places, times a pool's four widths and two entries).  The
-    mathematics is :func:`xla_attention` under
-    ``nn.attention.chunk_incremental_bias``: scores, sums and an online
-    softmax in float32, the weights rounded to the values' dtype before
-    the second product; only the order of summation differs.  (A query
-    none of whose places is valid, a padding token before its row's first
-    real one, averages the blocks read where the full product averaged
-    the row: nobody reads either.)
+    mathematics is :func:`xla_attention` under ``chunk_incremental_bias``
+    and ``nn.attention.grouped_attention``'s with no window and no sink:
+    scores, sums and an online softmax in float32, the weights rounded to
+    the values' dtype before the second product; only the order of
+    summation differs, and **no array of ``Hq x W x T`` scores exists**.
+    (A query none of whose places is valid averages the blocks read where
+    the full product averaged the row: nobody reads either.)
 
-    On a TPU, leaves that lie positions-minor go through
-    :func:`ragged_chunk_attention` (``force`` ∈ {"ragged", "xla", None}
-    overrides, as in :func:`decode_attention`); everything else takes a
-    ``fori_loop`` over blocks sliced out of the leaves, the form
-    ``nn.latent_attention.latent_rows_attention`` has for a latent row
-    off a TPU (on one a latent row's chunk has a kernel of its own,
-    :func:`latent_chunk_attention`: one head that every query head
-    shares, so a tile of heads against one block)."""
+    On a TPU a kernel, and **the body follows the operands**
+    (:func:`_chunk_body`; ``force`` ∈ {"ragged", "xla", None} overrides):
+    :func:`ragged_chunk_attention` for one query head a key head over
+    positions-minor leaves (OPT), else :func:`grouped_chunk_attention`,
+    each leaf as it lies.  Every other backend, and leaves that do not
+    tile, take a ``fori_loop`` over blocks sliced out of the leaves (a
+    latent row's chunk has its own: :func:`latent_chunk_attention`)."""
     block = chunk_key_block(k.shape)
-    takes = _chunk_kernel_takes(k.shape, v.shape, k.dtype, block)
-    if force == "ragged" and not takes:
-        raise ValueError(f"rows {tuple(k.shape)} / {tuple(v.shape)} do "
-                         f"not tile for the chunk kernel")
-    if force == "ragged" or (force is None and _on_tpu() and takes):
+    body = _chunk_body(q.shape, k.shape, v.shape, k.dtype, block)
+    if force == "ragged" and body is None:
+        raise ValueError(f"no chunk kernel takes rows {tuple(k.shape)}")
+    kernel = force == "ragged" or (force is None and _on_tpu())
+    if kernel and body == "heads":
         return ragged_chunk_attention(q, k, v, row, index, pad, block=block,
                                       interpret=not _on_tpu())
-    b, h, w, d = q.shape
-    dv = v.shape[3]
+    if kernel and body == "groups":
+        return grouped_chunk_attention(q, k, v, row, index, pad, block=block,
+                                       interpret=not _on_tpu())
+    # a key head's queries one after another, as the grouped kernel has
+    # them: query row i stands at position index + i % w
+    b, hq, w, d = q.shape
+    hkv, dv = k.shape[1], v.shape[3]
+    rows = hq // hkv * w
+    qg = q.reshape(b, hkv, rows, d)
     scale = jnp.float32(1.0 / (d ** 0.5))
-    q_pos = index + jnp.arange(w, dtype=jnp.int32)
+    q_pos = index + jnp.arange(rows, dtype=jnp.int32) % w
 
     def step(j, carry):
         m, den, acc = carry
         start = j * block
-        k_j = jax.lax.dynamic_slice(k, (row, 0, start, 0), (b, h, block, d))
-        v_j = jax.lax.dynamic_slice(v, (row, 0, start, 0), (b, h, block, dv))
-        s = jnp.einsum("bhqd,bhkd->bhqk", q, k_j,
+        k_j = jax.lax.dynamic_slice(k, (row, 0, start, 0),
+                                    (b, hkv, block, d))
+        v_j = jax.lax.dynamic_slice(v, (row, 0, start, 0),
+                                    (b, hkv, block, dv))
+        s = jnp.einsum("bhqd,bhkd->bhqk", qg, k_j,
                        preferred_element_type=jnp.float32) * scale
         k_pos = start + jnp.arange(block, dtype=jnp.int32)
         ok = (k_pos[None, None, :] <= q_pos[None, :, None]) \
             & ~jax.lax.dynamic_slice(pad, (row, start),
-                                     (b, block))[:, None, :]   # [B, W, block]
+                                     (b, block))[:, None, :]  # [B, rows, block]
         s = jnp.where(ok[:, None], s, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         p = jnp.exp(s - m_new[..., None])
@@ -1831,12 +1840,12 @@ def chunk_attention(q, k, v, row, index, pad, *,
             preferred_element_type=jnp.float32)
         return m_new, den, acc
 
-    init = (jnp.full((b, h, w), _NEG_INF, jnp.float32),
-            jnp.zeros((b, h, w), jnp.float32),
-            jnp.zeros((b, h, w, dv), jnp.float32))
+    init = (jnp.full((b, hkv, rows), _NEG_INF, jnp.float32),
+            jnp.zeros((b, hkv, rows), jnp.float32),
+            jnp.zeros((b, hkv, rows, dv), jnp.float32))
     _, den, acc = jax.lax.fori_loop(0, (index + w - 1) // block + 1, step,
                                     init)
-    return (acc / den[..., None]).astype(q.dtype)
+    return (acc / den[..., None]).astype(q.dtype).reshape(b, hq, w, dv)
 
 
 def _per_shard(kernel, mesh, q, k, v, bias):
@@ -1861,3 +1870,215 @@ def _per_shard(kernel, mesh, q, k, v, bias):
                        head if bias.shape[1] > 1 else None, None, None))
     return shard_map_compat(kernel, mesh, in_specs=tuple(specs),
                             out_specs=spec)(*args)
+
+
+# ---------------------------------------------------------------------------
+# A prefill chunk over the live key blocks of its grouped-query rows
+# ---------------------------------------------------------------------------
+# (At the file's end, behind the dispatch: a kernel's lowered text carries
+# the lines of its body and of every call on the way to it, the entries
+# above among them, so a line more ahead of those would change the text of
+# every pool's programs.)
+
+__all__ += ["grouped_chunk_attention"]
+
+
+def _grouped_chunk_kernel(at_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
+                          m_ref, l_ref, acc_ref, *, scale: float, block: int,
+                          width: int, rows: int, tiles):
+    """One (row, key head, query tile, key block) program of a chunk over
+    a row whose key head serves a group of query heads: the tile's queries
+    are rows of ``q_ref [tile, d]``, row ``i`` of the head's ``G x W``
+    being chunk position ``i % width`` of its query head ``i // width``,
+    against the head's block of each leaf as the leaf lies (``tiles``:
+    :func:`cache_kernels.cache_row_tiles` of the keys and of the values):
+    width-minor ``[block, width]`` (``"sublanes"``) or positions-minor
+    ``[width, block]`` (``"lanes"``), which only says which axis a product
+    contracts.  ``rows`` queries at a time, unrolled over the tile as in
+    :func:`_latent_chunk_kernel`: scores ``[rows, d] x [d, block]`` on the
+    MXU in float32, each exponential taken once, summed in float32 for
+    the denominator and rounded to the values' dtype for the context
+    ``[rows, block] x [block, dv]``.  ``o_ref [tile, dv]``; the softmax
+    state ``m_ref``, ``l_ref [tile, 1]`` and ``acc_ref [tile, dv]`` are
+    float32 and stay on the chip from a tile's first block to its last:
+    no score leaves it."""
+    j = pl.program_id(3)
+    tile = q_ref.shape[0]
+    index = at_ref[1]
+    k_dims = (((1,), (1 if tiles[0] == "sublanes" else 0,)), ((), ()))
+    v_dims = (((1,), (0 if tiles[1] == "sublanes" else 1,)), ((), ()))
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(j * block < index + width)
+    def _body():
+        k = k_ref[...]
+        v = v_ref[...]
+        bias = bias_ref[...]                                   # [1, block]
+        shape = (rows, block)
+        k_pos = j * block + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        masks = {}
+        for g in range(tile // rows):
+            # a tile starts on a head's first position, so a group's
+            # place in its chunk is static
+            first = g * rows % width
+            if first not in masks:
+                masks[first] = k_pos <= index + first + (
+                    row if rows <= width else jax.lax.rem(row, width))
+            at = slice(g * rows, (g + 1) * rows)
+            s = jax.lax.dot_general(
+                q_ref[at], k, k_dims,
+                preferred_element_type=jnp.float32)            # [rows, block]
+            s = jnp.where(masks[first], s * scale + bias, _NEG_INF)
+            m_prev = m_ref[at]                                 # [rows, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[at] = alpha * l_ref[at] + jnp.sum(p, axis=1, keepdims=True)
+            m_ref[at] = m_new
+            acc_ref[at] = acc_ref[at] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, v_dims,
+                preferred_element_type=jnp.float32)            # [rows, dv]
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _finish():
+        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def _grouped_chunk_tiles(group: int, width: int):
+    """``(tile, rows)`` of :func:`_grouped_chunk_kernel` for a key head's
+    ``group x width`` query rows: the most rows a product takes, up to
+    :data:`_LATENT_CHUNK_ROWS`, that are whole chunks or a whole part of
+    one, and the most of them a grid step holds, up to
+    :data:`_LATENT_CHUNK_TILE`, that are whole chunks."""
+    total = group * width
+    rows = max(n for n in range(1, min(total, _LATENT_CHUNK_ROWS) + 1)
+               if total % n == 0 and (n % width == 0 or width % n == 0))
+    tile = max(n for n in range(width, min(total, max(
+        _LATENT_CHUNK_TILE, width)) + 1, width)
+        if total % n == 0 and n % rows == 0)
+    return tile, rows
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def grouped_chunk_attention(q, k, v, row, index, pad, *, block: int,
+                            interpret: bool = False):
+    """:func:`chunk_attention` as a Pallas TPU kernel for **grouped
+    queries and leaves of either layout**: ``q [B, Hq, W, d]`` over rows
+    ``row .. row+B`` of ``k [S, Hkv, T, d]`` and ``v [S, Hkv, T, dv]``,
+    ``Hq = G x Hkv``, each leaf handed over as it lies
+    (:func:`cache_kernels.cache_row_tiles`: the ``swapaxes`` of a
+    positions-minor leaf changes a name and no byte); ``pad [S, T]``.
+    The queries are rounded to the keys' dtype for the product, as
+    :func:`ragged_decode_attention` rounds them, and the context comes
+    back in the dtype they came in.
+
+    The grid is (row, key head, query tile, key block), key blocks
+    innermost; the first row and the chunk's position go ahead as scalar
+    prefetch.  A key head's ``G x W`` query rows lie one after another
+    (a reshape of ``q``) and a tile of them (:func:`_grouped_chunk_tiles`)
+    meets each live block of the head once.  A step past the chunk's last
+    block skips its arithmetic and names that last block again, so
+    nothing is fetched for it: what is read is ``index + W`` rounded up to
+    ``block``, and no score is written anywhere.  A function of its own
+    under ``jit`` so that a model's layers and a pool's programs share one
+    trace of the kernel (:func:`_ragged_decode`)."""
+    from bigdl_tpu.ops.cache_kernels import cache_row_tiles
+    b, hq, w, d = q.shape
+    hkv, t, dv = v.shape[1], v.shape[2], v.shape[3]
+    group = hq // hkv
+    tile, rows = _grouped_chunk_tiles(group, w)
+    tiles = tuple(cache_row_tiles(a.shape, a.dtype) for a in (k, v))
+    at = jnp.stack([jnp.asarray(row, jnp.int32),
+                    jnp.asarray(index, jnp.int32)])
+    bias = jnp.where(jax.lax.dynamic_slice(pad, (at[0], 0), (b, t)),
+                     _NEG_INF, 0.0).astype(jnp.float32)[:, None]
+
+    def own(bi, h, ti, j, at):
+        return bi, h, ti, 0
+
+    def flags(bi, h, ti, j, at):
+        return bi, 0, jnp.minimum(j, (at[1] + w - 1) // block)
+
+    def lanes(bi, h, ti, j, at):
+        return at[0] + bi, h, 0, flags(bi, h, ti, j, at)[2]
+
+    def sublanes(bi, h, ti, j, at):
+        return at[0] + bi, h, flags(bi, h, ti, j, at)[2], 0
+
+    def leaf(a, how):
+        """A leaf as it lies, and the block of it a step reads."""
+        if how == "lanes":
+            return jnp.swapaxes(a, 2, 3), pl.BlockSpec(
+                (None, None, a.shape[3], block), lanes)
+        return a, pl.BlockSpec((None, None, block, a.shape[3]), sublanes)
+
+    (k, k_spec), (v, v_spec) = leaf(k, tiles[0]), leaf(v, tiles[1])
+    out = pl.pallas_call(
+        functools.partial(_grouped_chunk_kernel, scale=1.0 / (d ** 0.5),
+                          block=block, width=w, rows=rows, tiles=tiles),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, hkv, group * w // tile, t // block),
+            in_specs=[pl.BlockSpec((None, None, tile, d), own),
+                      k_spec, v_spec,
+                      pl.BlockSpec((None, 1, block), flags)],
+            out_specs=pl.BlockSpec((None, None, tile, dv), own),
+            scratch_shapes=[_scratch(s) for s in
+                            ((tile, 1), (tile, 1), (tile, dv))]),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, group * w, dv), q.dtype),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=_CHUNK_VMEM),
+    )(at, q.astype(k.dtype).reshape(b, hkv, group * w, d), k, v, bias)
+    return out.reshape(b, hq, w, dv)
+
+
+def _grouped_chunk_takes(q_shape, k_shape, v_shape, dtype,
+                         block: int) -> bool:
+    """Whether :func:`grouped_chunk_attention` takes queries ``q_shape
+    [B, Hq, W, d]`` over leaves of these shapes: each leaf tiles one way
+    or the other (:func:`cache_kernels.cache_row_tiles`), blocks of whole
+    lane tiles, products of whole sublane tiles
+    (:func:`_grouped_chunk_tiles`), and what a step holds inside three
+    quarters of the kernel's VMEM: the tile's queries and its float32
+    context (both double-buffered, a width under the lanes filling
+    them), the float32 state, two blocks of each leaf, a product's scores
+    and weights (9 MB of 32 at the mimo cut's 2,048 rows of 192)."""
+    from bigdl_tpu.ops.cache_kernels import _sublanes, cache_row_tiles
+    d, dv = (max(s[3], _LANES) for s in (k_shape, v_shape))
+    tile, rows = _grouped_chunk_tiles(q_shape[1] // k_shape[1], q_shape[2])
+    size = jnp.dtype(dtype).itemsize
+    need = (2 * tile * (d * size + dv * 4) + 4 * tile * (dv + 2 * _LANES)
+            + 2 * block * (d + dv) * size + 4 * rows * 3 * block)
+    return (block % _LANES == 0 and rows % _sublanes(dtype) == 0
+            and cache_row_tiles(k_shape, dtype) is not None
+            and cache_row_tiles(v_shape, dtype) is not None
+            and 4 * need <= 3 * _CHUNK_VMEM)
+
+
+def _chunk_body(q_shape, k_shape, v_shape, dtype, block: int) -> Optional[str]:
+    """Which kernel takes a chunk's queries ``q_shape [B, Hq, W, d]`` over
+    leaves of these shapes: ``"heads"``, :func:`ragged_chunk_attention`
+    (one query head a key head over leaves that both lie positions-minor:
+    every head of a block in one step, the program OPT's pool has had
+    since PR 47); ``"groups"``, :func:`grouped_chunk_attention` (grouped
+    heads, or a width-minor leaf); None where the leaves tile for
+    neither."""
+    if q_shape[1] % k_shape[1] or v_shape[:3] != k_shape[:3]:
+        raise ValueError(f"chunk attention takes Hq a multiple of Hkv: q "
+                         f"{tuple(q_shape)}, k {tuple(k_shape)}, v "
+                         f"{tuple(v_shape)}")
+    if q_shape[1] == k_shape[1] \
+            and _chunk_kernel_takes(k_shape, v_shape, dtype, block):
+        return "heads"
+    if _grouped_chunk_takes(q_shape, k_shape, v_shape, dtype, block):
+        return "groups"
+    return None

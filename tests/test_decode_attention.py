@@ -641,3 +641,156 @@ def test_latent_call_fetches_each_block_once_and_refuses_other_shapes():
     with pytest.raises(ValueError, match="no key block"):
         ak.latent_decode_attention(ql, qr, rotary, latent, lengths,
                                    scale=1.0, block_k=96, interpret=True)
+
+
+# ---- a prefill chunk over grouped-query rows: live key blocks only ----------
+# (d, dv) by how the two leaves lie at rows of CHUNK_T places: both
+# width-minor (trinity-mini, Falcon-H1), both positions-minor (LFM2, OPT),
+# keys positions-minor with values width-minor (MiMo)
+CHUNK_T, CHUNK_W = 768, 16
+CHUNK_LEAVES = {"sublanes-sublanes": (128, 128), "lanes-lanes": (64, 64),
+                "lanes-sublanes": (192, 128)}
+# where a count of blocks could go wrong: the chunk's first position
+CHUNK_PLACES = {"first-block": 3, "across-an-edge": 250,
+                "last-block": CHUNK_T - CHUNK_W}
+
+
+@pytest.mark.parametrize("place", sorted(CHUNK_PLACES))
+@pytest.mark.parametrize("leaves", sorted(CHUNK_LEAVES))
+@pytest.mark.parametrize("group,dtype", [
+    (1, jnp.float32), (4, jnp.float32), (5, jnp.bfloat16), (8, jnp.float32),
+    (16, jnp.bfloat16)], ids=["g1", "g4", "g5-bf16", "g8", "g16-bf16"])
+def test_a_chunk_over_grouped_rows_equals_grouped_attention_over_the_row(
+        group, dtype, leaves, place):
+    """``ops.chunk_attention`` with ``G`` query heads a key head, over each
+    pair of leaf layouts, against ``grouped_attention`` over the whole row
+    under the chunk's causal mask and padding flags: the kernel a TPU
+    takes (interpreted) and the loop every other backend has.  **Every
+    place past the chunk's last block holds NaN** in the leaves the entry
+    is handed and zero in the oracle's: a block read in vain would show in
+    the result, and none does.  Float32 rows agree to a re-ordered sum; a
+    bfloat16 row also rounds a weight under a running maximum where the
+    oracle rounds it under the row's."""
+    from bigdl_tpu.nn.attention import grouped_attention
+    from bigdl_tpu.ops.cache_kernels import cache_row_tiles
+    (d, dv), index = CHUNK_LEAVES[leaves], CHUNK_PLACES[place]
+    hkv, slots, row = 2, 3, 1
+    r = np.random.RandomState(group + index)
+    # queries the row's dtype holds exactly, handed over in float32
+    q = jnp.asarray(r.randn(1, hkv * group, CHUNK_W, d),
+                    dtype).astype(jnp.float32)
+    k = jnp.asarray(0.3 * r.randn(slots, hkv, CHUNK_T, d), dtype)
+    v = jnp.asarray(r.randn(slots, hkv, CHUNK_T, dv), dtype)
+    assert "-".join(cache_row_tiles(a.shape, dtype) for a in (k, v)) == leaves
+    pad = np.zeros((slots, CHUNK_T), bool)
+    pad[row, 1] = pad[row, index // 2] = pad[row, index + 2] = True
+    pad[row + 1] = True                     # another slot's flags: not read
+    pad = jnp.asarray(pad)
+    block = ak.chunk_key_block(k.shape)
+    assert block == ak.CHUNK_KEY_BLOCK
+    unread = jnp.arange(CHUNK_T)[None, None, :, None] \
+        >= -(-(index + CHUNK_W) // block) * block
+    want = grouped_attention(
+        q.astype(dtype), jnp.where(unread, 0, k)[row:row + 1],
+        jnp.where(unread, 0, v)[row:row + 1],
+        index + jnp.arange(CHUNK_W)[None], jnp.arange(CHUNK_T)[None], None,
+        pad[row:row + 1])
+    tol = dict(rtol=2e-5, atol=2e-5) if dtype == jnp.float32 \
+        else dict(rtol=2e-2, atol=2e-2)
+    for how in ("ragged", "xla"):
+        got = ak.chunk_attention(q, jnp.where(unread, jnp.nan, k),
+                                 jnp.where(unread, jnp.nan, v), row, index,
+                                 pad, force=how)
+        assert got.shape == want.shape and got.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), **tol)
+
+
+def test_the_chunk_body_follows_the_leaves_and_the_group(monkeypatch):
+    """One query head a key head over leaves that both lie positions-minor
+    keeps ``ragged_chunk_attention`` (OPT's pool traces to the call it
+    always did); grouped heads, or a width-minor leaf, take
+    ``grouped_chunk_attention``, a tile of whole chunks of a key head's
+    queries a grid step; rows that tile for neither are refused when the
+    kernel is forced and take the loop otherwise."""
+    taken = []
+    for name in ("ragged_chunk_attention", "grouped_chunk_attention"):
+        def spy(q, *args, _name=name, **kw):
+            taken.append(_name)
+            return q
+        monkeypatch.setattr(ak, name, spy)
+
+    def call(hq, hkv, t, d, dtype=jnp.float32, w=16):
+        del taken[:]
+        ak.chunk_attention(
+            jnp.zeros((1, hq, w, d)), jnp.zeros((2, hkv, t, d), dtype),
+            jnp.zeros((2, hkv, t, d), dtype), 0, 0, jnp.zeros((2, t), bool),
+            force="ragged")
+        return taken[0]
+    assert call(32, 32, 2048, 64) == "ragged_chunk_attention"
+    assert call(32, 8, 5632, 64) == "grouped_chunk_attention"
+    assert call(4, 4, 512, 128) == "grouped_chunk_attention"
+    with pytest.raises(ValueError):       # rows of 100 places tile no way
+        call(8, 2, 100, 64)
+    with pytest.raises(ValueError):       # 12 bfloat16 rows a product
+        call(3, 1, 512, 128, jnp.bfloat16, w=4)
+    with pytest.raises(ValueError):       # 5 query heads over 2 key heads
+        call(5, 2, 512, 128)
+    # a product of up to 256 rows, whole chunks or a whole part of one; a
+    # tile of up to 2,048, whole chunks
+    assert ak._grouped_chunk_tiles(16, 256) == (2048, 256)
+    assert ak._grouped_chunk_tiles(5, 256) == (1280, 256)
+    assert ak._grouped_chunk_tiles(5, 64) == (320, 64)
+    assert ak._grouped_chunk_tiles(8, 32) == (256, 256)
+    assert ak._grouped_chunk_tiles(1, 512) == (512, 256)
+
+
+@pytest.mark.parametrize("how", ["ragged", "xla"])
+def test_grouped_query_attention_chunk_reads_live_blocks_of_full_rows_only(
+        how, monkeypatch):
+    """``GroupedQueryAttention.forward`` for a chunk (scalar ``index``,
+    ``slot``) with the head norms, the gate, a key scale and rotation on:
+    through ``ops.chunk_attention`` (the kernel a TPU takes, interpreted,
+    and the loop) it gives what it gave through ``grouped_attention`` over
+    the slot's whole row, and writes the same leaves; a window layer and a
+    layer with a sink still answer None and never ask for it."""
+    from bigdl_tpu.nn.attention import GroupedQueryAttention
+    slots, max_len, width, index, slot = 3, 512, 16, 250, 1
+    kinds = {"full": {}, "window": dict(window=64),
+             "sink": dict(sink=True)}
+    calls = []
+    entry = ak.chunk_attention
+
+    def spy(*args):
+        calls.append(args[4])
+        return entry(*args, force=how)
+    monkeypatch.setattr(ak, "chunk_attention", spy)
+    for kind, over in kinds.items():
+        layer = GroupedQueryAttention(
+            64, 8, 2, 128, rope_theta=1e4, rotary_dim=32, value_scale=0.5,
+            key_scale=0.7, qk_norm=True, gate=True, **over)
+        r = np.random.RandomState(7)
+        for lin in (layer.q_layer, layer.k_layer, layer.v_layer,
+                    layer.output_layer, layer.gate_layer):
+            lin.weight = jnp.asarray(
+                0.2 * r.randn(*lin.weight.shape), jnp.float32)
+        x = jnp.asarray(r.randn(1, width, 64), jnp.float32)
+        cache = {n: jnp.asarray(r.randn(*leaf.shape), jnp.float32)
+                 for n, leaf in layer.init_cache(
+                     slots, max_len, ring_margin=width).items()}
+        pad = jnp.asarray(r.rand(slots, max_len) < 0.2).at[:, 0].set(False)
+        del calls[:]
+        got, kv_got = layer.forward(x, index, cache, pad, slot=slot)
+        if kind != "full":
+            assert calls == [] and layer.chunk_key_block(cache) is None
+            continue
+        assert calls == [index] and layer.chunk_key_block(cache) == 256
+        with monkeypatch.context() as m:
+            m.setattr(GroupedQueryAttention, "chunk_key_block",
+                      lambda self, cache: None)
+            want, kv_want = layer.forward(x, index, cache, pad, slot=slot)
+        assert calls == [index]
+        for n in ("k", "v"):
+            assert np.array_equal(np.asarray(kv_got[n]),
+                                  np.asarray(kv_want[n]))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)

@@ -798,3 +798,43 @@ def test_the_pool_counts_what_the_step_reads_of_its_full_rows(
         assert st["decode_positions_read"] == n * slots * max_len
     want = np.asarray(m.generate(jnp.asarray(prompt)[None], new, chunk=24))
     assert np.array_equal(row, want[0])
+
+
+@pytest.mark.parametrize("family", ["mimo_v2", "falcon_h1", "lfm2_moe",
+                                    "afmoe"])
+def test_a_chunk_over_grouped_query_rows_reads_up_to_its_own_position(
+        family):
+    """A pool of each family whose full rows are grouped-query ones, at
+    its tests' tiny configuration and rows of 96 places: its full layers
+    answer the chunk's key block (32: what 256 shares with the row; 256 at
+    a cell's row), the model and the pool repeat it, and
+    ``chunk_positions_read`` follows the chunk's position: a prompt of 41
+    tokens goes out as five chunks of 8, of which the last ends in the
+    second block.  (That the tokens are those of a request generated
+    alone is held by every engine test of this file and of the families'
+    own, which take this path too.)"""
+    import math
+    from bigdl_tpu import models
+    from bigdl_tpu.ops.attention_kernels import CHUNK_KEY_BLOCK
+    from tests import test_afmoe, test_lfm2_moe, test_state_space
+    tiny = {"mimo_v2": sys.modules[__name__], "falcon_h1": test_state_space,
+            "lfm2_moe": test_lfm2_moe, "afmoe": test_afmoe}[family]
+    max_len, chunk = 96, 8
+    block = math.gcd(max_len, CHUNK_KEY_BLOCK)
+    m = getattr(models, family)(tiny.CFG, max_len).eval_mode()
+    prompt = np.arange(41, dtype=np.int32) % tiny.VOCAB + 1
+    chunks = [(s, chunk) for s in range(0, 40, chunk)]
+    engine = GenerationScheduler(m, slots=2, prefill_chunk=chunk)
+    try:
+        assert engine.pool.chunk_key_block == block \
+            == m.chunk_key_block(engine.pool.caches)
+        row = engine.submit(prompt, 1)
+        engine.shutdown()
+        st = engine.stats()
+    finally:
+        engine.shutdown()
+    assert len(row) == len(prompt) + 1
+    assert st["chunks_joint"] + st["chunks_alone"] == len(chunks)
+    assert st["chunk_positions_live"] == sum(s + w for s, w in chunks)
+    assert st["chunk_positions_read"] == 4 * block + 2 * block \
+        == sum(block * -(-(s + w) // block) for s, w in chunks)

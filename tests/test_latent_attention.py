@@ -751,8 +751,9 @@ def test_chunk_position_counters_follow_a_known_schedule(rows):
     ``tests/test_generation.py`` has it for ``TransformerLM``).  A latent
     pool's chunk reads the live key blocks of its slot's row, 128 places
     here (what 512 and a row of 384 share), on every backend, and the
-    pool counts so; a pool of grouped-query rows reads every chunk's row
-    whole (``HybridDecoder.chunk_key_block`` answers None for it)."""
+    pool counts so; so does a pool of grouped-query rows, whose full
+    layers read blocks of what 256 and the row share (the whole row of 64
+    here: ``tests/test_hybrid_decoder.py`` has rows of several blocks)."""
     if rows == "latent":
         m, _ = build(384)
         chunk, prompt = 64, np.arange(300, dtype=np.int32) % VOCAB + 1
@@ -763,7 +764,7 @@ def test_chunk_position_counters_follow_a_known_schedule(rows):
         m = mimo_v2(MIMO, MAX_LEN).eval_mode()
         chunk, prompt = CHUNK, np.arange(14, dtype=np.int32) % VOCAB + 1
         chunks = [(0, 4), (4, 4), (8, 4), (12, 1)]
-        block = None
+        block = MAX_LEN
     engine = GenerationScheduler(m, slots=2, prefill_chunk=chunk)
     try:
         assert engine.pool.chunk_key_block == block \
@@ -777,6 +778,5 @@ def test_chunk_position_counters_follow_a_known_schedule(rows):
     assert np.array_equal(row, want[0])
     assert st["chunks_joint"] + st["chunks_alone"] == len(chunks)
     assert st["chunk_positions_live"] == sum(s + w for s, w in chunks)
-    assert st["chunk_positions_read"] == (
-        len(chunks) * MAX_LEN if block is None
-        else sum(block * -(-(s + w) // block) for s, w in chunks))
+    assert st["chunk_positions_read"] == sum(
+        block * -(-(s + w) // block) for s, w in chunks)

@@ -35,7 +35,9 @@ from jax.sharding import SingleDeviceSharding
 from bigdl_tpu.nn.moe import ROUTING
 from bigdl_tpu.ops import cache_kernels
 from bigdl_tpu.ops import conv_bn_kernels as ck
-from bigdl_tpu.ops.attention_kernels import (flash_attention,
+from bigdl_tpu.ops.attention_kernels import (_grouped_chunk_takes,
+                                             flash_attention,
+                                             grouped_chunk_attention,
                                              latent_chunk_attention,
                                              latent_chunk_takes,
                                              ragged_decode_attention)
@@ -187,6 +189,34 @@ LATENT_CHUNK_CASES = [
     _latent_chunk_case(32, 64, 112, 7168, 512, 64, jnp.bfloat16)]
 
 
+def _grouped_chunk_case(width, hq, hkv, slots, t, d, dv, dtype):
+    """The grouped chunk kernel: ``width`` float32 queries a head, ``hq``
+    heads over ``hkv``, over the live key blocks of a row of the pooled
+    leaves ``[slots, hkv, t, d]`` and ``[slots, hkv, t, dv]``, each handed
+    over as the pool holds it."""
+    def build(sds):
+        args = [sds((1, hq, width, d), jnp.float32),
+                sds((slots, hkv, t, d), dtype), sds((slots, hkv, t, dv), dtype),
+                sds((), jnp.int32), sds((), jnp.int32),
+                sds((slots, t), jnp.bool_)]
+        assert _grouped_chunk_takes(args[0].shape, args[1].shape,
+                                    args[2].shape, dtype, 256)
+        return functools.partial(grouped_chunk_attention, block=256), args
+    name = "grouped_chunk-%dx%dq%dkv-%dx%dx%d-%s" % (
+        width, hq, hkv, t, d, dv, jnp.dtype(dtype).name)
+    return pytest.param(build, id=name)
+
+
+# the full layers whose chunk the benchmark serves at the pool's full
+# width: the trinity-mini cut's (32 heads over 4, both leaves width-minor),
+# the mimo-v2.5 cut's (64 over 4, keys of 192 positions-minor, values of 128
+# width-minor) and the lfm2-24b-a2b cut's (32 over 8, both positions-minor)
+GROUPED_CHUNK_CASES = [
+    _grouped_chunk_case(256, 32, 4, 96, 14336, 128, 128, jnp.bfloat16),
+    _grouped_chunk_case(256, 64, 4, 32, 6144, 192, 128, jnp.bfloat16),
+    _grouped_chunk_case(256, 32, 8, 128, 5632, 64, 64, jnp.bfloat16)]
+
+
 def _row_write_case(slots, heads, t, d, dv, dtype):
     """The row-write kernel over a layer's two leaves, each handed over as
     the chip stores it (``cache_row_writer`` says how)."""
@@ -212,7 +242,8 @@ ROW_WRITE_CASES = [_row_write_case(32, 4, 6144, 192, 128, jnp.bfloat16),
 CASES = (
     [_flash_case(s, bias, bwd) for s in FLASH_SHAPES
      for bias in (False, True) for bwd in (False, True)]
-    + DECODE_CASES + LATENT_CHUNK_CASES + ROW_WRITE_CASES
+    + DECODE_CASES + LATENT_CHUNK_CASES + GROUPED_CHUNK_CASES
+    + ROW_WRITE_CASES
     + [_matmul_case(s, bwd) for s in MATMUL_SHAPES for bwd in (False, True)]
     + [_conv3_case(s, bwd) for s in CONV3_SHAPES for bwd in (False, True)]
 )
@@ -581,6 +612,19 @@ def _kernel_calls(text, also=()):
     return tuple(calls.count(name) for name in names)
 
 
+CHUNK_KERNEL = "grouped_chunk_attention"
+
+
+def _whole_row_scores(text, heads, kv_heads, width, max_len):
+    """Every float32 array of a compiled program that is as large as a
+    chunk's scores over a whole full row: ``[heads, width, max_len]`` or
+    ``[kv_heads, group, width, max_len]`` behind any leading ones.  A
+    chunk that attends through ``ops.chunk_attention`` makes none (its
+    scores never leave the kernel)."""
+    return re.findall(r"f32\[(?:1,)*(?:%d|%d,%d),%d,%d\]" % (
+        heads, kv_heads, heads // kv_heads, width, max_len), text)
+
+
 def _lower_cut_program(program, sharding):
     from bigdl_tpu.models import mimo_v2
     from bigdl_tpu.serving.generation import SlotPool
@@ -625,8 +669,10 @@ def test_cut_pool_program_copies_no_leaf_and_expands_no_key_on_v5e(
     lie: a ``bitcast``) and no ``dynamic-update-slice`` into a leaf or the
     flags, and its two full layers attend through the ragged decode
     kernel, which takes the same leaves the same way; the chunk program
-    writes windows, as it did.  Which path a process takes it asks
-    ``_on_tpu()``; here the test answers."""
+    writes windows, as it did, and its two full layers attend through the
+    grouped chunk kernel over the same leaves: no float32 array of 64 x
+    256 x 6,144 scores is left in either chunk program.  Which path a
+    process takes it asks ``_on_tpu()``; here the test answers."""
     from bigdl_tpu.ops import attention_kernels
     monkeypatch.setattr(attention_kernels, "_on_tpu", lambda: True)
     lowered, cfg, caches = _lower_cut_program(
@@ -649,20 +695,25 @@ def test_cut_pool_program_copies_no_leaf_and_expands_no_key_on_v5e(
     updates = re.findall(
         r"= (?:%s|pred\[32,%d\])\S* dynamic-update-slice\(" % (
             leaf, s["max_len"]), text)
+    calls = _kernel_calls(text, also=(CHUNK_KERNEL,))
     if program == "decode":
-        assert _kernel_calls(text) == (cfg["num_hidden_layers"], full)
+        assert calls == (cfg["num_hidden_layers"], full, 0)
         assert not updates
         assert " while(" not in text
     elif program == "chunk_prefill":
-        assert _kernel_calls(text) == (0, 0)
+        assert calls == (0, 0, full)
         assert "dynamic-update-slice" in text
     else:
-        # the joint program: the step's kernels and the chunk's windows
-        # (two leaves a layer and the flags), and still no loop
-        assert _kernel_calls(text) == (cfg["num_hidden_layers"], full)
+        # the joint program: the step's kernels, the chunk's windows (two
+        # leaves a layer and the flags) and its kernel in the two full
+        # layers, and still no loop
+        assert calls == (cfg["num_hidden_layers"], full, full)
         assert len(updates) == 2 * cfg["num_hidden_layers"] + 1
         assert " while(" not in text
     heads = cfg["num_attention_heads"]
+    # a chunk's scores over a full row (403 MB a layer) are nowhere
+    assert not _whole_row_scores(text, heads, 4, s["prefill_chunk"],
+                                 s["max_len"])
     expanded = r"bf16\[\d+,(?:%d|4,16|8,8),(?:%d|%d),(?:128|192)\]" % (
         heads, s["max_len"], ring)
     assert not re.findall(expanded, text)
@@ -780,22 +831,27 @@ def test_state_pool_program_moves_each_state_in_place_on_v5e(
     leaf = r"(?:f32\[48,32,256,128\]|bf16\[48,4,(?:3584,128|128,3584)\])"
     assert not re.findall(
         r"= %s\S* (?:copy|copy-start|transpose|scatter)\(" % leaf, text)
-    calls = _kernel_calls(text)
+    calls = _kernel_calls(text, also=(CHUNK_KERNEL,))
     whiles = len(re.findall(r" while\(", text))
     updates = re.findall(
         r"= f32\[48,32,256,128\]\S* dynamic-update-slice\(", text)
     fusions = len(re.findall(r"f32\[48,32,256,128\]\S*\) fusion\(", text))
     if program == "decode":
-        assert (calls, whiles, len(updates)) == ((layers, layers), 0, 0)
+        assert (calls, whiles, len(updates)) == ((layers, layers, 0), 0, 0)
         assert fusions == layers
     elif program == "chunk_prefill":
-        assert (calls, whiles, len(updates)) == ((0, 0), layers, layers)
+        # every layer's chunk attends through the grouped chunk kernel (a
+        # lone chunk's rows give no logits: the last layer's context feeds
+        # nothing and the compiler drops its call)
+        assert (calls, whiles, len(updates)) == ((0, 0, layers - 1), layers,
+                                                 layers)
     else:
         # the joint program: what the two hold, added; the rows' update
         # still one fusion a layer over the state the chunk wrote into
-        assert (calls, whiles, len(updates)) == ((layers, layers), layers,
-                                                 layers)
+        assert (calls, whiles, len(updates)) == (
+            (layers, layers, layers), layers, layers)
         assert fusions == layers
+    assert not _whole_row_scores(text, 20, 4, 256, 3584)
     mem = compiled.memory_analysis()
     held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 13.5e9 < held < 15.5 * 2 ** 30 if program != "chunk_prefill" \
@@ -1124,6 +1180,10 @@ def test_conv_moe_pool_program_copies_no_leaf_and_no_stack_on_v5e(
         assert (calls["_write_cache_rows"], calls["_ragged_decode"]) == (0, 0)
     else:
         assert (calls["_write_cache_rows"], calls["_ragged_decode"]) == (2, 2)
+    # a chunk attends its slot's two rows through the grouped chunk kernel
+    assert calls[CHUNK_KERNEL] == (0 if program == "decode" else 2)
+    assert abstract.chunk_key_block(caches) == 256
+    assert not _whole_row_scores(text, 32, 8, 256, 5632)
     assert " while(" not in text and "ragged-dot" not in text
     mem = compiled.memory_analysis()
     held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
@@ -1212,7 +1272,7 @@ def test_gated_window_pool_program_copies_no_ring_row_or_stack_on_v5e(
     assert [layer["self"]["k"].shape for layer in layers] == [
         (96, 4, 14336 if i == 3 else 2304, 128) for i in range(5)]
     assert abstract.decode_key_block(caches) == 512
-    assert abstract.chunk_key_block(caches) is None
+    assert abstract.chunk_key_block(caches) == 256
     assert abstract.cache_write_programs(caches) == 1 + 5
     compiled = lowered.compile()
     text = compiled.as_text()
@@ -1237,6 +1297,11 @@ def test_gated_window_pool_program_copies_no_ring_row_or_stack_on_v5e(
         assert (calls["_write_cache_rows"], calls["_ragged_decode"]) == (0, 0)
     else:
         assert (calls["_write_cache_rows"], calls["_ragged_decode"]) == (5, 1)
+    # a chunk attends its slot's one full row through the grouped chunk
+    # kernel (its scores over the row would be 470 MB) and its four rings
+    # through the grouped product
+    assert calls[CHUNK_KERNEL] == (0 if program == "decode" else 1)
+    assert not _whole_row_scores(text, 32, 4, 256, 14336)
     assert " while(" not in text and "ragged-dot" not in text
     mem = compiled.memory_analysis()
     held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
